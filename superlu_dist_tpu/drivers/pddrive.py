@@ -105,11 +105,7 @@ def main(argv=None) -> int:
                                   complex_needs_cpu)
     if args.backend != "host" and not complex_needs_cpu(np.dtype(fdt)):
         import jax
-        try:
-            accel = jax.default_backend() != "cpu"
-        except RuntimeError:  # no backend reachable -> CPU-class run
-            accel = False
-        if accel:
+        if jax.default_backend() != "cpu":
             apply_accel_amalg_defaults()
 
     opts = Options(
@@ -189,7 +185,8 @@ def _solve_fused(a, b, opts, stats):
         # the fused solver is pair-capable (make_fused_solver pair
         # mode), so the default gate applies: SLU_COMPLEX_PAIR=1
         # lifts it and the complex pipeline compiles complex-free
-        with complex_device_gate(fdt, a.dtype):
+        with complex_device_gate(fdt, a.dtype, stats=stats,
+                                 phase=phase):
             step = make_fused_solver(plan, dtype=fdt)
             with stats.timer(phase):
                 # host arrays in: the pair-mode wrapper must encode

@@ -11,8 +11,10 @@ SRC/superlu_defs.h:577-598):
     lu   = factorize(A, plan=plan)            # per value set
     x    = solve(lu, b)                       # per right-hand side
 
-Backends: "jax" (bucketed level-batched device execution, the TPU path)
-and "host" (numpy reference multifrontal).
+Backends: "jax" (bucketed level-batched device execution, the TPU
+path; what backend="auto" means without a grid), "dist" (the same on a
+process grid) and "host" (numpy reference multifrontal, the test
+oracle — only ever by explicit request).
 """
 
 from __future__ import annotations
@@ -104,14 +106,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     if fdt.name != options.factor_dtype:
         options = options.replace(factor_dtype=fdt.name)
     if backend == "auto":
-        if grid is not None:
-            backend = "dist"
-        else:
-            try:
-                from ..ops import batched  # noqa: F401
-                backend = "jax"
-            except ImportError:
-                backend = "host"
+        backend = "dist" if grid is not None else "jax"
     elif backend != "dist" and grid is not None:
         raise ValueError(
             f"backend={backend!r} conflicts with grid=; pass "
@@ -122,13 +117,15 @@ def factorize(a: CSRMatrix, options: Options | None = None,
             np.dtype(options.factor_dtype), getattr(grid, "mesh", grid)):
         raise ValueError(
             "complex factorization on a TPU mesh is disabled: "
-            "base-level complex lowering hangs on this platform "
-            "(TPU_SMOKE.jsonl c128_kernel; utils/platform.py). "
-            "Use a CPU mesh, or SLU_COMPLEX_TPU=1 to override.")
+            "native complex does not compile on this chip "
+            "(utils/platform.py) and the pair lowering is "
+            "single-device. Use a CPU mesh, or SLU_COMPLEX_TPU=1 to "
+            "override.")
     # drop any stale stamp from a direct ops-layer call the driver
     # never read (the host path below stamps nothing)
     obs.take_cost("factor")
-    with complex_device_gate(np.dtype(options.factor_dtype)), \
+    with complex_device_gate(np.dtype(options.factor_dtype),
+                             stats=stats, phase=_phase), \
             stats.timer(_phase):
         if backend == "host":
             host_lu = ref_multifrontal.factorize_host(
@@ -309,7 +306,8 @@ def solve(lu: LUFactorization, b: np.ndarray,
 
     from ..utils.platform import complex_device_gate
     factor_dt = np.dtype(lu.effective_options.factor_dtype)
-    with complex_device_gate(factor_dt, bb.dtype):
+    with complex_device_gate(factor_dt, bb.dtype, stats=stats,
+                             phase="SOLVE"):
         obs.take_cost("solve")  # drop any stale unread stamp
         with stats.timer("SOLVE"):
             x = from_factor_sol(solver(lu, to_factor_rhs(bb)))

@@ -599,8 +599,8 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
             # into fewer padded groups (_coalesce_buckets) — the
             # latency-regime trade: fewer sequential group bodies on
             # the device at the price of padded flops/slab; the
-            # tau/cap amalgamation's sibling lever, priced by
-            # tools/tpu_fire.sh chain arms.
+            # tau/cap amalgamation's sibling lever (TPU_AB_CHAIN.jsonl
+            # priced it at -2 % / -22 %; ROADMAP D2).
             by_bucket = _coalesce_buckets(by_bucket,
                                           _level_merge_limit())
         for (wb, mb), slist in sorted(by_bucket.items()):
@@ -1177,8 +1177,8 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     With SLU_TPU_PALLAS_SCATTER=1 (ops/pallas_scatter) the scatter
     side of eligible buckets runs as the tiled Pallas scatter engine
     (the dsuperlu_gpu.cu:115-143 analog): per-child one-hot expansion
-    on the MXU accumulating into per-front VMEM tiles — priced as a
-    fire-plan chain arm before any default flips."""
+    on the MXU accumulating into per-front VMEM tiles — to be priced
+    on the chip before any default flips."""
     if not ncols:
         ncols = mb
     f_loc = n_pad * mb * ncols
@@ -1834,10 +1834,8 @@ def factor_arm(sched=None, dtype=None) -> str:
     # serve layer reports must agree with the arm records are stamped
     # with, or TTL hints chase the wrong history
     flag = flags.env_str("SLU_TPU_PALLAS", "auto").strip().lower()
-    if pallas_lu.kernel_available(np.float32) and (
-            flag == "1"
-            or (flag not in ("0", "false", "off")
-                and jax.default_backend() == "tpu")):
+    if flag == "1" or (flag not in ("0", "false", "off")
+                       and jax.default_backend() == "tpu"):
         return "merged+pallas"
     return "merged"
 

@@ -238,13 +238,14 @@ def partial_lu_batch(F, thresh, *, wb: int, nb: int = 32,
     Returns (F', tiny_count, zero_pivot_count).  Dispatches to the
     VMEM-resident Pallas kernel when enabled (ops/pallas_lu.py).
     `pallas` overrides the env-resolved routing: True routes this
-    call through the kernel when it is structurally available (the
+    call through the kernel when Mosaic can lower the dtype (the
     merged factor segments' small-bucket promotion,
     ops/batched.factor_seg_metas), False forces the XLA path, None
     keeps the historical SLU_TPU_PALLAS resolution."""
     from . import pallas_lu
+    from .pallas_common import mosaic_dtype
     use = (pallas_lu.enabled(F.dtype) if pallas is None
-           else bool(pallas) and pallas_lu.kernel_available(F.dtype))
+           else bool(pallas) and mosaic_dtype(F.dtype))
     if use and pallas_lu.usable(F.shape[-1], F.dtype):
         return pallas_lu.partial_lu_batch_pallas(F, thresh, wb=wb)
     f = functools.partial(partial_lu, wb=wb, nb=nb)

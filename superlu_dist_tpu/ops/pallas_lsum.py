@@ -11,15 +11,15 @@ costs more than the math.  This kernel fuses them: one grid step per
 front holds Li, L21, xb, y and upd in VMEM and runs both contractions
 back-to-back on the MXU — y never leaves the chip.
 
-Gating: `SLU_TRISOLVE_PALLAS=1` only (default OFF — the fire-plan
-chain arm prices it on hardware before any default flips, the
-pallas_scatter discipline).  f32/bf16 real only: f64 has no Mosaic
-lowering (pallas_lu precedent) and complex/pair lanes keep the XLA
-einsum fallback (`trisolve._fwd_member` — the dense fallback is the
-default path, not an afterthought).  Interpret mode runs the same
-kernel on CPU for the correctness oracle (tests/test_trisolve.py);
-tools/tpu_smoke.py's `pallas_lsum_compile` check certifies the
-Mosaic compile on real hardware, peer to `pallas_scatter_compile`.
+Gating: `SLU_TRISOLVE_PALLAS=1` only (default OFF — to be priced on
+the chip before any default flips, the pallas_scatter discipline).
+f32/bf16 real only: f64 has no Mosaic lowering (pallas_lu precedent)
+and complex/pair lanes keep the XLA einsum fallback
+(`trisolve._fwd_member` — the dense fallback is the default path, not
+an afterthought).  Interpret mode runs the same kernel on CPU for the
+correctness oracle (tests/test_trisolve.py);
+chip_smoke.py's `pallas_kernels` phase certifies the Mosaic compile
+on real hardware, next to the scatter and panel-LU kernels.
 
 Precision: both dots run HIGHEST (multi-pass f32) — the same pin
 `_hi_prec` applies to the XLA einsums, so arm-to-arm differences stay
@@ -34,46 +34,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.experimental import pallas as pl
+
 from .. import flags
-
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-try:
-    # same x64-off tracing shim as ops/pallas_lu and pallas_scatter
-    # (Mosaic has no 64-bit lowering; weak Python scalars must trace
-    # at 32 bit)
-    from jax._src.config import enable_x64 as _x64_setting
-    _HAVE_X64_CTX = True
-except ImportError:  # pragma: no cover
-    import contextlib
-
-    _HAVE_X64_CTX = False
-
-    def _x64_setting(_v):
-        return contextlib.nullcontext()
+from .pallas_common import (VMEM_BUDGET_BYTES, interpret_default,
+                            mosaic_dtype)
 
 
 def enabled(dtype) -> bool:
     """Route merged forward steps through the fused lsum kernel?
     SLU_TRISOLVE_PALLAS=1 only; real f32/bf16 only."""
-    if not _HAVE_PALLAS:
-        return False
-    if not _HAVE_X64_CTX and jax.config.jax_enable_x64:
-        return False
-    dtype = np.dtype(dtype)
-    if dtype.kind == "c" or dtype.itemsize == 8:
-        return False
-    return flags.env_str("SLU_TRISOLVE_PALLAS", "0") == "1"
-
-
-# per-front VMEM residency: Li + L21 + xb + y + upd (+ an output
-# copy); beyond this the XLA einsum pair keeps the group
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+    return (mosaic_dtype(dtype)
+            and flags.env_str("SLU_TRISOLVE_PALLAS", "0") == "1")
 
 
 def usable(trim: int, wb: int, rb: int, nrhs: int, dtype) -> bool:
@@ -82,7 +54,7 @@ def usable(trim: int, wb: int, rb: int, nrhs: int, dtype) -> bool:
     it = np.dtype(dtype).itemsize
     need = (wb * wb + rb * wb + wb * nrhs * 2
             + 2 * rb * nrhs) * it
-    return need <= _VMEM_BUDGET_BYTES
+    return need <= VMEM_BUDGET_BYTES
 
 
 def _lsum_kernel(Li_ref, L21_ref, xb_ref, y_ref, upd_ref):
@@ -110,9 +82,9 @@ def lsum_panel(Li_p, L21_p, xb, *, interpret: bool | None = None):
     rb = L21_p.shape[1]
     R = xb.shape[2]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     kern = _lsum_kernel
-    with _x64_setting(False):
+    with jax.enable_x64(False):
         y, upd = pl.pallas_call(
             kern,
             grid=(t,),

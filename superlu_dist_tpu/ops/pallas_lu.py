@@ -28,52 +28,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from .. import flags
-
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-try:
-    # the package enables jax_enable_x64 globally (f64 refinement),
-    # but the kernel must trace in 32-bit mode: weak Python literals
-    # (jnp.where(..., 0), jnp.eye's iota) otherwise enter the jaxpr as
-    # i64/f64 scalars, and Mosaic has no 64-bit lowering — its 64→32
-    # convert self-recurses and its layout pass fails ("failed to
-    # legalize func.return").  Private-API import, so guarded.
-    from jax._src.config import enable_x64 as _x64_setting
-    _HAVE_X64_CTX = True
-except ImportError:  # pragma: no cover
-    import contextlib
-
-    _HAVE_X64_CTX = False
-
-    def _x64_setting(_v):
-        return contextlib.nullcontext()
-
-
-def kernel_available(dtype) -> bool:
-    """Structural availability of the kernel for `dtype` — the
-    non-policy half of `enabled()`: pallas importable, the x64-off
-    tracing shim present when x64 is globally on, and a real sub-f64
-    dtype (no complex / no 64-bit in Mosaic)."""
-    if not _HAVE_PALLAS:
-        return False
-    if not _HAVE_X64_CTX and jax.config.jax_enable_x64:
-        # without the x64-off tracing shim (private-API import failed)
-        # a hardware compile would hit the Mosaic 64-bit crash this
-        # module documents — use the XLA path instead of crashing
-        return False
-    if np.dtype(dtype).kind == "c":
-        return False
-    if np.dtype(dtype).itemsize == 8:
-        # f64: the kernel traces with x64 disabled and Mosaic has no
-        # 64-bit lowering — always the XLA path
-        return False
-    return True
+from .pallas_common import (VMEM_BUDGET_BYTES, interpret_default,
+                            mosaic_dtype)
 
 
 def enabled(dtype) -> bool:
@@ -91,23 +51,22 @@ def enabled(dtype) -> bool:
     factor segments coalesce; `merged_eligible` promotes exactly that
     regime.  Complex dtypes always use the XLA path (no complex in
     Mosaic)."""
-    if not kernel_available(dtype):
-        return False
-    return flags.env_str("SLU_TPU_PALLAS", "0").strip() == "1"
+    return (mosaic_dtype(dtype)
+            and flags.env_str("SLU_TPU_PALLAS", "0").strip() == "1")
 
 
 def merged_eligible(wb: int, mb: int, dtype) -> bool:
     """Merged-factor-segment promotion (ISSUE 12): inside a merged
     staged factor segment (ops/batched.get_factor_segments) the
-    panel-LU kernel engages BY DEFAULT for the µs-scale buckets the
-    fire-plan chain arms priced it ahead on — wb ≤ 8, mb ≤ 16, the
+    panel-LU kernel engages BY DEFAULT for the µs-scale buckets
+    PALLAS_AB.json priced it ahead on — wb ≤ 8, mb ≤ 16, the
     (8, 16)-class population that level merging coalesces — on real
     TPU hardware only (kernels are resolved by measurement; interpret
     mode would merely slow the CPU rehearsal, and the bitwise fp64
     A/B never reaches here because f64 is structurally ineligible).
     SLU_TPU_PALLAS=0 restores the XLA path; =1 forces the kernel for
     every usable bucket (the historical A/B arm)."""
-    if not kernel_available(dtype) or not usable(mb, dtype):
+    if not mosaic_dtype(dtype) or not usable(mb, dtype):
         return False
     flag = flags.env_str("SLU_TPU_PALLAS", "auto").strip().lower()
     if flag in ("0", "false", "off"):
@@ -117,14 +76,10 @@ def merged_eligible(wb: int, mb: int, dtype) -> bool:
     return jax.default_backend() == "tpu" and wb <= 8 and mb <= 16
 
 
-# the kernel keeps input+output front copies VMEM-resident (~16 MB/core
-# on v5e); beyond this the XLA path takes over for that bucket
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
-
-
 def usable(mb: int, dtype) -> bool:
-    """Does one (mb × mb) front fit the kernel's VMEM working set?"""
-    return 2 * mb * mb * np.dtype(dtype).itemsize <= _VMEM_BUDGET_BYTES
+    """Does one (mb × mb) front, input + output copy, fit the
+    kernel's VMEM working set?"""
+    return 2 * mb * mb * np.dtype(dtype).itemsize <= VMEM_BUDGET_BYTES
 
 
 def _tiny_replace_sel(piv, thresh, dtype):
@@ -286,7 +241,7 @@ def partial_lu_batch_pallas(F, thresh, *, wb: int,
     (F', tiny_total, nzero_total)."""
     N, mb, _ = F.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     thresh_arr = jnp.asarray(thresh, dtype=F.dtype).reshape(1, 1)
     # blocked kernel (MXU TRSM/GEMM per nb-wide panel) where its slice
     # boundaries are expressible: any nb in interpret mode, 128-aligned
@@ -319,7 +274,7 @@ def partial_lu_batch_pallas(F, thresh, *, wb: int,
             "for deferred Mosaic lowering of the unrolled block chain",
             stacklevel=2)
         sys.setrecursionlimit(20000)
-    with _x64_setting(False):
+    with jax.enable_x64(False):
         out, tiny, nzero = _pallas_lu_call(kern, N, mb, F.dtype,
                                            interpret)(thresh_arr, F)
     return out, jnp.sum(tiny), jnp.sum(nzero)

@@ -24,8 +24,9 @@ arithmetic for free.  The kernel emits a DELTA array (zeros where no
 child lands, thanks to the donated-zeros aliasing) which the caller
 adds to the assembled front batch.
 
-Gating: `SLU_TPU_PALLAS_SCATTER=1` only (default OFF — this is the
-A/B arm the fire plan prices on hardware; interpret mode runs the
+Gating: `SLU_TPU_PALLAS_SCATTER=1` only (default OFF — an A/B arm
+no chip run has priced yet; chip_smoke.py certifies that Mosaic
+compiles it and that it matches the oracle; interpret mode runs the
 same kernel on CPU for the correctness oracle in
 tests/test_ea_blocks.py).  f32/bf16 only: f64 has no Mosaic lowering
 (pallas_lu precedent) and complex never reaches here (pair mode
@@ -46,54 +47,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from .. import flags
-
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-try:
-    # same x64-off tracing shim as ops/pallas_lu (Mosaic has no 64-bit
-    # lowering; weak Python scalars must trace at 32 bit)
-    from jax._src.config import enable_x64 as _x64_setting
-    _HAVE_X64_CTX = True
-except ImportError:  # pragma: no cover
-    import contextlib
-
-    _HAVE_X64_CTX = False
-
-    def _x64_setting(_v):
-        return contextlib.nullcontext()
+from .pallas_common import (VMEM_BUDGET_BYTES, interpret_default,
+                            mosaic_dtype)
 
 
 def enabled(dtype) -> bool:
     """Use the Pallas scatter engine?  SLU_TPU_PALLAS_SCATTER=1 only —
-    OFF by default until the fire-plan chain arm prices it on real
-    hardware (the pallas_lu lesson: kernels are resolved by
+    OFF by default: no chip run has priced it against the XLA element
+    scatter yet (the pallas_lu lesson: kernels are resolved by
     measurement, not hope)."""
-    if not _HAVE_PALLAS:
-        return False
-    if not _HAVE_X64_CTX and jax.config.jax_enable_x64:
-        return False
-    dtype = np.dtype(dtype)
-    if dtype.kind == "c" or dtype.itemsize == 8:
-        return False
-    return flags.env_str("SLU_TPU_PALLAS_SCATTER", "0") == "1"
-
-
-# front tile + child block + two one-hot factors, input and output
-# copies — beyond this the XLA element path keeps the bucket
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+    return (mosaic_dtype(dtype)
+            and flags.env_str("SLU_TPU_PALLAS_SCATTER", "0") == "1")
 
 
 def usable(mb: int, ncols: int, rc_b: int, tc_b: int, dtype) -> bool:
     it = np.dtype(dtype).itemsize
     need = (2 * mb * ncols + rc_b * tc_b
             + rc_b * mb + tc_b * ncols) * it
-    return need <= _VMEM_BUDGET_BYTES
+    return need <= VMEM_BUDGET_BYTES
 
 
 def _scatter_kernel(fb_ref, upd_ref, pr_ref, pc_ref, base_ref,
@@ -107,23 +82,22 @@ def _scatter_kernel(fb_ref, upd_ref, pr_ref, pc_ref, base_ref,
     prev = fb_ref[jnp.maximum(i - 1, 0)]
     first = jnp.logical_or(i == 0, fb_ref[i] != prev)
     upd = upd_ref[0]                              # (rc_b, tc_b)
-    pr = pr_ref[0]                                # (rc_b,)
-    pc = pc_ref[0]                                # (tc_b,)
+    pr = pr_ref[0]                                # (1, rc_b) row
+    pc = pc_ref[0]                                # (tc_b, 1) column
     rc_b, tc_b = upd.shape
-    # S_r (rc_b, mb), S_c (tc_b, ncols): sentinel pos == mb/ncols has
-    # no matching iota lane -> all-zero row -> dropped
-    rows = jax.lax.broadcasted_iota(jnp.int32, (rc_b, mb), 1)
+    # S_rᵀ (mb, rc_b), S_c (tc_b, ncols), each built in the
+    # orientation its matmul consumes (no transposed operand for
+    # Mosaic to lower): sentinel pos == mb/ncols matches no iota
+    # entry -> all-zero column/row -> dropped
+    rows = jax.lax.broadcasted_iota(jnp.int32, (mb, rc_b), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (tc_b, ncols), 1)
-    S_r = (rows == pr[:, None]).astype(upd.dtype)
-    S_c = (cols == pc[:, None]).astype(upd.dtype)
-    mid = jax.lax.dot_general(
-        upd, S_c, dimension_numbers=(((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)       # (rc_b, ncols)
-    contrib = jax.lax.dot_general(
-        S_r, mid, dimension_numbers=(((0,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+    S_rT = (rows == pr).astype(upd.dtype)
+    S_c = (cols == pc).astype(upd.dtype)
+    mid = jnp.dot(upd, S_c, precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)   # (rc_b, ncols)
+    contrib = jnp.dot(S_rT, mid, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32
+                      ).astype(out_ref.dtype)           # (mb, ncols)
 
     del base_ref   # aliased zeros: only its unvisited blocks matter
 
@@ -147,21 +121,25 @@ def scatter_add_delta(upd, pr, pc, fb, *, mb: int, ncols: int,
     serialized element scatter."""
     K = upd.shape[0]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(K,),
         in_specs=[
             pl.BlockSpec((1,) + upd.shape[1:], lambda i, fb: (i, 0, 0)),
-            pl.BlockSpec((1, pr.shape[1]), lambda i, fb: (i, 0)),
-            pl.BlockSpec((1, pc.shape[1]), lambda i, fb: (i, 0)),
+            # positions ride 3D — rows as (K, 1, rc_b), columns as
+            # (K, tc_b, 1): Mosaic wants a block's last two dims
+            # tile-aligned OR equal to the array's, which a (1, rc_b)
+            # block of a (K, rc_b) array is not
+            pl.BlockSpec((1, 1, pr.shape[1]), lambda i, fb: (i, 0, 0)),
+            pl.BlockSpec((1, pc.shape[1], 1), lambda i, fb: (i, 0, 0)),
             pl.BlockSpec((1, mb, ncols), lambda i, fb: (fb[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, mb, ncols),
                                lambda i, fb: (fb[i], 0, 0)),
     )
     kern = functools.partial(_scatter_kernel, mb=mb, ncols=ncols)
-    with _x64_setting(False):
+    with jax.enable_x64(False):
         delta = pl.pallas_call(
             kern,
             grid_spec=grid_spec,
@@ -172,5 +150,6 @@ def scatter_add_delta(upd, pr, pc, fb, *, mb: int, ncols: int,
             # written at visited indices)
             input_output_aliases={4: 0},
             interpret=interpret,
-        )(fb, upd, pr, pc, jnp.zeros((n_pad, mb, ncols), upd.dtype))
+        )(fb, upd, pr[:, None, :], pc[:, :, None],
+          jnp.zeros((n_pad, mb, ncols), upd.dtype))
     return delta

@@ -290,9 +290,8 @@ def _sharded_factor_operands(plan, dsched, per):
 # complex operands 2/5, sharded real/imag-plane operands 0/6.  Every
 # variation re-rolls unknown odds, so the policy is: pin the
 # best-measured shape for complex on this client, shard the real path
-# (which has never drawn a loss) — and let the TPU hardware smoke
-# (tools/tpu_smoke.py c128 check) decide the real-hardware question,
-# where no such pathology exists.
+# (which has never drawn a loss).  On a TPU mesh complex is refused
+# outright (utils/platform.complex_mesh_blocked).
 
 
 def _shard_vals(dtype) -> bool:

@@ -4,7 +4,7 @@ durable artifacts (ISSUE 12, ROADMAP item 1a).
 The durable factor store (resilience/store.py) lets a fresh replica
 skip the FACTORIZATION; until this module nothing let it skip the
 COMPILATION: a genuinely fresh process re-paid 14–33 s of jit
-trace/lower warmup plus a 2m4s whole-phase XLA:CPU compile (BENCH_r05)
+trace/lower warmup plus a 2m4s whole-phase XLA:CPU compile
 before serving its first solve.  With static pivoting both costs are
 cacheable artifacts — the task graph is fixed at plan time, so the
 whole-phase programs are pure functions of (schedule layout, dtype,
@@ -103,16 +103,10 @@ def ensure_xla_cache() -> None:
         return
     _xla_wired = True
     import jax
-    if (jax.config.jax_compilation_cache_dir
-            or flags.env_opt("JAX_COMPILATION_CACHE_DIR")):
+    if jax.config.jax_compilation_cache_dir:
         return                      # an explicit cache wins
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(d, "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1)
-    except Exception:               # noqa: BLE001 — optional leg; the
-        pass                        # export leg still works without it
+    from ..utils.cache import place_compile_cache
+    place_compile_cache(os.path.join(d, "xla"))
 
 
 # --------------------------------------------------------------------

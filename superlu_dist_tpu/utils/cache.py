@@ -1,41 +1,53 @@
-"""Host-fingerprinted persistent-compile-cache directory.
+"""Where jax's persistent compilation cache lives.
+
+`place_compile_cache` is the ONE place the package, its tools and
+its tests set `jax_compilation_cache_dir`.  The rule:
+
+  * `JAX_COMPILATION_CACHE_DIR` set — jax already reads it; nothing
+    is set here, so whoever launched the process (an operator, the
+    chip tool handing back one output directory) decides where
+    compiled programs persist.
+  * unset, accelerator — one fixed path inside the checkout
+    (`.jax_cache-accel`), never one made from a temporary name, a
+    pid or the time: a cache that moves is a cache that never hits.
+  * unset, CPU — the host-fingerprinted directory below.
 
 XLA:CPU AOT cache entries embed the COMPILING machine's feature set;
 loading them on a host with different CPU features is at best a loud
 warning and at worst wrong code (cpu_aot_loader "could lead to
 execution errors such as SIGILL").  Workspaces here migrate between
-machines, so the cache directory name carries a fingerprint of the
-host's CPU flags — each machine type gets its own cache and never
-loads another's objects.
-
-Accelerator artifacts are different: a TPU executable is keyed by
-the DEVICE target and does not depend on host-CPU identity, so those
-runs use a stable un-fingerprinted directory (``cache_dir_for``).
-The 2026-08-01 live window showed why the host split is NOT harmless
-for them: the CPU fingerprint includes raw CPUID only when the
-native library is already built, so the same host can compute two
-different fingerprints across a session (pre-/post- first native
-build) and orphan the expensively-compiled TPU programs in a
-directory no later run looks at.
+machines, so the CPU cache directory name carries a fingerprint of
+the host's CPU flags — each machine type gets its own cache and never
+loads another's objects.  It is stable per host.  A TPU executable is
+keyed by the DEVICE target and does not depend on host-CPU identity,
+which is why accelerator runs share the un-fingerprinted directory.
 """
 
 from __future__ import annotations
 
 import hashlib
+import os
 import platform
 import re
 
+from .. import flags
 
-def ensure_portable_cpu_isa(flags: str) -> str:
+
+def ensure_portable_cpu_isa(xla_flags: str) -> str:
     """Append --xla_cpu_max_isa=AVX2 unless an ISA cap is already
     present.  The single definition of the portability guard for
     live-migrating VMs (model-tuned XLA:CPU artifacts executed on a
     different host model produced NaN solves and a SIGSEGV); used by
     tests/conftest.py, bench.py and the 16-device subprocess test."""
-    flags = flags or ""
-    if "xla_cpu_max_isa" not in flags:
-        flags = (flags + " --xla_cpu_max_isa=AVX2").strip()
-    return flags
+    xla_flags = xla_flags or ""
+    if "xla_cpu_max_isa" not in xla_flags:
+        xla_flags = (xla_flags + " --xla_cpu_max_isa=AVX2").strip()
+    return xla_flags
+
+
+def _repo_cache_base() -> str:
+    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def cache_dir_for(base: str, accel: bool) -> str:
@@ -44,6 +56,25 @@ def cache_dir_for(base: str, accel: bool) -> str:
     directory (device-target-keyed entries, host identity
     irrelevant); CPU runs get the host-fingerprinted one."""
     return base + "-accel" if accel else host_cache_dir(base)
+
+
+def place_compile_cache(path: str | None = None) -> str:
+    """Place jax's persistent compilation cache (module docstring)
+    and return the directory in force.  `path` overrides the
+    in-checkout default for callers that own a directory (the test
+    suite's CPU cache, the AOT store's `<dir>/xla` leg); without it
+    the default is resolved from the backend jax actually chose, so
+    this call initializes the backend."""
+    env = flags.env_opt("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if path is None:
+        path = cache_dir_for(_repo_cache_base(),
+                             accel=jax.default_backend() != "cpu")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+    return path
 
 
 def host_fingerprint(include_isa: bool = True) -> str:
@@ -120,9 +151,8 @@ def _fingerprint(include_isa: bool) -> str:
     # portability guard for live-migrating VMs) must not share a dir
     # with full-ISA artifacts from the same host
     if include_isa:
-        from .. import flags as _flags
         m = re.search(r"--xla_cpu_max_isa=(\S+)",
-                      _flags.env_str("XLA_FLAGS"))
+                      flags.env_str("XLA_FLAGS"))
         if m:
             parts.append(f"isa={m.group(1).lower()}")
     key = "|".join(parts)

@@ -1,60 +1,27 @@
-"""JAX version compatibility shims.
+"""The two JAX surface calls every mesh entry point shares.
 
-The package targets current JAX surface names; this container pins
-jax 0.4.37, where two of them are missing:
-
-  * `jax.shard_map` — the stable alias landed later; 0.4.37 carries
-    `jax.experimental.shard_map.shard_map` with `check_rep` instead
-    of `check_vma` (same semantics: disable the replication checker,
-    which rejects the psum-of-diffs solve reconciliation).
-  * `jax.config.update("jax_num_cpu_devices", n)` — the config knob
-    landed later; 0.4.37 spells it as the
-    `--xla_force_host_platform_device_count=N` XLA flag, which must
-    be set before backend init.
-
-Every mesh entry point routes through these two helpers so the same
-source runs on both surfaces.
+Written for the one installation there is (jax 0.9.0): `jax.shard_map`
+and the `jax_num_cpu_devices` config option.  Kept as helpers so the
+`check_vma` default and the "too late to resize" answer live in one
+place.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map with the 0.4.x fallback (check_vma→check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def set_cpu_devices(n: int) -> bool:
-    """Request n XLA:CPU virtual devices.  Returns True when the
-    request could still take effect (backend not yet initialized on
-    the flag path); callers treat False as "already initialized —
-    whatever device count exists is what you get"."""
+    """Request n XLA:CPU virtual devices.  Returns False when the
+    backend already exists (jax refuses the update) — callers treat
+    that as "whatever device count exists is what you get"."""
     try:
         jax.config.update("jax_num_cpu_devices", n)
-        return True
-    except AttributeError:
-        pass
-    import re
-    from ..flags import env_str
-    flags = env_str("XLA_FLAGS")
-    opt = f"--xla_force_host_platform_device_count={n}"
-    if "xla_force_host_platform_device_count" in flags:
-        # rewrite a conflicting pre-existing count instead of silently
-        # keeping it (an inherited =2 would strand a 16-device request)
-        flags = re.sub(
-            r"--xla_force_host_platform_device_count=\d+", opt, flags)
-        os.environ["XLA_FLAGS"] = flags
-    else:
-        os.environ["XLA_FLAGS"] = (flags + " " + opt).strip()
-    # effective only if no backend exists yet
-    from jax._src import xla_bridge
-    return not xla_bridge.backends_are_initialized()
+    except RuntimeError:
+        return False
+    return True

@@ -4,11 +4,14 @@ The reference implements its sequential preprocessing passes in C
 (SRC/etree.c, SRC/mmd.c, SRC/mc64ad_dist.c, SRC/symbfact.c); this build
 keeps them native too, compiled once into `_slu_host.so` and loaded via
 ctypes.  Every entry point has a pure-Python twin in
-superlu_dist_tpu/plan/ that serves as fallback and test oracle, so the
-library is an accelerator, never a requirement.
+superlu_dist_tpu/plan/ that serves as the test oracle.
 
-The shared object is built lazily on first use (g++ -O3 -shared); a
-build failure is remembered and everything silently falls back.
+The shared object is git-ignored and built lazily on first use
+(g++ -O3 -shared) from csrc/.  A build or load failure is remembered
+and the plan layer runs its Python twins — correct, but a Python
+ordering at n=27,000 turns a 4 s plan into minutes — so the failure
+is a RuntimeWarning carrying the compiler's message, never silent,
+and chip_smoke.py fails outright when `available()` is False.
 """
 
 from __future__ import annotations
@@ -17,6 +20,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -73,11 +77,17 @@ def _compile_so(src: str, out: str, timeout: int = 300) -> bool:
             check=True, capture_output=True, timeout=timeout)
         os.replace(tmp, out)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        stderr = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"superlu_dist_tpu: building {os.path.basename(out)} from "
+            f"{src} failed ({e!r}); the pure-Python twins take over. "
+            + stderr.decode("utf-8", "replace")[-800:],
+            RuntimeWarning, stacklevel=2)
         return False
 
 
@@ -155,8 +165,12 @@ def _load():
             lib.slu_version.restype = ctypes.c_int64
             assert lib.slu_version() == 6
             _lib = lib
-        except (OSError, AssertionError, AttributeError):
+        except (OSError, AssertionError, AttributeError) as e:
             _failed = True
+            warnings.warn(
+                f"superlu_dist_tpu: loading {path} failed ({e!r}); "
+                "the pure-Python twins take over", RuntimeWarning,
+                stacklevel=2)
     return _lib
 
 

@@ -123,6 +123,11 @@ class Stats:
     # condition estimate of the LAST factorization served through this
     # run (numerics/gscon.ensure_rcond), None when not estimated
     rcond: float | None = None
+    # phase -> backend, for phases that did NOT run on the default
+    # backend (utils/platform.complex_device_gate places complex
+    # programs on "cpu" when the default backend is a TPU); empty when
+    # everything ran where jax put it
+    placement: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     @contextlib.contextmanager
     def timer(self, phase: str):
@@ -218,6 +223,10 @@ class Stats:
         lines.append(f"  refinement steps:     {self.refine_steps}")
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
+        if self.placement:
+            placed = ", ".join(f"{p} on {b}" for p, b in
+                               sorted(self.placement.items()))
+            lines.append(f"  placed off-default:   {placed}")
         # process-wide compile + health telemetry (obs/): the jit
         # caches and the health monitor are process-scoped like the
         # compile caches themselves, so the report shows the process

@@ -211,7 +211,7 @@ def test_cold_boot_drill_two_processes(tmp_path):
     (misses == 0, hits >= 1).  Slow tier: two interpreter+jax boots —
     tier-1's budget keeps the in-process AOT pins; the drill itself
     is gated every round via the committed cold_boot record
-    (tools/regress.py) and fire-plan step 4d."""
+    (tools/regress.py)."""
     import sys
     sys.path.insert(0, str(__import__("pathlib").Path(
         __file__).resolve().parents[1]))

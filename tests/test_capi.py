@@ -18,6 +18,12 @@ CSRC = os.path.join(os.path.dirname(os.path.dirname(
                     or shutil.which("make") is None,
                     reason="embedding toolchain unavailable")
 def test_capi_demo_from_c_host():
+    # the demo binary is not under version control (csrc/capi_demo is
+    # git-ignored): build it here, from source, every time
+    try:
+        os.unlink(os.path.join(CSRC, "capi_demo"))
+    except FileNotFoundError:
+        pass
     r = subprocess.run(["make", "libslu_tpu_c.so", "capi_demo"],
                        cwd=CSRC, capture_output=True, text=True,
                        timeout=300)

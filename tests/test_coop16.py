@@ -30,11 +30,11 @@ jax.config.update("jax_platforms", "cpu")
 from superlu_dist_tpu.utils.compat import set_cpu_devices
 set_cpu_devices(16)
 
-from superlu_dist_tpu.utils.cache import host_cache_dir
+from superlu_dist_tpu.utils.cache import (host_cache_dir,
+                                          place_compile_cache)
 import os
-jax.config.update("jax_compilation_cache_dir", host_cache_dir(
+place_compile_cache(host_cache_dir(
     os.path.join(os.environ["PYTHONPATH"], ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
 
 from superlu_dist_tpu import Options, csr_from_scipy
 from superlu_dist_tpu.ops.batched import get_schedule

@@ -8,8 +8,8 @@ import re
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DOCS = ("README.md", "DESIGN.md", "PARITY.md", "ROUND2.md",
-        "ROUND4.md", "MIGRATION.md")
+DOCS = ("README.md", "DESIGN.md", "PARITY.md", "MIGRATION.md",
+        "PERF.md", ".claude/skills/verify/SKILL.md")
 _PAT = re.compile(
     r"\b((?:tests|tools|csrc|superlu_dist_tpu)/[\w/.]+\.(?:py|f90|cpp|c|so|md))")
 

@@ -201,10 +201,8 @@ def test_factor_arm_labels(monkeypatch):
     monkeypatch.setenv("SLU_TPU_PALLAS", "1")
     assert B.factor_arm(sched, np.float64) == "merged"
     # forced + eligible dtype claims the kernel
-    from superlu_dist_tpu.ops import pallas_lu
-    if pallas_lu.kernel_available(np.float32):
-        assert B.factor_arm(sched, np.float32) == "merged+pallas"
-        assert B.factor_arm() == "merged+pallas"
+    assert B.factor_arm(sched, np.float32) == "merged+pallas"
+    assert B.factor_arm() == "merged+pallas"
     monkeypatch.setenv("SLU_TPU_PALLAS", "0")
     assert B.factor_arm(sched, np.float32) == "merged"
 

@@ -320,7 +320,7 @@ def test_mesh_aot_warm_boot_serves_from_exports(tmp_path, monkeypatch):
     rebuilt world (fresh plan objects — the fresh-process stand-in)
     deserializes the mesh factor + merged solve exports (hits >= 2,
     misses == 0) and serves bitwise-identical results.  The
-    two-process drill rides tools/serve_bench + fire-plan step 4d."""
+    two-process drill is tools/serve_bench.py --cold-boot."""
     mesh = _mesh2()
     a = laplacian_3d(4)
     b = np.random.default_rng(0).standard_normal((a.n, 2))

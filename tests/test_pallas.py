@@ -9,9 +9,6 @@ import jax.numpy as jnp
 from superlu_dist_tpu.ops.dense_lu import partial_lu_batch
 from superlu_dist_tpu.ops import pallas_lu
 
-pytestmark = pytest.mark.skipif(not pallas_lu._HAVE_PALLAS,
-                                reason="pallas unavailable")
-
 
 @pytest.mark.parametrize("mb,wb,n", [(16, 8, 3), (32, 32, 2),
                                      (64, 16, 5),
@@ -56,27 +53,7 @@ def test_pallas_end_to_end_solve(monkeypatch):
     a = laplacian_2d(8)
     xtrue = np.arange(1.0, a.n + 1.0)
     b = a.to_scipy() @ xtrue
-    try:
-        x, _, _ = gssvx(Options(factor_dtype="float32"), a, b,
-                        backend="jax")
-    except ValueError as e:
-        # Known lowering bug in some jax builds (observed: jax 0.4.37
-        # in this container, failing at seed): embedding the Pallas
-        # kernel call inside the factor while_loop trips an MLIR
-        # verifier error — a func.call whose trailing operand lowers
-        # i64 against an i32-typed callee.  That is the COMPILER
-        # mis-typing the call it itself emitted (the kernel passes
-        # every interpret-mode test above), so only this exact
-        # signature skips; any other failure — numerical or structural
-        # — still fails the suite.  Fixed jax builds take the assert
-        # path below.
-        msg = str(e)
-        if "func.call" in msg and "operand type mismatch" in msg:
-            pytest.skip("jax/Mosaic lowering bug in this environment: "
-                        "func.call i64/i32 operand mismatch when the "
-                        "Pallas LU kernel is embedded in the factor "
-                        "while_loop (present at seed; kernel itself "
-                        "passes interpret-mode tests)")
-        raise
+    x, _, _ = gssvx(Options(factor_dtype="float32"), a, b,
+                    backend="jax")
     relerr = np.linalg.norm(x - xtrue) / np.linalg.norm(xtrue)
     assert relerr < 1e-10

@@ -282,24 +282,14 @@ def test_pallas_lsum_oracle():
     """The fused Pallas lsum kernel (interpret mode on CPU) matches
     the einsum pair it replaces."""
     from superlu_dist_tpu.ops import pallas_lsum
-    if not pallas_lsum._HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     rng = np.random.default_rng(6)
     t, wb, rb, R = 5, 16, 40, 3
     Li = rng.standard_normal((t, wb, wb)).astype(np.float32)
     L21 = rng.standard_normal((t, rb, wb)).astype(np.float32)
     xb = rng.standard_normal((t, wb, R)).astype(np.float32)
-    try:
-        y, upd = pallas_lsum.lsum_panel(
-            jnp.asarray(Li), jnp.asarray(L21), jnp.asarray(xb),
-            interpret=True)
-    except Exception as e:   # noqa: BLE001 — environment lowering bug
-        msg = str(e)
-        if "func.call" in msg and "operand type mismatch" in msg:
-            pytest.skip("jax/Mosaic lowering bug in this "
-                        "environment: func.call i64/i32 operand "
-                        "mismatch")
-        raise
+    y, upd = pallas_lsum.lsum_panel(
+        jnp.asarray(Li), jnp.asarray(L21), jnp.asarray(xb),
+        interpret=True)
     yr, ur = pallas_lsum._oracle()(jnp.asarray(Li),
                                    jnp.asarray(L21),
                                    jnp.asarray(xb))
@@ -312,24 +302,13 @@ def test_pallas_lsum_oracle():
 def test_pallas_lsum_merged_solve(monkeypatch):
     """SLU_TRISOLVE_PALLAS=1 routes merged forward members through
     the kernel (interpret on CPU) and still solves to the oracle."""
-    from superlu_dist_tpu.ops import pallas_lsum
-    if not pallas_lsum._HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     monkeypatch.setenv("SLU_TRISOLVE", "merged")
     monkeypatch.setenv("SLU_TRISOLVE_PALLAS", "1")
     a = laplacian_3d(6)
     xtrue, b = manufactured_rhs(a)
     lu = factorize(a, Options(factor_dtype="float32"),
                    backend="jax")
-    try:
-        x = solve(lu, b)
-    except Exception as e:   # noqa: BLE001 — environment lowering bug
-        msg = str(e)
-        if "func.call" in msg and "operand type mismatch" in msg:
-            pytest.skip("jax/Mosaic lowering bug in this "
-                        "environment: func.call i64/i32 operand "
-                        "mismatch")
-        raise
+    x = solve(lu, b)
     np.testing.assert_allclose(x, xtrue, rtol=1e-4, atol=1e-4)
     assert trisolve.active_arm() == "merged+pallas"
 

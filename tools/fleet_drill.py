@@ -30,8 +30,7 @@ log) and that tools/trace_export.py converts it per-replica.
 
 One JSON line is appended to SLU_FLEET_OUT (default FLEET.jsonl);
 tools/regress.py gates the committed history.  Wire-up:
-`python -m tools.fleet_drill`, `python bench.py --fleet`, or the
-tpu_fire.sh fleet step.  Knobs: SLU_FLEET_REPLICAS / SLU_FLEET_K /
+`python -m tools.fleet_drill` or `python bench.py --fleet`.  Knobs: SLU_FLEET_REPLICAS / SLU_FLEET_K /
 SLU_FLEET_REQUESTS / SLU_FLEET_KILL_AFTER / SLU_FLEET_TTL_S.
 
 MESH-REPLICA ARM (ISSUE 17): `SLU_FLEET_MESH=N` runs every replica
@@ -407,8 +406,9 @@ def run_drill(argv=()) -> dict:
 
     names = [f"r{i}" for i in range(n_replicas)]
     sockets = {n: os.path.join(workdir, n + ".sock") for n in names}
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # three or more replica processes cannot share one chip: the
+    # drill is a CPU correctness drill, pinned so and stamped so
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     env["SLU_FLIGHT_JSONL"] = flight_log     # ONE shared fleet trace
     env["SLU_FLEET_TTL_S"] = str(ttl_s)
@@ -761,8 +761,9 @@ def run_day_drill(argv=()) -> dict:
     flight_log = os.path.join(workdir, "fleet_flight.jsonl")
     os.makedirs(store_dir, exist_ok=True)
 
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # three or more replica processes cannot share one chip: the
+    # drill is a CPU correctness drill, pinned so and stamped so
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     env["SLU_FLIGHT_JSONL"] = flight_log
     env["SLU_FLEET_TTL_S"] = str(ttl_s)

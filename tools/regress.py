@@ -1,8 +1,8 @@
 """Perf-regression sentinel over the committed measurement history.
 
 Five rounds of records are committed (SERVE_LATENCY.jsonl,
-SOLVE_LATENCY.jsonl, PREC_AB.jsonl, CHAOS.jsonl, BENCH_r*.json /
-TPU_BENCH_LIVE.json) but until this tool nothing turned that history
+SOLVE_LATENCY.jsonl, PREC_AB.jsonl, CHAOS.jsonl, BENCH_r*.json)
+but until this tool nothing turned that history
 into a GATE: a perf loss — the silent-regression failure mode the
 HPL-exascale pipelining work warns about (PAPERS.md, arxiv
 2304.10397) — would land invisibly.  This module maintains a
@@ -72,7 +72,7 @@ Baseline-update workflow (DESIGN.md §15): a LEGITIMATE perf change
 ships with `--update` in the same commit — the new BASELINES.json is
 reviewed next to the code that moved the numbers.  A regression is
 the same diff WITHOUT a code story: the gate (serve_bench post-run,
-the tpu_fire.sh arm, tests/test_regress.py in tier-1) rejects it
+tests/test_regress.py in tier-1) rejects it
 before it lands.  Missing-platform records are tolerated (TPU lines
 are absent on the CPU box): those checks report `skip`, never fail.
 
@@ -144,8 +144,9 @@ def _read_jsonl(path: str) -> list[dict]:
 
 
 def _bench_records(root: str) -> list[dict]:
-    """GFLOP/s records from TPU_BENCH_LIVE.json and the BENCH_r*.json
-    driver wrappers (whose bench line hides in the `tail` text)."""
+    """GFLOP/s records from the BENCH_r*.json driver wrappers (whose
+    bench line hides in the `tail` text).  A line names its platform;
+    the pre-PR-23 lines carried `cpu_fallback` instead."""
     out = []
 
     def _adopt(rec, src):
@@ -155,10 +156,13 @@ def _bench_records(root: str) -> list[dict]:
             return
         if rec.get("measurement_invalid"):
             return
+        platform = rec.get("platform")
+        if platform is None and "cpu_fallback" in rec:
+            platform = "cpu" if rec["cpu_fallback"] else "tpu"
+        if platform is None:
+            return          # a line that names no platform is no record
         out.append({"gflops": float(rec["value"]),
-                    "platform": ("cpu" if rec.get("cpu_fallback")
-                                 else "tpu"),
-                    "src": src})
+                    "platform": platform, "src": src})
 
     for path in sorted(glob.glob(os.path.join(root, "BENCH_r*.json"))):
         try:
@@ -175,12 +179,6 @@ def _bench_records(root: str) -> list[dict]:
                     _adopt(json.loads(ln), os.path.basename(path))
                 except ValueError:
                     pass
-    live = os.path.join(root, "TPU_BENCH_LIVE.json")
-    if os.path.exists(live):
-        try:
-            _adopt(json.load(open(live)), "TPU_BENCH_LIVE.json")
-        except (OSError, ValueError):
-            pass
     return out
 
 

@@ -50,26 +50,14 @@ def _jax_env():
     """Shared platform/cache setup; returns (repo_root, jax device)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
-    from superlu_dist_tpu.utils.cache import (cache_dir_for,
-                                              ensure_portable_cpu_isa)
+    from superlu_dist_tpu.utils.cache import (ensure_portable_cpu_isa,
+                                              place_compile_cache)
     if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
         os.environ["XLA_FLAGS"] = ensure_portable_cpu_isa(
             os.environ.get("XLA_FLAGS", ""))
     import jax
-    envp = os.environ.get("JAX_PLATFORMS")
-    if envp:
-        try:
-            jax.config.update("jax_platforms", envp)
-        except Exception:
-            pass
     dev = jax.devices()[0]
-    on_accel = dev.platform != "cpu"
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir_for(
-            os.path.join(repo, ".jax_cache"), accel=on_accel))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:
-        pass
+    place_compile_cache()
     return repo, dev
 
 
@@ -687,11 +675,18 @@ def run_cold_boot_child(k: int, requests: int) -> dict:
     the test_warmup subprocess protocol."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
-    from superlu_dist_tpu.utils.cache import ensure_portable_cpu_isa
+    from superlu_dist_tpu.utils.cache import (ensure_portable_cpu_isa,
+                                              place_compile_cache)
     if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
         os.environ["XLA_FLAGS"] = ensure_portable_cpu_isa(
             os.environ.get("XLA_FLAGS", ""))
     import jax
+    dev = jax.devices()[0]
+    # the compile cache goes where every other run's goes
+    # (JAX_COMPILATION_CACHE_DIR, else the checkout's fixed dir), so
+    # a later drill finds it again; only the store and the AOT export
+    # dir — what the drill is about — are the drill's own
+    place_compile_cache()
 
     # persistent compile-cache hit/miss counters (the warmup drill's
     # monitoring-event probe): informational — the GATE rides the
@@ -735,6 +730,8 @@ def run_cold_boot_child(k: int, requests: int) -> dict:
         "compile_cache_hits": cc_hits[0],
         "compile_cache_misses": cc_misses[0],
         "finite": finite,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
     }
     svc.close()
     print("RESULT " + json.dumps(rec))
@@ -754,7 +751,7 @@ def run_cold_boot(argv=(), k=None, requests=None, out_path=None):
         AND `aot.misses == 0` with `aot.hits >= 1` (every AOT-wrapped
         whole-phase program deserialized instead of re-traced — the
         new contract), i.e. the 14–33 s jit warmup and the 2m4s
-        whole-phase compile (BENCH_r05) are both skipped.
+        whole-phase compile are both skipped.
 
     Appends one `mode=cold_boot` line to SLU_SERVE_OUT (default
     SERVE_LATENCY.jsonl); tools/regress.py gates the counters.  A
@@ -777,9 +774,6 @@ def run_cold_boot(argv=(), k=None, requests=None, out_path=None):
         env = dict(os.environ)
         env["SLU_FT_STORE"] = store_dir
         env["SLU_AOT_CACHE"] = aot_dir
-        # hermetic compile cache: the drill proves the <aot>/xla leg,
-        # not whatever cache the ambient environment points at
-        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH",
                                                         "")
         t0 = time.perf_counter()
@@ -809,8 +803,9 @@ def run_cold_boot(argv=(), k=None, requests=None, out_path=None):
         shutil.rmtree(store_dir, ignore_errors=True)
         shutil.rmtree(aot_dir, ignore_errors=True)
 
-    import jax  # platform stamp only; children did the real work
-    dev = jax.devices()[0]
+    # this parent never imports jax: the two children run one after
+    # the other and each needs the device to itself; the platform
+    # stamp is the warm child's own
     gate = {
         "warm_store": second["factorizations"] == 0
         and second["store_hits"] >= 1,
@@ -836,8 +831,8 @@ def run_cold_boot(argv=(), k=None, requests=None, out_path=None):
         "ready_speedup": round(
             first["t_ready_s"] / max(second["t_ready_s"], 1e-9), 2),
         "gate": gate,
-        "platform": dev.platform,
-        "device_kind": getattr(dev, "device_kind", ""),
+        "platform": second["platform"],
+        "device_kind": second["device_kind"],
         "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }
     if not gate["passed"]:
@@ -1145,7 +1140,10 @@ def run_stream(argv=()):
     drill_seed = int(os.environ.get("SLU_CHAOS_SEED", "0") or "0")
 
     def child(kind, extra_env):
-        env = dict(os.environ)
+        # this parent has already run the overlap arms on jax, so it
+        # holds whatever accelerator there is; the kill drill is a
+        # CPU correctness drill and its children say so
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["SLU_FT_STORE"] = store_dir
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH",
                                                         "")
@@ -1204,6 +1202,7 @@ def run_stream(argv=()):
         shutil.rmtree(jdir, ignore_errors=True)
 
     drill = {
+        "platform": "cpu",      # the children's, pinned above
         "chaos_spec": spec,
         "killed_rc": victim.returncode,
         "killed_by_sigkill": killed_by_sigkill,
@@ -1313,8 +1312,8 @@ def _regress_gate(repo):
     now the latest — gate it against the committed baselines."""
     if os.environ.get("SLU_REGRESS", "1") == "0":
         return
-    # script-style invocation (tpu_fire.sh: `python tools/serve_bench.py`)
-    # puts tools/ on sys.path, not the repo root; the cold-boot parent
+    # script-style invocation (`python tools/serve_bench.py`) puts
+    # tools/ on sys.path, not the repo root; the cold-boot parent
     # never calls _setup() (it only orchestrates child processes), so
     # ensure the root is importable here
     if repo not in sys.path:

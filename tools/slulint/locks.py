@@ -347,7 +347,7 @@ class Auditor:
             # generic fallback for receivers that LOOK like threads —
             # guarded, because `.join()` is also str.join/os.path.join
             # (store.py does path work adjacent to its lock) and a
-            # false positive here aborts the fire plan
+            # false positive here fails the lint gate
             d = _dotted(tgt)
             self._emit(fm, RULE_JOIN_LOCK, call.lineno,
                        f"{'.'.join(d) or '<expr>'}.join() while "

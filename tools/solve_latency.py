@@ -13,8 +13,8 @@ one JSON line per nrhs:
 The headline contract: per-RHS cost at nrhs=64 within 2x of the
 amortized ideal — the sweep chain is O(#groups) regardless of R, so
 wide RHS blocks amortize it and the einsums grow on the MXU's free
-axis.  Run by tools/tpu_fire.sh in live windows (appends to
-SOLVE_LATENCY.jsonl); CPU rehearsal via JAX_PLATFORMS=cpu.
+axis.  One process; appends to SOLVE_LATENCY.jsonl with the platform
+it ran on (a `cpu` line is a rehearsal, never a speed).
 """
 
 import json
@@ -28,8 +28,8 @@ import numpy as np
 def main():
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    from superlu_dist_tpu.utils.cache import (cache_dir_for,
-                                              ensure_portable_cpu_isa)
+    from superlu_dist_tpu.utils.cache import (ensure_portable_cpu_isa,
+                                              place_compile_cache)
     if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
         os.environ["XLA_FLAGS"] = ensure_portable_cpu_isa(
             os.environ.get("XLA_FLAGS", ""))
@@ -37,12 +37,7 @@ def main():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     dev = jax.devices()[0]
     on_accel = dev.platform != "cpu"
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir_for(
-            os.path.join(repo, ".jax_cache"), accel=on_accel))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:
-        pass
+    place_compile_cache()
     if on_accel:
         from superlu_dist_tpu.utils.platform import (
             apply_accel_amalg_defaults)
