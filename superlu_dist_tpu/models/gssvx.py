@@ -213,14 +213,27 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     return lu
 
 
+def _dist_sweep(lu: LUFactorization, b_factor_order: np.ndarray,
+                trans: bool):
+    """The mesh sweep under the spans `ops/batched` gives the
+    one-device sweep: rhs to the devices → answer on the host."""
+    from ..parallel import factor_dist
+    with obs.span("solve.sweep", cat="solve",
+                  args={"nrhs": (b_factor_order.shape[1]
+                                 if b_factor_order.ndim == 2 else 1),
+                        "trans": int(trans)}):
+        X = factor_dist.dist_solve(lu.device_lu, b_factor_order,
+                                   trans=trans)
+        with obs.span("solve.fetch", cat="solve"):
+            return np.asarray(X)
+
+
 def _solve_factored(lu: LUFactorization, b_factor_order: np.ndarray):
     """Triangular solves in factor ordering/scaling."""
     if lu.backend == "host":
         return ref_multifrontal.solve_host(lu.host_lu, b_factor_order)
     if lu.backend == "dist":
-        from ..parallel import factor_dist
-        return np.asarray(factor_dist.dist_solve(lu.device_lu,
-                                                 b_factor_order))
+        return _dist_sweep(lu, b_factor_order, trans=False)
     from ..ops import batched
     return batched.solve_device(lu.device_lu, b_factor_order)
 
@@ -231,9 +244,7 @@ def _solve_factored_trans(lu: LUFactorization, b_factor_order: np.ndarray):
         return ref_multifrontal.solve_host_trans(lu.host_lu,
                                                  b_factor_order)
     if lu.backend == "dist":
-        from ..parallel import factor_dist
-        return np.asarray(factor_dist.dist_solve(
-            lu.device_lu, b_factor_order, trans=True))
+        return _dist_sweep(lu, b_factor_order, trans=True)
     from ..ops import batched
     return batched.solve_device_trans(lu.device_lu, b_factor_order)
 

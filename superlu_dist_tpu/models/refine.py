@@ -103,8 +103,13 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
         denom = np.where(denom == 0.0, 1.0, denom)
         return float(np.max(np.abs(r) / denom))
 
-    r = bk - asp @ xk
-    berr = berr_of(r, xk)
+    def residual(xv):
+        # the host residual and its berr: two sparse products
+        with obs.span("refine.residual", cat="refine"):
+            rv = bk - asp @ xv
+            return rv, berr_of(rv, xv)
+
+    r, berr = residual(xk)
     steps = 0
     # health trajectories (obs/health.py): the berr path of the loop
     # and the forward-error proxy ‖δ‖/‖x‖ per step — the runtime
@@ -120,8 +125,7 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
         with obs.span("REFINE_STEP", args={"berr": berr}):
             d = from_factor_sol(solve_factored(lu, to_factor_rhs(r)))
             x_new = xk + d
-            r_new = bk - asp @ x_new
-            berr_new = berr_of(r_new, x_new)
+            r_new, berr_new = residual(x_new)
         steps += 1
         berr_traj.append(berr_new)
         if track_ferr:

@@ -18,12 +18,20 @@ by tests/test_obs_trace.py):
     one JSON event per line as spans close (the event log twin).
 
 When disabled, `span()` returns a single reusable no-op context
-manager — one module-global read and an identity return per call, no
-allocation, no lock.  When enabled, a span costs two
-`perf_counter_ns` reads, one small dict and one lock acquisition at
-close.  The in-memory buffer is capped (`_EVENT_CAP`); past it new
-events are counted as dropped instead of growing without bound under
-sustained serve traffic.
+manager — one module-global read, one `is_enabled()` probe and an
+identity return per call, no allocation, no lock.  When enabled, a
+span costs two `perf_counter_ns` reads, one small dict and one lock
+acquisition at close.  The in-memory buffer is capped (`_EVENT_CAP`);
+past it new events are counted as dropped instead of growing without
+bound under sustained serve traffic.
+
+Second sink, no flag: while a `jax.profiler` session is live
+(`start_trace` … `stop_trace`, whoever started it), `span()` and
+`instant()` also write `slu.<name>` into the profiler's own trace, on
+the clock the device operations are recorded on, with `args` as the
+event's stats.  The session is the switch.  `complete()` (a span
+whose start predates the call) cannot be written there and stays with
+the SLU_OBS tracer, as does everything `enabled()` gates.
 """
 
 from __future__ import annotations
@@ -34,6 +42,8 @@ import os
 import sys
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .. import flags
 
@@ -54,6 +64,26 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _BothSpan:
+    """One call site, both sinks: the profiler's annotation outside,
+    the SLU_OBS span inside."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, span):
+        self._annotation = annotation
+        self._span = span
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return False
 
 
 class _Span:
@@ -306,16 +336,30 @@ def get_tracer() -> Tracer | None:
     return _tracer
 
 
+def _annotation(name: str, args: dict | None):
+    return (TraceAnnotation("slu." + name, **args) if args
+            else TraceAnnotation("slu." + name))
+
+
 def span(name: str, cat: str = "phase", args: dict | None = None):
     """The ONE hot-path entry: a context manager that is a shared
-    no-op singleton when tracing is off."""
+    no-op singleton when nothing is listening, the profiler's
+    `slu.<name>` annotation while a profiler session is live, the
+    SLU_OBS span when the tracer is on, and both when both."""
     t = _tracer
+    if TraceAnnotation.is_enabled():
+        a = _annotation(name, args)
+        return a if t is None else _BothSpan(a, t.span(name, cat, args))
     if t is None:
         return NULL_SPAN
     return t.span(name, cat, args)
 
 
 def instant(name: str, cat: str = "event", args: dict | None = None) -> None:
+    if TraceAnnotation.is_enabled():
+        # the profiler has no instant event: an empty span marks it
+        with _annotation(name, args):
+            pass
     t = _tracer
     if t is not None:
         t.instant(name, cat, args)

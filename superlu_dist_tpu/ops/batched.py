@@ -1160,6 +1160,15 @@ def psum_exact(x, axis):
     return jax.lax.psum(x, axis)
 
 
+# Kernel scopes (`jax.named_scope`, trace-time only): ONE fixed
+# vocabulary across ops/ and parallel/factor_dist.py, so a device
+# operation in a profiler trace names the code it came from —
+# slu.assemble (values into fronts), slu.extend_add, slu.partial_lu,
+# slu.tri_inverse, slu.schur, slu.store (panels into the flats),
+# slu.fwd, slu.bwd, slu.lsum (the contributor chain), slu.resid
+# (device SpMV).  The innermost scope is the operation's kernel.
+
+@jax.named_scope("slu.extend_add")
 def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
             ncols: int = 0, allow_pallas: bool = True):
     """Extend-add of child update blocks into the flat front batch F.
@@ -1249,6 +1258,7 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     return F
 
 
+@jax.named_scope("slu.extend_add")
 def _ea_add_blocks(F, upd_buf, eb_blocks, eb_meta, *, mb: int,
                    n_pad: int, ncols: int = 0):
     """Block-copy extend-add lane (GroupSpec.eb_hosts): each record is
@@ -1311,14 +1321,16 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
     # position 3 carries both extend-add lanes: element-gather buckets
     # and contiguous block-copy buckets (GroupSpec.dev docstring)
     elem_blocks, blk_blocks = ea_blocks
-    F = jnp.zeros(n_pad * mb * ncols, dtype)
-    # a_dst/one_dst carry DISTINCT out-of-bounds padding, so the
-    # unique-indices promise holds; add-scatter index pairs are
-    # dst-sorted by the schedule builder, so they also promise
-    # indices_are_sorted — both enable parallel scatter lowerings
-    F = F.at[a_dst].add(vals[a_src], mode="drop",
-                        unique_indices=True, indices_are_sorted=True)
-    F = F.at[one_dst].set(one, mode="drop", unique_indices=True)
+    with jax.named_scope("slu.assemble"):
+        F = jnp.zeros(n_pad * mb * ncols, dtype)
+        # a_dst/one_dst carry DISTINCT out-of-bounds padding, so the
+        # unique-indices promise holds; add-scatter index pairs are
+        # dst-sorted by the schedule builder, so they also promise
+        # indices_are_sorted — both enable parallel scatter lowerings
+        F = F.at[a_dst].add(vals[a_src], mode="drop",
+                            unique_indices=True,
+                            indices_are_sorted=True)
+        F = F.at[one_dst].set(one, mode="drop", unique_indices=True)
     # force_xla: the batch engine (superlu_dist_tpu/batch/engine.py)
     # traces this body under jax.vmap, where a pallas_call's batching
     # rule is not a path we certify — the _factor_group_impl_pair
@@ -1335,8 +1347,9 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
         # psums, the Schur slice stays device-local (no recombination
         # gather).  Counters replicate — owner device counts them.
         from .coop_sharded import coop_sharded_lu_batch
-        Lsrc, Usrc, slab, tiny_g, nzero_g = coop_sharded_lu_batch(
-            F, pos_idx, thresh, wb=wb, cp=cp, tp=tp, axis=axis)
+        with jax.named_scope("slu.partial_lu"):
+            Lsrc, Usrc, slab, tiny_g, nzero_g = coop_sharded_lu_batch(
+                F, pos_idx, thresh, wb=wb, cp=cp, tp=tp, axis=axis)
         upd_src = slab
         on_owner = (_flat_axis_index(axis) == 0).astype(jnp.int32)
         tiny_g = tiny_g * on_owner
@@ -1346,8 +1359,9 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
         # cooperative column-sharded LU over the full replicated
         # front; counters replicate, so take them from the owner only
         from .coop_lu import coop_partial_lu_batch
-        F, tiny_g, nzero_g = coop_partial_lu_batch(
-            F, thresh, wb=wb, ndev=ndev, axis=axis)
+        with jax.named_scope("slu.partial_lu"):
+            F, tiny_g, nzero_g = coop_partial_lu_batch(
+                F, thresh, wb=wb, ndev=ndev, axis=axis)
         on_owner = (_flat_axis_index(axis) == 0).astype(jnp.int32)
         tiny_g = tiny_g * on_owner
         nzero_g = nzero_g * on_owner
@@ -1363,49 +1377,50 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
                     else True if pallas_diag else None))
         Lsrc, Usrc, upd_src = F[:, :, :wb], F[:, :wb, :], F[:, wb:, wb:]
 
-    rows = jnp.arange(mb)[:, None]
-    colsw = jnp.arange(wb)[None, :]
-    Lpanel = jnp.where(rows > colsw, Lsrc,
-                       jnp.where(rows == colsw, one, 0))
-    Upanel = jnp.where(colsw.T <= jnp.arange(mb)[None, :], Usrc, 0)
+    with jax.named_scope("slu.store"):
+        rows = jnp.arange(mb)[:, None]
+        colsw = jnp.arange(wb)[None, :]
+        Lpanel = jnp.where(rows > colsw, Lsrc,
+                           jnp.where(rows == colsw, one, 0))
+        Upanel = jnp.where(colsw.T <= jnp.arange(mb)[None, :], Usrc, 0)
     Li = unit_lower_inverse(Lpanel[:, :wb, :])
     Ui = upper_inverse(Upanel[:, :, :wb])
-
-    L_flat = jax.lax.dynamic_update_slice(L_flat, Lpanel.reshape(-1),
-                                          (L_off,))
-    U_flat = jax.lax.dynamic_update_slice(U_flat, Upanel.reshape(-1),
-                                          (U_off,))
-    Li_flat = jax.lax.dynamic_update_slice(Li_flat, Li.reshape(-1),
-                                           (Li_off,))
-    Ui_flat = jax.lax.dynamic_update_slice(Ui_flat, Ui.reshape(-1),
-                                           (Ui_off,))
-    if mb > wb and (not sharded or tp > 0):
-        upd = upd_src.reshape(-1)
-        if axis is not None and coop:
-            # coop content at the single owner-slot offset: sharded —
-            # each device writes its OWN (rb, tp) owned-column slice
-            # (device-varying, consumed device-locally by the sharded
-            # parent); legacy replicated — every device writes the
-            # SAME full square, so consumers read it locally either
-            # way and no gather is ever needed
-            off = upd_off
-        elif axis is not None and gather:
-            # ancestor propagation: the reference's dreduceAncestors3d /
-            # Z-axis panel exchange becomes one tiled all_gather along
-            # the mesh axis — device-major local slabs concatenate into
-            # exactly the global slab layout
-            upd = jax.lax.all_gather(upd, axis, tiled=True)
-            off = upd_off
-        elif axis is not None:
-            # gather-free subforest interior (zone-affine placement):
-            # every consumer of this slab lives on this device, so
-            # each device writes only its own device-major slice and
-            # no ICI traffic happens (dsparseTreeFactor's layer-local
-            # phase, SRC/pdgstrf3d.c:292-322)
-            off = upd_off + _flat_axis_index(axis) * upd.size
-        else:
-            off = upd_off
-        upd_buf = jax.lax.dynamic_update_slice(upd_buf, upd, (off,))
+    with jax.named_scope("slu.store"):
+        L_flat = jax.lax.dynamic_update_slice(L_flat, Lpanel.reshape(-1),
+                                              (L_off,))
+        U_flat = jax.lax.dynamic_update_slice(U_flat, Upanel.reshape(-1),
+                                              (U_off,))
+        Li_flat = jax.lax.dynamic_update_slice(Li_flat, Li.reshape(-1),
+                                               (Li_off,))
+        Ui_flat = jax.lax.dynamic_update_slice(Ui_flat, Ui.reshape(-1),
+                                               (Ui_off,))
+        if mb > wb and (not sharded or tp > 0):
+            upd = upd_src.reshape(-1)
+            if axis is not None and coop:
+                # coop content at the single owner-slot offset: sharded —
+                # each device writes its OWN (rb, tp) owned-column slice
+                # (device-varying, consumed device-locally by the sharded
+                # parent); legacy replicated — every device writes the
+                # SAME full square, so consumers read it locally either
+                # way and no gather is ever needed
+                off = upd_off
+            elif axis is not None and gather:
+                # ancestor propagation: the reference's dreduceAncestors3d /
+                # Z-axis panel exchange becomes one tiled all_gather along
+                # the mesh axis — device-major local slabs concatenate into
+                # exactly the global slab layout
+                upd = jax.lax.all_gather(upd, axis, tiled=True)
+                off = upd_off
+            elif axis is not None:
+                # gather-free subforest interior (zone-affine placement):
+                # every consumer of this slab lives on this device, so
+                # each device writes only its own device-major slice and
+                # no ICI traffic happens (dsparseTreeFactor's layer-local
+                # phase, SRC/pdgstrf3d.c:292-322)
+                off = upd_off + _flat_axis_index(axis) * upd.size
+            else:
+                off = upd_off
+            upd_buf = jax.lax.dynamic_update_slice(upd_buf, upd, (off,))
     return (upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
             tiny + tiny_g, nzero + nzero_g)
 
@@ -1439,6 +1454,7 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
     ncols = mb
     one_pl = jnp.stack([jnp.ones((), rdt), jnp.zeros((), rdt)])
 
+    @jax.named_scope("slu.assemble")
     def assemble(f, v, o):
         f = f.at[a_dst].add(v[a_src], mode="drop",
                             unique_indices=True,
@@ -1455,32 +1471,36 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
         f, u, blk_blocks, eb_meta, mb=mb, n_pad=n_pad,
         ncols=ncols))(F, upd_buf)
     F = F.reshape(2, n_pad, mb, ncols)
-    F, tiny_g, nzero_g = partial_lu_pair_batch(F, thresh, wb=wb)
+    with jax.named_scope("slu.partial_lu"):
+        F, tiny_g, nzero_g = partial_lu_pair_batch(F, thresh, wb=wb)
     Lsrc, Usrc = F[:, :, :, :wb], F[:, :, :wb, :]
 
-    rows = jnp.arange(mb)[:, None]
-    colsw = jnp.arange(wb)[None, :]
-    Lpanel = jnp.where(rows > colsw, Lsrc, 0)
-    Lpanel = Lpanel.at[0].add(                 # unit diagonal, plane 0
-        jnp.where(rows == colsw, jnp.ones((), rdt), 0))
-    Upanel = jnp.where(colsw.T <= jnp.arange(mb)[None, :], Usrc, 0)
-    Li = unit_lower_inverse_pair(Lpanel[:, :, :wb, :])
-    Ui = upper_inverse_pair(Upanel[:, :, :, :wb])
+    with jax.named_scope("slu.store"):
+        rows = jnp.arange(mb)[:, None]
+        colsw = jnp.arange(wb)[None, :]
+        Lpanel = jnp.where(rows > colsw, Lsrc, 0)
+        Lpanel = Lpanel.at[0].add(             # unit diagonal, plane 0
+            jnp.where(rows == colsw, jnp.ones((), rdt), 0))
+        Upanel = jnp.where(colsw.T <= jnp.arange(mb)[None, :], Usrc, 0)
+    with jax.named_scope("slu.tri_inverse"):
+        Li = unit_lower_inverse_pair(Lpanel[:, :, :wb, :])
+        Ui = upper_inverse_pair(Upanel[:, :, :, :wb])
 
-    z = jnp.zeros((), jnp.int32)
-    L_flat = jax.lax.dynamic_update_slice(
-        L_flat, Lpanel.reshape(2, -1), (z, L_off))
-    U_flat = jax.lax.dynamic_update_slice(
-        U_flat, Upanel.reshape(2, -1), (z, U_off))
-    Li_flat = jax.lax.dynamic_update_slice(
-        Li_flat, Li.reshape(2, -1), (z, Li_off))
-    Ui_flat = jax.lax.dynamic_update_slice(
-        Ui_flat, Ui.reshape(2, -1), (z, Ui_off))
-    if mb > wb:
-        upd_buf = jax.lax.dynamic_update_slice(
-            upd_buf, F[:, :, wb:, wb:].reshape(2, -1),
-            (jnp.zeros((), getattr(upd_off, "dtype", jnp.int32)),
-             upd_off))
+    with jax.named_scope("slu.store"):
+        z = jnp.zeros((), jnp.int32)
+        L_flat = jax.lax.dynamic_update_slice(
+            L_flat, Lpanel.reshape(2, -1), (z, L_off))
+        U_flat = jax.lax.dynamic_update_slice(
+            U_flat, Upanel.reshape(2, -1), (z, U_off))
+        Li_flat = jax.lax.dynamic_update_slice(
+            Li_flat, Li.reshape(2, -1), (z, Li_off))
+        Ui_flat = jax.lax.dynamic_update_slice(
+            Ui_flat, Ui.reshape(2, -1), (z, Ui_off))
+        if mb > wb:
+            upd_buf = jax.lax.dynamic_update_slice(
+                upd_buf, F[:, :, wb:, wb:].reshape(2, -1),
+                (jnp.zeros((), getattr(upd_off, "dtype", jnp.int32)),
+                 upd_off))
     return (upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
             tiny + tiny_g, nzero + nzero_g)
 
@@ -1572,6 +1592,7 @@ def _psub(P, fn):
     return tuple(fn(p) for p in P) if isinstance(P, tuple) else fn(P)
 
 
+@jax.named_scope("slu.fwd")
 def _fwd_group_impl(X, L_flat, Li_flat, col_idx, struct_idx, L_off,
                     Li_off, *, mb: int, wb: int, n_pad: int,
                     cplx: bool = False):
@@ -1594,6 +1615,7 @@ def _fwd_group_impl(X, L_flat, Li_flat, col_idx, struct_idx, L_off,
 
 
 
+@jax.named_scope("slu.bwd")
 def _bwd_group_impl(X, U_flat, Ui_flat, col_idx, struct_idx, U_off,
                     Ui_off, *, mb: int, wb: int, n_pad: int,
                     cplx: bool = False):
@@ -1618,6 +1640,7 @@ def _bwd_group_impl(X, U_flat, Ui_flat, col_idx, struct_idx, U_off,
 # backward on unit-upper Lᵀ, same schedule/groups, panels transposed
 # on the fly (einsum-transpose is free on the MXU)
 
+@jax.named_scope("slu.fwd")
 def _fwd_group_T_impl(X, U_flat, Ui_flat, col_idx, struct_idx, U_off,
                       Ui_off, *, mb: int, wb: int, n_pad: int,
                       cplx: bool = False):
@@ -1637,6 +1660,7 @@ def _fwd_group_T_impl(X, U_flat, Ui_flat, col_idx, struct_idx, U_off,
 
 
 
+@jax.named_scope("slu.bwd")
 def _bwd_group_T_impl(X, L_flat, Li_flat, col_idx, struct_idx, L_off,
                       Li_off, *, mb: int, wb: int, n_pad: int,
                       cplx: bool = False):
@@ -2156,13 +2180,17 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         pairs = [(t[5], t[6]) for t in per_group]
         dtype = np.dtype(dtype)
 
+        # the programs' names are what a profiler trace calls them
+        # (`jit_slu_factor`), and part of their persistent-cache key,
+        # which the kernels' scope names are not: a rename is what
+        # makes a cache written before the scopes existed miss once
         @jax.jit
-        def factor_fn(vals):
+        def slu_factor(vals):
             return _factor_loop(sched, vals, thresh_np, dtype,
                                 per_group, None, pair=pair)
 
         @functools.partial(jax.jit, static_argnames=("trans",))
-        def solve_fn(L, U, Li, Ui, b, trans=False):
+        def slu_solve(L, U, Li, Ui, b, trans=False):
             return _solve_loop(sched, (L, U, Li, Ui), b, dtype, pairs,
                                None, trans=trans, pair=pair)
 
@@ -2185,17 +2213,17 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         # refused at call time.  Real dtypes always run on the
         # backend they export for.
         from ..resilience import aot
-        factor_w = factor_fn
+        factor_w = slu_factor
         if not pair and dtype.kind != "c":
             factor_w = aot.wrap_jit(
-                "phase_factor", factor_fn,
+                "phase_factor", slu_factor,
                 aot.schedule_fingerprint(
                     sched, dtype,
                     extra=("phase_factor", bool(pair),
                            float(thresh_np))))
         cache[key] = (
             obs.watch_jit("factor", factor_w, cost_phase="FACT"),
-            obs.watch_jit("solve", solve_fn, cost_phase="SOLVE"))
+            obs.watch_jit("solve", slu_solve, cost_phase="SOLVE"))
         return cache[key]
 
 
@@ -2254,41 +2282,46 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
             else bb.astype(xdt))
     from . import trisolve
     merged = trisolve.trisolve_mode() == "merged"
-    if isinstance(lu, StagedLU):
-        # merged: reuse the handle-cached packed panels so repeated
-        # FACTORED solves skip the per-solve re-slice
-        X = _staged_sweeps(lu.schedule, lu.panels,
-                           jnp.asarray(bin_), lu.dtype, trans,
-                           pair=pair,
-                           packs=(trisolve.get_packs(lu)
-                                  if merged else None))
-    elif merged:
-        # the packed FACTORED fast path (ops/trisolve.py): panels
-        # pre-sliced once per factorization, lsum layout instead of
-        # scatter-adds — the serve hot path's program.  Cost
-        # attribution happens inside solve_packed (same thread-local
-        # hand-off as below).
-        X = trisolve.solve_packed(lu, bin_, trans)
-    else:
-        _, solve_fn = _phase_fns(lu.schedule, lu.dtype,
-                                 _thresh_for(lu.plan, lu.dtype),
-                                 pair=pair)
-        bj = jnp.asarray(bin_)
-        # `trans` passed POSITIONALLY: a static_argnames keyword
-        # call drops jax to the slow python dispatch path (the PR 7
-        # lesson, enforced by slulint's static-kwarg rule)
-        X = solve_fn(lu.L_flat, lu.U_flat, lu.Li_flat, lu.Ui_flat,
-                     bj, trans)
-        # the EXECUTED signature's program cost — the solve wrapper
-        # serves the whole nrhs bucket ladder, so a shared last-miss
-        # field would misattribute (a 1-wide solve adopting the
-        # 64-wide program's flops); thread-local, not on the handle,
-        # so concurrent solves through one cached factorization don't
-        # cross-attribute either
-        obs.stamp_cost("solve", solve_fn.cost_of(
-            lu.L_flat, lu.U_flat, lu.Li_flat, lu.Ui_flat, bj,
-            trans))
-    out = np.asarray(X)
+    # merged: the handle-cached packed panels, so repeated FACTORED
+    # solves skip the per-solve re-slice.  Taken BEFORE the sweep
+    # span opens: the first solve of a factorization packs here
+    # (`slu.solve.pack`), and that is not sweep time
+    packs = trisolve.get_packs(lu) if merged else None
+    with obs.span("solve.sweep", cat="solve",
+                  args={"nrhs": bb.shape[1], "trans": int(trans)}):
+        if isinstance(lu, StagedLU):
+            X = _staged_sweeps(lu.schedule, lu.panels,
+                               jnp.asarray(bin_), lu.dtype, trans,
+                               pair=pair, packs=packs)
+        elif merged:
+            # the packed FACTORED fast path (ops/trisolve.py): panels
+            # pre-sliced once per factorization, lsum layout instead
+            # of scatter-adds — the serve hot path's program.  Cost
+            # attribution happens inside solve_packed (same
+            # thread-local hand-off as below; its own get_packs is
+            # a hit by now).
+            X = trisolve.solve_packed(lu, bin_, trans)
+        else:
+            _, solve_fn = _phase_fns(lu.schedule, lu.dtype,
+                                     _thresh_for(lu.plan, lu.dtype),
+                                     pair=pair)
+            bj = jnp.asarray(bin_)
+            # `trans` passed POSITIONALLY: a static_argnames keyword
+            # call drops jax to the slow python dispatch path (the PR
+            # 7 lesson, enforced by slulint's static-kwarg rule)
+            X = solve_fn(lu.L_flat, lu.U_flat, lu.Li_flat,
+                         lu.Ui_flat, bj, trans)
+            # the EXECUTED signature's program cost — the solve
+            # wrapper serves the whole nrhs bucket ladder, so a shared
+            # last-miss field would misattribute (a 1-wide solve
+            # adopting the 64-wide program's flops); thread-local, not
+            # on the handle, so concurrent solves through one cached
+            # factorization don't cross-attribute either
+            obs.stamp_cost("solve", solve_fn.cost_of(
+                lu.L_flat, lu.U_flat, lu.Li_flat, lu.Ui_flat, bj,
+                trans))
+        with obs.span("solve.fetch", cat="solve"):
+            out = np.asarray(X)
     if pair:
         out = _pair_decode_sol(out, xdt)
     return out[:, 0] if squeeze else out

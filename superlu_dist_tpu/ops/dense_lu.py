@@ -133,6 +133,7 @@ def _tiny_replace(piv, thresh, dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("wb", "nb"))
+@jax.named_scope("slu.partial_lu")
 def partial_lu(F, thresh, *, wb: int, nb: int = 32):
     """Factor the leading `wb` columns of the square front F (mb×mb) in
     place: returns (F', tiny_count, zero_pivot_count) where F' holds L
@@ -205,8 +206,9 @@ def partial_lu(F, thresh, *, wb: int, nb: int = 32):
         F = jax.lax.dynamic_update_slice(F, D, (k0, k0))
         # exact Newton triangular inverses of the nb×nb factors: MXU
         # matmuls instead of triangular_solve's sequential column sweep
-        U11i = _newton_tri_inverse(D, lower=False, unit=False)
-        L11i = _newton_tri_inverse(D, lower=True, unit=True)
+        with jax.named_scope("slu.tri_inverse"):
+            U11i = _newton_tri_inverse(D, lower=False, unit=False)
+            L11i = _newton_tri_inverse(D, lower=True, unit=True)
         # L21 = A21 · U11⁻¹ over the full column slice; keep rows ≥
         # k0+nb (rows < k0 hold finished U entries, D already written)
         colp = jax.lax.dynamic_slice(F, (0, k0), (mb, nb))
@@ -221,9 +223,10 @@ def partial_lu(F, thresh, *, wb: int, nb: int = 32):
         rowp2 = jnp.where(keep_c, U12, rowp)
         F = jax.lax.dynamic_update_slice(F, rowp2, (k0, 0))
         # trailing GEMM restricted to i, j ≥ k0+nb via masking
-        Lcol = jnp.where(keep_r, colp2, 0)
-        Urow = jnp.where(keep_c, rowp2, 0)
-        F = F - Lcol @ Urow
+        with jax.named_scope("slu.schur"):
+            Lcol = jnp.where(keep_r, colp2, 0)
+            Urow = jnp.where(keep_c, rowp2, 0)
+            F = F - Lcol @ Urow
         return F, tiny, nzero
 
     tiny0 = jnp.zeros((), jnp.int32)
@@ -247,12 +250,14 @@ def partial_lu_batch(F, thresh, *, wb: int, nb: int = 32,
     use = (pallas_lu.enabled(F.dtype) if pallas is None
            else bool(pallas) and mosaic_dtype(F.dtype))
     if use and pallas_lu.usable(F.shape[-1], F.dtype):
-        return pallas_lu.partial_lu_batch_pallas(F, thresh, wb=wb)
+        with jax.named_scope("slu.partial_lu"):
+            return pallas_lu.partial_lu_batch_pallas(F, thresh, wb=wb)
     f = functools.partial(partial_lu, wb=wb, nb=nb)
     Fs, tinys, nzeros = jax.vmap(lambda x: f(x, thresh))(F)
     return Fs, jnp.sum(tinys), jnp.sum(nzeros)
 
 
+@jax.named_scope("slu.tri_inverse")
 def unit_lower_inverse(L):
     """inv(L) for batched unit-lower (N, w, w) — the DiagInv
     preparation (SRC/pdgssvx.c:1436-1447): turns the solve's TRSV into
@@ -260,6 +265,7 @@ def unit_lower_inverse(L):
     return _blocked_tri_inverse(L, lower=True, unit=True)
 
 
+@jax.named_scope("slu.tri_inverse")
 def upper_inverse(U):
     """inv(U) for batched upper-triangular (N, w, w)."""
     return _blocked_tri_inverse(U, lower=False, unit=False)

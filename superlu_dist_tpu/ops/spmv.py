@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -39,6 +40,7 @@ from .. import flags
 from ..sparse import CSRMatrix
 
 
+@jax.named_scope("slu.resid")
 def coo_spmv(rows, cols, vals, x, n: int):
     """y = A·x with A given as COO arrays; x is (n,) or (n, nrhs).
     All jittable; rows/cols may be padded with index n (dropped)."""
@@ -82,6 +84,7 @@ def ell_cols_from_src(src, indices, n_cols: int):
     return idx[np.minimum(src, len(idx) - 1)]
 
 
+@jax.named_scope("slu.resid")
 def ell_spmv(ell_cols, ell_vals, x):
     """y = A·x with A in padded-ELL form: per-row gather of the fixed
     band + row-sum reduction — zero scatter ops in the lowered HLO.
@@ -96,6 +99,7 @@ def ell_spmv(ell_cols, ell_vals, x):
     return jnp.sum(ell_vals * xg, axis=1)
 
 
+@jax.named_scope("slu.resid")
 def ell_spmv_df64(ell_cols, vals_hi, vals_lo, x_hi, x_lo):
     """Double-word accumulation lane of the ELL product: A and x as
     exact (hi, lo) fp32 pairs, the band reduction compensated — the
@@ -108,6 +112,7 @@ def ell_spmv_df64(ell_cols, vals_hi, vals_lo, x_hi, x_lo):
     return df64_ell_spmv(ell_cols, vals_hi, vals_lo, x_hi, x_lo)
 
 
+@jax.named_scope("slu.resid")
 def coo_spmv_df64(rows, cols, vals_hi, vals_lo, x_hi, x_lo, n: int):
     """Double-word COO lane: per-term products are exact df64, but the
     row scatter-add cannot carry a compensated sum, so accumulation

@@ -477,21 +477,25 @@ def get_packs(device_lu):
     ent = getattr(device_lu, "_trisolve_packs", None)
     if ent is not None and ent[0] == key:
         return ent[1]
+    from .. import obs
     with _build_lock:
         ent = getattr(device_lu, "_trisolve_packs", None)
         if ent is not None and ent[0] == key:
             return ent[1]
         ts = get_trisolve(device_lu.schedule)
         panels = getattr(device_lu, "panels", None)
-        if panels is not None:
-            packs = pack_panels_staged(ts, panels)
-        else:
-            # eager (op-by-op) slicing: one-time per factorization,
-            # no throwaway jit compile
-            packs = pack_panels(ts, (device_lu.L_flat,
-                                     device_lu.U_flat,
-                                     device_lu.Li_flat,
-                                     device_lu.Ui_flat))
+        # the miss path only: a hit opens no span
+        with obs.span("solve.pack", cat="solve",
+                      args={"groups": len(ts.groups)}):
+            if panels is not None:
+                packs = pack_panels_staged(ts, panels)
+            else:
+                # eager (op-by-op) slicing: one-time per
+                # factorization, no throwaway jit compile
+                packs = pack_panels(ts, (device_lu.L_flat,
+                                         device_lu.U_flat,
+                                         device_lu.Li_flat,
+                                         device_lu.Ui_flat))
         packs = PackSet(packs)
         device_lu._trisolve_packs = (key, packs)
         return packs
@@ -507,6 +511,7 @@ def get_packs(device_lu):
 _CHAIN_UNROLL = 4
 
 
+@jax.named_scope("slu.lsum")
 def chain_subtract(xb, UPD, u_gidx, J: int):
     """The contributor-subtract chain: ONE gather of all J planes,
     then the sequential fold — the subtraction ORDER is the bitwise
@@ -546,6 +551,7 @@ def _mm(sub, A, xe, cplx):
     return _mm_enc(sub, A, xe, cplx)
 
 
+@jax.named_scope("slu.fwd")
 def _fwd_member(state, g, gs, pack, idx, cplx, trans):
     """One group's forward lsum step on the dense buffers.  State is
     (B, UPD, Y): xb = B[cols] minus the contributor chain (replayed
@@ -586,6 +592,7 @@ def _fwd_member(state, g, gs, pack, idx, cplx, trans):
     return B, UPD, Y
 
 
+@jax.named_scope("slu.bwd")
 def _bwd_member(XF, Y, g, gs, pack, idx, cplx, trans):
     """One group's backward step: xb from this group's own dense Y
     block, ancestor rows gathered from XF slots, the solution written
@@ -725,19 +732,22 @@ def _solve_packed_fn(sched, dtype, pair: bool):
         from ..resilience import aot
 
         def mk(trans):
+            # named for the profiler and for the persistent-cache
+            # key (the batched._phase_fns note)
             @jax.jit
-            def solve_fn(packs, b):
+            def slu_solve_packed(packs, b):
                 with jax.default_matmul_precision("float32"):
                     return sweep(ts, packs, b, dtype, trans,
                                  pair=pair)
-            wrapped = solve_fn
+            wrapped = slu_solve_packed
             if not pair and np.dtype(dtype).kind != "c":
                 # complex lanes skip AOT: the complex-on-TPU gate
                 # executes them on the host CPU under a TPU default
                 # backend, and an export records one platform (the
                 # batched._phase_fns note)
                 wrapped = aot.wrap_jit(
-                    f"solve_packed.{'T' if trans else 'N'}", solve_fn,
+                    f"solve_packed.{'T' if trans else 'N'}",
+                    slu_solve_packed,
                     aot.schedule_fingerprint(
                         sched, dtype, extra=("packed", bool(pair))))
             return obs.watch_jit("solve", wrapped,

@@ -184,6 +184,7 @@ def _solve_loop(dsched, flats, b, dtype, per_group, axis,
         X = _enc(X, cplx)
     Xs = X                       # last reconciled snapshot (axis mode)
 
+    @jax.named_scope("slu.lsum")
     def sync(X, Xs):
         Xn = Xs + jax.lax.psum(X - Xs, axis)
         return Xn, Xn
@@ -435,7 +436,13 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     # and an unexportable shard_map falls back to the plain jit inside
     # AotJit — never a dispatch break.
     from ..resilience import aot
-    factor_fn = jax.jit(lambda vsel: mapped(vsel, *idx_args))
+    # named for the profiler and for the persistent-cache key (the
+    # batched._phase_fns note)
+    @jax.jit
+    def slu_dist_factor(vsel):
+        return mapped(vsel, *idx_args)
+
+    factor_fn = slu_dist_factor
     if sharded_in:
         factor_fn = aot.wrap_jit(
             "dist_factor", factor_fn,
@@ -521,6 +528,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                 y_off=gs.y_off + di * gs.trim * g.wb,
                 u_off=gs.u_off + di * gs.trim * gs.rtrim)
 
+        @jax.named_scope("slu.lsum")
         def sync(cur, snap):
             new = snap + psum_exact(cur - snap, axis)
             return new, new
@@ -557,11 +565,11 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
         out_specs=P(), check_vma=False)
 
     @jax.jit
-    def solve(L_flat, U_flat, Li_flat, Ui_flat, b):
+    def slu_dist_solve_merged(L_flat, U_flat, Li_flat, Ui_flat, b):
         return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args)
 
-    solve = _aot_wrap_dist("dist_solve_merged", solve, dsched, mesh,
-                           axis, dtype, trans)
+    solve = _aot_wrap_dist("dist_solve_merged", slu_dist_solve_merged,
+                           dsched, mesh, axis, dtype, trans)
     return obs.watch_jit("dist_solve_merged", solve,
                          cost_phase="SOLVE")
 
@@ -656,11 +664,11 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
         out_specs=P(), check_vma=False)
 
     @jax.jit
-    def solve(L_flat, U_flat, Li_flat, Ui_flat, b):
+    def slu_dist_solve(L_flat, U_flat, Li_flat, Ui_flat, b):
         return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args)
 
-    solve = _aot_wrap_dist("dist_solve", solve, dsched, mesh, axis,
-                           dtype, trans)
+    solve = _aot_wrap_dist("dist_solve", slu_dist_solve, dsched, mesh,
+                           axis, dtype, trans)
     return obs.watch_jit("dist_solve", solve, cost_phase="SOLVE")
 
 

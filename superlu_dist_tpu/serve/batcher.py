@@ -265,31 +265,36 @@ class MicroBatcher:
         max_bucket = self.ladder[-1]
         while True:
             with self._cond:
-                while not self._pending and not self._closed:
-                    self._cond.wait()
-                if not self._pending and self._closed:
-                    return
-                # linger until the widest bucket fills or the oldest
-                # request has waited max_linger_s.  A pending deadline
-                # that cannot outlast the linger window forfeits it:
-                # flush IMMEDIATELY, so the solve gets the whole
-                # remaining budget instead of being dispatched at (or
-                # dropped after) the deadline — tight-deadline traffic
-                # trades batch occupancy for latency by construction
-                flush_at = self._pending[0].t_submit + self.max_linger_s
-                while (len(self._pending) < max_bucket
-                       and not self._closed):
-                    tight = any(
-                        r.deadline is not None
-                        and r.deadline - _DEADLINE_FLUSH_MARGIN_S
-                        < flush_at
-                        for r in self._pending)
-                    if tight:
-                        break
-                    remaining = flush_at - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(timeout=remaining)
+                # the flusher with nothing to feed the device: no
+                # request yet, or lingering for the bucket to fill
+                with obs.span("serve.wait", cat="serve"):
+                    while not self._pending and not self._closed:
+                        self._cond.wait()
+                    if not self._pending and self._closed:
+                        return
+                    # linger until the widest bucket fills or the
+                    # oldest request has waited max_linger_s.  A
+                    # pending deadline that cannot outlast the linger
+                    # window forfeits it: flush IMMEDIATELY, so the
+                    # solve gets the whole remaining budget instead of
+                    # being dispatched at (or dropped after) the
+                    # deadline — tight-deadline traffic trades batch
+                    # occupancy for latency by construction
+                    flush_at = (self._pending[0].t_submit
+                                + self.max_linger_s)
+                    while (len(self._pending) < max_bucket
+                           and not self._closed):
+                        tight = any(
+                            r.deadline is not None
+                            and r.deadline - _DEADLINE_FLUSH_MARGIN_S
+                            < flush_at
+                            for r in self._pending)
+                        if tight:
+                            break
+                        remaining = flush_at - time.monotonic()
+                        if remaining <= 0:
+                            break
+                        self._cond.wait(timeout=remaining)
                 batch = self._pending[:max_bucket]
                 del self._pending[:len(batch)]
                 # claimed but unresolved: visible to _flusher_died
@@ -327,7 +332,6 @@ class MicroBatcher:
             live.append(r)
         if not live:
             return
-        t0 = time.monotonic()
         k = bucket_for(len(live), self.ladder)
         # per-request flight linkage: one recorder-global batch id
         # ties the records dispatched together (None when off).  The
@@ -335,6 +339,17 @@ class MicroBatcher:
         # request, appended after the solve — this loop runs on the
         # flusher thread, the serve throughput bottleneck.
         bid = flight.next_batch_id()
+        # one span a batch on the flusher's thread, parent of its
+        # three stages: assemble, batch_solve, fanout
+        with obs.span("serve.batch", cat="serve",
+                      args={"batch": (bid if bid is not None
+                                      else self.batches_dispatched),
+                            "live": len(live), "bucket": k}):
+            self._serve_batch(live, now, k, bid)
+
+    def _serve_batch(self, live: list[_Request], now: float, k: int,
+                     bid) -> None:
+        t0 = time.monotonic()
         with obs.span("serve.assemble", cat="serve",
                       args={"batch": len(live), "nrhs": k}):
             B = np.zeros((self.lu.n, k), dtype=self.dtype)
@@ -374,18 +389,20 @@ class MicroBatcher:
         # p99 latency attribution in obs/flight.py needs to know
         # whether the merged lsum kernel or the legacy sweep ran
         arm = _trisolve_arm(self.lu) if bid is not None else None
-        for j, r in enumerate(live):
-            if r.flight is not None:
-                r.flight.event(
-                    "queue", wait_us=int((now - r.t_submit) * 1e6),
-                    batch=bid, bucket=k, occupancy=occ,
-                    solve_us=solve_us, arm=arm,
-                    mesh=self._mesh_leg)
-            if r.deadline is not None and done > r.deadline:
-                # the work is done, but a missed deadline must never
-                # read as success — the caller already moved on
-                self.metrics.inc("batcher.deadline_missed")
-                r.future.set_exception(DeadlineExceeded(
-                    "solved after deadline"))
-            else:
-                r.future.set_result(np.array(X[:, j]))
+        with obs.span("serve.fanout", cat="serve"):
+            for j, r in enumerate(live):
+                if r.flight is not None:
+                    r.flight.event(
+                        "queue", wait_us=int((now - r.t_submit) * 1e6),
+                        batch=bid, bucket=k, occupancy=occ,
+                        solve_us=solve_us, arm=arm,
+                        mesh=self._mesh_leg)
+                if r.deadline is not None and done > r.deadline:
+                    # the work is done, but a missed deadline must
+                    # never read as success — the caller already
+                    # moved on
+                    self.metrics.inc("batcher.deadline_missed")
+                    r.future.set_exception(DeadlineExceeded(
+                        "solved after deadline"))
+                else:
+                    r.future.set_result(np.array(X[:, j]))

@@ -295,3 +295,244 @@ def test_stats_measured_cost_adoption():
     # mirroring add_ops/utime (gflops stays per-run consistent)
     st.set_measured_cost("FACT", {"flops": 2e9})
     assert st.ops_measured["FACT"] == 1e10
+
+
+# --------------------------------------------------------------------
+# the profiler sink: a live jax.profiler session is the switch
+# --------------------------------------------------------------------
+
+def _slu_events(trace_dir):
+    """(line, name, start_ns, end_ns, stats) of every `slu.*` host
+    event in the session's .xplane.pb; a line is one thread."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("slu."):
+                    out.append(((plane.name, li), ev.name,
+                                int(ev.start_ns),
+                                int(ev.start_ns + ev.duration_ns),
+                                dict(ev.stats)))
+    return out
+
+
+def _session(tmp_path_factory, name):
+    import jax
+    d = str(tmp_path_factory.mktemp(name))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    return d
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One profiler session on the CPU over a refactorization, two
+    solves and a served batch; and the same solve outside it."""
+    import jax
+    from superlu_dist_tpu import Stats, plan_factorization
+    from superlu_dist_tpu.serve import MicroBatcher
+    obs.configure(enabled=False)
+    a = _testmat()
+    opts = Options(factor_dtype="float32")
+    plan = plan_factorization(a, opts)
+    b = a.to_scipy() @ np.random.default_rng(5).standard_normal(a.n)
+    x_plain = solve(factorize(a, opts, plan=plan), b)
+    assert obs.span("x") is obs.NULL_SPAN
+    d = _session(tmp_path_factory, "prof")
+    try:
+        st = Stats()
+        lu = factorize(a, opts, plan=plan, stats=st)
+        xs = [solve(lu, b, stats=st), solve(lu, b, stats=st)]
+        steps = st.refine_steps     # the batcher's solve counts on
+        with obs.span("test.caller"):
+            mb = MicroBatcher(lu, max_linger_s=0.001, ladder=(1, 4))
+            x_served = mb.submit(b).result(timeout=60)
+            mb.close()
+    finally:
+        jax.profiler.stop_trace()
+    return {"events": _slu_events(d), "refine_steps": steps, "xs": xs,
+            "x_plain": x_plain, "x_served": x_served}
+
+
+def _inside(events, child, parent):
+    """Every `child` event lies inside a `parent` event of its line."""
+    kids = [e for e in events if e[1] == child]
+    assert kids, child
+    for line, _, s, e, _ in kids:
+        assert any(pl == line and ps <= s and e <= pe
+                   for pl, pn, ps, pe, _ in events if pn == parent), \
+            (child, parent)
+
+
+def test_profiler_session_solve_spans(profiled):
+    """(a) factorize + two solves under a session: the phases and the
+    new leaves are in the .xplane.pb, the pack exactly once (the
+    second solve hits), one REFINE_STEP a counted step, children
+    inside their parents."""
+    evs = profiled["events"]
+    count = {}
+    for _, name, *_ in evs:
+        count[name] = count.get(name, 0) + 1
+    for name in ("slu.FACT", "slu.SOLVE", "slu.REFINE",
+                 "slu.solve.sweep", "slu.solve.fetch",
+                 "slu.REFINE_STEP", "slu.refine.residual"):
+        assert count.get(name), name
+    assert count["slu.solve.pack"] == 1
+    steps = profiled["refine_steps"]
+    assert steps > 0
+    # the caller's two solves; the batcher's are not theirs
+    caller = next(e[0] for e in evs if e[1] == "slu.test.caller")
+    mine = [e for e in evs if e[0] == caller
+            and not any(c[1] == "slu.test.caller"
+                        and c[2] <= e[2] and e[3] <= c[3]
+                        for c in evs)]
+    assert sum(e[1] == "slu.REFINE_STEP" for e in mine) == steps
+    # a residual before the loop and one a step, for each solve
+    assert sum(e[1] == "slu.refine.residual" for e in mine) == steps + 2
+    _inside(evs, "slu.solve.pack", "slu.SOLVE")
+    _inside(evs, "slu.solve.fetch", "slu.solve.sweep")
+    _inside(evs, "slu.REFINE_STEP", "slu.REFINE")
+    _inside(evs, "slu.refine.residual", "slu.REFINE")
+    # the pack is the sweep's elder sibling, never its parent or child
+    (pack,) = [e for e in evs if e[1] == "slu.solve.pack"]
+    for e in evs:
+        if e[1] == "slu.solve.sweep" and e[0] == pack[0]:
+            assert e[2] >= pack[3] or e[3] <= pack[2]
+    sweep = next(e for e in evs if e[1] == "slu.solve.sweep")
+    assert sweep[4]["nrhs"] == 1 and sweep[4]["trans"] == 0
+    assert pack[4]["groups"] >= 1
+
+
+def test_profiler_session_flusher_spans(profiled):
+    """(b) a served request: wait, batch (with its `batch` stat) and
+    its three stages on ONE thread that is not the caller's."""
+    evs = profiled["events"]
+    caller = next(e[0] for e in evs if e[1] == "slu.test.caller")
+    names = ("slu.serve.wait", "slu.serve.batch", "slu.serve.assemble",
+             "slu.serve.batch_solve", "slu.serve.fanout")
+    lines = {e[0] for e in evs if e[1] in names}
+    assert {e[1] for e in evs} >= set(names)
+    assert len(lines) == 1 and caller not in lines
+    batch = next(e for e in evs if e[1] == "slu.serve.batch")
+    assert batch[4]["live"] == 1 and batch[4]["bucket"] == 1
+    assert isinstance(batch[4]["batch"], int)
+    for stage in names[2:]:
+        _inside(evs, stage, "slu.serve.batch")
+    # the solve the flusher runs names its own phases there too
+    (flusher,) = lines
+    assert any(e[0] == flusher and e[1] == "slu.solve.sweep"
+               for e in evs)
+
+
+def test_profiler_session_is_the_switch(tmp_path_factory):
+    """(c) the session is the only switch: inside it a span is the
+    profiler's annotation, before and after it the shared no-op, and
+    the no-op path keeps the bound this file gives it."""
+    import jax
+    obs.configure(enabled=False)
+    assert obs.span("x") is obs.NULL_SPAN
+    _session(tmp_path_factory, "switch")
+    try:
+        assert obs.span("x") is not obs.NULL_SPAN
+        assert not obs.enabled()        # SLU_OBS gates stay off
+    finally:
+        jax.profiler.stop_trace()
+    assert obs.span("x") is obs.NULL_SPAN
+    assert obs.span("y", args={"k": 1}) is obs.NULL_SPAN
+    t0 = time.perf_counter()
+    for _ in range(200_000):
+        with obs.span("phase"):
+            pass
+    wall = time.perf_counter() - t0
+    assert wall < 2.0, f"disabled span path too slow: {wall:.3f}s"
+
+
+def test_profiler_session_both_sinks(tmp_path_factory):
+    """SLU_OBS tracer and a session together: one call site writes
+    both, with the tracer's own names."""
+    import jax
+    t = obs.configure(enabled=True)
+    d = _session(tmp_path_factory, "both")
+    try:
+        with obs.span("outer", args={"k": 2}):
+            obs.instant("mark")
+            obs.complete("late", 0.001)
+    finally:
+        jax.profiler.stop_trace()
+        obs.configure(enabled=False)
+    assert {e["name"] for e in t.events()} == {"outer", "mark", "late"}
+    prof = {e[1]: e for e in _slu_events(d)}
+    # a retrospective span cannot be written into the profiler
+    assert set(prof) == {"slu.outer", "slu.mark"}
+    assert prof["slu.outer"][4]["k"] == 2
+
+
+def test_profiler_session_changes_no_answer(profiled):
+    """(d) bit-identical answers with and without a session (the
+    ferr trajectory, which costs work, stays SLU_OBS's)."""
+    for x in profiled["xs"]:
+        assert np.array_equal(x, profiled["x_plain"])
+    assert np.array_equal(np.asarray(profiled["x_served"]),
+                          profiled["x_plain"])
+
+
+_SCOPES = {"factor": ("slu.assemble", "slu.extend_add",
+                      "slu.partial_lu", "slu.tri_inverse",
+                      "slu.schur", "slu.store"),
+           "solve": ("slu.fwd", "slu.bwd", "slu.lsum"),
+           "resid": ("slu.resid",)}
+
+
+@pytest.fixture(scope="module")
+def lowered_op_names():
+    """op_name metadata of the lowered factor, packed-solve and
+    device-SpMV programs, by program."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from superlu_dist_tpu.ops import batched, spmv, trisolve
+    from superlu_dist_tpu.utils.testmat import laplacian_3d
+    a = laplacian_3d(6)
+    d = factorize(a, Options(factor_dtype="float32"),
+                  backend="jax").device_lu
+    factor_fn, _ = batched._phase_fns(
+        d.schedule, d.dtype, batched._thresh_for(d.plan, d.dtype))
+    solve_fn = trisolve._solve_packed_fn(d.schedule, d.dtype, False)[0]
+    texts = {
+        "factor": factor_fn.lower(
+            jnp.zeros(len(d.plan.coo_rows), jnp.float32)),
+        "solve": solve_fn.lower(trisolve.get_packs(d),
+                                jnp.zeros((a.n, 1), jnp.float32)),
+        "resid": jax.jit(spmv.ell_spmv).lower(
+            jnp.zeros((8, 3), jnp.int32), jnp.zeros((8, 3)),
+            jnp.zeros(8)),
+    }
+    return {k: (set(re.findall(r"slu\.[a-z_]+",
+                               low.as_text(debug_info=True))),
+                re.search(r"module @(\w+)", low.as_text()).group(1))
+            for k, low in texts.items()}
+
+
+@pytest.mark.parametrize(
+    "program,scope",
+    [(p, s) for p, scopes in _SCOPES.items() for s in scopes])
+def test_kernel_scope_in_lowered_program(lowered_op_names, program,
+                                         scope):
+    """(e) every name of the kernels' vocabulary is in the op_name
+    metadata of the program that runs it — and nothing outside the
+    vocabulary is."""
+    names, _ = lowered_op_names[program]
+    assert scope in names
+    assert names <= {s for v in _SCOPES.values() for s in v}
+
+
+def test_watched_programs_are_named(lowered_op_names):
+    """The programs' own names key the persistent compile cache,
+    which the scopes do not: a stable `slu_*` name each."""
+    assert lowered_op_names["factor"][1] == "jit_slu_factor"
+    assert lowered_op_names["solve"][1] == "jit_slu_solve_packed"
