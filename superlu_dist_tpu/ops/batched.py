@@ -1575,7 +1575,7 @@ def _slice_panel(flat, off, size: int, shape: tuple):
     flat, handling both storages: a 1-D flat yields the panel array; a
     (2, N) stacked real/imag flat yields an (Ar, Ai) pair for
     _mm_enc.  `off` may be a traced jnp scalar (the in-program sweep)
-    or a host int (the eager trisolve pack) — the plane index matches
+    or a host int (`trisolve.pack_panels`) — the plane index matches
     its dtype either way (dynamic_slice requires uniform index
     dtypes)."""
     if flat.ndim == 2:
@@ -2041,7 +2041,7 @@ def _staged_sweeps(sched, panels, bf, dtype, trans: bool,
     if trisolve.trisolve_mode() == "merged":
         ts = trisolve.get_trisolve(sched)
         if packs is None:
-            packs = trisolve.pack_panels_staged(ts, panels)
+            packs = trisolve.pack_device(sched, panels)
         return trisolve.staged_sweeps(ts, packs, bf, dtype, trans,
                                       pair=pair)
     dtype = np.dtype(dtype)
@@ -2811,8 +2811,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
             b = jnp.asarray(b).astype(rrdt if pair else rdt)
             # pack the solve panels once per factorization so the
             # refinement loop's repeated sweeps skip the re-slice
-            packs = (trisolve.pack_panels_staged(
-                         trisolve.get_trisolve(sched), panels)
+            packs = (trisolve.pack_device(sched, panels)
                      if trisolve.trisolve_mode() == "merged"
                      else None)
 

@@ -402,45 +402,68 @@ def get_trisolve(sched) -> TrisolveSchedule:
         return cache[key]
 
 
+def _sched_fn(sched, key, build):
+    """The watched program cached on a schedule under `key`, built
+    once (`build()`) under the lock; the hit path takes none."""
+    cache = getattr(sched, "_trisolve_fns", None)
+    if cache is not None:
+        fn = cache.get(key)
+        if fn is not None:
+            return fn
+    with _build_lock:
+        cache = getattr(sched, "_trisolve_fns", None)
+        if cache is None:
+            cache = sched._trisolve_fns = {}
+        if key not in cache:
+            cache[key] = build()
+        return cache[key]
+
+
 # --------------------------------------------------------------------
 # packed solve panels
 # --------------------------------------------------------------------
 
+def _trim_pack(g, gs, Lp, Up, Li, Ui):
+    """One group's (Li, L21, Ui, U12) from its four full panels: the
+    dead padded lanes beyond `trim` dropped, the diagonal blocks cut
+    off L and U.  Panels in either storage form (`_psub`)."""
+    from .batched import _psub
+    t, wb = gs.trim, g.wb
+    return (_psub(Li, lambda p: p[:t]),
+            _psub(Lp, lambda p: p[:t, wb:, :]),         # L21
+            _psub(Ui, lambda p: p[:t]),
+            _psub(Up, lambda p: p[:t, :, wb:]))         # U12
+
+
 def pack_panels(ts: TrisolveSchedule, flats):
     """Slice the four solve operand families — Li, L21, Ui, U12 — out
     of the factor flats, dead lanes dropped, as a per-group list.
-    Traceable: runs inside the fused programs (where XLA hoists it
-    out of the refinement while_loop) and eagerly for the packed
-    FACTORED path (once per factorization, cached on the handle).
-    Pair-stored (2, N) flats pack to (Ar, Ai) tuples — the `_mm_enc`
-    operand form."""
-    from .batched import _psub, _slice_panel
+    Traceable: the fused and mesh programs call it in their own
+    traces (where XLA hoists it out of the refinement while_loop).
+    The packed FACTORED path does not come through here: its one pack
+    program a factorization (`pack_device`) cuts the flats into
+    per-group flats and runs `pack_panels_staged` on them, to the
+    same leaves.  Pair-stored (2, N) flats pack to (Ar, Ai) tuples —
+    the `_mm_enc` operand form."""
+    from .batched import _slice_panel
     L_flat, U_flat, Li_flat, Ui_flat = flats
-    sched = ts.sched
-    packs = []
-    for g, gs in zip(sched.groups, ts.groups):
-        t = gs.trim
-        Lp = _slice_panel(L_flat, g.L_off, g.n_loc * g.mb * g.wb,
-                          (g.n_loc, g.mb, g.wb))
-        Up = _slice_panel(U_flat, g.U_off, g.n_loc * g.wb * g.mb,
-                          (g.n_loc, g.wb, g.mb))
-        Li = _slice_panel(Li_flat, g.Li_off, g.n_loc * g.wb * g.wb,
-                          (g.n_loc, g.wb, g.wb))
-        Ui = _slice_panel(Ui_flat, g.Ui_off, g.n_loc * g.wb * g.wb,
-                          (g.n_loc, g.wb, g.wb))
-        wb = g.wb
-        packs.append((
-            _psub(Li, lambda p: p[:t]),
-            _psub(Lp, lambda p, wb=wb: p[:t, wb:, :]),      # L21
-            _psub(Ui, lambda p: p[:t]),
-            _psub(Up, lambda p, wb=wb: p[:t, :, wb:]),      # U12
-        ))
-    return packs
+    return [_trim_pack(
+        g, gs,
+        _slice_panel(L_flat, g.L_off, g.n_loc * g.mb * g.wb,
+                     (g.n_loc, g.mb, g.wb)),
+        _slice_panel(U_flat, g.U_off, g.n_loc * g.wb * g.mb,
+                     (g.n_loc, g.wb, g.mb)),
+        _slice_panel(Li_flat, g.Li_off, g.n_loc * g.wb * g.wb,
+                     (g.n_loc, g.wb, g.wb)),
+        _slice_panel(Ui_flat, g.Ui_off, g.n_loc * g.wb * g.wb,
+                     (g.n_loc, g.wb, g.wb)))
+        for g, gs in zip(ts.sched.groups, ts.groups)]
 
 
 def pack_panels_staged(ts: TrisolveSchedule, panels):
-    """pack_panels for StagedLU per-group local flats (offset 0)."""
-    from .batched import _psub
+    """pack_panels for per-group local flats (offset 0): a StagedLU's
+    panels, or a DeviceLU's flats cut by `_group_flats`.  The body of
+    the pack program (`_pack_fn`) for both handle forms."""
 
     def view(flat, shape):
         if getattr(flat, "ndim", 1) == 2:      # (2, N) pair planes
@@ -448,28 +471,80 @@ def pack_panels_staged(ts: TrisolveSchedule, panels):
             return (P[0], P[1])
         return flat.reshape(shape)
 
-    sched = ts.sched
-    packs = []
-    for g, gs, p in zip(sched.groups, ts.groups, panels):
-        t = gs.trim
-        L, U, Li, Ui = p
-        Lp = view(L, (g.n_loc, g.mb, g.wb))
-        Up = view(U, (g.n_loc, g.wb, g.mb))
-        Lip = view(Li, (g.n_loc, g.wb, g.wb))
-        Uip = view(Ui, (g.n_loc, g.wb, g.wb))
-        wb = g.wb
-        packs.append((
-            _psub(Lip, lambda pp: pp[:t]),
-            _psub(Lp, lambda pp, wb=wb: pp[:t, wb:, :]),
-            _psub(Uip, lambda pp: pp[:t]),
-            _psub(Up, lambda pp, wb=wb: pp[:t, :, wb:]),
-        ))
-    return packs
+    return [_trim_pack(g, gs,
+                       view(L, (g.n_loc, g.mb, g.wb)),
+                       view(U, (g.n_loc, g.wb, g.mb)),
+                       view(Li, (g.n_loc, g.wb, g.wb)),
+                       view(Ui, (g.n_loc, g.wb, g.wb)))
+            for g, gs, (L, U, Li, Ui) in zip(ts.sched.groups,
+                                             ts.groups, panels)]
+
+
+def _group_flats(sched, flats):
+    """A DeviceLU's four flats cut at the groups' offsets into the
+    per-group local flats a StagedLU holds (its `panels`; the flats
+    ARE those concatenated in group order).  Either storage: the
+    cut runs along the last axis, so (2, N) planes give (2, size)."""
+
+    def cut(flat, off, size):
+        return jax.lax.slice_in_dim(flat, off, off + size,
+                                    axis=flat.ndim - 1)
+
+    L, U, Li, Ui = flats
+    return [(cut(L, g.L_off, g.n_loc * g.mb * g.wb),
+             cut(U, g.U_off, g.n_loc * g.wb * g.mb),
+             cut(Li, g.Li_off, g.n_loc * g.wb * g.wb),
+             cut(Ui, g.Ui_off, g.n_loc * g.wb * g.wb))
+            for g in sched.groups]
+
+
+def _pack_fn(sched):
+    """Cached watched jit of the pack for one schedule: `fn(store)`
+    -> PackSet, where `store` is a DeviceLU's four factor flats (1-D,
+    or (2, N) pair planes) or a StagedLU's per-group panels; jax keys
+    the trace on the store's structure and avals.  Lives beside the
+    packed solve programs on the schedule, so every refactorization
+    on a held plan dispatches the program its first one compiled.
+
+    One body for both forms: flats are first cut into the per-group
+    local flats the staged form holds.  The barrier after the cut is
+    load-bearing on the TPU: without it the compiler moves a
+    panel's reshape before its static slice wherever the offset is
+    not tile-aligned, i.e. reshapes the WHOLE flat to (N/wb, wb),
+    padded 16-fold at wb=8, once a group (n=27,000, v5e: 75 s of
+    compile, 220 MB of code and 1.1 GB of scratch, against 2 s,
+    11 MB and none with it)."""
+    from .. import obs
+
+    def build():
+        ts = get_trisolve(sched)
+
+        # named for the profiler (the batched._phase_fns note)
+        @jax.jit
+        def slu_pack(store):
+            if hasattr(store[0], "ndim"):       # four flats, not panels
+                store = jax.lax.optimization_barrier(
+                    _group_flats(sched, store))
+            return PackSet(pack_panels_staged(ts, store))
+
+        return obs.watch_jit("pack", slu_pack)
+
+    return _sched_fn(sched, ("pack", merge_cells_limit(),
+                             seg_cells_limit()), build)
+
+
+def pack_device(sched, store) -> PackSet:
+    """The packed solve panels of one factorization in ONE device
+    program: `store` is a DeviceLU's four flats or a StagedLU's
+    panels.  The flats are not donated (get_diag_u, the legacy sweep,
+    gscon and autodiff still read them)."""
+    return _pack_fn(sched)(store)
 
 
 def get_packs(device_lu):
-    """Per-handle packed panels, built once per factorization on the
-    first solve and cached — the solve-optimized mirror of the factor
+    """Per-handle packed panels, built by one device program
+    (`jit_slu_pack`) on the first solve of each factorization and
+    cached on the handle — the solve-optimized mirror of the factor
     slabs (the reference keeps dedicated lsum solve structures the
     same way; costs one extra ~factor-sized HBM residency, see
     DESIGN.md §16)."""
@@ -482,21 +557,15 @@ def get_packs(device_lu):
         ent = getattr(device_lu, "_trisolve_packs", None)
         if ent is not None and ent[0] == key:
             return ent[1]
-        ts = get_trisolve(device_lu.schedule)
-        panels = getattr(device_lu, "panels", None)
+        store = getattr(device_lu, "panels", None)
+        if store is None:
+            store = (device_lu.L_flat, device_lu.U_flat,
+                     device_lu.Li_flat, device_lu.Ui_flat)
         # the miss path only: a hit opens no span
         with obs.span("solve.pack", cat="solve",
-                      args={"groups": len(ts.groups)}):
-            if panels is not None:
-                packs = pack_panels_staged(ts, panels)
-            else:
-                # eager (op-by-op) slicing: one-time per
-                # factorization, no throwaway jit compile
-                packs = pack_panels(ts, (device_lu.L_flat,
-                                         device_lu.U_flat,
-                                         device_lu.Li_flat,
-                                         device_lu.Ui_flat))
-        packs = PackSet(packs)
+                      args={"groups": len(device_lu.schedule.groups),
+                            "programs": 1}):
+            packs = pack_device(device_lu.schedule, store)
         device_lu._trisolve_packs = (key, packs)
         return packs
 
@@ -705,20 +774,10 @@ def _solve_packed_fn(sched, dtype, pair: bool):
     ('solve'), so the serve zero-recompile gate and the per-signature
     cost attribution see one unified solve surface."""
     from .. import obs
-    key = _packed_key(dtype, pair)
-    cache = getattr(sched, "_trisolve_fns", None)
-    if cache is not None:
-        fn = cache.get(key)
-        if fn is not None:
-            return fn
-    with _build_lock:
-        cache = getattr(sched, "_trisolve_fns", None)
-        if cache is None:
-            cache = sched._trisolve_fns = {}
-        if key in cache:
-            return cache[key]
+
+    def build():
         ts = get_trisolve(sched)
-        dtype = np.dtype(dtype)
+        dt = np.dtype(dtype)
 
         # TWO positional-only jits instead of one with a static
         # `trans` kwarg: a static_argnames keyword call drops jax to
@@ -737,10 +796,10 @@ def _solve_packed_fn(sched, dtype, pair: bool):
             @jax.jit
             def slu_solve_packed(packs, b):
                 with jax.default_matmul_precision("float32"):
-                    return sweep(ts, packs, b, dtype, trans,
+                    return sweep(ts, packs, b, dt, trans,
                                  pair=pair)
             wrapped = slu_solve_packed
-            if not pair and np.dtype(dtype).kind != "c":
+            if not pair and dt.kind != "c":
                 # complex lanes skip AOT: the complex-on-TPU gate
                 # executes them on the host CPU under a TPU default
                 # backend, and an export records one platform (the
@@ -749,12 +808,13 @@ def _solve_packed_fn(sched, dtype, pair: bool):
                     f"solve_packed.{'T' if trans else 'N'}",
                     slu_solve_packed,
                     aot.schedule_fingerprint(
-                        sched, dtype, extra=("packed", bool(pair))))
+                        sched, dt, extra=("packed", bool(pair))))
             return obs.watch_jit("solve", wrapped,
                                  cost_phase="SOLVE")
 
-        cache[key] = (mk(False), mk(True))
-        return cache[key]
+        return mk(False), mk(True)
+
+    return _sched_fn(sched, _packed_key(dtype, pair), build)
 
 
 def solve_packed(lu, bb, trans: bool):
@@ -925,6 +985,17 @@ def _contract_build_packed_solve():
     return fn, (get_packs(d), jnp.zeros((a.n, 1), jnp.float32)), {}
 
 
+def _contract_build_pack():
+    from .. import factorize
+    from ..options import Options
+    from ..utils.testmat import laplacian_3d
+    lu = factorize(laplacian_3d(8), Options(factor_dtype="float32"),
+                   backend="jax")
+    d = lu.device_lu
+    flats = (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)
+    return _pack_fn(d.schedule), (flats,), {}
+
+
 def _contract_build_staged_fwd_segment():
     import jax.numpy as jnp
 
@@ -954,6 +1025,14 @@ HLO_CONTRACTS = (
      "note": "the legacy sweep's scatter-adds were the slowest op "
              "class at nrhs=1 (PR 7); the packed lsum layout must "
              "stay scatter-free"},
+    {"name": "trisolve.pack",
+     "phase": "pack",
+     "env": {"SLU_TRISOLVE": "merged"},
+     "contracts": ("no_scatter", "no_host_callback"),
+     "build": _contract_build_pack,
+     "note": "one program of static slices a factorization: a "
+             "scatter or a host round trip here is paid by every "
+             "step of a time-stepper"},
     {"name": "trisolve.staged_fwd_segment",
      "phase": "solve",
      "env": {"SLU_TRISOLVE": "merged", "SLU_STAGED": "1"},

@@ -490,8 +490,8 @@ _SCOPES = {"factor": ("slu.assemble", "slu.extend_add",
 
 @pytest.fixture(scope="module")
 def lowered_op_names():
-    """op_name metadata of the lowered factor, packed-solve and
-    device-SpMV programs, by program."""
+    """op_name metadata of the lowered factor, packed-solve, pack
+    and device-SpMV programs, by program."""
     import re
     import jax
     import jax.numpy as jnp
@@ -508,6 +508,8 @@ def lowered_op_names():
             jnp.zeros(len(d.plan.coo_rows), jnp.float32)),
         "solve": solve_fn.lower(trisolve.get_packs(d),
                                 jnp.zeros((a.n, 1), jnp.float32)),
+        "pack": trisolve._pack_fn(d.schedule).lower(
+            (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)),
         "resid": jax.jit(spmv.ell_spmv).lower(
             jnp.zeros((8, 3), jnp.int32), jnp.zeros((8, 3)),
             jnp.zeros(8)),
@@ -536,3 +538,4 @@ def test_watched_programs_are_named(lowered_op_names):
     which the scopes do not: a stable `slu_*` name each."""
     assert lowered_op_names["factor"][1] == "jit_slu_factor"
     assert lowered_op_names["solve"][1] == "jit_slu_solve_packed"
+    assert lowered_op_names["pack"][1] == "jit_slu_pack"

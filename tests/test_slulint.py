@@ -300,6 +300,7 @@ def test_registry_covers_the_acceptance_invariants():
     zero-scatter, df64 zero-f64."""
     names = {e["name"]: e for e in contracts.iter_contracts()}
     assert "no_scatter" in names["trisolve.packed_solve"]["contracts"]
+    assert "no_scatter" in names["trisolve.pack"]["contracts"]
     assert "no_scatter" in names["residual.ell_spmv"]["contracts"]
     assert "no_f64" in names["df64.fused_core"]["contracts"]
     assert "check" in names["df64.eft_mul"]          # EFT probe

@@ -175,6 +175,7 @@ def test_packed_program_scatter_free():
     three drifting copies."""
     from tools.slulint.contracts import assert_contract
     assert_contract("trisolve.packed_solve")
+    assert_contract("trisolve.pack")
     assert_contract("trisolve.staged_fwd_segment")
 
 
