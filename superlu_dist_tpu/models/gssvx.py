@@ -169,6 +169,13 @@ def factorize(a: CSRMatrix, options: Options | None = None,
             raise ValueError(f"unknown backend {backend!r}")
     lu.options = options
     stats.add_ops(_phase, plan.factor_flops)
+    # useful against executed flops of THIS factorization: the plan's
+    # count, and the same formula over the schedule's bucket-padded
+    # slots (the host oracle pads nothing)
+    sched = getattr(lu.device_lu, "schedule", None)
+    stats.factor_flops = plan.factor_flops
+    stats.factor_flops_executed = (sched.executed_flops if sched
+                                   else plan.factor_flops)
     # XLA cost-analysis flop accounting (SLU_OBS_COST=1): the program
     # cost the backend stamped for THIS call (thread-local hand-off,
     # obs/compile_watch.py), accumulated per factorization like
@@ -205,7 +212,9 @@ def factorize(a: CSRMatrix, options: Options | None = None,
         dtype=options.factor_dtype,
         perturbation=(lu.ledger.to_dict() if lu.ledger.perturbed
                       else None),
-        mem=mem)
+        mem=mem,
+        flops={"useful": stats.factor_flops,
+               "executed": stats.factor_flops_executed})
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,

@@ -63,13 +63,16 @@ class HealthMonitor:
                       pivot_growth: float | None = None,
                       dtype: str = "",
                       perturbation: dict | None = None,
-                      mem: dict | None = None) -> None:
+                      mem: dict | None = None,
+                      flops: dict | None = None) -> None:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
         so snapshot() exposes WHERE and how much, not just a lifetime
         count.  `mem` is the device-memory watermark record
-        (obs/memory.py) — every factorization carries one."""
+        (obs/memory.py) — every factorization carries one.  `flops`
+        is its {useful, executed} flop count (Stats.factor_flops,
+        Stats.factor_flops_executed)."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -85,6 +88,7 @@ class HealthMonitor:
                 "perturbation": (dict(perturbation)
                                  if perturbation is not None else None),
                 "mem": dict(mem) if mem is not None else None,
+                "flops": dict(flops) if flops is not None else None,
             })
         if tiny_pivots:
             _tracer.instant("health.tiny_pivots", cat="health",

@@ -239,6 +239,19 @@ class BatchedSchedule:
     # block lane exists (the legacy +1 sentinel slot).
     upd_pad: int = 1
 
+    @functools.cached_property
+    def executed_flops(self) -> float:
+        """plan/frontal.front_flops over every scheduled slot at its
+        bucket shape (wb, mb - wb), padding slots included: what the
+        factor program runs, against the plan's useful
+        `factor_flops` (ROADMAP S3: executed vs useful, always).  A
+        cooperative group's fronts are shared by the devices and
+        count once."""
+        from ..plan.frontal import front_flops
+        return float(sum(
+            g.n_loc * (1 if g.coop else self.ndev)
+            * front_flops(g.wb, g.mb - g.wb) for g in self.groups))
+
     def comm_summary(self, dtype=np.float64, nrhs: int = 1) -> dict:
         """Static per-step collective traffic (the SCT_t comm-volume
         counters, SRC/util_dist.h:194-317, computed from the schedule

@@ -102,6 +102,12 @@ class Stats:
     lu_nnz: int = 0
     lu_bytes: int = 0
     workspace_bytes: int = 0
+    # flops of the LAST factorization under this Stats: the plan's
+    # useful count (plan/frontal.front_flops over the fronts' (w, r))
+    # and what the schedule executes (the same formula over every
+    # scheduled slot at its bucket shape, padding slots included)
+    factor_flops: float = 0.0
+    factor_flops_executed: float = 0.0
     # collective traffic: predicted from the schedule (comm_summary)
     # and measured from the compiled HLO (hlo_collective_stats) — the
     # SCT_print3D comm-volume contract
@@ -195,6 +201,8 @@ class Stats:
             "escalations": self.escalations,
             "lu_nnz": self.lu_nnz,
             "lu_bytes": self.lu_bytes,
+            "factor_flops": self.factor_flops,
+            "factor_flops_executed": self.factor_flops_executed,
             "comm_predicted": dict(self.comm_predicted),
             "factor_events": [dict(e) for e in self.factor_events],
             "mem_watermarks": dict(self.mem_watermarks),
@@ -248,6 +256,12 @@ class Stats:
         if self.lu_nnz:
             lines.append(
                 f"  nnz(L+U): {self.lu_nnz}  LU bytes: {self.lu_bytes}")
+        if self.factor_flops_executed:
+            share = 100.0 * self.factor_flops / self.factor_flops_executed
+            lines.append(
+                f"  factor flops: {self.factor_flops:.4g} useful, "
+                f"{self.factor_flops_executed:.4g} executed "
+                f"({share:.1f} %)")
         if self.comm_predicted:
             lines.append("** Collective traffic (predicted) **")
             for k, v in self.comm_predicted.items():
