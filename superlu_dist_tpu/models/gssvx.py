@@ -176,6 +176,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     stats.factor_flops = plan.factor_flops
     stats.factor_flops_executed = (sched.executed_flops if sched
                                    else plan.factor_flops)
+    stats.ea_elements = sched.ea_elements if sched else {}
     # XLA cost-analysis flop accounting (SLU_OBS_COST=1): the program
     # cost the backend stamped for THIS call (thread-local hand-off,
     # obs/compile_watch.py), accumulated per factorization like
@@ -214,7 +215,8 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                       else None),
         mem=mem,
         flops={"useful": stats.factor_flops,
-               "executed": stats.factor_flops_executed})
+               "executed": stats.factor_flops_executed},
+        extend_add=stats.ea_elements)
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,

@@ -64,7 +64,8 @@ class HealthMonitor:
                       dtype: str = "",
                       perturbation: dict | None = None,
                       mem: dict | None = None,
-                      flops: dict | None = None) -> None:
+                      flops: dict | None = None,
+                      extend_add: dict | None = None) -> None:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
@@ -72,7 +73,8 @@ class HealthMonitor:
         count.  `mem` is the device-memory watermark record
         (obs/memory.py) — every factorization carries one.  `flops`
         is its {useful, executed} flop count (Stats.factor_flops,
-        Stats.factor_flops_executed)."""
+        Stats.factor_flops_executed), `extend_add` its extend-add
+        elements by lane (Stats.ea_elements)."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -89,6 +91,8 @@ class HealthMonitor:
                                  if perturbation is not None else None),
                 "mem": dict(mem) if mem is not None else None,
                 "flops": dict(flops) if flops is not None else None,
+                "extend_add": ({k: dict(v) for k, v in extend_add.items()}
+                               if extend_add else None),
             })
         if tiny_pivots:
             _tracer.instant("health.tiny_pivots", cat="health",

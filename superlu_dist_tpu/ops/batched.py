@@ -81,6 +81,18 @@ def _pad_pos(pos: np.ndarray, w: int, wb: int) -> np.ndarray:
     return np.where(pos < w, pos, pos + (wb - w))
 
 
+def _inverse_positions(pos: np.ndarray, extent: int,
+                       absent: int) -> np.ndarray:
+    """(..., L) positions (each row distinct where < extent; sentinels
+    ≥ extent) -> (..., extent) inverse maps: position -> its index in
+    the row, `absent` where no entry of the row sits there."""
+    inv = np.full(pos.shape[:-1] + (extent + 1,), absent, dtype=np.int64)
+    np.put_along_axis(
+        inv, np.minimum(pos, extent),
+        np.broadcast_to(np.arange(pos.shape[-1]), pos.shape), axis=-1)
+    return inv[..., :extent]
+
+
 @dataclasses.dataclass
 class GroupSpec:
     """One (level, bucket) batch of fronts, block-partitioned over
@@ -111,7 +123,11 @@ class GroupSpec:
     # only for sharded-coop parents, whose destination columns are
     # owned-slot indices instead of front positions
     ea_hosts: tuple
-    ea_meta: tuple             # per-bucket (rc_b, tc_b, K, C) statics
+    # per-bucket statics (rc_b, tc_b, K, C): C children a chunk on the
+    # element lane; C == 0 marks a ROW-lane bucket (_ea_row_lane,
+    # _ea_add_rows), whose meta carries a fifth entry, the sorted
+    # distinct slab strides of its children
+    ea_meta: tuple
     col_idx: np.ndarray        # (ndev, n_loc, wb) global cols, pad -> n
     struct_idx: np.ndarray     # (ndev, n_loc, mb-wb) pad -> n
     upd_off_global: int        # start of this group's global slab
@@ -177,11 +193,17 @@ class GroupSpec:
             sdt = (jnp.int32 if int(self.a_src.max(initial=0)) < 2**31 - 1
                    else jnp.int64)
             eblocks = []
-            for (rc_b, tc_b, K, C), (so, st, db, pr, pc) in zip(
+            for (rc_b, tc_b, _, C, *_), (so, st, db, pr, pc) in zip(
                     self.ea_meta, self.ea_hosts):
                 span = (int(so.max(initial=0))
                         + int(st.max(initial=0)) * rc_b + tc_b)
                 edt = jnp.int32 if span < 2**31 - 1 else jnp.int64
+                if C == 0:
+                    # row lane: the positions ship as their inverse
+                    # maps (front row -> child row, owned column ->
+                    # child column; _ea_add_rows)
+                    pr = _inverse_positions(pr, self.mb, rc_b)
+                    pc = _inverse_positions(pc, ncols, tc_b)
                 prd = jnp.asarray(pr, dtype=jnp.int32)
                 eblocks.append((jnp.asarray(so, dtype=edt),
                                 jnp.asarray(st, dtype=edt),
@@ -234,9 +256,11 @@ class BatchedSchedule:
     # tail padding of the update slab (in elements): the block-copy
     # extend-add lane reads each (li, lj) sub-block as one (li·st)
     # dynamic_slice whose final row over-reads up to st−lj elements
-    # past the child slab; the pad guarantees the slice never clamps
-    # (a clamped dynamic_slice silently SHIFTS its window).  1 when no
-    # block lane exists (the legacy +1 sentinel slot).
+    # past the child slab, and the row lane reads a child as one
+    # (rc_b·st) slice, rc_b − rc slab rows past it; the pad guarantees
+    # neither slice ever clamps (a clamped dynamic_slice silently
+    # SHIFTS its window).  1 when neither lane reaches past the slab
+    # (the legacy +1 sentinel slot).
     upd_pad: int = 1
 
     @functools.cached_property
@@ -251,6 +275,29 @@ class BatchedSchedule:
         return float(sum(
             g.n_loc * (1 if g.coop else self.ndev)
             * front_flops(g.wb, g.mb - g.wb) for g in self.groups))
+
+    @functools.cached_property
+    def ea_elements(self) -> dict:
+        """Extend-add elements a factorization moves, by lane
+        (`element`, `row`, `block`) and over all devices: `padded` is
+        what the program touches at its bucket shapes (K-padding
+        records included), `real` the children's own entries (Σ rc·tc
+        of the plan; a column no device owns is nobody's)."""
+        out = {k: {"padded": 0, "real": 0}
+               for k in ("element", "row", "block")}
+        for g in self.groups:
+            ncols = g.cp if g.cp > 0 else g.mb
+            for (rc_b, tc_b, K, C, *_), (_, _, _, pr, pc) in zip(
+                    g.ea_meta, g.ea_hosts):
+                lane = out["row" if C == 0 else "element"]
+                lane["padded"] += pr.shape[0] * K * rc_b * tc_b
+                lane["real"] += int(((pr < g.mb).sum(-1)
+                                     * (pc < ncols).sum(-1)).sum())
+            for (li, lj, _, K), (_, _, _, w) in zip(g.eb_meta,
+                                                    g.eb_hosts):
+                out["block"]["padded"] += w.shape[0] * K * li * lj
+                out["block"]["real"] += int(w.sum()) * li * lj
+        return out
 
     def comm_summary(self, dtype=np.float64, nrhs: int = 1) -> dict:
         """Static per-step collective traffic (the SCT_t comm-volume
@@ -468,6 +515,28 @@ def _plan_child_blocks(ps_row, min_run: int | None = None,
     return runs
 
 
+# Row lane of the extend-add (`_ea_add_rows`): front entries one child
+# moves by whole rows in the time the element lane's serialized scatter
+# moves ONE entry.  Measured on a v5e over thirteen bucket shapes
+# (PERF.md §6, PR 29): the element lane costs 17 ns an entry alone and
+# 31 ns inside the factor program, whatever the shape; a row-lane child
+# costs about 7 µs plus 0.05 ns an entry of its parent front (mb·ncols),
+# whatever its own size.  340–600 by those readings; 256 leaves the
+# break-even cases on the element lane.
+_EA_ROW_GAIN = 256
+# the row lane's fixed cost a child (one loop turn of a handful of
+# device operations), in front entries at the rate above
+_EA_ROW_FIXED = 1 << 17
+
+
+def _ea_row_lane(rc_b: int, tc_b: int, mb: int, ncols: int) -> bool:
+    """Whether a child bucket of padded shape (rc_b, tc_b) under fronts
+    of (mb, ncols) rides the row lane: by the bucket's and the front's
+    shapes alone, so `ea_meta` (a static key of every factor program)
+    says which lane a bucket rides."""
+    return rc_b * tc_b * _EA_ROW_GAIN >= mb * ncols + _EA_ROW_FIXED
+
+
 def _coop_mb_min() -> int:
     """Minimum padded front size for cooperative (column-sharded)
     factorization; SLU_COOP_MB overrides, 0 disables."""
@@ -533,6 +602,8 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
     block_on = _ea_block_on()
     blk_min_run = _ea_block_min_run()
     max_blk_stride = 0           # sizes the upd-slab tail pad
+    row_read_end = 0             # farthest slab element a row-lane
+                                 # read reaches (so + rc_b·stride)
 
     sup_upd_off = np.full(fp.nsuper, -1, dtype=np.int64)
     # actual slab row/col stride each front was WRITTEN with — its
@@ -882,6 +953,11 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
             # cols); K aligned across devices and rounded to the chunk
             # size when chunked.  The chunk cap bounds the per-chunk
             # transient gather/scatter tensors (~16 MB int32).
+            # A bucket at or over the size test (_ea_row_lane) rides
+            # the ROW lane: one child a loop turn, moved by whole rows
+            # (C = 0 in its meta).  Its read is one dynamic_slice
+            # reshaped at the child's slab stride, so the bucket's
+            # distinct strides join its meta (a fifth, static entry).
             by_rc: dict = {}
             for d in range(ndev):
                 for rec in child_recs[d]:
@@ -893,7 +969,15 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                 per_d = by_rc[(rc_b, tc_b)]
                 K = _next_bucket(max(len(v) for v in per_d))
                 C = max(1, (1 << 22) // (rc_b * tc_b))
-                if K > C:
+                row = ()
+                if _ea_row_lane(rc_b, tc_b, mb, ncols):
+                    C = 0
+                    recs = [rec for v in per_d for rec in v]
+                    row = (tuple(sorted({int(r[2]) for r in recs})),)
+                    row_read_end = max(
+                        [row_read_end]
+                        + [r[1] + rc_b * r[2] for r in recs])
+                elif K > C:
                     K = -(-K // C) * C
                 else:
                     C = K
@@ -925,7 +1009,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                     if 0 < nreal < K:
                         db[d, nreal:] = db[d, nreal - 1]
                 ea_hosts.append((so, st, db, pr, pc))
-                ea_meta.append((rc_b, tc_b, K, C))
+                ea_meta.append((rc_b, tc_b, K, C) + row)
 
             # bucket the block-copy records by exact (li, lj, stride):
             # every record in a bucket shares its slice shapes, so one
@@ -1063,7 +1147,8 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                            L_total=L_cur, U_total=U_cur,
                            Li_total=Li_cur, Ui_total=Ui_cur,
                            sup_dev=sup_dev,
-                           upd_pad=1 + max_blk_stride)
+                           upd_pad=max(1 + max_blk_stride,
+                                       row_read_end - upd_peak))
 
 
 def get_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
@@ -1077,7 +1162,8 @@ def get_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                   _coop_solve_rotate())
            if ndev > 1 else 0,
            _level_merge_limit() if _level_merge_on() else None,
-           (_ea_block_min_run() if _ea_block_on() else None))
+           (_ea_block_min_run() if _ea_block_on() else None),
+           _EA_ROW_GAIN)
     if key not in cache:
         cache[key] = build_schedule(plan, ndev)
     return cache[key]
@@ -1190,7 +1276,10 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     device.  Children are bucketed by padded (rc, tc); buckets with
     many children run as a fori_loop over C-child chunks so the
     transient index/update tensors stay bounded (~tens of MB) instead
-    of materializing a whole leaf level at once.
+    of materializing a whole leaf level at once.  A bucket at or over
+    the size test (meta C == 0) takes the row lane, `_ea_add_rows`, in
+    its place in the bucket order: its positions arrive as inverse
+    maps and no per-entry index is built.
 
     `ncols` is the front's column count (mb for the square layout;
     cp for sharded-coop owned-column slices, whose destination column
@@ -1210,8 +1299,8 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     # scatter there (allow_pallas=False from _factor_group_impl_pair)
     use_ps = allow_pallas and pallas_scatter.enabled(F.dtype)
 
-    for (rc_b, tc_b, K, C), (so, st, db, pr, pc) in zip(ea_meta,
-                                                        ea_blocks):
+    for (rc_b, tc_b, K, C, *row), (so, st, db, pr, pc) in zip(
+            ea_meta, ea_blocks):
         so = so.reshape(-1)
         st = st.reshape(-1)
         db = db.reshape(-1)
@@ -1222,8 +1311,15 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
             # must represent the ARRAY SIZE in the index dtype (wrap
             # normalization), so a >2 GiB-element upd_buf needs int64
             # source indices even when this group's own span is small
+            # (and the clamp arithmetic of the row lane's dynamic_slice
+            # must not wrap)
             so = so.astype(jnp.int64)
             st = st.astype(jnp.int64)
+        if C == 0:
+            F = _ea_add_rows(F, upd_buf, so, st, db, pr, pc, rc_b=rc_b,
+                             tc_b=tc_b, K=K, strides=row[0], mb=mb,
+                             n_pad=n_pad, ncols=ncols)
+            continue
 
         def add_chunk(Ff, so, st, db, pr, pc):
             ai = jnp.arange(rc_b, dtype=so.dtype)
@@ -1269,6 +1365,55 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
                     jax.lax.dynamic_slice_in_dim(pc, s0, C, 0))
             F = jax.lax.fori_loop(0, K // C, body, F)
     return F
+
+
+def _ea_add_rows(F, upd_buf, so, st, db, inv_r, inv_c, *, rc_b: int,
+                 tc_b: int, K: int, strides: tuple, mb: int, n_pad: int,
+                 ncols: int):
+    """Row lane of `_ea_add` (meta C == 0): one child a loop turn,
+    moved by whole rows — no index per matrix entry exists here.  The
+    child's update is READ as the contiguous block it is (one
+    dynamic_slice of rc_b·stride slab elements from `so`, reshaped to
+    rows at the slab stride, which is one of the bucket's static
+    `strides`; BatchedSchedule.upd_pad covers the over-read), PULLED
+    into its parent's shape by two whole-row gathers through the
+    inverse position maps the host ships (`inv_r`: front row -> child
+    row, `inv_c`: front column -> child column; each points where the
+    child has none at an appended zero row) with a transpose between,
+    and ADDED densely to the front's rows.  Children collide within a
+    parent, so they are added one at a time, in record order; a
+    K-padding record (every position absent) adds zeros."""
+    F2 = F.reshape(n_pad * mb, ncols)
+
+    def read_at(stride: int):
+        def read(off):
+            blk = jax.lax.dynamic_slice(upd_buf, (off,),
+                                        (rc_b * stride,))
+            blk = blk.reshape(rc_b, stride)[:, :tc_b]
+            # a slab row narrower than the bucket holds the child whole
+            return jnp.pad(blk, ((0, 0), (0, tc_b - blk.shape[1])))
+        return read
+
+    reads = [read_at(s) for s in strides]
+    below = jnp.asarray(strides[:-1], st.dtype)
+
+    def add_one(i, F2):
+        blk = (reads[0](so[i]) if len(reads) == 1 else jax.lax.switch(
+            jnp.sum(st[i] > below), reads, so[i]))
+        tall = jnp.concatenate([blk, jnp.zeros((1, tc_b), blk.dtype)]) \
+            .at[inv_r[i]].get(mode="promise_in_bounds")  # (mb, tc_b)
+        wide = jnp.concatenate([tall.T, jnp.zeros((1, mb), blk.dtype)]) \
+            .at[inv_c[i]].get(mode="promise_in_bounds")  # (ncols, mb)
+        row0 = (db[i] // ncols).astype(jnp.int32)
+        z = jnp.zeros((), jnp.int32)
+        cur = jax.lax.dynamic_slice(F2, (row0, z), (mb, ncols))
+        return jax.lax.dynamic_update_slice(F2, cur + wide.T, (row0, z))
+
+    if K == 1:
+        F2 = add_one(0, F2)
+    else:
+        F2 = jax.lax.fori_loop(0, K, add_one, F2)
+    return F2.reshape(-1)
 
 
 @jax.named_scope("slu.extend_add")

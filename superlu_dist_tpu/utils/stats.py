@@ -108,6 +108,11 @@ class Stats:
     # scheduled slot at its bucket shape, padding slots included)
     factor_flops: float = 0.0
     factor_flops_executed: float = 0.0
+    # extend-add elements of the LAST factorization by lane
+    # (BatchedSchedule.ea_elements: element / row / block, each
+    # {padded, real}); empty on the host backend
+    ea_elements: Dict[str, Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
     # collective traffic: predicted from the schedule (comm_summary)
     # and measured from the compiled HLO (hlo_collective_stats) — the
     # SCT_print3D comm-volume contract
@@ -203,6 +208,8 @@ class Stats:
             "lu_bytes": self.lu_bytes,
             "factor_flops": self.factor_flops,
             "factor_flops_executed": self.factor_flops_executed,
+            "ea_elements": {k: dict(v)
+                            for k, v in self.ea_elements.items()},
             "comm_predicted": dict(self.comm_predicted),
             "factor_events": [dict(e) for e in self.factor_events],
             "mem_watermarks": dict(self.mem_watermarks),
@@ -262,6 +269,10 @@ class Stats:
                 f"  factor flops: {self.factor_flops:.4g} useful, "
                 f"{self.factor_flops_executed:.4g} executed "
                 f"({share:.1f} %)")
+        if self.ea_elements:
+            lines.append("  extend-add elements (padded / real): " + ", ".join(
+                f"{k} {v['padded']:.4g} / {v['real']:.4g}"
+                for k, v in self.ea_elements.items()))
         if self.comm_predicted:
             lines.append("** Collective traffic (predicted) **")
             for k, v in self.comm_predicted.items():

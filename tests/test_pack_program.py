@@ -181,3 +181,37 @@ def test_pack_program_compiles_small_for_v5e(v5e_chip, monkeypatch):
     assert mem.temp_size_in_bytes < 2 ** 20
     assert mem.generated_code_size_in_bytes < 32 * 2 ** 20
     assert mem.output_size_in_bytes <= mem.argument_size_in_bytes
+
+
+def test_row_lane_body_compiles_small_for_v5e(v5e_chip):
+    """The extend-add row lane (`_ea_add_rows`) at the benchmark's
+    largest bucket: two children of 3,072 padded rows, read at a slab
+    stride that is no multiple of a tile (one child at another, so
+    the read is a switch), into a front of 6,144 x 6,144 (wb 512).
+    The TPU's compiler keeps it a loop of slices, row gathers and
+    transposes: scratch of a few fronts, code of a megabyte or two.
+    (The element lane's body for this bucket compiles in 20 s to
+    7 MB of code; a reshape hoisted over the slice, as in the pack
+    program's trap above, would show as scratch of the slab's size.)"""
+    rc_b, mb, K, strides = 3072, 6144, 2, (2816, 3584)
+    meta = ((rc_b, rc_b, K, 0, strides),)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+
+    blocks = ((sds((K,), jnp.int32), sds((K,), jnp.int32),
+               sds((K,), jnp.int32), sds((K, mb), jnp.int32),
+               sds((K, mb), jnp.int32)),)
+    fn = jax.jit(lambda F, u, b: batched._ea_add(
+        F, u, b, meta, mb=mb, n_pad=1), donate_argnums=0)
+    on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mem = fn.lower(sds((mb * mb,), jnp.float32),
+                       sds((150_000_000,), jnp.float32),
+                       blocks).compile().memory_analysis()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", on)
+    front = 4 * mb * mb
+    assert mem.temp_size_in_bytes < 3 * front
+    assert mem.generated_code_size_in_bytes < 4 * 2 ** 20
