@@ -39,7 +39,7 @@ import statistics
 import sys
 import time
 
-RELERR_MAX = 1e-9       # bench.py's accuracy bar
+RELERR_MAX = 1e-9       # the benchmark's accuracy bar
 
 
 def _parse(argv):
@@ -333,7 +333,7 @@ def run(args) -> int:
         "mesh": args.mesh, "compile_cache_dir": cache_dir,
         "berr_max": berr_max, "relerr_max": RELERR_MAX,
         # a library caller of gssvx: Options() defaults, NOT the
-        # tau=400 %/cap=1024 that pddrive and bench.py apply through
+        # tau=400 %/cap=1024 that pddrive applies through
         # utils/platform.apply_accel_amalg_defaults
         "amalgamation": {
             "amalg_tau": opts.amalg_tau, "amalg_cap": opts.amalg_cap,

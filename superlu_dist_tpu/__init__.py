@@ -23,7 +23,7 @@ It also pins the default matmul precision to "highest": on TPU the
 default f32 matmul is a single bf16 MXU pass (~3 decimal digits), which
 silently degrades the f32 factorization to bf16 class — measured
 err~2.3e-3 vs the f64 ground truth on hardware, versus ~1e-7 for true
-f32 (tools/pallas_ab.py) — and stalls the f64 iterative-refinement
+f32 (pre-round chip record, not re-measured) — and stalls the f64 iterative-refinement
 contract for conditioned matrices (cond·ε_factor must stay < 1,
 SURVEY.md §2.6).  Solvers sell accuracy classes, not matmul throughput;
 override with SLU_MATMUL_PREC=default|high|highest if you know better.

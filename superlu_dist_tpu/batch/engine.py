@@ -96,8 +96,7 @@ def _batched_factor_segment_jit(upd_buf, vals, thresh, a_srcs, a_dsts,
 
 
 # the compile-watch proxy the zero-recompiles-after-warmup gate probes
-# (phase "batch_factor"; bench.py --batch and the serve coalescer both
-# dispatch through it)
+# (phase "batch_factor"; the serve coalescer dispatches through it)
 _batched_factor_segment = obs.watch_jit(
     "batch_factor", _batched_factor_segment_jit, cost_phase="FACT",
     donate=(0,))
@@ -208,7 +207,7 @@ def per_sample_factorize(plan: FactorPlan, values: np.ndarray,
                          scaled: bool = False) -> StagedLU:
     """ONE value set factorized unbatched under the SHARED plan — the
     per-sample execution the bitwise contract pins batch_factorize
-    against, and the sequential arm of bench.py --batch's A/B.  Note
+    against.  Note
     this is NOT models.gssvx.factorize on the member matrix: planning
     re-equilibrates from the member's values, so an independently
     planned factorization legitimately differs in roundoff the moment

@@ -68,7 +68,7 @@ def warmup_batch(plan, values1: np.ndarray, dtype=np.float64,
     representative value set (the unbatched arm's warmup discipline,
     per rung): after this, dispatches at any batch size quantized to
     the ladder hit compiled programs — the zero-recompile contract
-    bench.py --batch and the coalescer gate on.  Returns the number
+    the coalescer gates on.  Returns the number
     of rungs warmed."""
     values1 = np.asarray(values1).reshape(1, -1)
     ladder = ladder or batch_ladder()

@@ -2,8 +2,8 @@
 
 The package and its tools grew ~50 `SLU_*` env knobs; this table is
 the single place they are all named and described.  The audit lives
-in tools/slulint (rules/envreads.flag_audit): it scans the package,
-tools/ and bench.py for `SLU_[A-Z_0-9]+` tokens and fails when a
+in tools/slulint (rules/envreads.flag_audit): it scans the package
+and tools/ for `SLU_[A-Z_0-9]+` tokens and fails when a
 read is undocumented here (or when an entry here no longer
 corresponds to any read) — tests/test_flags.py is a thin wrapper
 over it, and `python -m tools.slulint` gates on it too.  The
@@ -41,9 +41,8 @@ FLAGS: dict[str, str] = {
     # --- level-merged factor sweep (ops/batched.py) ---
     "SLU_FACTOR_MERGE_CELLS": "front-cell bound (n_loc*mb*ncols) at or below which a factor group joins a merged staged dispatch segment (default 65536); 0 = legacy per-group staged dispatch (the A/B arm).  Merging is dispatch granularity only — factors are bitwise-identical to the legacy sweep",
     "SLU_FACTOR_SEG_CELLS": "total front-cell budget of one merged factor segment (default 1048576) — bounds per-segment staged program size so segment compiles stay in the per-group compile class",
-    "SLU_FACTOR_MIN_SPEEDUP": "bench.py --factor-ab gate: required merged-vs-legacy staged factor-wall speedup at n=8000 (default 1.0 = never lose on the timeshared CPU box; the chip number is not measured).  A failed gate stamps measurement_invalid and persists nothing",
     # --- AOT executable persistence (resilience/aot.py) ---
-    "SLU_AOT_CACHE": "AOT executable-persistence directory (0/off/unset = disabled, zero overhead): whole-phase jits (phase factor + packed solve) serialize via jax.export write-through/read-through, keyed by a schedule-layout + dtype + merge-flag fingerprint, and the XLA persistent compilation cache is pointed at <dir>/xla when not already configured — a fresh process skips trace+lower by deserializing and the backend compile through the cache (tools/serve_bench.py --cold-boot is the drill).  Write-through costs one serialize per new program signature; mismatched-fingerprint entries are refused with a typed error and quarantined, never served",
+    "SLU_AOT_CACHE": "AOT executable-persistence directory (0/off/unset = disabled, zero overhead): whole-phase jits (phase factor + packed solve) serialize via jax.export write-through/read-through, keyed by a schedule-layout + dtype + merge-flag fingerprint, and the XLA persistent compilation cache is pointed at <dir>/xla when not already configured — a fresh process skips trace+lower by deserializing and the backend compile through the cache.  Write-through costs one serialize per new program signature; mismatched-fingerprint entries are refused with a typed error and quarantined, never served",
     # --- residual SpMV layout (ops/spmv.py) ---
     "SLU_SPMV_LAYOUT": "auto|ell|coo residual SpMV layout (ell = scatter-free padded rows)",
     "SLU_SPMV_ELL_WASTE": "max ELL padding ratio over true nnz before falling back to COO (default 4)",
@@ -69,28 +68,21 @@ FLAGS: dict[str, str] = {
     "SLU_TRACE_JSONL": "JSONL event-log path, appended through as spans close (implies SLU_OBS; adds one file write per span)",
     "SLU_OBS_COST": "1 = XLA cost-analysis FLOP/byte accounting on each jit cache miss -> Stats.ops_measured (re-pays one AOT lower+compile per NEW signature; zero cost on the recompile-free hot path)",
     # --- request-scoped flight recorder + SLO engine (obs/flight.py, obs/slo.py) ---
-    "SLU_FLIGHT": "1/0 per-request flight recorder: every SolveService request gets a monotonic rid and a stage-event record (admit/cache/queue/solve/refine + resilience events) in a bounded ring; off = ONE module-global pointer check on the request path (zero growth, pinned by the serve_bench --flight-ab record); on costs a few dict/list appends per request (<5% at the k=8 CPU load)",
+    "SLU_FLIGHT": "1/0 per-request flight recorder: every SolveService request gets a monotonic rid and a stage-event record (admit/cache/queue/solve/refine + resilience events) in a bounded ring; off = ONE module-global pointer check on the request path (zero growth); on costs a few dict/list appends per request",
     "SLU_FLIGHT_JSONL": "flight-record JSONL sink path, one line per RETAINED record as it finishes (implies SLU_FLIGHT; adds one file write per retained request; self-disables on I/O error; tools/trace_export.py renders it as per-request Perfetto tracks)",
     "SLU_FLIGHT_RING": "flight-record ring capacity (default 256): completed records kept for obs.snapshot()/lookup; non-ok outcomes are always retained until displaced by newer records",
     "SLU_FLIGHT_SAMPLE": "keep 1-in-N of `ok` flight records (default 1 = all); failures are ALWAYS retained regardless — sampling bounds sink volume under sustained healthy traffic, never traceability",
     "SLU_SLO": "SLO declaration: '1' = defaults (p99_ms=100, avail=0.99, window_s=60); 'p99_ms=50,avail=0.999,window_s=60[;scope:field=v]' with n-bucket/dtype-tier scoped overrides; sliding-window burn-rate accounting per (n-bucket, dtype tier) with exemplar rids on violated windows; off = one pointer check per request completion",
-    "SLU_FLIGHT_AB_TRIALS": "serve_bench --flight-ab interleaved trial-pair count (default 5; median per arm is the measurement)",
-    "SLU_FLIGHT_MAX_OVERHEAD": "serve_bench --flight-ab failure threshold on flight-on vs flight-off throughput loss (default 0.05 — the ISSUE-8 overhead acceptance)",
     # --- fleet telemetry export + aggregation (obs/export.py, obs/aggregate.py, obs/memory.py) ---
     "SLU_OBS_EXPORT": "telemetry export listener address ('unix:/path/sock', 'host:port', or a bare port on 127.0.0.1): serves the versioned obs snapshot as JSON (/snapshot) and Prometheus-style text (/metrics) over a minimal HTTP loop; unset/0 (default) = no listener, and the serve path pays ONE module-global pointer check (nothing per request — export reads snapshots on its own threads)",
     "SLU_OBS_EXPORT_JSONL": "periodic export write-through path: one schema-stamped snapshot line per period appended beside the durable store (tracer sink discipline: self-disables on I/O error, never throws into serving); implies the exporter is on even without a listener",
     "SLU_OBS_EXPORT_PERIOD_S": "export write-through period in seconds (default 5.0); each tick costs one registry snapshot + one file append on the exporter's own thread",
     "SLU_OBS_MEM": "1 = live device-memory probes (jax device.memory_stats live/peak bytes) on every factorization's watermark record; off (default) = the analytic slab-extent bytes model only (free: a few int multiplies from the schedule), so every factorization record still carries plan_bytes_predicted",
-    "SLU_PLAN_LATENCY_OUT": "plan-build latency record sink (ROADMAP 5a): plan/plan.py appends one mode=plan_latency line (t_plan_s, pattern sha1, n, nnz) per cold plan build when set; bench.py --plan-latency writes its gated ladder records here too (default PLAN_LATENCY.jsonl); self-disabling sink, one file append per plan build",
-    "SLU_PLAN_LATENCY_KS": "bench.py --plan-latency grid-size ladder, comma-separated laplacian_3d ks (default 8,12,16,20 — n 512..8000); each k is one cold plan-build + schedule-build timing record",
-    "SLU_EXPORT_AB_TRIALS": "serve_bench --export-ab interleaved trial-pair count (default 5; median per arm is the measurement)",
-    "SLU_EXPORT_MAX_OVERHEAD": "serve_bench --export-ab failure threshold on export-on vs export-off throughput loss (default 0.05 — the ISSUE-19 acceptance, same bar as flight-ab)",
-    "SLU_REGRESS": "0 = skip the perf-regression sentinel gate serve_bench runs after appending its record (tools/regress.py vs BASELINES.json; default on)",
+    "SLU_PLAN_LATENCY_OUT": "plan-build latency record sink (ROADMAP 5a): plan/plan.py appends one mode=plan_latency line (t_plan_s, pattern sha1, n, nnz) per cold plan build when set; self-disabling sink, one file append per plan build",
     # --- mixed precision (precision/, options.py, serve/service.py) ---
     "SLU_PREC_RESIDUAL": "auto|plain|doubleword|fp64 default Options.residual_mode: how the IR residual accumulates (doubleword = two-float fp32 df64, ~25 f32 flops/term vs 2 — noise next to fp64 EMULATION on TPU, and zero f64 ops in the jitted path; host loop uses native f64 either way)",
     "SLU_PREC_LADDER": "comma dtype list overriding the escalation ladder (default bfloat16,float32,float64; sorted by eps, climbed one rung per failed refinement contract — each rung re-pays one factorization)",
     "SLU_PREC_TIERS": "1 = serve-layer dtype-TIER serving: a cold high-precision request rides resident lower-rung factors via df64 refinement (saves a cold factorization; costs ~2-3 extra refinement sweeps per solve, berr-guarded with automatic re-key on miss)",
-    "SLU_PREC_AB_OUT": "bench.py --prec output path (default PREC_AB.jsonl)",
     # --- numerical trust layer (numerics/, models/gssvx.py, serve/) ---
     "SLU_COND_ESTIMATE": "1 = eager Hager-Higham rcond estimation after every driver/serve factorization (numerics/gscon.py): at most 2*SLU_COND_MAXITER+2 refinement-free packed-trisolve solves per factorization, ZERO extra factorizations; off (default) = rcond stays lazy via ensure_rcond and the condition policy never engages",
     "SLU_COND_MAXITER": "Hager-Higham iteration cap per rcond estimate (default 5; each iteration is one forward + one transpose solve)",
@@ -101,99 +93,42 @@ FLAGS: dict[str, str] = {
     # --- resilience (resilience/, serve/factor_cache.py) ---
     "SLU_BREAKER_THRESHOLD": "per-key circuit-breaker failure threshold (resilience/breaker.py; default 3): this many consecutive lead-factorization failures open the circuit; 0 at the ServeConfig layer disables the breaker entirely",
     "SLU_BREAKER_COOLDOWN_S": "circuit-breaker open-state cooldown seconds (default 30): requests during the cooldown get an immediate FactorPoisoned, then ONE half-open probe is admitted — success closes, failure re-opens for another cooldown",
-    "SLU_COST_HINT_MAX_AGE_S": "staleness horizon on the factor_cost_hint_s trajectory (serve/errors.py; default 2592000 = 30 days, 0 disables): SOLVE_LATENCY.jsonl records older than this are ignored when sizing fleet lease TTLs and stream cadence, so neither ever sizes itself off a weeks-old measurement; with no fresh record the callers' conservative fallback applies",
     "SLU_FT_STORE": "durable factor-store directory: FactorCache write-through/read-through persistence tier (atomic rename + sha256 framing + per-array ABFT checksum; corrupt entries quarantined to *.quarantined, never served; a restarted replica boots warm)",
     "SLU_CHAOS": "fault-injection spec 'site=prob[:param],...' — sites: factor_raise, factor_nan, store_flip, flusher_raise, latency (param = sleep seconds), store_latency, lease_steal, replica_kill, refactor_raise, refactor_slow, swap_kill (the stream pipeline's background-failure + mid-swap-crash sites), near_singular (param = skew strength: deterministic value-skew of incoming stream values toward rank deficiency, the rcond-drift drill's fault); deterministic per-site seeded streams; every site is one pointer check when unset",
     "SLU_CHAOS_SEED": "chaos RNG seed (default 0): same spec+seed replays the identical failure sequence",
-    "SLU_CHAOS_OUT": "serve_bench --chaos record path (default CHAOS.jsonl)",
-    # --- fleet coordination (fleet/, serve/, tools/fleet_drill.py) ---
+    # --- fleet coordination (fleet/, serve/) ---
     "SLU_FLEET": "1 = fleet-wide single-flight over the shared factor store (fleet/lease.py): a cold key elects ONE leader across every replica process sharing SLU_FT_STORE via an O_EXCL lease file; followers poll-with-backoff and adopt the published entry; a dead leader's expired lease is stolen.  Off = the in-process single-flight only",
-    "SLU_FLEET_TTL_S": "fleet lease TTL override in seconds (0/unset = factor-cost-scaled default: SLU_FLEET_TTL_SCALE x the measured t_factor_s from SOLVE_LATENCY.jsonl, clamped to [10, 1800] s) — the bound on how long a dead leader blocks a key before its lease is stolen",
-    "SLU_FLEET_TTL_SCALE": "multiplier on the measured factorization cost when sizing the default lease TTL (default 2.0: a lease outlives the factorization it guards with 2x headroom)",
+    "SLU_FLEET_TTL_S": "fleet lease TTL override in seconds (0/unset = 120 s) — the bound on how long a dead leader blocks a key before its lease is stolen",
     "SLU_FLEET_POLL_S": "fleet follower poll interval seconds (default 0.05), growing 1.5x per round to a 1 s cap — the cadence followers re-probe the store for the leader's published entry",
     "SLU_FLEET_VNODES": "virtual nodes per replica on the consistent-hash ring (default 64): smooths per-replica keyspace shares; membership changes still move only the joined/left replica's arc",
-    "SLU_FLEET_REPLICAS": "fleet drill replica-process count (default 3; the drill requires >=3 so a kill leaves a pool, not a pair)",
-    "SLU_FLEET_REQUESTS": "fleet drill chaos-load request count (default 48)",
-    "SLU_FLEET_K": "fleet drill grid size k (3D Laplacian, n=k^3; default 4)",
-    "SLU_FLEET_OUT": "fleet drill record path (default FLEET.jsonl)",
-    "SLU_FLEET_KILL_AFTER": "fraction of the drill's load phase served before the victim replica is kill -9'd (default 0.33)",
-    # --- elastic fleet controller (fleet/policy.py, fleet/controller.py, tools/fleet_drill.py --day) ---
+    # --- elastic fleet controller (fleet/policy.py, fleet/controller.py) ---
     "SLU_FLEET_BURN_HIGH": "SLO burn rate at or above which the controller scales up and sheds low-weight tenants (default 2.0 — the window is burning error budget at twice the allowed rate)",
     "SLU_FLEET_BURN_LOW": "SLO burn rate at or below which the controller may retire a surplus replica (default 0.25); between the low and high marks the fleet holds steady (hysteresis)",
     "SLU_FLEET_MIN_REPLICAS": "floor on live replica count — the controller never retires below it (default 1)",
     "SLU_FLEET_MAX_REPLICAS": "ceiling on live replica count — the controller never spawns past it (default 8)",
     "SLU_FLEET_SCALE_COOLDOWN_S": "minimum seconds between controller scaling actions in either direction (default 60) — capacity transitions are scheduled events, never oscillation",
     "SLU_FLEET_PREFACTOR_MIN": "demand count at which a non-resident pattern key becomes a prefactor target (default 2): the controller schedules warming at the key's ring home through the lease single-flight path",
-    "SLU_FLEET_DAY_OUT": "day-in-the-life drill record path (tools/fleet_drill.py --day; default FLEET_DAY.jsonl)",
-    "SLU_FLEET_DAY_REQUESTS": "day drill base request count per load phase (default 32; the diurnal curve scales each phase off this)",
-    "SLU_FLEET_DAY_P99_MS": "day drill per-phase p99 ceiling in ms (default 10000): a structural hang/cliff bound across every transition, generous to timeshared-box noise",
     "SLU_SERVE_BLAS_THREADS": "host BLAS pool size pinned by the first SolveService, process-wide (default 1; 0 = leave the pool alone; needs threadpoolctl, silently no-op without it) — a multi-threaded OpenBLAS pool's spin-wait barriers let one caller monopolize every core, so a background refactorization's host BLAS stalls concurrent solves (stream overlap A/B measured 1.45x p99 before the pin, 1.05x after); zero per-request overhead (one-time pool resize)",
-    # --- streaming refactorization (stream/, tools/serve_bench.py --stream) ---
+    # --- streaming refactorization (stream/) ---
     "SLU_STREAM_TRIP": "stream cadence escalation threshold as a fraction of the hard berr-guard limit (default 0.25): a stale solve's refined berr past trip_frac x 64·eps(refine_dtype) fires the stream_drift health escalation and requests a background refactorization; the hard limit itself always withholds the result (typed StaleFactorError, never served past the guard)",
-    "SLU_STREAM_INTERVAL_SCALE": "minimum seconds between background refactor starts as a multiple of the measured factorization cost (default 1.0) — bounds the pipeline's background duty cycle; the cost estimate is the handle's own refactor-wall EWMA, falling back to the arm-aware factor_cost_hint_s trajectory (the same figure that sizes fleet lease TTLs)",
+    "SLU_STREAM_INTERVAL_SCALE": "minimum seconds between background refactor starts as a multiple of the measured factorization cost (default 1.0) — bounds the pipeline's background duty cycle; the cost estimate is the handle's own refactor-wall EWMA (1 s before the first wall is measured)",
     "SLU_STREAM_MAX_LAG": "steps the live values may trail the resident generation before a refactor is forced regardless of berr (default 0 = disabled; drift in the measured berr is the primary cadence signal)",
     "SLU_STREAM_PROBE": "1/0 probe solve before a generation publishes (default 1): one refined solve on the fresh factors — builds the PackSet, warms the nrhs=1 program, and refuses a factorization whose solve path is broken; costs one solve per refactorization, zero on the serve path",
-    "SLU_STREAM_STEPS": "serve_bench --stream value-drift step count per load phase (default 24)",
-    "SLU_STREAM_STEP_HZ": "serve_bench --stream drift step rate in steps/s (default 4)",
-    "SLU_STREAM_DRIFT": "serve_bench --stream per-step relative value drift amplitude (default 5e-4: calibrated so a full 24-step walk refines ~2 decades inside the berr guard off the pinned generation-1 factors; 2e-3 breaches by step ~8)",
-    "SLU_STREAM_TRIALS": "serve_bench --stream interleaved overlap A/B pair count (default 3; the measurement is the p99 ratio over each arm's POOLED ok latencies across all trials — per-pair ratios ride the worst ~2 samples of each run and flip on scheduler noise; they stay in the record as pair_ratios)",
-    "SLU_STREAM_OVERLAP_TOL": "serve_bench --stream gate ceiling on steady-state p99 of the background-refactor arm over the pinned (no-refactor) arm (default 1.10 — the ISSUE-13 overlap acceptance); a failed gate stamps measurement_invalid and persists nothing",
     "SLU_STREAM_RCOND_DRIFT": "stream cadence rcond-drift trigger ratio (default 100): a background refactorization is requested when the latest generation's estimated rcond fell below baseline/ratio — conditioning decay caught alongside the berr trajectory; inert unless rcond estimates flow (SLU_COND_ESTIMATE)",
     # --- native library (utils/native.py) ---
     "SLU_TPU_NO_NATIVE": "1 = never build/load the native helper .so (pure-python fallbacks)",
-    # --- accelerator amalgamation defaults (utils/platform.py) ---
-    # --- bench.py driver ---
-    "SLU_BENCH_K": "bench grid size k (Laplacian family)",
-    "SLU_BENCH_NRHS": "bench right-hand-side count",
-    "SLU_BENCH_SHAPE": "bench matrix family selector (2d|3d|...)",
-    "SLU_BENCH_FACTOR_DTYPE": "bench factorization dtype override",
-    "SLU_BENCH_PRIME_SCIPY": "1 = only (re)compute the scipy baseline cache and exit",
-    "SLU_BENCH_STAGED_MIN_K": "bench k at which staged execution is allowed on",
-    "SLU_BENCH_SWEEP": "1 = run the multi-config bench sweep",
-    "SLU_BENCH_SWEEP_KS": "comma list of k values for the sweep",
-    "SLU_BENCH_SWEEP_PATH": "output path for sweep records (default BENCH_SWEEP.jsonl)",
-    "SLU_GAUNTLET_OUT": "bench.py --gauntlet record path (default GAUNTLET.jsonl): the hard-matrix corpus drill appends one per-case line per entry plus one mode=gauntlet summary record, regress-gated on zero silent-wrong answers; a failed gate stamps measurement_invalid and persists nothing",
-    # --- tools/ drivers ---
-    "SLU_SCALE_K": "tools/scale_run.py grid size (k=64 is the 262k certification)",
-    "SLU_SCALE_OUT": "tools/scale_run.py output json path",
-    "SLU_SOLVE_K": "tools/solve_latency.py / bench.py --solve-sweep grid size (defaults 30 / 20)",
-    "SLU_SOLVE_MIN_SPEEDUP": "bench.py --solve-sweep gate: required merged-vs-legacy per-rhs speedup at nrhs=1 (default 2.0, the ISSUE-9 acceptance)",
-    "SLU_SOLVE_WORSE_TOL": "bench.py --solve-sweep gate: max merged/legacy wall ratio tolerated at nrhs=8/64 (default 1.10 — timeshared-box noise)",
-    "SLU_SOLVE_SWEEP_OUT": "bench.py --solve-sweep output path (default SOLVE_LATENCY.jsonl)",
-    "SLU_PROFILE_K": "tools/tpu_profile.py grid size",
-    "SLU_PROFILE_OUT": "tools/tpu_profile.py output json path",
-    "SLU_PROFILE_DRYRUN": "1 = tpu_profile rehearsal on CPU (host planes only)",
-    "SLU_AB_CHAIN": "tools/pallas_ab.py in-jit repetitions per dispatch (default 8)",
-    "SLU_AB_CONFIGS": "tools/pallas_ab.py 'wb,mb,N;...' config override (interpret smoke)",
-    # --- serve layer (tools/serve_bench.py) ---
-    "SLU_SERVE_K": "serve_bench grid size k (3D Laplacian, n=k^3; default 8)",
-    "SLU_SERVE_CONCURRENCY": "serve_bench closed-loop worker count (default 16)",
-    "SLU_SERVE_REQUESTS": "serve_bench total request count (default 192)",
-    "SLU_SERVE_LINGER_MS": "serve_bench micro-batcher max linger (ms, default 2)",
-    "SLU_SERVE_OUT": "serve_bench output path (default SERVE_LATENCY.jsonl)",
-    "SLU_SERVE_MIN_SPEEDUP": "serve_bench regression floor on batched-vs-sequential speedup (default 1.0 = never lose; timeshared-box noise)",
-    "SLU_SERVE_MIXED": "1 = serve_bench mixed-dtype-traffic scenario: same matrix at two precision rungs (f64 native + f32/df64), alternating traffic, pinning ZERO recompiles across rungs on the obs compile counter",
-    # --- differentiable solve (autodiff/solve.py, bench.py --grad) ---
+    # --- differentiable solve (autodiff/solve.py) ---
     "SLU_AD_REFINE": "differentiable-forward refinement steps (default 1): sparse_solve returns the k-step refined solution while its VJP stays the exact-fixed-point adjoint (DESIGN.md §24); 0 = raw resident apply — the primal then carries NO A_values dependence (d/dA finite differences read 0 while the VJP still answers the implicit-function question)",
     "SLU_AD_JIT": "1 (default) = dispatch the autodiff forward/adjoint legs through the cached compile-watched jits (obs phases grad_fwd/adjoint — the zero-recompile and HLO-contract surface); 0 = trace them op-by-op eager (debug lane)",
-    "SLU_GRAD_OUT": "bench.py --grad record path (default GRAD.jsonl): FD-oracle + adjoint/forward cost record under the promote discipline; a failed gate stamps measurement_invalid and persists nothing",
-    "SLU_GRAD_K": "bench.py --grad grid size (3D Laplacian, n=k^3; default 10)",
-    "SLU_GRAD_TRIALS": "bench.py --grad timing trials per leg (default 5; median is the measurement)",
-    "SLU_GRAD_RATIO_MAX": "bench.py --grad gate ceiling on the adjoint/forward median wall ratio (default 1.5 — the ISSUE-18 bar: the adjoint is one resident transpose sweep plus pattern gathers, the same program class as a forward solve)",
-    # --- mesh-resident serving (serve/service.py, parallel/factor_dist.py, tools/, bench.py) ---
-    "SLU_SERVE_MESH": "1 = mesh-resident serving: ServeConfig.mesh defaults to a device mesh (SLU_MESH_SHAPE), the factor cache factors through the shard_map'd dist backend, every request key carries an Options.mesh_shape leg, and factor_cost_hint_s resolves the 'dist' cost arm.  Off (default) = single-device serving, one env read of overhead at ServeConfig construction and at cost-hint resolution",
+    # --- mesh-resident serving (serve/service.py, parallel/factor_dist.py) ---
+    "SLU_SERVE_MESH": "1 = mesh-resident serving: ServeConfig.mesh defaults to a device mesh (SLU_MESH_SHAPE), the factor cache factors through the shard_map'd dist backend, and every request key carries an Options.mesh_shape leg.  Off (default) = single-device serving, one env read of overhead at ServeConfig construction",
     "SLU_MESH_SHAPE": "mesh grid for SLU_SERVE_MESH=1 ('2x2x2', '8'; default: all local devices on one flat axis) — resolved once per ServeConfig construction, zero per-request overhead",
-    "SLU_FLEET_MESH": "fleet drill mesh-replica arm (tools/fleet_drill.py): device count each replica process provisions as a CPU mesh (compat.set_cpu_devices) and serves mesh-resident on; 0 (default) = single-device replicas.  All replicas share one shape so cache keys match pool-wide and store adoption/single-flight hold with a mesh leader",
-    "SLU_MULTICHIP_OUT": "bench.py --multichip-serve record path (default MULTICHIP_r06.json): the one-device vs mesh-replica serve A/B record (throughput, p99, recompile pin, bitwise-vs-mesh-oracle, per-boundary collective bytes), regress-gated; a failed gate stamps measurement_invalid and persists nothing",
-    # --- batch engine (batch/, serve/coalescer.py, bench.py --batch) ---
+    # --- batch engine (batch/, serve/coalescer.py) ---
     "SLU_BATCH_SOLVE_MODE": "batched-trisolve program arm (batch/engine.py): 'scan' (default) loops members inside ONE jit via lax.scan, keeping every lane's ops at exact per-sample shapes — the bitwise pin; 'vmap' is the dense batched arm for accelerators (XLA:CPU's batch-collapsed dot kernels reassociate reductions on trim==1 groups, drifting 1-2 ulp, so 'vmap' trades the bitwise pin for batched-kernel throughput).  One env read per cached program build, zero per-dispatch overhead",
     "SLU_BATCH_LADDER": "batch-size bucket ladder for the batch engine and factor coalescer, comma ints ascending (default '1,4,8,16,32'); sizes quantize UP a rung (short batches pad by replicating a live member), so after warmup the compiled-program population is bounded by the rung count — the zero-recompile contract.  Read once per warmup/coalescer construction",
     "SLU_BATCH_COALESCE": "1 = serve-layer factor coalescing (serve/coalescer.py): same-pattern cold factor requests arriving within the coalesce window merge into one batch_factorize dispatch up the B-ladder, results fanned back into ordinary per-key cache residents; off (default) = every cold key factors solo (zero overhead: the serve path checks this once per SolveService construction)",
     "SLU_BATCH_WINDOW_MS": "factor-coalescer max linger (ms, default 2): how long the first cold request of a pattern waits for same-pattern siblings before the flusher dispatches the batch — the factor-side twin of SLU_SERVE_LINGER_MS; latency cost is bounded by the window, throughput gain by the rung reached",
     "SLU_BATCH_MEMBER_POLICY": "coalescer member-failure policy: 'refuse' (default) = a singular/ill batch member gets its typed per-index refusal (ZeroDivisionError analog) and ONLY that member fails; 'fallback' = failed members retry solo through the ordinary unbatched factor path (costs one extra factorization for the failed member; siblings are untouched either way)",
-    "SLU_BATCH_K": "bench.py --batch batch counts, comma ints (default '64,256'): how many same-pattern systems each A/B arm factors+solves; the k=256 point is the promote-gate measurement",
-    "SLU_BATCH_OUT": "bench.py --batch record path (default BATCH.jsonl): batched-vs-sequential factor+solve A/B under the promote discipline (throughput ratio, bitwise pin, recompile pin); a failed gate stamps measurement_invalid and persists nothing",
-    "SLU_BATCH_MIN_SPEEDUP": "bench.py --batch gate floor on the batched/sequential throughput ratio at the k=256, n=128 point (default 1.5 — the ISSUE-20 bar: one dispatch amortizing schedule/dispatch overhead across B value sets must beat B sequential dispatches clearly, not marginally)",
 }
 
 # Tokens the registry test's grep will hit that are NOT env flags:

@@ -17,9 +17,8 @@ neighbours already hold.  This package closes those three gaps:
     never read torn), the leader heartbeats while it factors and
     publishes through the store's atomic rename, followers poll with
     backoff and ADOPT the verified published entry, and a dead
-    leader's expired lease is STOLEN through an exclusive rename —
-    TTL sized off the measured factorization cost
-    (serve/errors.factor_cost_hint_s).  Every wait/adopt/steal step
+    leader's expired lease is STOLEN through an exclusive rename.
+    Every wait/adopt/steal step
     lands on the request's flight record.
   * `router.py` — consistent-hash key routing: residency is
     deliberate, warm traffic lands where the factor lives, and the
@@ -31,12 +30,9 @@ neighbours already hold.  This package closes those three gaps:
     DegradedResult beats an outage, and an untyped error is never the
     answer.
 
-Proven by `tools/fleet_drill.py` (bench.py --fleet): ≥3 replica
-processes on one shared store under chaos load, one `kill -9`'d
-mid-load, gating zero lost/hung requests, warm takeover with zero
-survivor factorizations for published keys, and exactly one
-fleet-wide factorization per cold key — committed as FLEET.jsonl and
-baselined in tools/regress.py.
+Held by tests/test_fleet.py: zero lost/hung requests, warm takeover
+with zero survivor factorizations for published keys, and exactly one
+fleet-wide factorization per cold key.
 
 ISSUE 16 adds the ELASTIC layer on the same substrate:
 
@@ -50,12 +46,9 @@ ISSUE 16 adds the ELASTIC layer on the same substrate:
   * `controller.py` — the gather → decide → actuate loop tying them
     together; any one actuation may fail, the loop never does.
 
-Proven by `tools/fleet_drill.py --day`: a day-in-the-life drill —
-diurnal load, tenant mix, a flash crowd, rolling restarts, one
-replica kill — gating zero lost requests, every shed typed, policy
-prefactor at exactly one factorization per cold key, and zero
-takeover factorizations; committed as FLEET_DAY.jsonl and baselined
-in tools/regress.py.
+Held by tests/test_fleet_controller.py: zero lost requests, every
+shed typed, policy prefactor at exactly one factorization per cold
+key, and zero takeover factorizations.
 """
 
 from .controller import FleetController, signals_from

@@ -6,7 +6,7 @@ replicas sharing one warm store still stampedes: N concurrent misses
 on one cold pattern are N *processes*, and a threading.Event cannot
 reach across them.  The measured economics make that the single worst
 failure of scale the serve layer has — 477 s of factorization per
-replica (SOLVE_LATENCY.jsonl) for work one replica could have done
+replica (pre-round chip record, not re-measured) for work one replica could have done
 for everyone.
 
 This module is the cross-process analog of `_Flight`, built on the
@@ -55,11 +55,9 @@ must cost an adopt, never a duplicate factorization; caught by the
 contended three-way race in tests/test_fleet.py).
 
 TTL sizing: a lease must outlive the factorization it guards, or
-healthy leaders get robbed mid-factor.  Default is
-`SLU_FLEET_TTL_SCALE` (2.0) × the measured cold-factorization cost
-(serve/errors.factor_cost_hint_s — the SOLVE_LATENCY.jsonl
-trajectory), clamped to [10 s, 1800 s]; `SLU_FLEET_TTL_S` overrides
-outright (the drill and tests shrink it to seconds).  The heartbeat
+healthy leaders get robbed mid-factor.  Default is 120 s;
+`SLU_FLEET_TTL_S` overrides outright (tests shrink it to seconds).
+The heartbeat
 refreshes the lease's OWN recorded ttl window, so a steal judgment
 never depends on the judging replica's configuration matching the
 leader's.
@@ -86,12 +84,7 @@ from ..utils.io import atomic_write_bytes
 
 LEASE_SUFFIX = ".lease"
 
-# TTL clamp: even a wild factor_cost_hint never sizes a lease under
-# the time a small factorization plausibly takes (10 s) or past the
-# point a dead leader should plainly have been buried (30 min)
-_TTL_MIN_S = 10.0
-_TTL_MAX_S = 1800.0
-_TTL_FALLBACK_S = 120.0        # no measured trajectory at all
+_TTL_DEFAULT_S = 120.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,17 +105,10 @@ class LeaseInfo:
 
 
 def default_ttl_s() -> float:
-    """`SLU_FLEET_TTL_S` override, else the factor-cost-scaled
-    default (see module docstring)."""
+    """`SLU_FLEET_TTL_S` override, else the default (see module
+    docstring)."""
     override = flags.env_float("SLU_FLEET_TTL_S", 0.0)
-    if override > 0:
-        return override
-    from ..serve.errors import factor_cost_hint_s
-    cost = factor_cost_hint_s()
-    scale = flags.env_float("SLU_FLEET_TTL_SCALE", 2.0)
-    if cost is None:
-        return _TTL_FALLBACK_S
-    return min(_TTL_MAX_S, max(_TTL_MIN_S, scale * cost))
+    return override if override > 0 else _TTL_DEFAULT_S
 
 
 class FleetCoordinator:

@@ -14,7 +14,7 @@ reference hedges that bet with (pdgscon / pdgsrfs, PAPER.md L5):
               feeding refusal, stamping, guard tightening and the
               escalation ladder
   gauntlet.py hard-matrix corpus + the zero-silent-wrong-answers
-              drill (bench.py --gauntlet, regress-gated)
+              drill (tests/test_numerics.py)
 """
 
 from .errors import (

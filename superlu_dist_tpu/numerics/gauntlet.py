@@ -12,8 +12,8 @@ exists to prevent.
 This module generates the corpus (condition-number ladder up to ~1/eps,
 structurally singular patterns, duplicated rows, wild scaling,
 indefinite shifts, NaN/Inf poisoning, malformed shapes) and classifies
-each solve attempt into the five-way taxonomy the regress gate checks
-(`bench.py --gauntlet` -> GAUNTLET.jsonl -> tools/regress.py):
+each solve attempt into the five-way taxonomy
+tests/test_numerics.py checks:
 
   accurate        plain result, berr within the accuracy class
   stamped         PerturbedResult/DegradedResult label rode the answer
@@ -185,8 +185,8 @@ def classify(case: dict, run) -> dict:
 
 def run_gauntlet(run=None) -> tuple:
     """Drive the whole corpus; returns (case records, summary).  `run`
-    defaults to the one-call driver under the ambient env (bench.py
-    --gauntlet sets SLU_COND_ESTIMATE=1 so the condition policy is in
+    defaults to the one-call driver under the ambient env (set
+    SLU_COND_ESTIMATE=1 to put the condition policy in
     force).  The summary's gate passes iff there are zero silent-wrong
     answers and zero untyped failures — the robustness bar, not a
     performance one."""

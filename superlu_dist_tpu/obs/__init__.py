@@ -33,8 +33,7 @@ Everything registers into ONE `Registry` (`REGISTRY`): per-run phase
 stats (utils/stats.py), serve metrics (serve/metrics.py), the compile
 watcher, the health monitor and the tracer, so `obs.snapshot()` is
 the single structured view and `obs.dump_text()` the single
-Prometheus-style text dump (wired into `SolveService` and
-`bench.py --serve`).
+Prometheus-style text dump (wired into `SolveService`).
 """
 
 from . import aggregate, export, flight, memory, slo

@@ -10,8 +10,7 @@ per-wrapper signature table detects the first call with a new
 (shape, dtype, static-arg) signature — a jit cache miss — counts it
 with full attribution, confirms against the jit's own `_cache_size()`
 when available, and emits a `compile` trace event into the span
-tracer.  `tools/serve_bench.py` reads `recompiles_under_load` from
-this counter instead of its former ad-hoc cache-size probe.
+tracer.
 
 With `SLU_OBS_COST=1` each miss additionally runs XLA cost analysis
 (`fn.lower(...).compile().cost_analysis()`) and records the compiled

@@ -22,12 +22,7 @@ Cost discipline: the request path is NOT hooked — export reads
 snapshots on its own threads, so with the flag unset the only cost
 anywhere is the one module-global pointer check (`_exporter is
 None`).  On, the serve overhead is the registry snapshot each period
-plus per-request handling on listener threads — gated <=5% by
-tools/serve_bench.py --export-ab, like flight-ab.
-
-The drill replicas additionally serve `export_snapshot()` over the
-replica wire protocol (tools/fleet_drill.py "obs_export" cmd), which
-is what feeds FleetController.gather() remotely.
+plus per-request handling on listener threads.
 """
 
 from __future__ import annotations

@@ -29,8 +29,7 @@ the `ok` ones (`SLU_FLIGHT_SAMPLE`, default 1 = all, ring-bounded by
 Gating contract (the serve analog of the tracer's): `SLU_FLIGHT=1`
 (or a programmatic `configure(enabled=True)`) turns the recorder on;
 off, every entry point is ONE module-global pointer check — the serve
-request path grows zero work (pinned by tests/test_flight.py and the
-serve_bench `--flight-ab` overhead record).
+request path grows zero work (pinned by tests/test_flight.py).
 
 Threading model: the submitting thread owns the record through
 routing (a thread-local set by SolveService around `_route`); the

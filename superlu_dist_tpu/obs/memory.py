@@ -67,8 +67,7 @@ def _analytic_bytes(lu) -> int:
 
 def schedule_bytes_predicted(schedule, dtype) -> int:
     """The same analytic model from a bare BatchedSchedule (for
-    callers that have no handle yet — bench.py --plan-latency prices
-    the prediction at plan time)."""
+    callers that have no handle yet: the prediction at plan time)."""
     itemsize = np.dtype(dtype).itemsize
     flats = (int(schedule.L_total) + int(schedule.U_total)
              + int(schedule.Li_total) + int(schedule.Ui_total))

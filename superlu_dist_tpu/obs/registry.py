@@ -4,8 +4,7 @@ package — phase stats (utils/stats.py), serve metrics
 tracer — registers a named provider here, so ONE `snapshot()` answers
 "where did the time go, did XLA recompile, are the numerics drifting"
 as a single dict, and `dump_text()` renders the same thing as a flat
-Prometheus-style text exposition (wired into `SolveService` and
-`bench.py --serve`).
+Prometheus-style text exposition (wired into `SolveService`).
 
 A provider is any object with a `snapshot() -> dict` method.
 Registration is last-wins per name (one live SolveService / one

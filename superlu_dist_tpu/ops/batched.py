@@ -138,8 +138,9 @@ class GroupSpec:
     # BLOCK-COPY extend-add lane (the scatter-free fast path): children
     # whose position vector decomposes into a few long contiguous runs
     # move as 2-D dynamic_slice → dynamic_update_slice block copies
-    # instead of element gather/scatter (TPU_PROFILE_r05: the element
-    # fusions run at 50–200 MB/s; contiguous copies run at HBM rate).
+    # instead of element gather/scatter (pre-round chip record, not
+    # re-measured: the element fusions run at 50–200 MB/s; contiguous
+    # copies run at HBM rate).
     # Per bucket key (li, lj, st): (so, dr, dc, w) stacked (ndev, K) —
     # source flat offset, dest block row/col in the (n_pad·mb, ncols)
     # front view, and a 0/1 mask killing K-padding records.
@@ -469,8 +470,9 @@ def _ea_block_on() -> bool:
     """Block-copy extend-add lane (SLU_EA_BLOCK, default ON): children
     whose extend-add position maps are a few long contiguous runs move
     as dynamic_slice/dynamic_update_slice 2-D block copies instead of
-    element gather/scatter — the answer to TPU_PROFILE_r05's
-    50–200 MB/s slab↔GEMM-buffer fusions.  =0 restores the pure
+    element gather/scatter — the answer to the 50–200 MB/s
+    slab↔GEMM-buffer fusions of a pre-round chip record, not
+    re-measured.  =0 restores the pure
     element formulation for A/B."""
     return flags.env_str("SLU_EA_BLOCK", "1").strip().lower() \
         not in ("0", "false", "off")
@@ -683,8 +685,9 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
             # into fewer padded groups (_coalesce_buckets) — the
             # latency-regime trade: fewer sequential group bodies on
             # the device at the price of padded flops/slab; the
-            # tau/cap amalgamation's sibling lever (TPU_AB_CHAIN.jsonl
-            # priced it at -2 % / -22 %; ROADMAP D2).
+            # tau/cap amalgamation's sibling lever (a pre-round chip
+            # record, not re-measured, priced it at -2 % / -22 %;
+            # ROADMAP D2).
             by_bucket = _coalesce_buckets(by_bucket,
                                           _level_merge_limit())
         for (wb, mb), slist in sorted(by_bucket.items()):
@@ -1988,10 +1991,8 @@ def factor_seg_metas(sched, members, dtype) -> tuple:
 
 def factor_arm(sched=None, dtype=None) -> str:
     """One-token description of the factor-sweep arm —
-    legacy|merged|merged+pallas — stamped onto factor-timing records
-    (SOLVE_LATENCY.jsonl) and read back by
-    serve/errors.factor_cost_hint_s so fleet lease TTLs track the
-    ACTIVE arm's measured cost (the trisolve active_arm sibling).
+    legacy|merged|merged+pallas — stamped onto chip_smoke.py's
+    records (the trisolve active_arm sibling).
     With a (schedule, dtype) the "+pallas" suffix is claimed only
     when some merged segment member actually routes through the
     kernel; without one it falls back to the env resolution.
@@ -2191,7 +2192,7 @@ def _staged_sweeps(sched, panels, bf, dtype, trans: bool,
 
     Under the merged trisolve arm (SLU_TRISOLVE, ops/trisolve.py)
     the per-group dispatch chain collapses to one dispatch per merged
-    SEGMENT over the lsum layout — bitwise-identical results, a
+    SEGMENT over the lsum layout — the same arithmetic, a
     fraction of the Python/dispatch overhead at small nrhs.  `packs`
     lets a caller that solves repeatedly against one panel set (the
     staged fused solver's refinement loop) pre-pack once."""
@@ -2354,8 +2355,8 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
 
         # compile telemetry (obs/compile_watch.py): each whole-phase
         # program reports its jit cache misses with shape/dtype
-        # attribution — the recompile counter serve_bench pins its
-        # zero-recompiles-after-warmup contract on.  The proxies
+        # attribution — the recompile counter the
+        # zero-recompiles-after-warmup contract is pinned on.  The proxies
         # delegate lower()/_cache_size() to the jits underneath.
         # With SLU_AOT_CACHE active the factor program is AOT-wrapped
         # (resilience/aot.py): a fresh process deserializes the
@@ -2718,7 +2719,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     # gather of a fixed band + row-sum, so the jitted refinement
     # residual lowers with ZERO scatter ops (the COO scatter-add ran
     # at ~600 MB/s on v5e, ~140 ms/step over the IR iterations;
-    # TPU_PROFILE_r05.json fusion.14932/14936).  plan COO order IS CSR
+    # pre-round chip record, not re-measured).  plan COO order IS CSR
     # row-major order (sparse.CSRMatrix.to_coo), so row boundaries
     # reconstruct from the row ids; SLU_SPMV_LAYOUT=coo restores the
     # scatter formulation for A/B ----

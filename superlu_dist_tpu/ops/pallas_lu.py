@@ -41,8 +41,8 @@ def enabled(dtype) -> bool:
     (interpret mode off-TPU), =0/unset leaves the global routing off.
 
     Default OFF — resolved by hardware measurement, not hope
-    (PALLAS_AB.json, tools/pallas_ab.py on TPU v5e, amortized in-jit
-    timing): the XLA fori_loop formulation is ~2x faster at every
+    (pre-round chip record, not re-measured; TPU v5e, amortized
+    in-jit timing): the XLA fori_loop formulation is ~2x faster at every
     bucket shape ≥ (wb=16, mb=32) (e.g. 44 vs 20 GFLOP/s at 512²) and
     both paths sit at true-f32 accuracy vs the f64 ground truth
     (~5e-7) under the package's "highest" matmul precision.  The
@@ -58,8 +58,8 @@ def enabled(dtype) -> bool:
 def merged_eligible(wb: int, mb: int, dtype) -> bool:
     """Merged-factor-segment promotion (ISSUE 12): inside a merged
     staged factor segment (ops/batched.get_factor_segments) the
-    panel-LU kernel engages BY DEFAULT for the µs-scale buckets
-    PALLAS_AB.json priced it ahead on — wb ≤ 8, mb ≤ 16, the
+    panel-LU kernel engages BY DEFAULT for the µs-scale buckets a
+    pre-round chip record (not re-measured) priced it ahead on — wb ≤ 8, mb ≤ 16, the
     (8, 16)-class population that level merging coalesces — on real
     TPU hardware only (kernels are resolved by measurement; interpret
     mode would merely slow the CPU rehearsal, and the bitwise fp64

@@ -6,7 +6,7 @@ Schur-update blocks land in parent fronts through an index map, and
 letting the generic runtime serialize those indexed writes is the
 difference between HBM-rate and broken throughput.  The round-5
 profile measured XLA's element scatter fusions at 50–200 MB/s on v5e
-(TPU_PROFILE_r05.json) — the TPU has no native scatter datapath, so
+(pre-round chip record, not re-measured) — the TPU has no native scatter datapath, so
 the fusion loops lane-by-lane.
 
 This kernel re-expresses the scatter as MXU work, the datapath the

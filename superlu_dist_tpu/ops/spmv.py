@@ -10,7 +10,8 @@ layouts serve it:
   * COO gather → multiply → segment-scatter-add (the original
     formulation).  XLA lowers the row scatter-add as a serialized
     kCustom fusion: measured 600 MB/s on v5e for the n=27k bench
-    residual (TPU_PROFILE_r05.json) — ~0.1% of HBM bandwidth.
+    residual (pre-round chip record, not re-measured) — ~0.1% of
+    HBM bandwidth.
   * padded ELL (default): each row stores a fixed-width band of
     column indices/values; y = rowsum(vals · x[cols]) is a pure
     gather + reduction, NO scatter at all.  The pad slots carry

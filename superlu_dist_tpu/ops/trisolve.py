@@ -5,7 +5,8 @@ factor schedule group by group mutating one (n+1, R) solution array:
 per group it dynamic-slices its panels out of the factor flats,
 gathers X rows, runs the two panel einsums, and SCATTER-ADDS the
 off-diagonal update back into X.  At small nrhs that program is
-latency-bound, not FLOP-bound (SOLVE_LATENCY.jsonl: 59 ms/rhs at
+latency-bound, not FLOP-bound (pre-round chip record, not
+re-measured: 59 ms/rhs at
 nrhs=1 vs 8.3 ms/rhs at nrhs=64 on TPU v5; the same-box CPU
 decomposition in DESIGN.md §16 measured the scatter-adds and
 per-solve panel re-slicing at ~40% of the nrhs=1 wall with the
@@ -26,8 +27,9 @@ applied to the data movement rather than the arithmetic:
     group) and consumers subtract their contributions through a
     precomputed gather, one J-step chain replaying the legacy
     scatter-add application order, so the compiled program contains
-    NO scatter at all and stays bitwise-identical to the legacy
-    sweep (pinned in tests/test_trisolve.py);
+    NO scatter at all and agrees with the legacy sweep to
+    4·eps·max|x| (pinned in tests/test_trisolve.py; the two are
+    separately compiled programs, so not bit for bit);
   * **level-merged segments** — consecutive small groups (the deep
     narrow chain tail that dominates nrhs=1 wall time) coalesce into
     single dispatch segments: the staged path dispatches one program
@@ -71,9 +73,8 @@ def trisolve_mode() -> str:
     """Active trisolve arm: 'merged' (the lsum/packed formulation) or
     'legacy' (the historical scatter-add level sweep).  SLU_TRISOLVE
     ∈ {auto, merged, legacy}; auto resolves to merged — the merged
-    arm is bitwise-identical to legacy by construction, so the flag
-    exists for A/B pricing (bench.py --solve-sweep) and rollback, not
-    correctness."""
+    arm does the legacy arm's arithmetic in its order, so the flag
+    exists for A/B pricing and rollback, not correctness."""
     v = flags.env_str("SLU_TRISOLVE", "auto").strip().lower()
     if v in ("legacy", "0", "off"):
         return "legacy"
@@ -109,8 +110,8 @@ def mesh_merged_on() -> bool:
     the row-partitioned merged trisolve?  Requires an EXPLICIT
     SLU_TRISOLVE=merged — `auto` keeps the proven X-psum sweep on
     meshes while the merged arm's collective behavior is priced on
-    real hardware (single-device auto is merged: it is
-    bitwise-identical and strictly fewer ops)."""
+    real hardware (single-device auto is merged: the same
+    arithmetic in strictly fewer ops)."""
     return flags.env_str("SLU_TRISOLVE",
                         "auto").strip().lower() == "merged"
 
@@ -212,14 +213,16 @@ def _idt(maxval: int):
 def build_trisolve(sched) -> TrisolveSchedule:
     """Build the lsum layout from a BatchedSchedule.
 
-    Bitwise contract: the merged sweep applies exactly the arithmetic
+    Order contract: the merged sweep applies the arithmetic
     of the legacy sweep — gathers and dense writes are data movement,
     the einsums run on identical per-front operands (dropping dead
     lanes does not change a kept lane's GEMV), and the
     contributor-subtract chain replays the legacy scatter-add
     application order (groups in program order; within a group, the
     update tensor's row-major iteration order — the order XLA applies
-    duplicate scatter indices in)."""
+    duplicate scatter indices in).  The compiler still contracts and
+    orders each program's multiply-adds for itself: the arms agree to
+    4·eps·max|x|, not bit for bit (tests/test_trisolve.py)."""
     ndev = sched.ndev
     n = sched.n
     groups = sched.groups
@@ -583,7 +586,7 @@ _CHAIN_UNROLL = 4
 @jax.named_scope("slu.lsum")
 def chain_subtract(xb, UPD, u_gidx, J: int):
     """The contributor-subtract chain: ONE gather of all J planes,
-    then the sequential fold — the subtraction ORDER is the bitwise
+    then the sequential fold — the subtraction ORDER is the
     contract (it replays the legacy scatter-add application order);
     long chains fold in a fori_loop (one compiled op instead of J —
     the deep-root-chain tail).  Shared by the XLA member body and the
@@ -603,7 +606,7 @@ def init_lsum_buffers(ts: "TrisolveSchedule", B0):
     """(B, UPD, Y) dense buffers for one sweep: B is the encoded RHS
     with the sentinel row appended, UPD/Y zero-initialized with their
     sentinel slots.  Row n and the UPD/XF sentinels are EXACT 0.0 —
-    load-bearing for the bitwise contract (x − 0 is bit-exact) — and
+    load-bearing for the order contract (x − 0 is bit-exact) — and
     the concatenate keeps the program scatter-free.  One definition
     serves the fused sweep, the staged dispatcher, the mesh body and
     its oracle."""

@@ -149,7 +149,7 @@ def _solve_loop(dsched, flats, b, dtype, per_group, axis,
     # merged trisolve arm (ops/trisolve.py, SLU_TRISOLVE): the
     # single-device sweep re-expressed over the lsum gather/update
     # layout — packed panels, dense update buffers, no scatters,
-    # bitwise-identical results.  The packing slices here are
+    # the same arithmetic in the same order.  The packing slices here are
     # loop-invariant inside the fused solvers' refinement while_loop,
     # so XLA hoists them and the repeated sweeps pay only the lsum
     # dataflow.  Mesh execution (axis mode) keeps the X psum sweep in
@@ -489,10 +489,12 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
     per supernode.  Interior segments (zone-affine subtrees) sweep
     with ZERO collectives.
 
-    Bit-matching contract: every dense slot is written exactly once
+    Matching contract: every dense slot is written exactly once
     by exactly one device and reconciled as v = 0 + (v - 0) + 0·…, so
-    the mesh execution is bitwise the sequential execution of the
-    same layout on one device (`mesh_oracle_solve` pins it)."""
+    the mesh execution does the arithmetic of the sequential
+    execution of the same layout on one device (`mesh_oracle_solve`;
+    tests/test_trisolve.py holds the two to 4·eps·max|x|: separately
+    compiled programs do not agree bit for bit)."""
     axis, ndev = _resolve_axis(mesh, axis)
     dsched = get_schedule(plan, ndev)
     from ..ops import trisolve as tsv
@@ -584,7 +586,7 @@ def mesh_oracle_solve(dlu: DistLU, b_factor_order,
     Every dense slot is written once by one device, and consumers
     gather cross-device slots only after the mesh's sync points would
     have replicated them (0 + (v - 0) + 0 + ... = v bit-exact), so
-    this sequential execution IS the mesh execution — the bit-match
+    this sequential execution IS the mesh execution's arithmetic — the
     oracle, no collectives, no shard_map."""
     from ..ops import trisolve as tsv
     from ..ops.batched import _dec, _enc
@@ -834,8 +836,7 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
     txt = lowerable.lower(dlu.L_flat, dlu.U_flat, dlu.Li_flat,
                           dlu.Ui_flat, b).compile().as_text()
     out["SOLVE"] = hlo_collective_stats(txt)
-    # mesh stamps (ISSUE 17 satellite): scalar legs the bench records
-    # carry into SOLVE_LATENCY/MULTICHIP lines so tools/regress.py can
+    # mesh stamps (ISSUE 17 satellite): scalar legs that let a caller
     # hold PER-DEVICE and PER-BOUNDARY ceilings, not just totals — a
     # mesh twice the size must not get twice the collective allowance.
     syncs = int(dlu.schedule.comm_summary(dlu.dtype, nrhs)

@@ -23,8 +23,8 @@ event instead of an outage:
 Consumed by serve/factor_cache.py (store, breaker, retry, factor
 validation), serve/batcher.py (flusher chaos + latency) and
 serve/service.py (degraded-mode serving).  Driven end to end by
-`tools/serve_bench.py --chaos`, which gates on zero hangs and zero
-silent wrong answers and writes CHAOS.jsonl.
+tests/test_resilience.py, which gates on zero hangs and zero
+silent wrong answers.
 """
 
 from .breaker import CircuitBreaker

@@ -38,9 +38,6 @@ a header echoing the fingerprint.  The loader REFUSES any mismatch —
 frame, fingerprint, jax version, undeserializable payload — with the
 typed `AotMismatch` and quarantines the entry (*.quarantined, the
 store convention): a stale or corrupt executable is never dispatched.
-`tools/serve_bench.py --cold-boot` is the drill: a second fresh
-process against a warm store + AOT cache must serve with
-factorizations == 0 AND aot misses == 0 (gated in tools/regress.py).
 
 Off (`SLU_AOT_CACHE` unset/0) this module costs one string check per
 program build — nothing on the dispatch path.

@@ -52,7 +52,7 @@ Determinism: each site owns a `random.Random` seeded from
 (`SLU_CHAOS_SEED`, site name), so the same spec+seed replays the same
 failure sequence regardless of which other sites fire — the property
 that makes a chaos regression debuggable.  Per-site fired counters
-feed the CHAOS.jsonl record (tools/serve_bench.py --chaos).
+ride `fired()`.
 
 Sites are NAMED here (SITES) and validated at install: a typo'd site
 in a spec is an error, not silence.

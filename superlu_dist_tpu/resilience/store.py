@@ -1,7 +1,7 @@
 """Durable factor store: crash-safe persistence of factorizations.
 
 A factorization costs minutes at production scale while a solve costs
-milliseconds (SOLVE_LATENCY.jsonl) — so a replica restart that drops
+milliseconds — so a replica restart that drops
 process memory is a multi-minute outage PER HOT KEY unless the factors
 survive on disk.  This module is the persistence tier under
 `serve/factor_cache.py` (`SLU_FT_STORE=dir`): write-through on every

@@ -6,15 +6,14 @@ factorization (factor_cache.py), RHS micro-batching over a fixed
 nrhs bucket ladder so the jitted solver never recompiles after warmup
 (batcher.py), a front door with admission control and per-request
 deadlines (service.py), structured metrics (metrics.py), and a
-seeded closed-loop load generator (loadgen.py).  Driven end to end by
-tools/serve_bench.py, which appends records to SERVE_LATENCY.jsonl.
+seeded closed-loop load generator (loadgen.py).
 
 Failure containment rides the sibling resilience/ package: the
 durable factor store (ServeConfig.store_dir / SLU_FT_STORE), per-key
 circuit breaker + bounded retry around cold factorizations, explicit
 FlusherDead futures when a batcher thread dies, and degraded-mode
 serving off stale factors (DegradedResult) — exercised by
-`tools/serve_bench.py --chaos` (CHAOS.jsonl).
+tests/test_resilience.py.
 
 Quickstart:
 

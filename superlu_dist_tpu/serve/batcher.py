@@ -1,7 +1,8 @@
 """RHS micro-batching: coalesce concurrent solves into one dispatch.
 
 The triangular-solve path is a chain of O(#groups) small dispatches
-whose cost is nearly flat in nrhs — SOLVE_LATENCY.jsonl: 59 ms at
+whose cost is nearly flat in nrhs — pre-round chip record, not
+re-measured: 59 ms at
 nrhs=1 vs 8.3 ms/rhs at nrhs=64, a 7× amortization.  This is the
 inference-server continuous-batching shape applied to RHS vectors:
 concurrent `submit(b)` calls against one factorization are gathered
@@ -227,7 +228,7 @@ class MicroBatcher:
         # a genuine bug outside _dispatch's own solve try, or the
         # chaos flusher_raise site — fails every pending AND claimed
         # request with an explicit FlusherDead, so callers get an
-        # error, never a hang (tools/serve_bench.py --chaos gates on
+        # error, never a hang (tests/test_resilience.py gates on
         # exactly this).
         try:
             self._run_loop()

@@ -12,14 +12,7 @@ the caller deserves to know came off stale factors.
 
 from __future__ import annotations
 
-import functools
-import json
-import os
-import time
-
 import numpy as np
-
-from .. import flags
 
 # the numerical-trust taxonomy (numerics/errors.py) re-exported here
 # so service callers import ONE failure vocabulary; numerics/ sits
@@ -63,9 +56,8 @@ class DeadlineExceeded(ServeError):
 class FactorMissError(ServeError):
     """Factor-cache miss under the fail-fast policy: this service is
     configured not to pay a factorization inline (they cost minutes at
-    production scale — `factor_cost_hint()` reads the measured figure
-    from SOLVE_LATENCY.jsonl so this text can't drift from the
-    trajectory); prefactor() the key or use miss_policy='factor'."""
+    production scale, `factor_cost_hint()`); prefactor() the key or
+    use miss_policy='factor'."""
 
 
 class FactorPoisoned(ServeError):
@@ -104,162 +96,8 @@ class DegradedResult(np.ndarray):
     honest alternative to an outage, never a silent substitute for a
     healthy solve."""
 
-def _record_factor_arm(rec: dict) -> str | None:
-    """The factor arm a t_factor_s record was measured under
-    (`factor_arm`, stamped by bench.py --solve-sweep); None for
-    pre-ISSUE-12 history."""
-    fa = rec.get("factor_arm")
-    return str(fa) if fa else None
 
-
-def _record_epoch(rec: dict) -> float | None:
-    """Epoch seconds of a record's `ts` stamp, or None when absent or
-    unparseable (age unknown — the staleness horizon cannot judge
-    it)."""
-    ts = rec.get("ts")
-    if not ts:
-        return None
-    try:
-        return time.mktime(time.strptime(str(ts),
-                                         "%Y-%m-%dT%H:%M:%S"))
-    except (ValueError, OverflowError):
-        return None
-
-
-# default staleness horizon on the measured trajectory (ISSUE 16):
-# a lease TTL or stream cadence must never size itself off a
-# weeks-old measurement — the fleet it guards has long since changed
-_COST_HINT_MAX_AGE_S = 30 * 86400.0
-
-
-@functools.lru_cache(maxsize=8)
-def _factor_cost_from(path: str, arm: str | None,
-                      max_age_s: float = 0.0) -> float | None:
-    """Latest t_factor_s in `path`, preferring the freshest record
-    measured under `arm`.  With an arm requested, records STAMPED
-    with a different arm are ignored — a merged-arm timing says
-    nothing honest about the legacy arm's cold wall (the arms differ
-    up to the whole dispatch-granularity lever) — and only unstamped
-    pre-ISSUE-12 history may stand in when the arm has no record yet.
-    No eligible record -> None, and the caller's conservative
-    fallback applies.
-
-    `max_age_s` > 0 is the staleness horizon
-    (`SLU_COST_HINT_MAX_AGE_S`): records stamped older than the
-    horizon are skipped outright; records with no parseable `ts`
-    (test fixtures, hand-written history) are exempt — the horizon
-    guards the stamped trajectory, it cannot judge an unknown age.
-
-    mode="factor_ab" rows are EXCLUDED: their t_factor_s is a WARM
-    in-process numeric-sweep timing (best-of interleaved passes,
-    compile and planning excluded — the A/B isolates the dispatch
-    lever), while this hint estimates the COLD wall a fleet lease
-    must outlive — plan build + compile-or-deserialize + the sweep.
-    Adopting the warm figure would collapse lease TTLs ~170x below
-    the cost they guard and invite mid-factorization lease steals."""
-    cutoff = (time.time() - max_age_s) if max_age_s > 0 else None
-    last_any = last_same = last_bare = None
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if rec.get("mode") == "factor_ab":
-                    continue
-                t = rec.get("t_factor_s")
-                if not t:
-                    continue
-                if cutoff is not None:
-                    epoch = _record_epoch(rec)
-                    if epoch is not None and epoch < cutoff:
-                        continue       # weeks-old: never size off it
-                v = float(t)
-                last_any = v
-                ra = _record_factor_arm(rec)
-                if ra is None:
-                    last_bare = v
-                if arm is not None and ra == arm:
-                    last_same = v
-    except OSError:
-        pass
-    if arm is None:
-        return last_any
-    return last_same if last_same is not None else last_bare
-
-
-def factor_cost_hint_s(arm: str | None = None) -> float | None:
-    """The latest measured cold-factorization wall (seconds) from
-    SOLVE_LATENCY.jsonl, or None when no record exists.  The numeric
-    twin of factor_cost_hint(): fleet/lease.py sizes its lease TTL
-    off this figure — a lease must outlive the factorization it
-    guards, and the measured trajectory is the only honest estimate
-    of that.
-
-    Arm-aware (ISSUE 12): with `arm` unset it resolves the ACTIVE
-    factor arm (ops/batched.factor_arm — legacy|merged|merged+pallas)
-    and prefers the freshest record measured under it, so a merged-arm
-    speedup SHRINKS lease TTLs instead of inheriting legacy-arm costs
-    (and an arm rollback re-inherits the honest slower figure).
-
-    Staleness-guarded (ISSUE 16): records older than the
-    `SLU_COST_HINT_MAX_AGE_S` horizon (default 30 days) and records
-    stamped under a DIFFERENT arm are ignored — with nothing fresh
-    and arm-honest left, this returns None and the caller's
-    conservative default applies (the lease TTL fallback, the stream
-    cadence floor) rather than a figure measured on a fleet that no
-    longer exists."""
-    if arm is None:
-        try:
-            # mesh-resident serving (ISSUE 17) factors through the
-            # shard_map'd dist program — a different cost curve from
-            # every single-device arm, so it gets its own ledger arm
-            # and leases sized under a mesh never inherit single-chip
-            # walls (or vice versa)
-            if flags.env_int("SLU_SERVE_MESH", 0):
-                arm = "dist"
-            else:
-                from ..ops.batched import factor_arm
-                arm = factor_arm()
-        except Exception:           # noqa: BLE001 — hint, not gate:
-            arm = None              # any resolution failure degrades
-                                    # to the arm-less freshest record
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "SOLVE_LATENCY.jsonl")
-    return _factor_cost_from(
-        path, arm,
-        flags.env_float("SLU_COST_HINT_MAX_AGE_S",
-                        _COST_HINT_MAX_AGE_S))
-
-
-@functools.lru_cache(maxsize=1)
 def factor_cost_hint() -> str:
-    """Human-readable cold-factorization cost for error messages —
-    centralized so the figure tracks the measured trajectory: reads
-    the latest `t_factor_s` record from SOLVE_LATENCY.jsonl at the
-    repo root, falling back to \"minutes\" when no record exists."""
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "SOLVE_LATENCY.jsonl")
-    last_t, last_desc = None, ""
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                t = rec.get("t_factor_s")
-                if t:
-                    last_t = float(t)
-                    last_desc = str(rec.get("desc", ""))
-    except OSError:
-        pass
-    if last_t is None:
-        return "minutes at production scale"
-    n = ""
-    if "n=" in last_desc:
-        n = f" ({last_desc[last_desc.index('n='):].split()[0]})"
-    return f"~{last_t:.0f} s measured{n}"
+    """Human-readable cold-factorization cost for error messages,
+    kept in one place so the refusals that quote it agree."""
+    return "minutes at production scale"

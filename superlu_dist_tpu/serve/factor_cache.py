@@ -1,6 +1,7 @@
 """LRU factor cache with single-flight factorization.
 
-SOLVE_LATENCY.jsonl measured the economics this module exploits: one
+A pre-round chip record (not re-measured) has the economics this
+module exploits: one
 n=27k factorization costs ~477 s while a held-factor solve costs 59 ms
 (8.3 ms/rhs at nrhs=64).  A service must therefore keep
 `LUFactorization` handles resident and amortize them across every

@@ -299,9 +299,8 @@ def run_stream_load(streams, *, steps: int = 16,
     from (seed, index) alone — so a killed process's surviving
     journal (`journal_path`, one flushed JSON line per completed
     request) tells a successor EXACTLY which indices to replay.
-    That replay contract is what lets the drift drill account every
-    request across a mid-run kill -9 (tools/serve_bench.py
-    --stream).
+    That replay contract is what lets a drill account every
+    request across a mid-run kill -9.
 
     `rate_hz` paces aggregate issuance (open-ish loop): request
     number p is released at `t_start + p / rate_hz`, so the load

@@ -9,10 +9,9 @@ runs under tier-1 CPU tests.
 Percentiles are exact over a bounded reservoir: histograms keep up to
 `sample_cap` raw samples (deterministic reservoir replacement past the
 cap, seeded RNG) plus exact count/sum/min/max, so the small loads
-tests and `tools/serve_bench.py` drive report true p50/p95/p99 while
+tests drive report true p50/p95/p99 while
 memory stays bounded under sustained traffic.  `Metrics.snapshot()`
-returns a plain-JSON dict — one line of which becomes the
-`SERVE_LATENCY.jsonl` record.
+returns a plain-JSON dict.
 
 A Metrics instance is also an `obs.Registry` provider (it has exactly
 the snapshot() contract): `register_obs()` places it in the unified
@@ -108,8 +107,7 @@ class Metrics:
     """Named counters + histograms behind one lock.
 
     One instance is shared by the factor cache, the micro-batchers and
-    the service front door; `snapshot()` is the JSON-ready view the
-    bench driver appends to SERVE_LATENCY.jsonl."""
+    the service front door; `snapshot()` is the JSON-ready view."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()

@@ -17,9 +17,8 @@ caller can rely on:
 Cold keys follow `miss_policy`: "factor" pays the factorization once
 (single-flight, so a thundering herd on one key does one
 factorization's worth of work); "failfast" raises FactorMissError so
-interactive traffic never blocks minutes behind a cold tenant (the
-measured figure lives in errors.factor_cost_hint, sourced from
-SOLVE_LATENCY.jsonl) — the operator prefactors keys out of band via
+interactive traffic never blocks minutes behind a cold tenant
+(errors.factor_cost_hint) — the operator prefactors keys out of band via
 `prefactor()`.
 
 Failure containment (resilience/): factorization failures are retried
@@ -32,8 +31,7 @@ resident, DEGRADED MODE solves through the stale factors with
 refinement against the fresh matrix behind the standard berr guard,
 returning a `DegradedResult`-stamped answer instead of an outage.
 
-Everything is observable through a shared Metrics registry; the
-snapshot feeds SERVE_LATENCY.jsonl (tools/serve_bench.py).
+Everything is observable through a shared Metrics registry.
 """
 
 from __future__ import annotations

@@ -24,9 +24,8 @@ Newton iterations, transient stepping, the reference's
                without learning serve/.
 
 Entry point: `SolveService.stream(a, options)` -> StreamHandle.
-Drilled end to end by `tools/serve_bench.py --stream` (drift +
-injected background failures + mid-swap kill -9), record committed to
-SERVE_LATENCY.jsonl and gated by tools/regress.py.
+Drilled by tests/test_stream.py (drift + injected background
+failures + the mid-swap kill site).
 """
 
 from .cadence import Cadence

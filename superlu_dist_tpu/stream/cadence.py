@@ -6,8 +6,7 @@ live values move away from the factored ones.  The hard line is the
 berr guard (the 64·eps accuracy class the serve layer already
 enforces on tier/degraded traffic): a result is NEVER served past it.
 Everything below that line is an economics question — a
-factorization costs `factor_cost_hint_s(arm)` (the measured
-SOLVE_LATENCY.jsonl trajectory, arm-aware since ISSUE 12) while a
+factorization costs seconds to minutes while a
 stale refined solve costs milliseconds, so the right schedule rides
 the stale factors as long as refinement honestly covers the drift and
 starts the next factorization early enough that it LANDS before the
@@ -37,12 +36,10 @@ alone can miss right up to the cliff (numerics/, ISSUE 15).
 plus a MIN INTERVAL between refactor starts — `interval_scale` x the
 factorization cost — bounding the background duty cycle so a noisy
 berr series cannot turn the pipeline into a hot loop of 477 s
-factorizations.  The cost estimate prefers this handle's own measured
-refactor walls (EWMA) and falls back to the repo trajectory hint.
+factorizations.  The cost estimate is this handle's own measured
+refactor walls (EWMA).
 
-Fleet coupling: the same `factor_cost_hint_s(arm)` figure sizes the
-fleet lease TTL (fleet/lease.py default_ttl_s), so the pool's lease
-window and this cadence shrink or grow together; with a coordinator
+Fleet coupling: with a coordinator
 attached, the background refactorization itself goes through the
 fleet single-flight (one leader factors a drifted key, every other
 replica adopts the published entry — once per pool, not N times), and
@@ -57,11 +54,9 @@ import time
 
 from .. import flags
 from ..obs import flight
-from ..serve.errors import factor_cost_hint_s
 
-# fallback factorization-cost estimate when neither a measured wall
-# nor a SOLVE_LATENCY.jsonl record exists (a fresh checkout's first
-# stream); deliberately small — the first real refactor replaces it
+# factorization-cost estimate before the first wall is measured;
+# deliberately small — the first real refactor replaces it
 _COST_FALLBACK_S = 1.0
 # trajectory points kept / used by the drift fit
 _TRAJ_CAP = 32
@@ -166,14 +161,12 @@ class Cadence:
     def cost_s(self) -> float:
         """Estimated wall of the next refactorization: this stream's
         own measured walls (EWMA — the pipeline seeds it with the
-        prime factorization and updates it per refactor), else the
-        arm-aware repo trajectory hint (the same figure fleet lease
-        TTLs are sized from)."""
+        prime factorization and updates it per refactor), else
+        the fallback."""
         with self._lock:
             if self._measured_wall_s is not None:
                 return self._measured_wall_s
-        hint = factor_cost_hint_s()
-        return hint if hint else _COST_FALLBACK_S
+        return _COST_FALLBACK_S
 
     def min_interval_s(self) -> float:
         base = self.interval_scale * self.cost_s()

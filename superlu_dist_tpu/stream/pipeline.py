@@ -11,7 +11,7 @@ reference's `SamePattern_SameRowPerm` rung, ROADMAP item 4): a
 (stream/swap.py), serves every solve through it immediately
 (refinement against the live values closes the drift gap, df64
 residual for sub-f64 factors — the PR 4/PR 5 machinery), and pays the
-`factor_cost_hint_s`-class factorization as a CONTAINED background
+factorization as a CONTAINED background
 task whose cadence the measured berr drift sets (stream/cadence.py).
 The compute/communication-overlap discipline of the HPL-exascale
 pipelining work (PAPERS.md, arxiv 2304.10397), applied to the
@@ -37,8 +37,8 @@ Containment contract (the robustness headline):
     precedes the in-memory swap by construction), so a restarted
     process primes warm from whichever generation the store last
     published — the `swap_kill` chaos site fires exactly between
-    validation and the in-memory assignment, and the drift drill
-    (tools/serve_bench.py --stream) gates the restart at
+    validation and the in-memory assignment, and
+    tests/test_stream.py gates the restart at
     factorizations == 0.
 
 Front-door integration: stream solves ride the REAL service plumbing
@@ -170,10 +170,8 @@ class StreamHandle:
         key = matrix_key(a, self.options)
         t0 = time.monotonic()
         lu = service.cache.get_or_factorize(a, self.options, key=key)
-        # the prime wall seeds the cadence's cost estimate: a
-        # PER-PATTERN figure (the repo-wide factor_cost_hint_s
-        # trajectory was measured at its own n and would mis-size a
-        # much smaller or larger stream); later refactor walls
+        # the prime wall seeds the cadence's cost estimate, a
+        # PER-PATTERN figure; later refactor walls
         # refine it by EWMA.  A warm store adopt under-estimates —
         # the first real refactor corrects it.
         self.cadence.note_swap(time.monotonic() - t0)

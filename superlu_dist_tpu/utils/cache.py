@@ -38,7 +38,7 @@ def ensure_portable_cpu_isa(xla_flags: str) -> str:
     present.  The single definition of the portability guard for
     live-migrating VMs (model-tuned XLA:CPU artifacts executed on a
     different host model produced NaN solves and a SIGSEGV); used by
-    tests/conftest.py, bench.py and the 16-device subprocess test."""
+    tests/conftest.py and the 16-device subprocess test."""
     xla_flags = xla_flags or ""
     if "xla_cpu_max_isa" not in xla_flags:
         xla_flags = (xla_flags + " --xla_cpu_max_isa=AVX2").strip()
@@ -77,20 +77,6 @@ def place_compile_cache(path: str | None = None) -> str:
     return path
 
 
-def host_fingerprint(include_isa: bool = True) -> str:
-    """The 12-hex-digit host fingerprint used by host_cache_dir.
-
-    include_isa=False drops the XLA_FLAGS `--xla_cpu_max_isa` cap
-    from the key: the cap changes what XLA COMPILES, so XLA artifact
-    caches must split on it, but callers fingerprinting the host for
-    non-XLA measurements (bench.py's scipy-baseline cache) must NOT —
-    a primer run without the cap and a bench run with it are the same
-    machine, and splitting them re-measures every baseline in-window
-    (observed 2026-08-01: fp flip on the same host seconds apart,
-    keyed purely by whether ensure_portable_cpu_isa had run)."""
-    return _fingerprint(include_isa)
-
-
 def host_cache_dir(base: str) -> str:
     """`base` extended with a stable fingerprint of this host's CPU.
 
@@ -108,15 +94,15 @@ def host_cache_dir(base: str) -> str:
     native library (csrc slu_cpuid_words — the same instructions
     LLVM's host detection executes), with /proc/cpuinfo as additional
     salt and the platform strings as last resort."""
-    return f"{base}-{_fingerprint(True)}"
+    return f"{base}-{_fingerprint()}"
 
 
-def _fingerprint(include_isa: bool) -> str:
+def _fingerprint() -> str:
     parts = []
     try:
         from . import native
         # cpuid_words_fast never triggers the FULL native build (this
-        # runs at conftest/bench startup) — it reuses the big .so when
+        # runs at conftest startup) — it reuses the big .so when
         # current, else builds the sub-second single-TU helper, so the
         # fingerprint is identical across every process of a session
         w = native.cpuid_words_fast()
@@ -150,10 +136,9 @@ def _fingerprint(include_isa: bool) -> str:
     # artifacts compiled under an ISA cap (--xla_cpu_max_isa, the
     # portability guard for live-migrating VMs) must not share a dir
     # with full-ISA artifacts from the same host
-    if include_isa:
-        m = re.search(r"--xla_cpu_max_isa=(\S+)",
-                      flags.env_str("XLA_FLAGS"))
-        if m:
-            parts.append(f"isa={m.group(1).lower()}")
+    m = re.search(r"--xla_cpu_max_isa=(\S+)",
+                  flags.env_str("XLA_FLAGS"))
+    if m:
+        parts.append(f"isa={m.group(1).lower()}")
     key = "|".join(parts)
     return hashlib.sha1(key.encode()).hexdigest()[:12]

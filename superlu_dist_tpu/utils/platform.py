@@ -78,7 +78,8 @@ def apply_accel_amalg_defaults() -> None:
     measured best on TPU, for callers that have already resolved an
     accelerator backend.  User-set env always wins.
 
-    Measured 2026-08-01 on v5e (TPU_AB_TAU.jsonl, n=27k, steady-state
+    Measured 2026-08-01 on v5e (pre-round chip record, not
+    re-measured; n=27k, steady-state
     wall of the fused solve — compare `best`, not GFLOP/s, since
     amalgamation grows flops by construction):
 
@@ -96,8 +97,7 @@ def apply_accel_amalg_defaults() -> None:
     library default stays CPU-safe.
 
     A library caller of gssvx does NOT get these: only entry points
-    that resolved an accelerator call this (pddrive, bench.py, the
-    profiling tools).  chip_smoke.py says which settings it ran with."""
+    that resolved an accelerator call this (pddrive).  chip_smoke.py says which settings it ran with."""
     for k, v in (("SUPERLU_AMALG_TAU_PCT", "400"),
                  ("SUPERLU_AMALG_CAP", "1024")):
         os.environ.setdefault(k, v)

@@ -12,8 +12,8 @@ reused at two levels, both verified by tests/test_warmup.py:
   executable cache, so the subsequent dispatch reuses the executables
   directly (no persistent-cache read, no deserialization).
 - LATER process: the artifacts land in the PERSISTENT compilation
-  cache (placed by utils/cache.place_compile_cache — bench.py,
-  chip_smoke.py and the test conftest all call it) and a fresh process's dispatch hits that
+  cache (placed by utils/cache.place_compile_cache —
+  chip_smoke.py and the test conftest call it) and a fresh process's dispatch hits that
   cache instead of the compiler (measured 38/38 signature hits):
   prime the cache once, dispatch fast in every later process that is
   handed the same cache directory.

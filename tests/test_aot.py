@@ -4,9 +4,7 @@ merge-flag fingerprint; a fresh process deserializes instead of
 re-tracing and its backend compile rides the persistent compilation
 cache.  Pinned here: the save/load verification envelope (sha frame,
 fingerprint refusal with the TYPED AotMismatch, quarantine), bitwise
-identity of AOT-served programs, the off-path being a no-op, and the
-fresh-process cold-boot drill itself (tools/serve_bench.run_cold_boot)
-at a tiny grid."""
+identity of AOT-served programs, and the off-path being a no-op."""
 
 import json
 import os
@@ -197,30 +195,3 @@ def test_aot_served_solve_bitwise_and_corrupt_fallback(
     assert any(p.endswith(aot.SUFFIX) for p in os.listdir(tmp_path))
     assert any(p.endswith(".quarantined")
                for p in os.listdir(tmp_path))
-
-
-# --------------------------------------------------------------------
-# the fresh-process drill (tools/serve_bench.run_cold_boot)
-# --------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_cold_boot_drill_two_processes(tmp_path):
-    """The drill end-to-end at a tiny grid: two fresh interpreters on
-    one shared store + AOT cache; the second must adopt the store
-    (factorizations == 0) and deserialize every AOT-wrapped program
-    (misses == 0, hits >= 1).  Slow tier: two interpreter+jax boots —
-    tier-1's budget keeps the in-process AOT pins; the drill itself
-    is gated every round via the committed cold_boot record
-    (tools/regress.py)."""
-    import sys
-    sys.path.insert(0, str(__import__("pathlib").Path(
-        __file__).resolve().parents[1]))
-    from tools.serve_bench import run_cold_boot
-    out = tmp_path / "out.jsonl"
-    rec = run_cold_boot(k=4, requests=4, out_path=str(out))
-    assert rec["gate"]["passed"]
-    assert rec["factorizations"] == 0
-    assert rec["aot_misses"] == 0 and rec["aot_hits"] >= 1
-    assert rec["cold"]["aot"]["saves"] >= 1
-    line = json.loads(out.read_text().splitlines()[-1])
-    assert line["mode"] == "cold_boot"

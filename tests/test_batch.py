@@ -73,16 +73,14 @@ def case_rand():
 
 @pytest.fixture(scope="module")
 def case_lap():
-    # n=216 keeps the second pattern class cheap here; the n=512
-    # bitwise pin lives in the committed BATCH.jsonl gate record
+    # n=216 keeps the second pattern class cheap here
     return _mk_case(laplacian_3d(6))
 
 
 @pytest.fixture(params=[
     "rand128",
     # the second elimination-tree shape rides the slow tier: tier-1
-    # keeps the rand128 + gauntlet pattern pins, and the n=512
-    # bitwise pin is in the committed BATCH.jsonl gate record
+    # keeps the rand128 + gauntlet pattern pins
     pytest.param("lap216", marks=pytest.mark.slow)])
 def batch_case(request):
     """(a, plan, vals[B,nnz], blu) per test shape — built once."""

@@ -66,7 +66,7 @@ def test_one_config_site_in_the_tree():
             sites += [os.path.join(dp, f) for f in fs
                       if f.endswith(".py")]
     sites += [os.path.join(ROOT, f) for f in
-              ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+              ("chip_smoke.py", "__graft_entry__.py")]
     hits = [os.path.relpath(p, ROOT) for p in sites
             if pat.search(open(p).read())]
     assert hits == ["superlu_dist_tpu/utils/cache.py"]

@@ -1,8 +1,8 @@
-"""chip_smoke.py and bench.py refuse to speak for a device they do not
+"""chip_smoke.py refuses to speak for a device it does not
 have, and the smoke's CPU rehearsal keeps the script itself alive.
 
 What these pin is the contract the driver checks on every PR: with no
-accelerator the two entry points exit non-zero and print NO result; a
+accelerator the entry point exits non-zero and prints NO result; a
 rehearsal prints records that name `cpu` and never an `"ok"`.  The
 chip pass itself cannot be tested here — it is what `python
 chip_smoke.py` through the chip tool is for.
@@ -14,11 +14,8 @@ import shutil
 import subprocess
 import sys
 
-import pytest
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(ROOT, "chip_smoke.py")
-BENCH = os.path.join(ROOT, "bench.py")
 
 
 def _run(argv, cwd=ROOT, **env_extra):
@@ -59,40 +56,6 @@ def test_chip_smoke_fails_alone(tmp_path):
     assert p.returncode != 0
     assert _json_lines(p.stdout) == []
     assert "cannot import the program" in p.stderr
-
-
-def test_bench_default_mode_fails_without_accelerator():
-    p = _run([BENCH])
-    assert p.returncode != 0
-    assert p.stdout.strip() == ""               # no metric line
-    assert "no result" in p.stderr
-
-
-def test_bench_peak_table_and_mfu_gate():
-    """An unknown device kind is an error, not a silent 0.0 peak; a
-    rate above the chip's peak is a broken measurement."""
-    sys.path.insert(0, ROOT)
-    import bench
-
-    class Dev:
-        def __init__(self, kind):
-            self.device_kind = kind
-
-    assert bench._device_peak_tflops(Dev("TPU v5 lite")) == 197.0
-    with pytest.raises(KeyError, match="no peak FLOP/s"):
-        bench._device_peak_tflops(Dev("cpu"))
-    assert not bench._mfu_invalid(40.0, 197.0)
-    assert not bench._mfu_invalid(196_999.0, 197.0)
-    assert bench._mfu_invalid(325_988.7, 197.0)
-
-
-def test_bench_has_no_probe_reexec_or_promotion():
-    src = open(BENCH).read()
-    for gone in ("_ensure_live_backend", "SLU_BENCH_CHILD",
-                 "SLU_BENCH_FORCE_FALLBACK", "SLU_BENCH_ASSUME_LIVE",
-                 "_load_hw_record", "_save_hw_record", "execve",
-                 "cpu_fallback", "promoted"):
-        assert gone not in src, gone
 
 
 def test_chip_smoke_rehearsal_names_cpu_and_never_ok(tmp_path):

@@ -228,104 +228,6 @@ def test_warmup_signatures_are_segment_keys(monkeypatch):
     assert all(len(k) == 9 for k in fsigs_leg)
 
 
-def test_factor_cost_hint_arm_aware(tmp_path):
-    """factor_cost_hint_s must prefer the freshest record measured
-    under the ACTIVE arm — a merged-arm speedup shrinks fleet lease
-    TTLs instead of inheriting legacy-arm costs — and fall back to
-    the freshest record of any arm for pre-arm history."""
-    import json
-
-    from superlu_dist_tpu.serve import errors
-    p = tmp_path / "SOLVE_LATENCY.jsonl"
-    recs = [
-        {"mode": "solve_sweep", "t_factor_s": 60.0},      # pre-arm
-        {"mode": "solve_sweep", "factor_arm": "legacy",
-         "t_factor_s": 50.0},
-        {"mode": "solve_sweep", "factor_arm": "merged",
-         "t_factor_s": 20.0},
-        {"mode": "solve_sweep", "factor_arm": "merged+pallas",
-         "t_factor_s": 5.0},
-        # factor_ab rows are WARM numeric-only timings — the hint
-        # must ignore them (a lease must outlive the COLD wall)
-        {"mode": "factor_ab", "arm": "merged",
-         "t_factor_s": 0.37},
-    ]
-    p.write_text("".join(json.dumps(r) + "\n" for r in recs))
-    f = errors._factor_cost_from
-    f.cache_clear()
-    assert f(str(p), "merged") == 20.0
-    assert f(str(p), "legacy") == 50.0
-    assert f(str(p), "merged+pallas") == 5.0
-    # an arm with no record of its own: only UNSTAMPED pre-arm
-    # history may stand in — a record stamped under a DIFFERENT arm
-    # is ignored (ISSUE 16: it says nothing honest about this arm's
-    # cold wall)
-    assert f(str(p), "no-such-arm") == 60.0
-    # no arm requested: freshest non-factor_ab record of any arm
-    assert f(str(p), None) == 5.0
-    # only-factor_ab history -> no hint (cold wall unknown)
-    q = tmp_path / "ab_only.jsonl"
-    q.write_text(json.dumps(
-        {"mode": "factor_ab", "arm": "merged",
-         "t_factor_s": 0.37}) + "\n")
-    assert f(str(q), "merged") is None
-    # empty file -> None
-    r = tmp_path / "empty.jsonl"
-    r.write_text("")
-    assert f(str(r), "merged") is None
-    # only DIFFERENT-arm history -> conservative None, never adoption
-    s = tmp_path / "other_arm.jsonl"
-    s.write_text(json.dumps(
-        {"mode": "solve_sweep", "factor_arm": "merged",
-         "t_factor_s": 20.0}) + "\n")
-    assert f(str(s), "legacy") is None
-
-
-def test_factor_cost_hint_staleness_horizon(tmp_path):
-    """ISSUE-16 satellite: records older than the configurable
-    horizon are ignored — a lease TTL must never size itself off a
-    weeks-old measurement — and ts-less records (age unknown) are
-    exempt from the horizon's judgment."""
-    import json
-    import time as _time
-
-    from superlu_dist_tpu.serve import errors
-    f = errors._factor_cost_from
-    now = _time.time()
-
-    def stamp(age_s):
-        return _time.strftime("%Y-%m-%dT%H:%M:%S",
-                              _time.localtime(now - age_s))
-
-    p = tmp_path / "SOLVE_LATENCY.jsonl"
-    p.write_text("".join(json.dumps(r) + "\n" for r in [
-        {"mode": "solve_sweep", "t_factor_s": 500.0,
-         "ts": stamp(40 * 86400)},                   # weeks old
-        {"mode": "solve_sweep", "t_factor_s": 60.0,
-         "ts": stamp(3600)},                          # an hour old
-    ]))
-    f.cache_clear()
-    # horizon on: the stale record never wins, fresh one does
-    assert f(str(p), None, 30 * 86400.0) == 60.0
-    # horizon off (0): historical behavior, freshest record wins
-    assert f(str(p), None, 0.0) == 60.0
-    # ONLY stale history + horizon -> None (conservative default)
-    q = tmp_path / "stale_only.jsonl"
-    q.write_text(json.dumps(
-        {"mode": "solve_sweep", "t_factor_s": 500.0,
-         "ts": stamp(40 * 86400)}) + "\n")
-    assert f(str(q), None, 30 * 86400.0) is None
-    assert f(str(q), None, 0.0) == 500.0
-    # ts-less record: age unknown, horizon cannot judge it
-    r = tmp_path / "no_ts.jsonl"
-    r.write_text(json.dumps(
-        {"mode": "solve_sweep", "t_factor_s": 45.0}) + "\n")
-    assert f(str(r), None, 30 * 86400.0) == 45.0
-    # the public surface threads the flag through (monkeypatch-free:
-    # the default horizon keeps the committed fresh history eligible)
-    assert errors.factor_cost_hint_s(arm=None) is not None
-
-
 def test_factor_segment_hlo_contract():
     """The registry entry next to the code: donated slab streaming +
     promised assembly scatters survive the merged segment lowering

@@ -1,7 +1,7 @@
 """SLU_LEVEL_MERGE: one padded group per etree level — the
 sequential-chain lever for the latency-bound accelerator regime
 (fewer group bodies on the device per step, paying padded flops/slab;
-TPU_AB_CHAIN.jsonl holds its only chip price).  Correctness
+its only chip price is a pre-round record, not re-measured).  Correctness
 contract here: the merged schedule must solve to the same accuracy as
 the bucketed one on every path (single-device, fused, trans, mesh),
 with the child-slab stride read exactly as written (sup_slab_rb —

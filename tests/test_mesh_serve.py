@@ -1,9 +1,7 @@
 """Mesh-resident serving (ISSUE 17): the serve tier on a 2-CPU-device
 mesh.
 
-What tier-1 pins here (the hardware gate re-measures the same
-invariants per round via bench.py --multichip-serve → MULTICHIP_r*.json
-→ tools/regress.py):
+What tier-1 pins here:
 
 * the serve-path mesh solve is bitwise `array_equal` to the sequential
   one-device `mesh_oracle_solve` of the SAME lsum layout (NOREFINE —
@@ -319,8 +317,7 @@ def test_mesh_aot_warm_boot_serves_from_exports(tmp_path, monkeypatch):
     """The in-process cold→warm drill for the shard_map'd programs: a
     rebuilt world (fresh plan objects — the fresh-process stand-in)
     deserializes the mesh factor + merged solve exports (hits >= 2,
-    misses == 0) and serves bitwise-identical results.  The
-    two-process drill is tools/serve_bench.py --cold-boot."""
+    misses == 0) and serves bitwise-identical results."""
     mesh = _mesh2()
     a = laplacian_3d(4)
     b = np.random.default_rng(0).standard_normal((a.n, 2))

@@ -125,8 +125,8 @@ def test_export_off_is_one_pointer_check():
     assert not export.export_enabled()
     assert export.get_exporter() is None
     assert "export" not in obs.snapshot()
-    # export_snapshot() itself stays available (the drill's replica
-    # wire protocol serves it regardless of the HTTP flag)
+    # export_snapshot() itself stays available regardless of the
+    # HTTP flag
     assert aggregate.is_export_snapshot(export.export_snapshot())
 
 
@@ -395,8 +395,8 @@ def test_memory_prediction_within_documented_slack():
 
 
 def test_schedule_bytes_predicted_matches_handle_model():
-    """bench.py --plan-latency prices the prediction from the bare
-    schedule; the handle-side model must agree with it."""
+    """The prediction from the bare schedule and the handle-side
+    model must agree."""
     from superlu_dist_tpu.ops.batched import build_schedule
     from superlu_dist_tpu.plan import plan_factorization
     a = _testmat(8)

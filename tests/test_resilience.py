@@ -580,12 +580,6 @@ def test_degraded_disabled_propagates_failure():
 # --------------------------------------------------------------------
 
 def test_factor_cost_hint_reads_measured_trajectory():
-    """The '~500 s' class figure must come from SOLVE_LATENCY.jsonl
-    (or say 'minutes'), never a hardcoded stale number."""
-    hint = factor_cost_hint()
-    assert "measured" in hint or "minutes" in hint
-    # this repo carries the measured record: the hint must cite it
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if os.path.exists(os.path.join(root, "SOLVE_LATENCY.jsonl")):
-        assert "s measured" in hint
+    """The refusal text quotes no figure: the program reads no bench
+    record (it once quoted the last line of a latency file)."""
+    assert factor_cost_hint() == "minutes at production scale"

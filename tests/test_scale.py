@@ -67,7 +67,7 @@ def test_extend_add_indexes_huge_slab():
     needs the index dtype to represent the ARRAY SIZE (wrap
     normalization), so int32 source offsets must upcast at trace time
     even when the group's own span is small.  Trace-only via
-    eval_shape — no 8 GiB allocation (found by tools/compile_scale.py
+    eval_shape — no 8 GiB allocation (found by a compile-only run
     at K=100: OverflowError 5516008065 out of bounds for int32)."""
     import functools
     import jax
@@ -87,34 +87,3 @@ def test_extend_add_indexes_huge_slab():
         jax.ShapeDtypeStruct((big,), jnp.float32),
         ea_blocks)
     assert out.shape == (n_pad * mb * mb,)
-
-
-import pytest
-
-
-@pytest.mark.scale
-def test_target_scale_end_to_end_262k():
-    """The audikw_1-class certification (BASELINE config #3 envelope,
-    EXAMPLE/pddrive3d.c): a REAL n=262,144 (k=64 3D Laplacian)
-    factorization + solve through the production staged path — plan,
-    parallel compile warmup, per-group staged dispatch, sweeps, f64
-    refinement — must execute (not just trace) and meet the accuracy
-    contract.  ~30+ min on a 1-core host, hence the scale marker; the
-    committed telemetry of this exact run is SCALE_r04.json
-    (tools/scale_run.py)."""
-    import json
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env.update(PYTHONPATH=repo, JAX_PLATFORMS="cpu", SLU_SCALE_K="64",
-               SLU_SCALE_OUT=os.path.join(repo, "SCALE_r04.json"))
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "scale_run.py")],
-        env=env, capture_output=True, text=True, timeout=7200)
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.splitlines()[-1])
-    assert rec["n"] == 262144 and rec["staged"]
-    assert rec["berr"] < 1e-14 and rec["relerr"] < 1e-12
-    assert rec["refine_steps"] >= 1 and rec["escalations"] == 0

@@ -26,8 +26,7 @@ each of those into a checked contract:
     guard (the PR 5 deadlock class), joins while holding a lock.
 
 Violations ratchet against the committed SLULINT_BASELINE.json
-(`--update` refreshes it, preserving per-entry justifications — the
-same legitimate-change workflow as tools/regress.py).  CLI:
+(`--update` refreshes it, preserving per-entry justifications).  CLI:
 
     python -m tools.slulint              # full gate; rc != 0 on new findings
     python -m tools.slulint --no-contracts   # fast: AST + locks only
@@ -78,12 +77,12 @@ def rel(path: str, root: str | None = None) -> str:
 
 
 def default_scan_files(root: str | None = None) -> list[str]:
-    """The gate's scan set: the package, tools/ and bench.py — the
+    """The gate's scan set: the package and tools/ — the
     same universe tests/test_flags.py always audited.  tests/ are
     deliberately out (fixtures under tests/fixtures/slulint SEED
     violations)."""
     root = root or repo_root()
-    out = [os.path.join(root, "bench.py")]
+    out = []
     for top in ("superlu_dist_tpu", "tools"):
         for dirpath, dirnames, filenames in os.walk(
                 os.path.join(root, top)):
@@ -91,7 +90,7 @@ def default_scan_files(root: str | None = None) -> list[str]:
             for f in sorted(filenames):
                 if f.endswith(".py"):
                     out.append(os.path.join(dirpath, f))
-    return [p for p in out if os.path.exists(p)]
+    return out
 
 
 _ANN = re.compile(r"#\s*slulint:\s*(.+?)\s*$")

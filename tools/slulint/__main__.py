@@ -34,7 +34,7 @@ def _run_ruff(root: str) -> tuple[list[Finding], bool]:
     try:
         proc = subprocess.run(
             [exe, "check", "--output-format", "json", "--exit-zero",
-             "superlu_dist_tpu", "tools", "bench.py"],
+             "superlu_dist_tpu", "tools"],
             cwd=root, capture_output=True, text=True, timeout=120)
         items = json.loads(proc.stdout or "[]")
     except (OSError, ValueError, subprocess.TimeoutExpired):

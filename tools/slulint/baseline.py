@@ -1,7 +1,6 @@
 """Baseline ratchet: committed findings that are tolerated, for now.
 
-Same legitimate-change workflow as tools/regress.py's BASELINES.json
-(DESIGN.md §15): a finding either gets FIXED, or it ships in
+A finding either gets FIXED, or it ships in
 SLULINT_BASELINE.json with a per-entry justification, reviewed next
 to the code that earns it.  The gate fails on any finding NOT in the
 baseline; baseline entries that no longer occur are reported as

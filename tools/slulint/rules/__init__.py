@@ -6,7 +6,7 @@ path and lives here so the catalog below is the one place to read
 where each rule applies:
 
   env-read         superlu_dist_tpu/** except flags.py (the gateway);
-                   tools/ and bench.py are drivers and exempt
+                   tools/ are drivers and exempt
   host-call-in-jit everywhere scanned — host-only calls (time.*,
                    np.random, print, open, env reads) inside
                    jit-decorated or traced-closure functions

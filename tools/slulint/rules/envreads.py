@@ -76,7 +76,7 @@ def _const_arg(call: ast.Call) -> str:
 
 def flag_audit(root: str) -> list[Finding]:
     """`undocumented-flag` / `stale-flag`: every SLU_* token in the
-    package, tools/ and bench.py must be documented in
+    package and tools/ must be documented in
     superlu_dist_tpu/flags.py FLAGS (or listed in NON_FLAG_TOKENS),
     and FLAGS must carry no entry nothing reads — the audit
     tests/test_flags.py ran as a grep since PR 2, now a slulint rule

@@ -26,11 +26,6 @@ converts those artifacts:
         replica, one ph="C" series per numeric provider leaf —
         the replica's counters over the run.  Auto-detected per line
         (the "slu.obs.snapshot" schema stamp).
-
-It is also the shared converter tools/tpu_profile.py uses to emit its
-fusion-class buckets as spans in the same trace format
-(`chrome_trace_from_profile`), so the profiled-step breakdown and the
-solver's own phase spans open in the same viewer.
 """
 
 from __future__ import annotations
@@ -290,38 +285,6 @@ def summarize(events: list) -> dict:
                                 + ev.get("dur", 0) / 1e3, 3)
     return {"events": len(events), "threads": len(tids),
             "compile_events": compiles, "spans": by_name}
-
-
-def chrome_trace_from_profile(rec: dict) -> list:
-    """tpu_profile.py summary record -> trace events: one synthetic
-    timeline per xplane plane, fusion-class buckets laid end-to-end on
-    a 'fusion classes' track and the top ops on a 'top ops' track (the
-    buckets are aggregates, so intra-track ordering is by weight, not
-    true time — the per-class totals are what the budget reads)."""
-    events = []
-    for pid, plane in enumerate(rec.get("planes", [])):
-        events.append({"name": "process_name", "ph": "M", "pid": pid,
-                       "tid": 0,
-                       "args": {"name": plane.get("plane", "?")}})
-        for tid, (track, items) in enumerate((
-                ("fusion classes",
-                 [(k, v) for k, v in plane.get(
-                     "fusion_class_ms", {}).items()]),
-                ("top ops",
-                 [(e["op"], e["total_ms"])
-                  for e in plane.get("events", [])])), start=1):
-            events.append({"name": "thread_name", "ph": "M",
-                           "pid": pid, "tid": tid,
-                           "args": {"name": track}})
-            ts = 0
-            for name, ms in items:
-                dur = max(1, int(ms * 1e3))
-                events.append({"name": name, "cat": "profile",
-                               "ph": "X", "ts": ts, "dur": dur,
-                               "pid": pid, "tid": tid,
-                               "args": {"total_ms": ms}})
-                ts += dur
-    return events
 
 
 def main(argv=None) -> int:
