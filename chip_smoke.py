@@ -286,8 +286,7 @@ def run(args) -> int:
         import numpy as np
         import scipy.sparse.linalg as spla
         import superlu_dist_tpu as slu
-        from superlu_dist_tpu.models.gssvx import (_ESC_BERR_SLACK,
-                                                   solve_rhs_dtype)
+        from superlu_dist_tpu.models.gssvx import _ESC_BERR_SLACK
         from superlu_dist_tpu.ops import batched, trisolve
         from superlu_dist_tpu.utils import native
         from superlu_dist_tpu.utils.cache import place_compile_cache
@@ -385,7 +384,9 @@ def run(args) -> int:
         refine_steps=st.refine_steps, escalations=st.escalations,
         phase_walls_s={p: round(t, 4) for p, t in st.utime.items()},
         factor_dtype=str(lu.device_lu.dtype),
-        sweep_dtype=str(solve_rhs_dtype(lu)),
+        # the operand dtype(s) the sweeps of this solve actually took
+        # (Stats.sweeps: the factor's precision, whatever b's is)
+        sweep_dtype="+".join(sorted(st.sweeps)),
         refine="host loop (models/refine.py), residual in "
                + lu.effective_options.refine_dtype,
         staged=hasattr(lu.device_lu, "panels"),

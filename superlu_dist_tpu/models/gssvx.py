@@ -276,11 +276,12 @@ def solve(lu: LUFactorization, b: np.ndarray,
     squeeze = b.ndim == 1
     bb = b[:, None] if squeeze else b
     if options.solve_dtype is not None:
-        # PrecisionPolicy.solve_dtype: pin the sweep-RHS precision
-        # instead of letting the caller's RHS dtype promote the whole
-        # solve pipeline (an fp32 service pipeline must not pay fp64
-        # sweeps because a client sent a float64 buffer).  Realness is
-        # the system's, precision is the policy's.
+        # PrecisionPolicy.solve_dtype: an explicit pin that downcasts
+        # the CLIENT's buffer (an fp32 service pipeline stays fp32
+        # end to end: residual and answer are then to the rounded b).
+        # It is not the sweeps' operand dtype, which follows the
+        # factors below whatever is pinned here.  Realness is the
+        # system's, precision is the policy's.
         sdt = np.dtype(options.solve_dtype)
         if np.issubdtype(bb.dtype, np.complexfloating):
             sdt = np.promote_types(sdt, np.complex64)
@@ -326,21 +327,39 @@ def solve(lu: LUFactorization, b: np.ndarray,
 
         solver = _solve_factored_trans
 
+    from ..precision.policy import sweep_operand_dtype
     from ..utils.platform import complex_device_gate
     factor_dt = np.dtype(lu.effective_options.factor_dtype)
+    sweeps: dict = {}       # this solve's sweeps by operand dtype
+
+    def sweep(lu_, v):
+        # every triangular sweep — x0's and each refinement
+        # correction's — takes its operand in the FACTOR's precision
+        # (psgsrfs_d2: residual in double, correction in single; the
+        # one rule the fused device loop shares).  The cast comes
+        # AFTER to_factor_rhs, so scaling and permutation run in the
+        # caller's / the refine dtype, and the residual, berr and
+        # x += δ stay there against the unrounded b.
+        op = v.astype(sweep_operand_dtype(factor_dt, v.dtype),
+                      copy=False)
+        for count in (sweeps, stats.sweeps):
+            count[op.dtype.name] = count.get(op.dtype.name, 0) + 1
+        return solver(lu_, op)
+
     with complex_device_gate(factor_dt, bb.dtype, stats=stats,
                              phase="SOLVE"):
         obs.take_cost("solve")  # drop any stale unread stamp
         with stats.timer("SOLVE"):
-            x = from_factor_sol(solver(lu, to_factor_rhs(bb)))
+            x = from_factor_sol(sweep(lu, to_factor_rhs(bb)))
         stats.set_measured_cost("SOLVE", obs.take_cost("solve"))
 
         if options.iter_refine != IterRefine.NOREFINE and lu.a is not None:
             from .refine import iterative_refine
             with stats.timer("REFINE"):
                 x, berr, steps, stalled = iterative_refine(
-                    lu, bb, x, solver, to_factor_rhs, from_factor_sol,
-                    trans=(options.trans == Trans.TRANS))
+                    lu, bb, x, sweep, to_factor_rhs, from_factor_sol,
+                    trans=(options.trans == Trans.TRANS),
+                    sweeps=sweeps)
             stats.berr = berr
             stats.refine_steps += steps
             stats.refine_stalled = stalled
@@ -373,13 +392,16 @@ def perm_scale_vectors(plan: FactorPlan, trans: Trans):
 
 
 def solve_rhs_dtype(lu: LUFactorization) -> np.dtype:
-    """The dtype a plain float64 RHS produces after the solve path's
-    promote_types against the factors — the ONE definition of the
-    compiled solve program's operand dtype, shared by warm_solve and
-    the serve micro-batcher (warming a different dtype compiles the
-    wrong program).  An explicit Options.solve_dtype
-    (PrecisionPolicy's sweep-precision pin) replaces the float64
-    default the promotion otherwise assumes of the RHS."""
+    """The HOST-side dtype of a solve fed plain float64 right-hand
+    sides: the one dtype the serve micro-batcher assembles a batch in
+    and warm_solve's zero block has (what a float64 RHS promotes to
+    against the factors; an explicit Options.solve_dtype, the pin
+    that downcasts client buffers, replaces the float64).  It is NOT
+    the compiled sweep program's operand dtype: solve() casts every
+    sweep's operand to the factor's precision
+    (precision/policy.sweep_operand_dtype) and keeps residual and
+    answer in the refine dtype against the batch as assembled here —
+    casting the batch itself would answer a rounded b."""
     opts = lu.effective_options
     rhs = (np.dtype(opts.solve_dtype) if opts.solve_dtype is not None
            else np.dtype(np.float64))

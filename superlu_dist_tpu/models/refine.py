@@ -3,8 +3,11 @@
 Classic Wilkinson loop: r = b − A·x (accumulated in refine_dtype, the
 psgsrfs_d2 mixed-precision strategy when the factorization ran in a
 lower precision, SRC/psgsrfs_d2.c:229), solve A·δ = r with the existing
-factorization, x += δ, until the componentwise backward error `berr`
-stops improving (same stopping rule family as the reference: stop when
+factorization IN THE FACTOR'S PRECISION (the caller's `solve_factored`
+casts r after `to_factor_rhs`: precision/policy.sweep_operand_dtype,
+the one rule this loop and the fused device loop share), x += δ in
+refine_dtype, until the componentwise backward error `berr` stops
+improving (same stopping rule family as the reference: stop when
 berr < eps or improvement < 2×).
 
 This is the HOST loop (scipy CSR residuals — already scatter-free).
@@ -83,7 +86,11 @@ def _operands(lu, sys_dtype):
 
 
 def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
-                     from_factor_sol, trans: bool = False):
+                     from_factor_sol, trans: bool = False,
+                     sweeps: dict | None = None):
+    """`sweeps` is the caller's live count of this solve's sweeps by
+    operand dtype (x0's included; `solve_factored` adds to it): it
+    rides the health ring's record next to `steps`."""
     opts = lu.effective_options
     # the system's realness is set by matrix AND rhs: a real matrix
     # with a complex b still needs a complex accumulator
@@ -151,7 +158,7 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                              berr_trajectory=berr_traj,
                              ferr_trajectory=ferr_traj,
                              converged=converged,
-                             stalled=stalled)
+                             stalled=stalled, sweeps=sweeps)
     # `stalled` rides back to the driver: the escalation ladder
     # (gssvx) labels its health event with the signal that fired
     # (precision/policy.classify_trigger), and "the loop quit because

@@ -124,10 +124,13 @@ class HealthMonitor:
     def record_refine(self, *, berr: float, steps: int,
                       berr_trajectory=(), ferr_trajectory=(),
                       converged: bool = True,
-                      stalled: bool = False) -> None:
+                      stalled: bool = False,
+                      sweeps: dict | None = None) -> None:
         """One refinement loop's outcome.  `ferr_trajectory` is the
         per-step forward-error estimate ‖δ‖/‖x‖ (the correction-norm
-        proxy for pdgsrfs' FERR output).  `stalled` means the loop
+        proxy for pdgsrfs' FERR output).  `sweeps` counts the solve's
+        triangular sweeps (x0's and the corrections') by operand
+        dtype.  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""
@@ -143,6 +146,7 @@ class HealthMonitor:
                 "ferr_trajectory": [float(f) for f in ferr_trajectory],
                 "converged": bool(converged),
                 "stalled": bool(stalled),
+                "sweeps": dict(sweeps or {}),
             })
         if stalled:
             _tracer.instant("health.refine_stalled", cat="health",
@@ -194,6 +198,7 @@ class HealthMonitor:
                 "last_berr": self.last_berr,
                 "last_pivot_growth": self.last_pivot_growth,
                 "last_solve": dict(last) if last else None,
+                "recent_solves": [dict(e) for e in self._recent],
                 "perturbed_factorizations":
                     self.perturbed_factorizations,
                 "pivot_growth_unavailable":

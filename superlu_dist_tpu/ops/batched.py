@@ -2750,6 +2750,12 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     # trace and the staged host loop, so the two cannot diverge ----
 
     rrdt = _real_dtype(rdt)
+    # the sweeps' operand dtype, by the ONE rule the host loop
+    # (models/gssvx.solve) states too; pair mode sweeps its real planes
+    from ..precision.policy import sweep_operand_dtype
+    sweep_dt = sweep_operand_dtype(dtype, rdt)
+    if pair:
+        sweep_dt = _real_dtype(sweep_dt)
 
     def _scale_impl(vals):
         # real scale factors: plane-wise in pair mode ((2, nnz)
@@ -2763,8 +2769,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
         both halves identically, so the same gather/scale works —
         only the target dtype changes to the factor PLANE dtype."""
         return ((r * ops["row_scale"][:, None])
-                [ops["inv_final_row"]]).astype(
-                    _real_dtype(dtype) if pair else dtype)
+                [ops["inv_final_row"]]).astype(sweep_dt)
 
     def _post_impl(y):
         """factor-order sweep output -> original-order correction."""

@@ -93,9 +93,9 @@ FACTOR_KEY_FIELDS = (
 # FACTORED rung (models/gssvx.py gssvx), never part of the cache key.
 # residual_mode/solve_dtype are the solve-side half of a
 # PrecisionPolicy (precision/policy.py): they change how refinement
-# accumulates and what RHS dtype the sweeps compile for, never what
-# factors are computed — so they ride the per-request merge and split
-# batcher variants, not cache entries.
+# accumulates and what dtype client right-hand sides are held in,
+# never what factors are computed — so they ride the per-request
+# merge and split batcher variants, not cache entries.
 SOLVE_TIME_FIELDS = ("trans", "iter_refine", "refine_dtype",
                      "max_refine_steps", "residual_mode",
                      "solve_dtype")
@@ -193,11 +193,13 @@ class Options:
     residual_mode: str = dataclasses.field(
         default_factory=lambda: _flags.env_str(
             "SLU_PREC_RESIDUAL", "auto") or "auto")
-    # Triangular-sweep RHS dtype (PrecisionPolicy.solve_dtype): None
-    # follows the factors' promotion rule (solve_rhs_dtype in
-    # models/gssvx.py — a float64 RHS promotes against the factor
-    # dtype); an explicit "float32" keeps an fp32 pipeline end-to-end
-    # instead of silently paying fp64 sweeps for an fp64 RHS.
+    # Client right-hand-side dtype pin (PrecisionPolicy.solve_dtype):
+    # None takes b as sent (a float64 b is refined and answered in
+    # float64 against the unrounded b); an explicit "float32"
+    # DOWNCASTS client buffers and keeps an fp32 pipeline end to end
+    # (the answer is then to the rounded b).  Either way the
+    # triangular sweeps take their operand in the factor's precision
+    # (precision/policy.sweep_operand_dtype), never in this one.
     solve_dtype: str | None = None
 
     # --- iterative refinement controls ---

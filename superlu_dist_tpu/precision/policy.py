@@ -12,8 +12,11 @@ through every numeric phase and the serve layer:
   * `factor_dtype` — the numeric factorization's precision (an
     Options.FACTOR_KEY_FIELDS member: it changes what factors are
     computed, so it re-keys the serve factor cache).
-  * `solve_dtype`  — the triangular-sweep RHS precision (a solve-time
-    knob; None follows the factors).
+  * `solve_dtype`  — an explicit pin of the dtype client right-hand
+    sides are cast to before the solve (a solve-time knob that
+    downcasts client buffers; None leaves them as sent).  It is NOT
+    the sweeps' operand dtype: that is always the factor's precision
+    (`sweep_operand_dtype`, below), whatever this field says.
   * `residual`     — how `r = b − A·x` is accumulated during
     refinement: PLAIN (working precision), DOUBLEWORD (two-float df64
     fp32 pairs, zero fp64 ops in the jitted path —
@@ -87,6 +90,23 @@ def resolve_residual_mode(options: Options) -> str:
             else ResidualMode.FP64.value)
 
 
+def sweep_operand_dtype(factor_dtype, operand_dtype) -> np.dtype:
+    """The ONE rule for the dtype a triangular sweep's operand is cast
+    to, called by the host loop (models/gssvx.solve, for x0's
+    right-hand side and every refinement correction's residual) and by
+    the device loop (ops/batched.make_fused_solver): the FACTOR's
+    precision — psgsrfs_d2's scheme (SRC/psgsrfs_d2.c:229: residual in
+    double, the correction solved in single).  Realness is the
+    system's: a complex operand on real factors takes the complex
+    dtype of the factor's width (complex64 for bfloat16, which has no
+    complex twin).  The residual, berr and the accumulation of the
+    answer stay in the refine dtype, outside this rule."""
+    fdt = np.dtype(factor_dtype)
+    if np.dtype(operand_dtype).kind == "c" and fdt.kind != "c":
+        return np.promote_types(fdt, np.complex64)
+    return fdt
+
+
 def _eps(dtype_name: str) -> float:
     """eps of a dtype name; jnp.finfo understands the ml_dtypes
     families (bfloat16) that numpy's doesn't."""
@@ -99,7 +119,7 @@ class PrecisionPolicy:
     """One precision strategy, applied to Options via `apply()`."""
 
     factor_dtype: str = "float32"
-    solve_dtype: Optional[str] = None      # None: follow the factors
+    solve_dtype: Optional[str] = None      # None: client rhs as sent
     residual: ResidualMode = ResidualMode.DOUBLEWORD
     target_dtype: str = "float64"          # the accuracy class sold
 

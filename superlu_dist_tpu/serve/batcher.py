@@ -114,13 +114,17 @@ class MicroBatcher:
         self.ladder = tuple(sorted(ladder))
         self.metrics = metrics or Metrics()
         self._solve_fn = solve_fn or solve
-        # the ONE dtype every batch is assembled in — program identity
-        # must not depend on batch composition.  Default: the shared
-        # gssvx.solve_rhs_dtype rule (complex factors promote to
-        # c128).  submit() rejects an RHS that would promote past it —
-        # unless `cast_rhs` (the variant carries an EXPLICIT
-        # Options.solve_dtype, whose whole point is downcasting client
-        # buffers to the pinned sweep precision).
+        # the ONE dtype every batch is assembled in — the residual's
+        # and the answer's dtype must not depend on batch composition.
+        # Default: the shared gssvx.solve_rhs_dtype rule (f64 against
+        # f32 factors; complex factors promote to c128).  It is the
+        # HOST side's dtype: solve() casts each sweep's operand to the
+        # factor's precision itself and refines against this batch
+        # unrounded, so the batch must NOT be cast down here.
+        # submit() rejects an RHS that would promote past it — unless
+        # `cast_rhs` (the variant carries an EXPLICIT
+        # Options.solve_dtype, whose whole point is downcasting
+        # client buffers).
         self.dtype = (np.dtype(dtype) if dtype is not None
                       else solve_rhs_dtype(lu))
         self.cast_rhs = cast_rhs
@@ -163,10 +167,10 @@ class MicroBatcher:
             raise ValueError(
                 f"rhs must be ({self.lu.n},); got {b.shape}")
         if self.cast_rhs:
-            # the variant's solve_dtype pin: the compiled program's
-            # dtype wins over the client buffer's (models/gssvx.solve
-            # performs the same cast; doing it here keeps the batch
-            # assembly single-dtype)
+            # the variant's solve_dtype pin: the pinned dtype wins
+            # over the client buffer's (models/gssvx.solve performs
+            # the same cast; doing it here keeps the batch assembly
+            # single-dtype)
             b = b.astype(self.dtype, copy=False)
         elif np.promote_types(b.dtype, self.dtype) != self.dtype:
             raise ValueError(

@@ -1224,11 +1224,11 @@ class SolveService:
                         "factors evicted concurrently; resubmit to "
                         "re-factor")
                 # assembly dtype from the MERGED options — the dtype
-                # the dispatch's solve() actually compiles for.  An
-                # explicit request solve_dtype both re-types the batch
-                # (no inline recompile on first live dispatch) and
-                # downcasts client buffers (cast_rhs) instead of
-                # tripping the promote-past rejection
+                # the dispatch's solve() refines and answers in (its
+                # sweeps take the factor's precision whatever this
+                # is).  An explicit request solve_dtype both re-types
+                # the batch and downcasts client buffers (cast_rhs)
+                # instead of tripping the promote-past rejection
                 merged = merge_solve_options(lu.effective_options,
                                              options)
                 from ..models.gssvx import solve_rhs_dtype

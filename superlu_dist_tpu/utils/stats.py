@@ -95,6 +95,11 @@ class Stats:
     # halving short of eps — models/refine.py); the escalation
     # ladder's trigger classification reads it
     refine_stalled: bool = False
+    # triangular sweeps under this Stats by operand dtype (x0's and
+    # every refinement correction's; models/gssvx.solve casts each to
+    # the factor's precision, precision/policy.sweep_operand_dtype,
+    # and counts it here: accumulates over solves like refine_steps)
+    sweeps: Dict[str, int] = dataclasses.field(default_factory=dict)
     # precision escalations: low-precision factor failed refinement,
     # refactored at refine_dtype (gssvx _should_escalate)
     escalations: int = 0
@@ -203,6 +208,7 @@ class Stats:
             "refine_steps": self.refine_steps,
             "berr": self.berr,
             "refine_stalled": self.refine_stalled,
+            "sweeps": dict(self.sweeps),
             "escalations": self.escalations,
             "lu_nnz": self.lu_nnz,
             "lu_bytes": self.lu_bytes,
@@ -236,6 +242,9 @@ class Stats:
                 for i, e in enumerate(self.factor_events))
             lines.append(f"    per factorization:  {per}")
         lines.append(f"  refinement steps:     {self.refine_steps}")
+        if self.sweeps:
+            lines.append("  sweeps by operand:    " + ", ".join(
+                f"{k} {v}" for k, v in sorted(self.sweeps.items())))
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
         if self.placement:

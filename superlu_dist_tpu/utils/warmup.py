@@ -126,8 +126,9 @@ def warmup_staged(plan, dtype="float32", nrhs: int = 1,
     """AOT-compile every distinct staged program for `plan`
     concurrently.  Covers the factor groups and the solve sweeps for
     `rhs_dtype` right-hand sides (default float64, the gssvx flow:
-    the sweep X carries the promoted dtype; a different rhs dtype
-    compiles separately on first use).
+    the sweep X carries the FACTOR's precision whatever the rhs's,
+    precision/policy.sweep_operand_dtype; only the rhs's realness
+    reaches the program).
 
     Returns {"factor_programs", "sweep_programs", "workers", "secs"}.
     """
@@ -210,9 +211,11 @@ def warmup_staged(plan, dtype="float32", nrhs: int = 1,
                 pair=False)
         lowered.compile()
 
-    # X carries promote(factor, rhs) and is real-encoded for complex
+    # X carries the sweep operand's dtype (the factor's precision, the
+    # rule gssvx.solve dispatches by) and is real-encoded for complex
     # systems (real/imag halves along the rhs axis — ops/batched._enc)
-    pdt = np.promote_types(dtype, np.dtype(rhs_dtype))
+    from ..precision.policy import sweep_operand_dtype
+    pdt = sweep_operand_dtype(dtype, rhs_dtype)
     x_cplx = pdt.kind == "c"
     xdt = B._real_dtype(pdt)
     r_hat = 2 * nrhs if x_cplx else nrhs
