@@ -183,8 +183,9 @@ def _solve_fused(a, b, opts, stats):
         from ..utils.platform import complex_device_gate
         fdt = effective_factor_dtype(a.dtype, dtype_name)
         # the fused solver is pair-capable (make_fused_solver pair
-        # mode), so the default gate applies: SLU_COMPLEX_PAIR=1
-        # lifts it and the complex pipeline compiles complex-free
+        # mode), so the default gate applies: on a TPU the rule
+        # (utils/platform.complex_lowering) gives it the pair
+        # lowering and the complex pipeline compiles complex-free
         with complex_device_gate(fdt, a.dtype, stats=stats,
                                  phase=phase):
             step = make_fused_solver(plan, dtype=fdt)

@@ -47,8 +47,8 @@ FLAGS: dict[str, str] = {
     "SLU_SPMV_LAYOUT": "auto|ell|coo residual SpMV layout (ell = scatter-free padded rows)",
     "SLU_SPMV_ELL_WASTE": "max ELL padding ratio over true nnz before falling back to COO (default 4)",
     # --- complex storage / platform gates (ops, utils/platform.py) ---
-    "SLU_COMPLEX_PAIR": "1 = store complex factors as stacked real/imag planes (TPU lowering workaround)",
-    "SLU_COMPLEX_TPU": "1 = re-enable on-accelerator complex despite the known mesh lowering hang",
+    "SLU_COMPLEX_PAIR": "TEST HOOK: 1 = force the pair lowering of complex (factors as stacked real/imag planes, an all-real program) on a backend that would run native, i.e. XLA:CPU.  Decides nothing on a TPU, where pair is what a complex factor dtype takes with no variable set (utils/platform.complex_lowering)",
+    "SLU_COMPLEX_TPU": "1 = run NATIVE complex on a TPU: no pair lowering, no CPU gate, the complex mesh block lifted — for whoever repairs the native lowering (native complex128 aborts today's TPU compiler)",
     "SLU_MATMUL_PREC": "default|high|highest jax matmul precision pin applied at import (__init__.py)",
     # --- cooperative mesh factorization (ops/coop_lu.py, coop_sharded.py) ---
     "SLU_COOP_SHARDED": "1/0 sharded cooperative mesh path vs legacy replicated coop",

@@ -118,13 +118,18 @@ def factorize(a: CSRMatrix, options: Options | None = None,
         raise ValueError(
             "complex factorization on a TPU mesh is disabled: "
             "native complex does not compile on this chip "
-            "(utils/platform.py) and the pair lowering is "
-            "single-device. Use a CPU mesh, or SLU_COMPLEX_TPU=1 to "
-            "override.")
+            "(utils/platform.py) and the pair lowering that runs "
+            "complex there is single-device. Use a CPU mesh, or "
+            "SLU_COMPLEX_TPU=1 to override.")
     # drop any stale stamp from a direct ops-layer call the driver
     # never read (the host path below stamps nothing)
     obs.take_cost("factor")
+    # complex on a TPU: the one-device jax backend takes the pair
+    # lowering and stays on the chip (utils/platform.complex_lowering);
+    # the host oracle and a CPU mesh have no pair storage and keep the
+    # gated placement
     with complex_device_gate(np.dtype(options.factor_dtype),
+                             pair_capable=(backend == "jax"),
                              stats=stats, phase=_phase), \
             stats.timer(_phase):
         if backend == "host":
@@ -216,7 +221,8 @@ def factorize(a: CSRMatrix, options: Options | None = None,
         mem=mem,
         flops={"useful": stats.factor_flops,
                "executed": stats.factor_flops_executed},
-        extend_add=stats.ea_elements)
+        extend_add=stats.ea_elements,
+        complex_lowering=stats.complex_lowering.get(_phase))
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,
@@ -331,6 +337,13 @@ def solve(lu: LUFactorization, b: np.ndarray,
     from ..utils.platform import complex_device_gate
     factor_dt = np.dtype(lu.effective_options.factor_dtype)
     sweeps: dict = {}       # this solve's sweeps by operand dtype
+    # a handle keeps the storage it was made with: pair-stored factors
+    # sweep on the default backend (all-real programs), natively
+    # stored ones cannot take the pair lowering and are gated on a TPU
+    stored = "native"
+    if lu.backend == "jax":
+        from ..ops.batched import _lu_is_pair
+        stored = "pair" if _lu_is_pair(lu.device_lu) else "native"
 
     def sweep(lu_, v):
         # every triangular sweep — x0's and each refinement
@@ -346,8 +359,9 @@ def solve(lu: LUFactorization, b: np.ndarray,
             count[op.dtype.name] = count.get(op.dtype.name, 0) + 1
         return solver(lu_, op)
 
-    with complex_device_gate(factor_dt, bb.dtype, stats=stats,
-                             phase="SOLVE"):
+    with complex_device_gate(factor_dt, bb.dtype,
+                             pair_capable=(stored == "pair"),
+                             stats=stats, phase="SOLVE"):
         obs.take_cost("solve")  # drop any stale unread stamp
         with stats.timer("SOLVE"):
             x = from_factor_sol(sweep(lu, to_factor_rhs(bb)))
@@ -359,7 +373,8 @@ def solve(lu: LUFactorization, b: np.ndarray,
                 x, berr, steps, stalled = iterative_refine(
                     lu, bb, x, sweep, to_factor_rhs, from_factor_sol,
                     trans=(options.trans == Trans.TRANS),
-                    sweeps=sweeps)
+                    sweeps=sweeps,
+                    lowering=stats.complex_lowering.get("SOLVE"))
             stats.berr = berr
             stats.refine_steps += steps
             stats.refine_stalled = stalled

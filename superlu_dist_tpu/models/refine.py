@@ -87,10 +87,13 @@ def _operands(lu, sys_dtype):
 
 def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                      from_factor_sol, trans: bool = False,
-                     sweeps: dict | None = None):
+                     sweeps: dict | None = None,
+                     lowering: str | None = None):
     """`sweeps` is the caller's live count of this solve's sweeps by
     operand dtype (x0's included; `solve_factored` adds to it): it
-    rides the health ring's record next to `steps`."""
+    rides the health ring's record next to `steps`, and so does
+    `lowering`, the complex lowering those sweeps ran under
+    (Stats.complex_lowering; None for a real system)."""
     opts = lu.effective_options
     # the system's realness is set by matrix AND rhs: a real matrix
     # with a complex b still needs a complex accumulator
@@ -158,7 +161,8 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                              berr_trajectory=berr_traj,
                              ferr_trajectory=ferr_traj,
                              converged=converged,
-                             stalled=stalled, sweeps=sweeps)
+                             stalled=stalled, sweeps=sweeps,
+                             complex_lowering=lowering)
     # `stalled` rides back to the driver: the escalation ladder
     # (gssvx) labels its health event with the signal that fired
     # (precision/policy.classify_trigger), and "the loop quit because

@@ -65,7 +65,8 @@ class HealthMonitor:
                       perturbation: dict | None = None,
                       mem: dict | None = None,
                       flops: dict | None = None,
-                      extend_add: dict | None = None) -> None:
+                      extend_add: dict | None = None,
+                      complex_lowering: str | None = None) -> None:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
@@ -74,7 +75,10 @@ class HealthMonitor:
         (obs/memory.py) — every factorization carries one.  `flops`
         is its {useful, executed} flop count (Stats.factor_flops,
         Stats.factor_flops_executed), `extend_add` its extend-add
-        elements by lane (Stats.ea_elements)."""
+        elements by lane (Stats.ea_elements), `complex_lowering` how
+        a complex factorization was lowered and where ("pair",
+        "native", or "cpu" for a gated placement:
+        Stats.complex_lowering; None for a real one)."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -93,6 +97,7 @@ class HealthMonitor:
                 "flops": dict(flops) if flops is not None else None,
                 "extend_add": ({k: dict(v) for k, v in extend_add.items()}
                                if extend_add else None),
+                "complex_lowering": complex_lowering,
             })
         if tiny_pivots:
             _tracer.instant("health.tiny_pivots", cat="health",
@@ -125,12 +130,14 @@ class HealthMonitor:
                       berr_trajectory=(), ferr_trajectory=(),
                       converged: bool = True,
                       stalled: bool = False,
-                      sweeps: dict | None = None) -> None:
+                      sweeps: dict | None = None,
+                      complex_lowering: str | None = None) -> None:
         """One refinement loop's outcome.  `ferr_trajectory` is the
         per-step forward-error estimate ‖δ‖/‖x‖ (the correction-norm
         proxy for pdgsrfs' FERR output).  `sweeps` counts the solve's
         triangular sweeps (x0's and the corrections') by operand
-        dtype.  `stalled` means the loop
+        dtype, `complex_lowering` says how they were lowered and
+        where (as record_factor's).  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""
@@ -147,6 +154,7 @@ class HealthMonitor:
                 "converged": bool(converged),
                 "stalled": bool(stalled),
                 "sweeps": dict(sweeps or {}),
+                "complex_lowering": complex_lowering,
             })
         if stalled:
             _tracer.instant("health.refine_stalled", cat="health",

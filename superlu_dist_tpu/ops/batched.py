@@ -1188,10 +1188,11 @@ def _real_dtype(dtype: np.dtype):
 def _pair_mode(dtype) -> bool:
     """Factor complex systems on stacked real/imag planes
     (ops/pair_lu, _factor_group_impl_pair) instead of native complex
-    storage — the lowering detour for platforms whose base-level
-    complex compilation is broken (utils/platform.py)."""
-    from ..utils.platform import complex_pair_enabled
-    return np.dtype(dtype).kind == "c" and complex_pair_enabled()
+    storage: what utils/platform.complex_lowering gives `dtype` (a
+    complex dtype on a TPU default backend; the tests' hook on
+    XLA:CPU)."""
+    from ..utils.platform import complex_lowering
+    return complex_lowering(dtype) == "pair"
 
 
 def _pair_encode_vals(scaled_vals, dtype) -> np.ndarray:
@@ -2146,13 +2147,15 @@ def _staged_factor_run(sched, vals, thresh_np, dtype,
         # singletons included, so the dispatched program set is
         # exactly what warmup_staged compiled); panels flatten back
         # to the per-group list every consumer expects.  REAL dtypes
-        # only: complex multiplies re-associate when XLA:CPU fuses
-        # across group boundaries (measured ~1e-17 element drift vs
-        # the per-group dispatch — the same program-shape-sensitive
-        # complex lowering this platform is already documented for),
-        # so complex/pair lanes keep the proven per-group dispatch
-        # and the bitwise contract stays exact where it is pinned
-        # (real fp64, the PR 7 bar)
+        # only, and the ground is an XLA:CPU one: complex multiplies
+        # re-associate when XLA:CPU fuses across group boundaries
+        # (measured there: ~1e-17 element drift vs the per-group
+        # dispatch; the same program-shape-sensitive complex lowering
+        # XLA:CPU is documented for above, _mm_enc), so complex/pair
+        # lanes keep the proven per-group dispatch and the bitwise
+        # contract stays exact where it is pinned (real fp64, the PR 7
+        # bar).  Whether the merged arm would hold for pair planes on
+        # a TPU has not been tried: no cell runs the staged path (M2)
         for seg in get_factor_segments(sched):
             ops = [sched.groups[i].dev(squeeze=True)[:4]
                    for i in seg]
@@ -2437,8 +2440,13 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
     # real-view encoded on the host so the compiled sweep contains no
     # complex ops at all (the whole point of the storage)
     pair = _lu_is_pair(lu)
-    bin_ = (_pair_encode_rhs(bb.astype(xdt)) if pair
-            else bb.astype(xdt))
+    if pair:
+        # the host's plane encode (and decode, below) of a sweep: a
+        # leaf span of its own, outside `slu.solve.sweep`
+        with obs.span("solve.codec", cat="solve"):
+            bin_ = _pair_encode_rhs(bb.astype(xdt))
+    else:
+        bin_ = bb.astype(xdt)
     from . import trisolve
     merged = trisolve.trisolve_mode() == "merged"
     # merged: the handle-cached packed panels, so repeated FACTORED
@@ -2482,7 +2490,8 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
         with obs.span("solve.fetch", cat="solve"):
             out = np.asarray(X)
     if pair:
-        out = _pair_decode_sol(out, xdt)
+        with obs.span("solve.codec", cat="solve"):
+            out = _pair_decode_sol(out, xdt)
     return out[:, 0] if squeeze else out
 
 

@@ -12,16 +12,33 @@ Complex on the TPU.  Probed on a TPU v5e under jax 0.9.0 / libtpu
   * the complex128 math on stacked real/imag planes (`ops/pair_lu`,
     an all-real program) compiles in ~20 s and matches numpy to 2e-15.
 
-So a complex factor/solve on a TPU backend is still placed on the
-host CPU backend (`complex_device_gate`) — an abort would take the
-caller's process with it — but no longer silently: a placement
-raises a `ComplexPlacementWarning` naming the dtype and where it
-went, and every gated phase records the placement on its `Stats`
-(`Stats.placement`).  `SLU_COMPLEX_PAIR=1` runs complex on
-the chip through the pair lowering (single device only);
-`SLU_COMPLEX_TPU=1` lifts the gate for native complex, for whoever
-repairs the lowering.  ROADMAP R3/D4 own the real fix: one complex
-path that runs on the chip.
+So which lowering a complex program takes is ONE rule on what the
+code observes, `complex_lowering`: on a TPU default backend a complex
+factor dtype runs ON THE TPU through the pair lowering (real and
+imaginary planes, no complex op in the program: `ops/pair_lu`,
+`ops/batched._factor_group_impl_pair`, the sweeps' real-view codec),
+on every path that implements it — `factorize`, `factorize(plan=...)`
+and `solve` on one device, one-program and staged; on any other
+backend it stays native.  No environment variable is needed
+(PR 32: PETSc ex11's Helmholtz system, n=65,536, runs so in the
+benchmark's cell `helm2d_n512.zstep`).  Each factorization and solve
+says which lowering it took on `Stats.complex_lowering`.
+
+What still leaves the chip, loudly: a path that cannot store pairs (a
+caller of `complex_device_gate(pair_capable=False)`; a handle whose
+factors are natively stored) is placed on the host CPU backend — an
+abort would take the caller's process with it — with a
+`ComplexPlacementWarning` naming the dtype and where it went, and
+`Stats.placement` / `Stats.complex_lowering` record "cpu"; a complex
+factorization on a TPU MESH is refused (`complex_mesh_blocked`: pair
+storage is single-device).
+
+`SLU_COMPLEX_PAIR=1` is a TEST HOOK: it forces the pair lowering on
+a backend that would run native (XLA:CPU, where tier-1 runs), and
+decides nothing on a TPU.  `SLU_COMPLEX_TPU=1` runs NATIVE complex on
+the TPU (no pair, no gate, the mesh block lifted), for whoever
+repairs the native lowering.  ROADMAP D4 owns deleting the paths this
+rule no longer reaches.
 
 Amalgamation.  `apply_accel_amalg_defaults` is the other thing here:
 entry points that resolved an accelerator env-default tau/cap to the
@@ -44,30 +61,42 @@ class ComplexPlacementWarning(UserWarning):
     the default backend is a TPU (module docstring)."""
 
 
-def complex_pair_enabled() -> bool:
-    """Real-pair complex lowering (ops/pair_lu +
-    batched._factor_group_impl_pair): the single-device complex
-    factor/solve runs on stacked real/imag planes, so the compiled
-    program contains NO complex ops and the native-complex compile
-    abort is never reached.  SLU_COMPLEX_PAIR=1 opts in; the raw pair
-    kernel compiles and runs on today's chip (module docstring), the
-    full pair solve has not been run there (ROADMAP R3)."""
-    return flags.env_str("SLU_COMPLEX_PAIR", "0") == "1"
+def _is_complex(dtype) -> bool:
+    return np.issubdtype(np.dtype(dtype), np.complexfloating)
+
+
+def complex_lowering(dtype) -> str:
+    """THE rule: how a program of this factor dtype is lowered on the
+    paths that implement both lowerings (module docstring).  "pair":
+    stacked real/imaginary planes, an all-real program — what a
+    complex dtype takes on a TPU default backend; "native": the
+    dtype's own arithmetic — every real dtype, and complex on any
+    other backend.  Two environment overrides, neither an option of
+    the program: SLU_COMPLEX_PAIR=1 forces pair wherever it is asked
+    (the tests' hook on XLA:CPU), SLU_COMPLEX_TPU=1 keeps a TPU
+    native."""
+    if not _is_complex(dtype):
+        return "native"
+    if flags.env_str("SLU_COMPLEX_PAIR", "0") == "1":
+        return "pair"
+    if flags.env_str("SLU_COMPLEX_TPU", "0") == "1":
+        return "native"
+    import jax
+    return "pair" if jax.default_backend() == "tpu" else "native"
 
 
 def complex_needs_cpu(dtype, pair_capable: bool = True) -> bool:
-    """True when `dtype` is complex and the default backend is a TPU
-    (see module docstring).  Pair mode lifts the gate — its programs
-    are all-real, so the native-complex compile is never attempted —
-    but only for callers that actually implement pair storage; a path
-    that still builds native-complex programs (the fused one-program
-    solver) passes pair_capable=False so the lift cannot route it
-    into the compile abort."""
-    if not np.issubdtype(np.dtype(dtype), np.complexfloating):
+    """True when a program of `dtype` has to leave the chip: `dtype`
+    is complex, the default backend is a TPU, and the caller cannot
+    take the pair lowering `complex_lowering` gives it there —
+    pair_capable=False is a path that still builds native-complex
+    programs (no pair storage of its own, or a handle whose factors
+    are natively stored).  SLU_COMPLEX_TPU=1 lifts the gate."""
+    if not _is_complex(dtype):
         return False
     if flags.env_str("SLU_COMPLEX_TPU", "0") == "1":
         return False
-    if pair_capable and complex_pair_enabled():
+    if pair_capable and complex_lowering(dtype) == "pair":
         return False
     import jax
     return jax.default_backend() == "tpu"
@@ -109,7 +138,7 @@ def complex_mesh_blocked(dtype, mesh) -> bool:
     independent of jax.default_backend(): a TPU mesh built while the
     default backend is CPU would hit the same compile abort, so the
     mesh's own devices are the predicate."""
-    if not np.issubdtype(np.dtype(dtype), np.complexfloating):
+    if not _is_complex(dtype):
         return False
     if flags.env_str("SLU_COMPLEX_TPU", "0") == "1":
         return False
@@ -127,9 +156,20 @@ def complex_device_gate(*dtypes, pair_capable: bool = True,
     repeated warning once per call site), and records
     `phase -> "cpu"` on `stats.placement` when the caller hands its
     Stats.  pair_capable=False for callers whose programs cannot use
-    pair storage (see complex_needs_cpu)."""
-    gated = [np.dtype(dt).name for dt in dtypes
+    pair storage (see complex_needs_cpu).
+
+    Whenever one of `dtypes` is complex the phase's lowering goes on
+    `stats.complex_lowering`: "cpu" for a gated placement, "pair"
+    where the caller can take it and `complex_lowering` gives it,
+    else "native"."""
+    cplx = [np.dtype(dt) for dt in dtypes if _is_complex(dt)]
+    gated = [dt.name for dt in cplx
              if complex_needs_cpu(dt, pair_capable=pair_capable)]
+    if cplx and stats is not None:
+        stats.complex_lowering[phase or "complex"] = (
+            "cpu" if gated
+            else complex_lowering(cplx[0]) if pair_capable
+            else "native")
     if not gated:
         yield False
         return
@@ -137,9 +177,9 @@ def complex_device_gate(*dtypes, pair_capable: bool = True,
     warnings.warn(
         f"superlu_dist_tpu: {gated[0]} programs are placed on the "
         "host CPU backend, not on the TPU: native complex does not "
-        "compile on this chip (utils/platform.py).  "
-        "SLU_COMPLEX_PAIR=1 runs complex on the TPU through the "
-        "real-pair lowering.", ComplexPlacementWarning, stacklevel=3)
+        "compile on this chip, and this path cannot take the "
+        "real-pair lowering that runs complex there "
+        "(utils/platform.py).", ComplexPlacementWarning, stacklevel=3)
     if stats is not None:
         stats.placement[phase or "complex"] = "cpu"
     with jax.default_device(jax.local_devices(backend="cpu")[0]):

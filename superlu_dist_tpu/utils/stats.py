@@ -144,6 +144,14 @@ class Stats:
     # programs on "cpu" when the default backend is a TPU); empty when
     # everything ran where jax put it
     placement: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # phase -> how a COMPLEX factorization or solve was lowered and
+    # where: "pair" (real/imaginary planes, an all-real program: what
+    # a TPU runs), "native", or "cpu" for a gated placement
+    # (utils/platform.complex_lowering, complex_device_gate); empty
+    # for a real system.  Each factorization's and each refined
+    # solve's value rides the health ring (`complex_lowering`)
+    complex_lowering: Dict[str, str] = dataclasses.field(
+        default_factory=dict)
 
     @contextlib.contextmanager
     def timer(self, phase: str):
@@ -209,6 +217,7 @@ class Stats:
             "berr": self.berr,
             "refine_stalled": self.refine_stalled,
             "sweeps": dict(self.sweeps),
+            "complex_lowering": dict(self.complex_lowering),
             "escalations": self.escalations,
             "lu_nnz": self.lu_nnz,
             "lu_bytes": self.lu_bytes,
@@ -251,6 +260,10 @@ class Stats:
             placed = ", ".join(f"{p} on {b}" for p, b in
                                sorted(self.placement.items()))
             lines.append(f"  placed off-default:   {placed}")
+        if self.complex_lowering:
+            lines.append("  complex lowering:     " + ", ".join(
+                f"{p} {how}" for p, how in
+                sorted(self.complex_lowering.items())))
         # process-wide compile + health telemetry (obs/): the jit
         # caches and the health monitor are process-scoped like the
         # compile caches themselves, so the report shows the process

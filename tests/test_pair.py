@@ -188,18 +188,24 @@ def test_pair_singular_raises(problem):
 
 
 def test_pair_gate_interaction(monkeypatch):
-    """SLU_COMPLEX_PAIR=1 lifts the complex→CPU gate: the pair
-    program is all-real, so the broken native lowering is never
-    exercised (utils/platform.complex_needs_cpu)."""
+    """SLU_COMPLEX_PAIR=1 is the tests' hook: it forces the pair
+    lowering on a backend that would run native (this one), and pair
+    programs are all-real, so they are never CPU-gated
+    (utils/platform.complex_lowering, complex_needs_cpu).  On a TPU
+    it decides nothing: pair is what runs there with it unset or 0."""
     from superlu_dist_tpu.utils import platform as plat
     monkeypatch.setenv("SLU_COMPLEX_TPU", "0")
     monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
-    assert plat.complex_pair_enabled()
+    assert plat.complex_lowering(np.complex64) == "pair"
     # pair enabled → never CPU-gated, whatever the backend
     assert plat.complex_needs_cpu(np.complex128) is False
     monkeypatch.setenv("SLU_COMPLEX_PAIR", "0")
-    assert not plat.complex_pair_enabled()
-    # real dtypes are never gated regardless
+    assert plat.complex_lowering(np.complex128) == "native"
+    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
+    assert plat.complex_lowering(np.complex128) == "pair"
+    assert plat.complex_needs_cpu(np.complex128) is False
+    # real dtypes are never pair, never gated
+    assert plat.complex_lowering(np.float64) == "native"
     assert plat.complex_needs_cpu(np.float64) is False
 
 
@@ -257,19 +263,27 @@ def test_pair_handle_survives_env_change(problem, monkeypatch):
 
 
 def test_fused_gate_ignores_pair(monkeypatch):
-    """The fused one-program solver has no pair storage: with
-    SLU_COMPLEX_PAIR=1 its CPU gate must still engage on a gated
-    platform (pair_capable=False), else the lift would route the
-    native-complex fused program into the measured TPU compile
-    wedge."""
+    """A caller without pair storage (pair_capable=False) is gated on
+    a TPU whatever the rule or the hook say: else the lift would
+    route a native-complex program into the measured TPU compile
+    abort.  The gate then engages, warns and records the placement."""
+    from superlu_dist_tpu import Stats
     from superlu_dist_tpu.utils import platform as plat
-    monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
     monkeypatch.setenv("SLU_COMPLEX_TPU", "0")
     monkeypatch.setattr(
         "jax.default_backend", lambda: "tpu")
-    assert plat.complex_needs_cpu(np.complex128) is False
-    assert plat.complex_needs_cpu(np.complex128,
-                                  pair_capable=False) is True
+    for hook in ("1", "0"):
+        monkeypatch.setenv("SLU_COMPLEX_PAIR", hook)
+        assert plat.complex_needs_cpu(np.complex128) is False
+        assert plat.complex_needs_cpu(np.complex128,
+                                      pair_capable=False) is True
+    st = Stats()
+    with pytest.warns(plat.ComplexPlacementWarning):
+        with plat.complex_device_gate(np.complex128, pair_capable=False,
+                                      stats=st, phase="FACT") as on:
+            assert on is True
+    assert st.placement == {"FACT": "cpu"}
+    assert st.complex_lowering == {"FACT": "cpu"}
 
 
 def test_pair_program_is_complex_free(problem):
