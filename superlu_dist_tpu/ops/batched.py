@@ -46,7 +46,8 @@ import numpy as np
 
 from .. import flags, obs
 from ..plan.plan import FactorPlan
-from .dense_lu import partial_lu_batch, unit_lower_inverse, upper_inverse
+from .dense_lu import (partial_lu_panels_batch, unit_lower_inverse,
+                       upper_inverse)
 
 
 def _next_pow2(x: int) -> int:
@@ -1533,11 +1534,10 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
         # the Pallas panel-LU kernel (ops/pallas_lu.merged_eligible):
         # the caller resolved eligibility per member bucket, so this
         # call routes through the kernel unconditionally-if-available
-        F, tiny_g, nzero_g = partial_lu_batch(
+        Lsrc, Usrc, upd_src, tiny_g, nzero_g = partial_lu_panels_batch(
             F, thresh, wb=wb,
             pallas=(False if force_xla
                     else True if pallas_diag else None))
-        Lsrc, Usrc, upd_src = F[:, :, :wb], F[:, :wb, :], F[:, wb:, wb:]
 
     with jax.named_scope("slu.store"):
         rows = jnp.arange(mb)[:, None]

@@ -2,20 +2,22 @@
 
 The panel-factorization kernel of the TPU build — the analog of
 pdgstrf2_trsm/Local_Dgstrf2 (SRC/pdgstrf2.c:26-98,404) fused with the
-U-row TRSM (pdgstrs2_omp) and the leading Schur update, expressed as a
-blocked right-looking LU of the front's leading wb columns:
+U-row TRSM (pdgstrs2_omp) and the Schur update, expressed as a blocked
+right-looking LU of the front's two panels (the leading wb columns and
+the leading wb rows), then the trailing update once:
 
     for each NB-wide column block:
         unblocked rank-1 panel factorization (tiny-pivot replacement,
         the GESP sqrt(eps)·‖A‖ rule of SRC/pdgstrf2.c)
         TRSM for the U block row (unit-lower solve)
-        masked GEMM trailing update (runs on the MXU)
+        masked rank-NB GEMM update of the two panels
+    Schur complement  F22 − L21·U12, one K = wb GEMM (runs on the MXU)
 
 Everything is static-shaped: `wb` (padded pivot width) and the front
 size come from the bucket plan, loop bounds are Python ints, and
 row/column masks replace dynamic-size slices so XLA sees one fused
-GEMM per block step.  Identity padding in columns [w, wb) makes the
-padded factorization equal the true one.
+GEMM per panel and block step.  Identity padding in columns [w, wb)
+makes the padded factorization equal the true one.
 """
 
 from __future__ import annotations
@@ -134,25 +136,31 @@ def _tiny_replace(piv, thresh, dtype):
 
 @functools.partial(jax.jit, static_argnames=("wb", "nb"))
 @jax.named_scope("slu.partial_lu")
-def partial_lu(F, thresh, *, wb: int, nb: int = 32):
-    """Factor the leading `wb` columns of the square front F (mb×mb) in
-    place: returns (F', tiny_count, zero_pivot_count) where F' holds L
-    (unit lower, cols < wb), U (upper, rows < wb) and the Schur
-    complement F'[wb:, wb:].
+def partial_lu_panels(F, thresh, *, wb: int, nb: int = 32):
+    """Factor the leading `wb` columns of the square front F (mb×mb),
+    panel first: returns (C, R, S, tiny_count, zero_pivot_count) where
+    C (mb×wb) is the column panel F'[:, :wb] (L unit lower below the
+    diagonal, U11 on and above it), R (wb×r, r = mb − wb) the row
+    panel U12 = F'[:wb, wb:], and S (r×r) the Schur complement
+    F[wb:, wb:] − L21·U12.
     `thresh` is the tiny-pivot threshold (0 disables replacement —
     pass a tiny positive to keep the guard).
 
-    The sequential rank-1 elimination loop runs on the (nb, nb)
-    diagonal block ONLY; the column panel (L21 = A21·U11⁻¹), row panel
-    (U12 = L11⁻¹·A12) and trailing update are batched triangular
-    solves and one GEMM per block — O(nb²) work per sequential step
-    instead of O(mb·nb), with the mb-sized dimension entirely on
-    matrix units."""
+    The block loop carries the two panels only: each block's rank-nb
+    update touches mb·wb + wb·r entries, and the r×r trailing matrix
+    is read once, after the loop, by one K = wb matmul.  The
+    sequential rank-1 elimination runs on the (nb, nb) diagonal block
+    ONLY; the column panel (L21 = A21·U11⁻¹) and the block row
+    (U12 = L11⁻¹·A12) are batched triangular solves — O(nb²) work per
+    sequential step instead of O(mb·nb), with the mb-sized dimension
+    entirely on matrix units."""
     mb = F.shape[-1]
+    r = mb - wb
     dtype = F.dtype
     nb = min(nb, wb)
     assert wb % nb == 0, "width buckets must be multiples of the block"
     rows = jnp.arange(mb)
+    cols_wb = jnp.arange(wb)
     rows_nb = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
     cols_nb = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
 
@@ -199,11 +207,11 @@ def partial_lu(F, thresh, *, wb: int, nb: int = 32):
         return jax.lax.fori_loop(0, nb // cu, chunk, (D, tiny, nzero))
 
     def block_step(kb, carry):
-        F, tiny, nzero = carry
+        C, R, tiny, nzero = carry
         k0 = kb * nb
-        D = jax.lax.dynamic_slice(F, (k0, k0), (nb, nb))
+        D = jax.lax.dynamic_slice(C, (k0, k0), (nb, nb))
         D, tiny, nzero = _factor_diag(D, tiny, nzero)
-        F = jax.lax.dynamic_update_slice(F, D, (k0, k0))
+        C = jax.lax.dynamic_update_slice(C, D, (k0, k0))
         # exact Newton triangular inverses of the nb×nb factors: MXU
         # matmuls instead of triangular_solve's sequential column sweep
         with jax.named_scope("slu.tri_inverse"):
@@ -211,50 +219,96 @@ def partial_lu(F, thresh, *, wb: int, nb: int = 32):
             L11i = _newton_tri_inverse(D, lower=True, unit=True)
         # L21 = A21 · U11⁻¹ over the full column slice; keep rows ≥
         # k0+nb (rows < k0 hold finished U entries, D already written)
-        colp = jax.lax.dynamic_slice(F, (0, k0), (mb, nb))
+        colp = jax.lax.dynamic_slice(C, (0, k0), (mb, nb))
         L21 = colp @ U11i
         keep_r = (rows >= k0 + nb)[:, None]
         colp2 = jnp.where(keep_r, L21, colp)
-        F = jax.lax.dynamic_update_slice(F, colp2, (0, k0))
-        # U12 = L11⁻¹ · A12 over the full row slice
-        rowp = jax.lax.dynamic_slice(F, (k0, 0), (nb, mb))
-        U12 = L11i @ rowp
-        keep_c = (rows >= k0 + nb)[None, :]
-        rowp2 = jnp.where(keep_c, U12, rowp)
-        F = jax.lax.dynamic_update_slice(F, rowp2, (k0, 0))
-        # trailing GEMM restricted to i, j ≥ k0+nb via masking
-        with jax.named_scope("slu.schur"):
-            Lcol = jnp.where(keep_r, colp2, 0)
-            Urow = jnp.where(keep_c, rowp2, 0)
-            F = F - Lcol @ Urow
-        return F, tiny, nzero
+        C = jax.lax.dynamic_update_slice(C, colp2, (0, k0))
+        # U12 = L11⁻¹ · A12 over the block row, which lies in both
+        # panels: inside the column panel keep cols ≥ k0+nb (cols < k0
+        # hold finished L entries); the row panel takes it whole
+        rowc = jax.lax.dynamic_slice(C, (k0, 0), (nb, wb))
+        keep_c = (cols_wb >= k0 + nb)[None, :]
+        rowc2 = jnp.where(keep_c, L11i @ rowc, rowc)
+        C = jax.lax.dynamic_update_slice(C, rowc2, (k0, 0))
+        # this block's rank-nb update of the PANELS alone, restricted
+        # to i, j ≥ k0+nb via masking; the trailing r×r waits
+        Lcol = jnp.where(keep_r, colp2, 0)
+        C = C - Lcol @ jnp.where(keep_c, rowc2, 0)
+        if r:
+            rowr = L11i @ jax.lax.dynamic_slice(R, (k0, 0), (nb, r))
+            R = jax.lax.dynamic_update_slice(R, rowr, (k0, 0))
+            R = R - Lcol[:wb] @ rowr
+        return C, R, tiny, nzero
 
     tiny0 = jnp.zeros((), jnp.int32)
-    F, tiny, nzero = jax.lax.fori_loop(
-        0, wb // nb, block_step, (F, tiny0, tiny0))
-    return F, tiny, nzero
+    C, R, tiny, nzero = jax.lax.fori_loop(
+        0, wb // nb, block_step,
+        (F[:, :wb], F[:wb, wb:], tiny0, tiny0))
+    S = F[wb:, wb:]
+    if r:
+        # the Schur complement, once: K = wb on the MXU
+        with jax.named_scope("slu.schur"):
+            S = S - C[wb:] @ R
+    return C, R, S, tiny, nzero
+
+
+@functools.partial(jax.jit, static_argnames=("wb", "nb"))
+def partial_lu(F, thresh, *, wb: int, nb: int = 32):
+    """partial_lu_panels reassembled in place of the front: returns
+    (F', tiny_count, zero_pivot_count) where F' holds L (unit lower,
+    cols < wb), U (upper, rows < wb) and the Schur complement
+    F'[wb:, wb:].  For callers of the whole-front contract; the factor
+    program takes the pieces (partial_lu_panels_batch)."""
+    C, R, S, tiny, nzero = partial_lu_panels(F, thresh, wb=wb, nb=nb)
+    right = jnp.concatenate([R, S], axis=0)
+    return jnp.concatenate([C, right], axis=1), tiny, nzero
+
+
+def _use_pallas(F, pallas: bool | None) -> bool:
+    """`pallas` overrides the env-resolved routing: True routes this
+    call through the VMEM-resident Pallas kernel (ops/pallas_lu.py)
+    when Mosaic can lower the dtype (the merged factor segments'
+    small-bucket promotion, ops/batched.factor_seg_metas), False
+    forces the XLA path, None keeps the historical SLU_TPU_PALLAS
+    resolution."""
+    from . import pallas_lu
+    from .pallas_common import mosaic_dtype
+    use = (pallas_lu.enabled(F.dtype) if pallas is None
+           else bool(pallas) and mosaic_dtype(F.dtype))
+    return use and pallas_lu.usable(F.shape[-1], F.dtype)
 
 
 def partial_lu_batch(F, thresh, *, wb: int, nb: int = 32,
                      pallas: bool | None = None):
     """vmapped partial_lu over a batch of fronts (N, mb, mb).
     Returns (F', tiny_count, zero_pivot_count).  Dispatches to the
-    VMEM-resident Pallas kernel when enabled (ops/pallas_lu.py).
-    `pallas` overrides the env-resolved routing: True routes this
-    call through the kernel when Mosaic can lower the dtype (the
-    merged factor segments' small-bucket promotion,
-    ops/batched.factor_seg_metas), False forces the XLA path, None
-    keeps the historical SLU_TPU_PALLAS resolution."""
-    from . import pallas_lu
-    from .pallas_common import mosaic_dtype
-    use = (pallas_lu.enabled(F.dtype) if pallas is None
-           else bool(pallas) and mosaic_dtype(F.dtype))
-    if use and pallas_lu.usable(F.shape[-1], F.dtype):
+    Pallas kernel where `_use_pallas` says so."""
+    if _use_pallas(F, pallas):
+        from . import pallas_lu
         with jax.named_scope("slu.partial_lu"):
             return pallas_lu.partial_lu_batch_pallas(F, thresh, wb=wb)
-    f = functools.partial(partial_lu, wb=wb, nb=nb)
-    Fs, tinys, nzeros = jax.vmap(lambda x: f(x, thresh))(F)
+    Fs, tinys, nzeros = jax.vmap(
+        lambda x: partial_lu(x, thresh, wb=wb, nb=nb))(F)
     return Fs, jnp.sum(tinys), jnp.sum(nzeros)
+
+
+def partial_lu_panels_batch(F, thresh, *, wb: int, nb: int = 32,
+                            pallas: bool | None = None):
+    """vmapped partial_lu_panels over a batch of fronts (N, mb, mb), in
+    the three pieces the factor program stores: returns (Lsrc (N, mb,
+    wb) = F'[:, :, :wb], Usrc (N, wb, mb) = F'[:, :wb, :], the Schur
+    complements (N, r, r), tiny_count, zero_pivot_count).  No mb×mb
+    front is reassembled; the Pallas kernel, which works on the whole
+    front in VMEM, is sliced.  `pallas` as in `_use_pallas`."""
+    if _use_pallas(F, pallas):
+        F, tiny, nzero = partial_lu_batch(F, thresh, wb=wb,
+                                          pallas=pallas)
+        return F[:, :, :wb], F[:, :wb, :], F[:, wb:, wb:], tiny, nzero
+    C, R, S, tinys, nzeros = jax.vmap(
+        lambda x: partial_lu_panels(x, thresh, wb=wb, nb=nb))(F)
+    Usrc = jnp.concatenate([C[:, :wb, :], R], axis=2)
+    return C, Usrc, S, jnp.sum(tinys), jnp.sum(nzeros)
 
 
 @jax.named_scope("slu.tri_inverse")

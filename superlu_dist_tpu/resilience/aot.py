@@ -194,13 +194,17 @@ def schedule_fingerprint(sched, dtype, extra=()) -> str:
     _pattern_sig), dtype, the merge-flag surface (factor + trisolve
     arms — a flag flip changes the program, so it must change the
     key), jax version and backend.  `extra` appends caller legs
-    (e.g. the packed-solve pair flag)."""
+    (e.g. the packed-solve pair flag).  The leading tag stands for the
+    kernels' own code, which nothing else here sees: an edit that
+    changes what a program computes for the same schedule (v3: the
+    panel-first dense_lu.partial_lu) bumps it, or a kept store serves
+    the old arithmetic."""
     import jax
 
     from ..ops import batched as B
     from ..ops import trisolve as T
     parts = (
-        "v2", jax.__version__, jax.default_backend(),
+        "v3", jax.__version__, jax.default_backend(),
         _pattern_sig(sched),
         np.dtype(dtype).str,
         int(sched.n), int(sched.ndev), int(sched.upd_total),
