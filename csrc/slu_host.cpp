@@ -316,10 +316,16 @@ int64_t slu_mdorder(int64_t n, const int64_t* indptr,
 // position rowperm[i]); duals u (rows), v (cols) satisfying
 // w(i,j) − u_i − v_j ≥ 0 with equality on matched edges, from which
 // the MC64 job=5 scalings are R_i = exp(u_i), C_j = exp(v_j)/cmax_j.
+// A search resets only the rows it touched: no length-n refill per
+// augmentation (a saddle point's zero block leaves a third of the
+// columns to search).  `work`, where not null, receives {columns
+// searched, rows finalized by all searches, edges scanned by all
+// searches}: counts of work, for the tests' bound.
 // Returns 0 on success, -1 if structurally singular.
-int64_t slu_mc64(int64_t n, const int64_t* colptr, const int64_t* rowind,
-                 const double* absval, int64_t* rowperm, double* u,
-                 double* v) {
+int64_t slu_mc64_counted(int64_t n, const int64_t* colptr,
+                         const int64_t* rowind, const double* absval,
+                         int64_t* rowperm, double* u, double* v,
+                         int64_t* work) {
   const double INF = std::numeric_limits<double>::infinity();
   std::vector<double> w(colptr[n]);
   std::vector<double> cmax(n, 0.0);
@@ -355,21 +361,25 @@ int64_t slu_mc64(int64_t n, const int64_t* colptr, const int64_t* rowind,
       }
     }
 
-  std::vector<double> dist(n);
+  std::vector<double> dist(n, INF);
   std::vector<int64_t> prev_col(n);  // row -> column it was reached from
-  std::vector<char> done(n);
-  std::vector<int64_t> done_rows;
+  std::vector<char> done(n, 0);
+  std::vector<int64_t> done_rows, touched;
+  int64_t searches = 0, finalized = 0, scanned = 0;
   using QI = std::pair<double, int64_t>;  // (dist, row)
   for (int64_t j0 = 0; j0 < n; ++j0) {
     if (match_col[j0] != -1) continue;
-    std::fill(dist.begin(), dist.end(), INF);
-    std::fill(done.begin(), done.end(), 0);
+    ++searches;
+    for (int64_t i : touched) { dist[i] = INF; done[i] = 0; }
+    touched.clear();
     done_rows.clear();
     std::priority_queue<QI, std::vector<QI>, std::greater<QI>> pq;
     for (int64_t p = colptr[j0]; p < colptr[j0 + 1]; ++p) {
       int64_t i = rowind[p];
       double d = w[p] - v[j0] - u[i];
+      ++scanned;
       if (d < dist[i]) {
+        if (dist[i] == INF) touched.push_back(i);
         dist[i] = d;
         prev_col[i] = j0;
         pq.push({d, i});
@@ -387,15 +397,18 @@ int64_t slu_mc64(int64_t n, const int64_t* colptr, const int64_t* rowind,
       if (jm == -1) { lsp = d; isp = i; break; }
       for (int64_t p = colptr[jm]; p < colptr[jm + 1]; ++p) {
         int64_t i2 = rowind[p];
+        ++scanned;
         if (done[i2] || w[p] == INF) continue;
         double d2 = d + (w[p] - v[jm] - u[i2]);
         if (d2 < dist[i2]) {
+          if (dist[i2] == INF) touched.push_back(i2);
           dist[i2] = d2;
           prev_col[i2] = jm;
           pq.push({d2, i2});
         }
       }
     }
+    finalized += static_cast<int64_t>(done_rows.size());
     if (isp == -1) return -1;  // no augmenting path: singular
 
     // dual update on finalized rows keeps feasibility (d ≤ lsp there)
@@ -419,7 +432,15 @@ int64_t slu_mc64(int64_t n, const int64_t* colptr, const int64_t* rowind,
     }
   }
   for (int64_t i = 0; i < n; ++i) rowperm[i] = match_row[i];
+  if (work) { work[0] = searches; work[1] = finalized; work[2] = scanned; }
   return 0;
+}
+
+int64_t slu_mc64(int64_t n, const int64_t* colptr, const int64_t* rowind,
+                 const double* absval, int64_t* rowperm, double* u,
+                 double* v) {
+  return slu_mc64_counted(n, colptr, rowind, absval, rowperm, u, v,
+                          nullptr);
 }
 
 // ---------------------------------------------------------------- hwpm

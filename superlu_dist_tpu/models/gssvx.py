@@ -101,7 +101,6 @@ def factorize(a: CSRMatrix, options: Options | None = None,
         plan = plan_factorization(a, options, stats=stats,
                                   user_perm_r=user_perm_r,
                                   user_perm_c=user_perm_c)
-    scaled = plan.scaled_values(a)
     fdt = effective_factor_dtype(a.dtype, options.factor_dtype)
     if fdt.name != options.factor_dtype:
         options = options.replace(factor_dtype=fdt.name)
@@ -132,6 +131,11 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                              pair_capable=(backend == "jax"),
                              stats=stats, phase=_phase), \
             stats.timer(_phase):
+        # the host's share of a refactorization before any dispatch:
+        # Dr·A·Dc in the plan's order (the backends' cast of it to the
+        # factor dtype closes a span of the same name)
+        with obs.span("fact.scale", cat="fact"):
+            scaled = plan.scaled_values(a)
         if backend == "host":
             host_lu = ref_multifrontal.factorize_host(
                 plan, scaled, dtype=np.dtype(options.factor_dtype))
@@ -182,6 +186,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     stats.factor_flops_executed = (sched.executed_flops if sched
                                    else plan.factor_flops)
     stats.ea_elements = sched.ea_elements if sched else {}
+    stats.gesp = dict(getattr(plan, "gesp", None) or {})
     # XLA cost-analysis flop accounting (SLU_OBS_COST=1): the program
     # cost the backend stamped for THIS call (thread-local hand-off,
     # obs/compile_watch.py), accumulated per factorization like
@@ -222,7 +227,8 @@ def factorize(a: CSRMatrix, options: Options | None = None,
         flops={"useful": stats.factor_flops,
                "executed": stats.factor_flops_executed},
         extend_add=stats.ea_elements,
-        complex_lowering=stats.complex_lowering.get(_phase))
+        complex_lowering=stats.complex_lowering.get(_phase),
+        gesp=stats.gesp)
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,

@@ -66,7 +66,8 @@ class HealthMonitor:
                       mem: dict | None = None,
                       flops: dict | None = None,
                       extend_add: dict | None = None,
-                      complex_lowering: str | None = None) -> None:
+                      complex_lowering: str | None = None,
+                      gesp: dict | None = None) -> None:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
@@ -78,7 +79,9 @@ class HealthMonitor:
         elements by lane (Stats.ea_elements), `complex_lowering` how
         a complex factorization was lowered and where ("pair",
         "native", or "cpu" for a gated placement:
-        Stats.complex_lowering; None for a real one)."""
+        Stats.complex_lowering; None for a real one), `gesp` the
+        plan's static-pivoting facts (plan/plan.gesp_facts;
+        Stats.gesp)."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -98,6 +101,7 @@ class HealthMonitor:
                 "extend_add": ({k: dict(v) for k, v in extend_add.items()}
                                if extend_add else None),
                 "complex_lowering": complex_lowering,
+                "gesp": dict(gesp) if gesp else None,
             })
         if tiny_pivots:
             _tracer.instant("health.tiny_pivots", cat="health",

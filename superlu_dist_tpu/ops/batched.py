@@ -2405,8 +2405,9 @@ def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
     else:
         factor_fn, _ = _phase_fns(sched, dtype,
                                   _thresh_for(plan, dtype), pair=pair)
-        vin = (_pair_encode_vals(scaled_vals, dtype) if pair
-               else scaled_vals.astype(dtype))
+        with obs.span("fact.scale", cat="fact"):
+            vin = (_pair_encode_vals(scaled_vals, dtype) if pair
+                   else scaled_vals.astype(dtype))
         vj = jnp.asarray(vin)
         (L_flat, U_flat, Li_flat, Ui_flat, tiny,
          nzero) = factor_fn(vj)

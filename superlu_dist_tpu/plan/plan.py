@@ -61,6 +61,10 @@ class FactorPlan:
     # over-counts useful work at high tau.  0.0 on plans predating
     # this field.
     true_factor_flops: float = 0.0
+    # what static pivoting did to this pattern, as counters (gesp_facts):
+    # every factorization on the plan carries them to its Stats and to
+    # the health ring.  Empty on plans predating the field.
+    gesp: dict = dataclasses.field(default_factory=dict)
 
     def __getstate__(self):
         # runtime attach points (ops/batched.get_schedule's
@@ -92,6 +96,27 @@ class FactorPlan:
         vals = a.data
         return (vals * self.row_scale[self.coo_rows]
                 * self.col_scale[self.coo_cols])
+
+
+def gesp_facts(n: int, equed: str, row_scale: np.ndarray,
+               col_scale: np.ndarray, perm_r: np.ndarray,
+               coo_rows: np.ndarray, coo_cols: np.ndarray) -> dict:
+    """The plan's GESP facts: rows the static-pivoting permutation
+    moves, what equilibration applied and over how many decades, and
+    the diagonal positions with no stored entry (the saddle point's
+    zero block).  All zeros and ones for an identity permutation with
+    `equed` 'N' on a full diagonal."""
+    on_diag = np.zeros(n, dtype=bool)
+    on_diag[coo_rows[coo_rows == coo_cols]] = True
+    return {
+        "rows_moved": int(np.count_nonzero(perm_r != np.arange(n))),
+        "n": int(n), "equed": str(equed),
+        "row_scale_min": float(np.min(row_scale)),
+        "row_scale_max": float(np.max(row_scale)),
+        "col_scale_min": float(np.min(col_scale)),
+        "col_scale_max": float(np.max(col_scale)),
+        "zero_diagonal": int(n - np.count_nonzero(on_diag)),
+    }
 
 
 def pattern_sha1(a: CSRMatrix) -> str:
@@ -305,7 +330,10 @@ def plan_from_perms(n: int, options: Options, stats: Stats,
         final_row=final_row, final_col=final_col,
         coo_rows=coo_rows, coo_cols=coo_cols,
         frontal=frontal, anorm=anorm,
-        true_factor_flops=true_factor_flops)
+        true_factor_flops=true_factor_flops,
+        gesp=gesp_facts(n, equed, r_eff, c_eff, perm_r, coo_rows,
+                        coo_cols))
+    stats.gesp = dict(plan.gesp)
     if autotune:
         from .autotune import autotuned_options
         tuned = autotuned_options(plan, options)

@@ -136,6 +136,9 @@ def _load():
             lib.slu_mc64.argtypes = [ctypes.c_int64, _I64, _I64, _F64,
                                      _I64, _F64, _F64]
             lib.slu_mc64.restype = ctypes.c_int64
+            lib.slu_mc64_counted.argtypes = [
+                ctypes.c_int64, _I64, _I64, _F64, _I64, _F64, _F64, _I64]
+            lib.slu_mc64_counted.restype = ctypes.c_int64
             lib.slu_hwpm.argtypes = [ctypes.c_int64, _I64, _I64, _F64,
                                      ctypes.c_int64, _I64]
             lib.slu_hwpm.restype = ctypes.c_int64
@@ -245,6 +248,15 @@ def mc64(n: int, colptr: np.ndarray, rowind: np.ndarray,
     """MC64 job=5 on CSC input.  Returns (rowperm, u, v) where
     rowperm[i] = destination position of row i and (u, v) are the dual
     potentials (R_i = exp(u_i), C_j = exp(v_j)/cmax_j scalings)."""
+    return mc64_counted(n, colptr, rowind, absval)[:3]
+
+
+def mc64_counted(n: int, colptr: np.ndarray, rowind: np.ndarray,
+                 absval: np.ndarray):
+    """`mc64` with the work it took: (rowperm, u, v, work), work =
+    {searches: columns the cheap pass left free, rows_finalized and
+    edges_scanned: over all their shortest-path searches}.  Counts,
+    not seconds: what the tests bound."""
     lib = _load()
     a_pc, pc = _c64(colptr)
     a_pr, pr = _c64(rowind)
@@ -252,11 +264,15 @@ def mc64(n: int, colptr: np.ndarray, rowind: np.ndarray,
     perm = np.empty(n, dtype=np.int64)
     u = np.empty(n, dtype=np.float64)
     v = np.empty(n, dtype=np.float64)
-    rc = lib.slu_mc64(n, pc, pr, pv, perm.ctypes.data_as(_I64),
-                      u.ctypes.data_as(_F64), v.ctypes.data_as(_F64))
+    work = np.zeros(3, dtype=np.int64)
+    rc = lib.slu_mc64_counted(n, pc, pr, pv, perm.ctypes.data_as(_I64),
+                              u.ctypes.data_as(_F64),
+                              v.ctypes.data_as(_F64),
+                              work.ctypes.data_as(_I64))
     if rc != 0:
         raise ValueError("structurally singular matrix (native mc64)")
-    return perm, u, v
+    return perm, u, v, dict(zip(
+        ("searches", "rows_finalized", "edges_scanned"), work.tolist()))
 
 
 def cpuid_words() -> np.ndarray:

@@ -152,6 +152,11 @@ class Stats:
     # solve's value rides the health ring (`complex_lowering`)
     complex_lowering: Dict[str, str] = dataclasses.field(
         default_factory=dict)
+    # the plan's GESP facts (plan/plan.gesp_facts: rows_moved, n,
+    # equed, row_scale_min/max, col_scale_min/max, zero_diagonal),
+    # stamped by the plan build and by every factorization on the
+    # plan; the health ring's factor records carry them as `gesp`
+    gesp: Dict[str, object] = dataclasses.field(default_factory=dict)
 
     @contextlib.contextmanager
     def timer(self, phase: str):
@@ -218,6 +223,7 @@ class Stats:
             "refine_stalled": self.refine_stalled,
             "sweeps": dict(self.sweeps),
             "complex_lowering": dict(self.complex_lowering),
+            "gesp": dict(self.gesp),
             "escalations": self.escalations,
             "lu_nnz": self.lu_nnz,
             "lu_bytes": self.lu_bytes,
@@ -250,6 +256,14 @@ class Stats:
                 f"#{i} {e['dtype'] or '?'}: {e['tiny_pivots']}"
                 for i, e in enumerate(self.factor_events))
             lines.append(f"    per factorization:  {per}")
+        if self.gesp:
+            g = self.gesp
+            lines.append(
+                f"  static pivoting:      {g['rows_moved']} of {g['n']} "
+                f"rows moved, {g['zero_diagonal']} zero diagonals, "
+                f"equed {g['equed']} (rows {g['row_scale_min']:.3g}.."
+                f"{g['row_scale_max']:.3g}, cols "
+                f"{g['col_scale_min']:.3g}..{g['col_scale_max']:.3g})")
         lines.append(f"  refinement steps:     {self.refine_steps}")
         if self.sweeps:
             lines.append("  sweeps by operand:    " + ", ".join(

@@ -122,6 +122,90 @@ def test_mc64_optimal_and_feasible():
             assert abs(w_ij - u[i] - v[j]) < 1e-8
 
 
+# -- MC64 on a saddle point (the configuration `stokes2d_sinker`) ------
+
+def _saddle_point(N):
+    """The staggered-grid Stokes matrix after the plan's own
+    equilibration: what `plan/rowperm.large_diag_perm` is handed.  A
+    third of its diagonal is structurally zero."""
+    from superlu_dist_tpu.plan import equilibrate
+    from test_stokes2d import GEN
+    a = GEN.generate(N)
+    csr = CSRMatrix(a.shape[0], a.shape[1], a.indptr.astype(np.int64),
+                    a.indices.astype(np.int64), a.data)
+    r, c, rowcnd, colcnd, amax = equilibrate.gsequ(csr)
+    _, r, c = equilibrate.laqgs(csr, r, c, rowcnd, colcnd, amax)
+    s = (sp.diags(r) @ a @ sp.diags(c)).tocsr()
+    s.sort_indices()
+    assert np.count_nonzero(s.diagonal() == 0.0) == N * N - 1
+    return s
+
+
+def _edge_weights(acsc):
+    av = np.abs(acsc.data)
+    cmax = np.maximum.reduceat(av, acsc.indptr[:-1])
+    col = np.repeat(np.arange(acsc.shape[0]), np.diff(acsc.indptr))
+    return col, np.log(cmax[col]) - np.log(av)
+
+
+@pytest.mark.parametrize("N", [8, 16, 24])
+def test_mc64_on_the_saddle_point_equals_the_oracle(N):
+    s = _saddle_point(N)
+    n = s.shape[0]
+    acsc = s.tocsc()
+    acsc.sort_indices()
+    perm, u, v = native.mc64(n, acsc.indptr.astype(np.int64),
+                             acsc.indices.astype(np.int64),
+                             np.abs(acsc.data))
+    assert sorted(perm) == list(range(n))
+    # every continuity row but the pin's leaves a zero diagonal
+    assert np.count_nonzero(perm != np.arange(n)) == 2 * (N * N - 1)
+    col, w = _edge_weights(acsc)
+    matched = perm[acsc.indices] == col
+    assert matched.sum() == n
+    oracle = large_diag_perm_py(CSRMatrix(
+        n, n, s.indptr.astype(np.int64), s.indices.astype(np.int64),
+        s.data))
+    theirs = oracle[acsc.indices] == col
+    # equal log-product of the diagonal magnitudes
+    assert abs(w[matched].sum() - w[theirs].sum()) <= 1e-10
+    # the duals are feasible, and tight on the matching
+    slack = w - u[acsc.indices] - v[col]
+    assert slack.min() >= -1e-12
+    assert np.abs(slack[matched]).max() <= 1e-12
+
+
+def test_mc64_work_on_the_saddle_point_is_bounded():
+    """No clock: the rows that all shortest-path searches finalize.
+    The cheap pass leaves some seventy of the 2,303 pressure columns
+    free at N = 48; each costs one search, which resets only the rows
+    it touched (no length-n refill per augmentation)."""
+    N = 48
+    s = _saddle_point(N)
+    n = s.shape[0]
+    acsc = s.tocsc()
+    acsc.sort_indices()
+    perm, _, _, work = native.mc64_counted(
+        n, acsc.indptr.astype(np.int64), acsc.indices.astype(np.int64),
+        np.abs(acsc.data))
+    assert np.count_nonzero(perm != np.arange(n)) == 2 * (N * N - 1)
+    assert 0 < work["searches"] <= 2 * N
+    bound = acsc.nnz * np.log2(n)
+    assert work["rows_finalized"] <= bound / 4
+    assert work["edges_scanned"] <= 2 * bound
+    # and where the cheap pass matches everything, no search runs
+    rng = np.random.default_rng(3)
+    d = (sp.random(200, 200, density=0.03, random_state=rng)
+         + sp.diags(np.full(200, 10.0))).tocsc()
+    d.sort_indices()
+    perm, _, _, work = native.mc64_counted(
+        200, d.indptr.astype(np.int64), d.indices.astype(np.int64),
+        np.abs(d.data))
+    assert np.array_equal(perm, np.arange(200))
+    assert work == {"searches": 0, "rows_finalized": 0,
+                    "edges_scanned": 0}
+
+
 def test_symbfact_matches_python():
     rng = np.random.default_rng(5)
     for n in (20, 70, 140):
