@@ -64,6 +64,10 @@ class LUFactorization:
     # tiny-pivot perturbation ledger factorize() stamps
     rcond: Optional[float] = None
     ledger: Optional[object] = None   # numerics.ledger.PerturbationLedger
+    # this factorization's record in the health ring (obs/health.py),
+    # kept so a solve that takes the pack's miss can say so there
+    factor_record: Optional[dict] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     @property
     def n(self) -> int:
@@ -123,6 +127,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     # drop any stale stamp from a direct ops-layer call the driver
     # never read (the host path below stamps nothing)
     obs.take_cost("factor")
+    obs.take_cost("pack")
     # complex on a TPU: the one-device jax backend takes the pair
     # lowering and stays on the chip (utils/platform.complex_lowering);
     # the host oracle and a CPU mesh have no pair storage and keep the
@@ -194,6 +199,12 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     # executions' wall, and a warm-cache refactorization never adopts
     # another schedule's program
     stats.set_measured_cost(_phase, obs.take_cost("factor"))
+    # where this factorization's solve mirror was dispatched: by
+    # `factorize_device` under the merged sweep ("at_factor"), else
+    # not yet ("none": a later solve that packs corrects the ring's
+    # record, solve() below)
+    pack = obs.take_cost("pack") or "none"
+    stats.note_pack(pack)
     stats.lu_nnz = plan.lu_nnz()
     stats.lu_bytes = stats.lu_nnz * np.dtype(options.factor_dtype).itemsize
     # numerical-health watch (obs/health.py): GESP never pivots at
@@ -217,7 +228,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     from ..obs import memory as obs_memory
     mem = obs_memory.watermarks(lu, phase=_phase)
     stats.mem_watermarks = mem
-    obs.HEALTH.record_factor(
+    lu.factor_record = obs.HEALTH.record_factor(
         tiny_pivots=int(getattr(src, "tiny_pivots", 0)),
         pivot_growth=(obs.pivot_growth(lu) if obs.enabled() else None),
         dtype=options.factor_dtype,
@@ -228,7 +239,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                "executed": stats.factor_flops_executed},
         extend_add=stats.ea_elements,
         complex_lowering=stats.complex_lowering.get(_phase),
-        gesp=stats.gesp)
+        gesp=stats.gesp, pack=pack)
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,
@@ -369,9 +380,16 @@ def solve(lu: LUFactorization, b: np.ndarray,
                              pair_capable=(stored == "pair"),
                              stats=stats, phase="SOLVE"):
         obs.take_cost("solve")  # drop any stale unread stamp
+        obs.take_cost("pack")
         with stats.timer("SOLVE"):
             x = from_factor_sol(sweep(lu, to_factor_rhs(bb)))
         stats.set_measured_cost("SOLVE", obs.take_cost("solve"))
+        # a handle that came without its packs took the miss in the
+        # sweep above (ops/trisolve.get_packs); every later sweep hits
+        pack = obs.take_cost("pack")
+        if pack:
+            stats.note_pack(pack)
+            obs.HEALTH.record_pack(lu.factor_record, pack)
 
         if options.iter_refine != IterRefine.NOREFINE and lu.a is not None:
             from .refine import iterative_refine

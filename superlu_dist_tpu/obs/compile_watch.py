@@ -308,14 +308,16 @@ COMPILE_WATCH = CompileWatch()
 _TLS = threading.local()
 
 
-def stamp_cost(kind: str, cost: dict | None) -> None:
-    """Record the just-executed program's cost ("factor"/"solve") for
-    this thread's in-flight driver call."""
+def stamp_cost(kind: str, cost: dict | str | None) -> None:
+    """Record the just-dispatched program's cost ("factor"/"solve": a
+    dict or None) for this thread's in-flight driver call; under
+    "pack", where the miss path of `ops/trisolve.get_packs` was taken
+    ("at_factor" / "at_solve")."""
     setattr(_TLS, kind, cost)
 
 
-def take_cost(kind: str) -> dict | None:
-    """Pop this thread's stamped cost.  Popping (not peeking) means a
+def take_cost(kind: str) -> dict | str | None:
+    """Pop this thread's stamp.  Popping (not peeking) means a
     backend path that stamps nothing — host, staged, dist solve —
     reads None instead of a stale earlier program's numbers."""
     c = getattr(_TLS, kind, None)

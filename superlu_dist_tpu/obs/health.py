@@ -67,7 +67,8 @@ class HealthMonitor:
                       flops: dict | None = None,
                       extend_add: dict | None = None,
                       complex_lowering: str | None = None,
-                      gesp: dict | None = None) -> None:
+                      gesp: dict | None = None,
+                      pack: str = "none") -> dict:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
@@ -81,7 +82,9 @@ class HealthMonitor:
         "native", or "cpu" for a gated placement:
         Stats.complex_lowering; None for a real one), `gesp` the
         plan's static-pivoting facts (plan/plan.gesp_facts;
-        Stats.gesp)."""
+        Stats.gesp), `pack` where its solve mirror was dispatched
+        ("at_factor", or "none" so far: Stats.packs).  Returns the
+        ring's record, for `record_pack`."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -89,7 +92,7 @@ class HealthMonitor:
                 self.last_pivot_growth = float(pivot_growth)
             if perturbation is not None:
                 self.perturbed_factorizations += 1
-            self._factor_recent.append({
+            rec = {
                 "tiny_pivots": int(tiny_pivots),
                 "dtype": dtype,
                 "pivot_growth": (float(pivot_growth)
@@ -102,11 +105,23 @@ class HealthMonitor:
                                if extend_add else None),
                 "complex_lowering": complex_lowering,
                 "gesp": dict(gesp) if gesp else None,
-            })
+                "pack": pack,
+            }
+            self._factor_recent.append(rec)
         if tiny_pivots:
             _tracer.instant("health.tiny_pivots", cat="health",
                             args={"count": int(tiny_pivots),
                                   "dtype": dtype})
+        return rec
+
+    def record_pack(self, rec: dict | None, where: str) -> None:
+        """A factorization's pack was dispatched after its record was
+        written (by its first solve, "at_solve"): correct the record
+        `record_factor` returned."""
+        if rec is None:
+            return
+        with self._lock:
+            rec["pack"] = where
 
     def record_pivot_growth_unavailable(self, *,
                                         dtype: str = "") -> None:

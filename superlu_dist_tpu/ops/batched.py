@@ -2391,6 +2391,7 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
 
 def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
                      dtype=np.float64):
+    from . import trisolve
     sched = get_schedule(plan, 1)
     dtype = np.dtype(dtype)
     pair = _pair_mode(dtype)
@@ -2411,16 +2412,27 @@ def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
         vj = jnp.asarray(vin)
         (L_flat, U_flat, Li_flat, Ui_flat, tiny,
          nzero) = factor_fn(vj)
-        nzero = int(nzero)
+        # `tiny` and `nzero` are still the running program's futures
         lu = DeviceLU(plan=plan, schedule=sched, dtype=dtype,
                       L_flat=L_flat, U_flat=U_flat,
                       Li_flat=Li_flat, Ui_flat=Ui_flat,
-                      tiny_pivots=int(tiny))
+                      tiny_pivots=tiny)
         # THIS call's program cost (SLU_OBS_COST=1), handed to the
         # Stats consumer via the thread-local slot — NOT the handle,
         # which the serve layer shares across threads
         obs.stamp_cost("factor", factor_fn.cost_of(vj))
+    # a factorization under the merged sweep hands back a handle whose
+    # packs are in flight: `jit_slu_pack` is dispatched on the factor
+    # program's output futures BEFORE the blocking reads below, so the
+    # host hands out its buffers while the chip factors (the staged
+    # run has blocked on its counts already and hides nothing)
+    if trisolve.sweeps_packed():
+        trisolve.get_packs(lu, at="factor")
+    nzero = int(nzero)
+    lu.tiny_pivots = int(lu.tiny_pivots)
     if nzero > 0:
+        # nothing of the dropped handle stays within a caller's reach
+        obs.take_cost("pack")
         # reference semantics: U(i,i) == 0 with ReplaceTinyPivot=NO is
         # the info=i singularity signal (SRC/pdgstrf.c header); the
         # host backend raises for the same input
@@ -2449,11 +2461,12 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
     else:
         bin_ = bb.astype(xdt)
     from . import trisolve
-    merged = trisolve.trisolve_mode() == "merged"
+    merged = trisolve.sweeps_packed()
     # merged: the handle-cached packed panels, so repeated FACTORED
-    # solves skip the per-solve re-slice.  Taken BEFORE the sweep
-    # span opens: the first solve of a factorization packs here
-    # (`slu.solve.pack`), and that is not sweep time
+    # solves skip the per-solve re-slice.  A hit since
+    # `factorize_device` packs; taken BEFORE the sweep span opens
+    # all the same: a handle made under another arm or cell limit
+    # packs here (`slu.solve.pack`), and that is not sweep time
     packs = trisolve.get_packs(lu) if merged else None
     with obs.span("solve.sweep", cat="solve",
                   args={"nrhs": bb.shape[1], "trans": int(trans)}):

@@ -81,6 +81,14 @@ def trisolve_mode() -> str:
     return "merged"
 
 
+def sweeps_packed() -> bool:
+    """Do solves sweep the packed panels (the merged arm)?  THE rule
+    for who packs: `factorize_device` dispatches a factorization's
+    pack where this holds and `_solve_device_common` takes the packs
+    where it holds, so the two sites cannot diverge."""
+    return trisolve_mode() == "merged"
+
+
 def merge_cells_limit() -> int:
     """A group whose panel-cell count (trim · mb · wb) is below this
     joins a merged dispatch segment (SLU_TRISOLVE_MERGE_CELLS,
@@ -553,13 +561,20 @@ def pack_device(sched, store) -> PackSet:
     return _pack_fn(sched)(store)
 
 
-def get_packs(device_lu):
+def get_packs(device_lu, at: str = "solve"):
     """Per-handle packed panels, built by one device program
-    (`jit_slu_pack`) on the first solve of each factorization and
-    cached on the handle — the solve-optimized mirror of the factor
-    slabs (the reference keeps dedicated lsum solve structures the
-    same way; costs one extra ~factor-sized HBM residency, see
-    DESIGN.md §16)."""
+    (`jit_slu_pack`) and cached on the handle — the solve-optimized
+    mirror of the factor slabs (the reference keeps dedicated lsum
+    solve structures the same way; costs one extra ~factor-sized HBM
+    residency, see DESIGN.md §16).  Under the merged arm
+    `factorize_device` takes the miss (`at="factor"`) on the factor
+    program's output futures, before it blocks on the pivot counts,
+    so the host hands out the pack's buffers while the chip factors
+    and every solve finds a hit; a handle that reaches its first
+    solve without packs (the arm or a cell limit changed since)
+    takes the miss there.  The miss stamps where it was taken
+    (`at_factor` / `at_solve`) for this thread's in-flight driver
+    call: `Stats.packs`, the health ring's `pack`."""
     key = (merge_cells_limit(), seg_cells_limit())
     ent = getattr(device_lu, "_trisolve_packs", None)
     if ent is not None and ent[0] == key:
@@ -576,9 +591,10 @@ def get_packs(device_lu):
         # the miss path only: a hit opens no span
         with obs.span("solve.pack", cat="solve",
                       args={"groups": len(device_lu.schedule.groups),
-                            "programs": 1}):
+                            "programs": 1, "at": at}):
             packs = pack_device(device_lu.schedule, store)
         device_lu._trisolve_packs = (key, packs)
+        obs.stamp_cost("pack", "at_" + at)
         return packs
 
 

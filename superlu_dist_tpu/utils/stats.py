@@ -152,6 +152,13 @@ class Stats:
     # solve's value rides the health ring (`complex_lowering`)
     complex_lowering: Dict[str, str] = dataclasses.field(
         default_factory=dict)
+    # where `ops/trisolve.get_packs` took its miss path under this
+    # Stats: a factorization dispatches its own pack under the merged
+    # sweep ("at_factor", ops/batched.factorize_device); "at_solve"
+    # counts handles that reached their first solve without one.  The
+    # health ring's factor records carry each one's as `pack`
+    packs: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: {"at_factor": 0, "at_solve": 0})
     # the plan's GESP facts (plan/plan.gesp_facts: rows_moved, n,
     # equed, row_scale_min/max, col_scale_min/max, zero_diagonal),
     # stamped by the plan build and by every factorization on the
@@ -185,6 +192,12 @@ class Stats:
                                    "dtype": str(dtype),
                                    "mem": (dict(mem)
                                            if mem is not None else None)})
+
+    def note_pack(self, where: str) -> None:
+        """One taken miss of the pack ("at_factor" / "at_solve");
+        "none" (no pack dispatched) counts nothing."""
+        if where in self.packs:
+            self.packs[where] += 1
 
     def set_measured_cost(self, phase: str, cost: dict | None) -> None:
         """Adopt an XLA cost-analysis record ({flops, bytes}) for ONE
@@ -222,6 +235,7 @@ class Stats:
             "berr": self.berr,
             "refine_stalled": self.refine_stalled,
             "sweeps": dict(self.sweeps),
+            "packs": dict(self.packs),
             "complex_lowering": dict(self.complex_lowering),
             "gesp": dict(self.gesp),
             "escalations": self.escalations,
@@ -268,6 +282,10 @@ class Stats:
         if self.sweeps:
             lines.append("  sweeps by operand:    " + ", ".join(
                 f"{k} {v}" for k, v in sorted(self.sweeps.items())))
+        if any(self.packs.values()):
+            lines.append(
+                f"  packs dispatched:     {self.packs['at_factor']} at "
+                f"factor, {self.packs['at_solve']} at solve")
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
         if self.placement:

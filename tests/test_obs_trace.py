@@ -371,9 +371,9 @@ def _inside(events, child, parent):
 
 def test_profiler_session_solve_spans(profiled):
     """(a) factorize + two solves under a session: the phases and the
-    new leaves are in the .xplane.pb, the pack exactly once (the
-    second solve hits), one REFINE_STEP a counted step, children
-    inside their parents."""
+    new leaves are in the .xplane.pb, the pack exactly once and
+    inside the factorization (both solves hit), one REFINE_STEP a
+    counted step, children inside their parents."""
     evs = profiled["events"]
     count = {}
     for _, name, *_ in evs:
@@ -394,15 +394,16 @@ def test_profiler_session_solve_spans(profiled):
     assert sum(e[1] == "slu.REFINE_STEP" for e in mine) == steps
     # a residual before the loop and one a step, for each solve
     assert sum(e[1] == "slu.refine.residual" for e in mine) == steps + 2
-    _inside(evs, "slu.solve.pack", "slu.SOLVE")
+    _inside(evs, "slu.solve.pack", "slu.FACT")
     _inside(evs, "slu.solve.fetch", "slu.solve.sweep")
     _inside(evs, "slu.REFINE_STEP", "slu.REFINE")
     _inside(evs, "slu.refine.residual", "slu.REFINE")
-    # the pack is the sweep's elder sibling, never its parent or child
+    # the pack is dispatched by the factorization, before any sweep
     (pack,) = [e for e in evs if e[1] == "slu.solve.pack"]
+    assert pack[4]["at"] == "factor"
     for e in evs:
         if e[1] == "slu.solve.sweep" and e[0] == pack[0]:
-            assert e[2] >= pack[3] or e[3] <= pack[2]
+            assert e[2] >= pack[3]
     sweep = next(e for e in evs if e[1] == "slu.solve.sweep")
     assert sweep[4]["nrhs"] == 1 and sweep[4]["trans"] == 0
     assert pack[4]["groups"] >= 1

@@ -7,7 +7,17 @@ Pinned here: its PackSet equals the op-by-op reference
 for leaf in value, shape and dtype; a solve through it is
 bit-identical to one through the reference packs; a refactorization
 on a held plan hits the compiled program; `slu.solve.pack` opens on
-the miss only and says how many programs it dispatched."""
+the miss only and says how many programs it dispatched and where.
+
+Under the merged sweep the miss is the factorization's own
+(`ops/batched.factorize_device` dispatches the pack on the factor
+program's output futures, before its blocking read): the handle comes
+back holding its packs, the span lies inside `FACT` with `at ==
+"factor"`, the first solve finds a hit, the packs and every answer
+are bitwise those of the lazy path (a handle stripped of its packs,
+which packs at its first solve), the legacy sweep dispatches nothing,
+a singular input still raises and leaves nothing behind, and
+`Stats.packs`, `Stats.report()` and the health ring say where."""
 
 import numpy as np
 import pytest
@@ -15,11 +25,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from superlu_dist_tpu import Options, factorize, obs, solve
+from superlu_dist_tpu import Options, Stats, YesNo, factorize, obs, solve
 from superlu_dist_tpu.obs.compile_watch import COMPILE_WATCH
 from superlu_dist_tpu.ops import batched, trisolve
 from superlu_dist_tpu.plan.plan import plan_factorization
 from superlu_dist_tpu.utils.testmat import helmholtz_2d, laplacian_3d
+
+from test_review_fixes import _singular_matrix
 
 # storage form -> (matrix, factor dtype, environment)
 _FORMS = {
@@ -31,28 +43,51 @@ _FORMS = {
 }
 
 
-def _handle(monkeypatch, form):
+def _factorize(monkeypatch, form, stats=None, arm="merged"):
     mk, dtype, env = _FORMS[form]
-    monkeypatch.setenv("SLU_TRISOLVE", "merged")
+    monkeypatch.setenv("SLU_TRISOLVE", arm)
     for k, v in env.items():
         monkeypatch.setenv(k, v)
     a = mk()
-    d = factorize(a, Options(factor_dtype=dtype),
-                  backend="jax").device_lu
+    return a, factorize(a, Options(factor_dtype=dtype), stats=stats,
+                        backend="jax")
+
+
+def _handle(monkeypatch, form):
+    a, lu = _factorize(monkeypatch, form)
+    d = lu.device_lu
     staged = isinstance(d, batched.StagedLU)
     assert staged == (form == "staged_panels")
     assert batched._lu_is_pair(d) == (form == "pair_planes")
     return a, d
 
 
+def _store(d):
+    if isinstance(d, batched.StagedLU):
+        return d.panels
+    return (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)
+
+
+def _rhs(a, form, seed=3):
+    rng = np.random.default_rng(seed)
+    b = rng.standard_normal(a.n)
+    if form == "pair_planes":
+        b = b + 1j * rng.standard_normal(a.n)
+    return b
+
+
+def _inside(e, parent):
+    """A tracer span within another, on one thread."""
+    return (e["tid"] == parent["tid"] and parent["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= parent["ts"] + parent["dur"])
+
+
 def _reference_packs(d):
     """The packs sliced op by op, outside any trace."""
     ts = trisolve.get_trisolve(d.schedule)
-    if isinstance(d, batched.StagedLU):
-        return trisolve.PackSet(
-            trisolve.pack_panels_staged(ts, d.panels))
-    return trisolve.PackSet(trisolve.pack_panels(
-        ts, (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)))
+    cut = (trisolve.pack_panels_staged if isinstance(d, batched.StagedLU)
+           else trisolve.pack_panels)
+    return trisolve.PackSet(cut(ts, _store(d)))
 
 
 @pytest.mark.parametrize("form", list(_FORMS))
@@ -106,6 +141,8 @@ def test_refactorization_on_held_plan_compiles_pack_once(monkeypatch):
     handles = []
     for _ in range(2):
         lu = factorize(a, opts, plan=plan, backend="jax")
+        # the factorization took the miss: the solve finds a hit
+        assert COMPILE_WATCH.misses("pack") - before == 1
         solve(lu, b)
         handles.append(lu.device_lu)
     d0, d1 = handles
@@ -126,17 +163,156 @@ def test_pack_span_opens_on_the_miss_only(monkeypatch):
     t.clear()
     try:
         for _ in range(2):                  # two factorizations
-            lu = factorize(a, opts, plan=plan, backend="jax")
-            solve(lu, b)                    # miss
+            lu = factorize(a, opts, plan=plan, backend="jax")   # miss
+            solve(lu, b)                    # hit
             solve(lu, b)                    # hit
             trisolve.get_packs(lu.device_lu)    # hit
+        events = t.events()
+    finally:
+        obs.configure(enabled=False)
+    spans = [e for e in events if e["name"] == "solve.pack"]
+    facts = [e for e in events if e["name"] == "FACT"]
+    assert len(spans) == len(facts) == 2
+    for e, f in zip(spans, facts):
+        assert e["args"]["programs"] == 1
+        assert e["args"]["groups"] == len(lu.device_lu.schedule.groups)
+        assert e["args"]["at"] == "factor" and _inside(e, f)
+    assert not any(_inside(e, p) for e in spans for p in events
+                   if p["name"] == "SOLVE")
+
+
+# -- the pack is the factorization's own ------------------------------
+
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_factorization_hands_back_its_packs(monkeypatch, form):
+    """The handle holds its packs when `factorize` returns; the span
+    opens once a factorization, inside FACT, and no solve opens one."""
+    t = obs.configure(enabled=True)
+    t.clear()
+    try:
+        a, lu = _factorize(monkeypatch, form)
+        d = lu.device_lu
+        held = getattr(d, "_trisolve_packs", None)
+        assert held is not None and isinstance(held[1], trisolve.PackSet)
+        assert type(d.tiny_pivots) is int
+        after_fact = list(t.events())
+        for _ in range(2):
+            solve(lu, _rhs(a, form))
+        assert trisolve.get_packs(d) is held[1]
+        events = t.events()
+    finally:
+        obs.configure(enabled=False)
+    (pack,) = [e for e in events if e["name"] == "solve.pack"]
+    (fact,) = [e for e in events if e["name"] == "FACT"]
+    assert pack in after_fact and _inside(pack, fact)
+    assert pack["args"]["at"] == "factor"
+    assert pack["args"]["programs"] == 1
+    assert sum(e["name"] == "SOLVE" for e in events) == 2
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_packs_at_factorization_equal_pack_device_after(monkeypatch, form):
+    """Dispatched on the factor program's futures or on its finished
+    arrays, the pack is the same program on the same values."""
+    _, lu = _factorize(monkeypatch, form)
+    d = lu.device_lu
+    early = d._trisolve_packs[1]
+    late = trisolve.pack_device(d.schedule, _store(d))
+    assert late is not early
+    assert (jax.tree_util.tree_structure(early)
+            == jax.tree_util.tree_structure(late))
+    got, want = (jax.tree_util.tree_leaves(p) for p in (early, late))
+    assert len(got) == len(want) >= 4 * len(d.schedule.groups)
+    for x, y in zip(got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
+def test_answers_bitwise_the_lazy_paths(monkeypatch, form):
+    """A handle stripped of its packs is the parent's: its first solve
+    takes the miss (`at_solve`, in its Stats and in its record of the
+    health ring) and answers bit for bit what the eager handle does."""
+    a, eager = _factorize(monkeypatch, form)
+    _, lazy = _factorize(monkeypatch, form)
+    del lazy.device_lu._trisolve_packs
+    b = _rhs(a, form)
+    st_e, st_l = Stats(), Stats()
+    t = obs.configure(enabled=True)
+    t.clear()
+    try:
+        x_e = solve(eager, b, stats=st_e)
+        x_l = solve(lazy, b, stats=st_l)
+        solve(lazy, b, stats=st_l)          # a hit by now
+        (pack,) = [e for e in t.events() if e["name"] == "solve.pack"]
+        solves = [e for e in t.events() if e["name"] == "SOLVE"]
+    finally:
+        obs.configure(enabled=False)
+    assert np.isfinite(x_e).all() and np.array_equal(x_e, x_l)
+    assert pack["args"]["at"] == "solve" and _inside(pack, solves[1])
+    assert st_e.packs == {"at_factor": 0, "at_solve": 0}
+    assert st_l.packs == {"at_factor": 0, "at_solve": 1}
+    assert eager.factor_record["pack"] == "at_factor"
+    assert lazy.factor_record["pack"] == "at_solve"
+    # `lazy` was the newest factorization: the ring's own copy says so
+    assert obs.HEALTH.snapshot()["last_factor"]["pack"] == "at_solve"
+
+
+@pytest.mark.parametrize("form", ["flats_f32", "staged_panels"])
+def test_legacy_sweep_dispatches_no_pack(monkeypatch, form):
+    st = Stats()
+    t = obs.configure(enabled=True)
+    t.clear()
+    try:
+        a, lu = _factorize(monkeypatch, form, stats=st, arm="legacy")
+        assert not hasattr(lu.device_lu, "_trisolve_packs")
+        x = solve(lu, _rhs(a, form), stats=st)
+        assert not hasattr(lu.device_lu, "_trisolve_packs")
         spans = [e for e in t.events() if e["name"] == "solve.pack"]
     finally:
         obs.configure(enabled=False)
-    assert len(spans) == 2
-    for e in spans:
-        assert e["args"]["programs"] == 1
-        assert e["args"]["groups"] == len(lu.device_lu.schedule.groups)
+    assert np.isfinite(x).all() and not spans
+    assert st.packs == {"at_factor": 0, "at_solve": 0}
+    assert "packs dispatched" not in st.report()
+    assert lu.factor_record["pack"] == "none"
+    assert obs.HEALTH.snapshot()["last_factor"]["pack"] == "none"
+
+
+@pytest.mark.parametrize("staged", ["0", "1"], ids=["flats", "staged"])
+def test_singular_input_raises_and_leaves_nothing(monkeypatch, staged):
+    """Two identical rows, pivot replacement off: an exactly-zero
+    pivot.  `nzero` is read after the pack was dispatched: the raise drops
+    the handle with its packs, the thread's stamp, and writes no
+    record."""
+    monkeypatch.setenv("SLU_TRISOLVE", "merged")
+    monkeypatch.setenv("SLU_STAGED", staged)
+    opts = Options(replace_tiny_pivot=YesNo.NO, equil=YesNo.NO)
+    st = Stats()
+    n_before = obs.HEALTH.snapshot()["factorizations"]
+    with pytest.raises(ZeroDivisionError):
+        factorize(_singular_matrix(), opts, stats=st, backend="jax")
+    assert obs.take_cost("pack") is None
+    assert st.packs == {"at_factor": 0, "at_solve": 0}
+    assert not st.factor_events
+    assert obs.HEALTH.snapshot()["factorizations"] == n_before
+
+
+@pytest.mark.parametrize("form", ["flats_f32", "staged_panels"])
+def test_counters_say_at_factor(monkeypatch, form):
+    st = Stats()
+    a, lu = _factorize(monkeypatch, form, stats=st)
+    for _ in range(2):
+        solve(lu, _rhs(a, form), stats=st)
+    assert st.packs == {"at_factor": 1, "at_solve": 0}
+    assert st.snapshot()["packs"] == st.packs
+    assert "packs dispatched:     1 at factor, 0 at solve" in st.report()
+    snap = obs.HEALTH.snapshot()
+    assert snap["last_factor"]["pack"] == "at_factor"
+    assert snap["factor_events"][-1]["pack"] == "at_factor"
+    # a second factorization under the same Stats counts once more
+    _, lu2 = _factorize(monkeypatch, form, stats=st)
+    assert st.packs == {"at_factor": 2, "at_solve": 0}
+    assert lu2.factor_record is not lu.factor_record
 
 
 @pytest.fixture(scope="module")
