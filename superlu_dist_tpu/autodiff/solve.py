@@ -231,10 +231,8 @@ class GradContext:
                     return self._adj_trace(packs, xbar, x, _lane)
 
                 fns = self._legs[lane] = (
-                    obs.watch_jit("grad_fwd", jax.jit(fwd_fn),
-                                  cost_phase="SOLVE"),
-                    obs.watch_jit("adjoint", jax.jit(adj_fn),
-                                  cost_phase="SOLVE"))
+                    obs.watch_jit("grad_fwd", jax.jit(fwd_fn)),
+                    obs.watch_jit("adjoint", jax.jit(adj_fn)))
         return fns
 
     def diff_fn(self, lane: Trans):

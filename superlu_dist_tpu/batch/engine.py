@@ -98,7 +98,7 @@ def _batched_factor_segment_jit(upd_buf, vals, thresh, a_srcs, a_dsts,
 # the compile-watch proxy the zero-recompiles-after-warmup gate probes
 # (phase "batch_factor"; the serve coalescer dispatches through it)
 _batched_factor_segment = obs.watch_jit(
-    "batch_factor", _batched_factor_segment_jit, cost_phase="FACT",
+    "batch_factor", _batched_factor_segment_jit,
     donate=(0,))
 
 
@@ -297,8 +297,7 @@ def _batch_solve_fns(sched, dtype):
                         lambda c, px: (c, member(*px)), 0,
                         (panels, b))
                     return ys
-            return obs.watch_jit("batch_solve", fn,
-                                 cost_phase="SOLVE")
+            return obs.watch_jit("batch_solve", fn)
 
         cache[key] = (mk(False), mk(True))
         return cache[key]

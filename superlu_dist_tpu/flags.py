@@ -66,7 +66,6 @@ FLAGS: dict[str, str] = {
     "SLU_OBS": "1/0 master observability switch: span tracer + pivot-growth capture (default off unless SLU_TRACE*/SLU_TRACE_JSONL set; off costs one pointer check per span — no gssvx tax, pinned by tests/test_obs_trace.py)",
     "SLU_TRACE": "Chrome trace-event JSON export path, written at process exit (1 = ./last.trace.json; implies SLU_OBS; ~1 µs + one dict per span while on)",
     "SLU_TRACE_JSONL": "JSONL event-log path, appended through as spans close (implies SLU_OBS; adds one file write per span)",
-    "SLU_OBS_COST": "1 = XLA cost-analysis FLOP/byte accounting on each jit cache miss -> Stats.ops_measured (re-pays one AOT lower+compile per NEW signature; zero cost on the recompile-free hot path)",
     # --- request-scoped flight recorder + SLO engine (obs/flight.py, obs/slo.py) ---
     "SLU_FLIGHT": "1/0 per-request flight recorder: every SolveService request gets a monotonic rid and a stage-event record (admit/cache/queue/solve/refine + resilience events) in a bounded ring; off = ONE module-global pointer check on the request path (zero growth); on costs a few dict/list appends per request",
     "SLU_FLIGHT_JSONL": "flight-record JSONL sink path, one line per RETAINED record as it finishes (implies SLU_FLIGHT; adds one file write per retained request; self-disables on I/O error; tools/trace_export.py renders it as per-request Perfetto tracks)",

@@ -126,7 +126,6 @@ def factorize(a: CSRMatrix, options: Options | None = None,
             "SLU_COMPLEX_TPU=1 to override.")
     # drop any stale stamp from a direct ops-layer call the driver
     # never read (the host path below stamps nothing)
-    obs.take_cost("factor")
     obs.take_cost("pack")
     # complex on a TPU: the one-device jax backend takes the pair
     # lowering and stays on the chip (utils/platform.complex_lowering);
@@ -169,11 +168,6 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                 cache[key] = factor_dist.make_dist_factor(
                     plan, mesh, dtype=np.dtype(options.factor_dtype))
             dist_lu = cache[key](scaled)
-            # single-signature closure, so the wrapper's last-miss
-            # cost IS this call's program; same thread-local hand-off
-            # as the batched path
-            obs.stamp_cost("factor",
-                           getattr(cache[key].jitted, "cost", None))
             stats.tiny_pivots += dist_lu.tiny_pivots
             stats.comm_predicted = dist_lu.schedule.comm_summary(
                 np.dtype(options.factor_dtype))
@@ -192,13 +186,6 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                                    else plan.factor_flops)
     stats.ea_elements = sched.ea_elements if sched else {}
     stats.gesp = dict(getattr(plan, "gesp", None) or {})
-    # XLA cost-analysis flop accounting (SLU_OBS_COST=1): the program
-    # cost the backend stamped for THIS call (thread-local hand-off,
-    # obs/compile_watch.py), accumulated per factorization like
-    # add_ops/utime — so gflops() divides N executions' flops by N
-    # executions' wall, and a warm-cache refactorization never adopts
-    # another schedule's program
-    stats.set_measured_cost(_phase, obs.take_cost("factor"))
     # where this factorization's solve mirror was dispatched: by
     # `factorize_device` under the merged sweep ("at_factor"), else
     # not yet ("none": a later solve that packs corrects the ring's
@@ -379,11 +366,9 @@ def solve(lu: LUFactorization, b: np.ndarray,
     with complex_device_gate(factor_dt, bb.dtype,
                              pair_capable=(stored == "pair"),
                              stats=stats, phase="SOLVE"):
-        obs.take_cost("solve")  # drop any stale unread stamp
-        obs.take_cost("pack")
+        obs.take_cost("pack")   # drop any stale unread stamp
         with stats.timer("SOLVE"):
             x = from_factor_sol(sweep(lu, to_factor_rhs(bb)))
-        stats.set_measured_cost("SOLVE", obs.take_cost("solve"))
         # a handle that came without its packs took the miss in the
         # sweep above (ops/trisolve.get_packs); every later sweep hits
         pack = obs.take_cost("pack")

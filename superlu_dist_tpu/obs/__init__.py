@@ -11,10 +11,14 @@ answer live:
     trace-event JSON (Perfetto-loadable; `tools/trace_export.py`)
     and/or a JSONL event log.  Gated by SLU_OBS / SLU_TRACE /
     SLU_TRACE_JSONL with a no-op singleton fast path when off.
-  * did XLA recompile? — `compile_watch`: per-jitted-phase cache-miss
-    counters with shape/dtype/static-arg attribution, and optional
-    XLA cost-analysis FLOP/byte accounting (SLU_OBS_COST=1) that
-    feeds `Stats.ops_measured`.
+  * did XLA recompile, and what did the start cost? —
+    `compile_watch`: per-jitted-phase cache-miss counters with
+    shape/dtype/static-arg attribution, and the start-up ledger: one
+    row a new program of the process (watched or eager) with its
+    seconds split into trace, lower, compile and persistent-cache
+    load from `jax.monitoring`, beside the plan's and the schedule's
+    phases (`COMPILE_WATCH.ledger()`).  Always on; it writes only
+    when a program is new.
   * are the numerics drifting? — `health`: tiny-pivot replacement
     counts, pivot-growth estimates, berr/ferr trajectories and
     escalation events — the GESP runtime-watch obligation.

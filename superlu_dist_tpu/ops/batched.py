@@ -38,6 +38,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 from typing import List, Optional
 
 import jax
@@ -591,6 +592,7 @@ def _coop_block() -> int:
 
 
 def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
+    t_build0 = time.perf_counter()
     fp = plan.frontal
     part = fp.sym.part
     xsup = part.xsup
@@ -1146,13 +1148,17 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
             g.bwd_sync = bool(any(anc_cross[int(s)]
                                   for s in g.sup_ids))
 
-    return BatchedSchedule(groups=groups, ndev=ndev, n=n,
-                           upd_total=upd_peak,
-                           L_total=L_cur, U_total=U_cur,
-                           Li_total=Li_cur, Ui_total=Ui_cur,
-                           sup_dev=sup_dev,
-                           upd_pad=max(1 + max_blk_stride,
-                                       row_read_end - upd_peak))
+    sched = BatchedSchedule(groups=groups, ndev=ndev, n=n,
+                            upd_total=upd_peak,
+                            L_total=L_cur, U_total=U_cur,
+                            Li_total=Li_cur, Ui_total=Ui_cur,
+                            sup_dev=sup_dev,
+                            upd_pad=max(1 + max_blk_stride,
+                                        row_read_end - upd_peak))
+    # once a schedule, never a step (get_schedule caches it)
+    obs.COMPILE_WATCH.record_phases(
+        t_build0, {"SCHEDULE": time.perf_counter() - t_build0})
+    return sched
 
 
 def get_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
@@ -2384,8 +2390,8 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
                     extra=("phase_factor", bool(pair),
                            float(thresh_np))))
         cache[key] = (
-            obs.watch_jit("factor", factor_w, cost_phase="FACT"),
-            obs.watch_jit("solve", slu_solve, cost_phase="SOLVE"))
+            obs.watch_jit("factor", factor_w),
+            obs.watch_jit("solve", slu_solve))
         return cache[key]
 
 
@@ -2417,10 +2423,6 @@ def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
                       L_flat=L_flat, U_flat=U_flat,
                       Li_flat=Li_flat, Ui_flat=Ui_flat,
                       tiny_pivots=tiny)
-        # THIS call's program cost (SLU_OBS_COST=1), handed to the
-        # Stats consumer via the thread-local slot — NOT the handle,
-        # which the serve layer shares across threads
-        obs.stamp_cost("factor", factor_fn.cost_of(vj))
     # a factorization under the merged sweep hands back a handle whose
     # packs are in flight: `jit_slu_pack` is dispatched on the factor
     # program's output futures BEFORE the blocking reads below, so the
@@ -2492,15 +2494,6 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
             # 7 lesson, enforced by slulint's static-kwarg rule)
             X = solve_fn(lu.L_flat, lu.U_flat, lu.Li_flat,
                          lu.Ui_flat, bj, trans)
-            # the EXECUTED signature's program cost — the solve
-            # wrapper serves the whole nrhs bucket ladder, so a shared
-            # last-miss field would misattribute (a 1-wide solve
-            # adopting the 64-wide program's flops); thread-local, not
-            # on the handle, so concurrent solves through one cached
-            # factorization don't cross-attribute either
-            obs.stamp_cost("solve", solve_fn.cost_of(
-                lu.L_flat, lu.U_flat, lu.Li_flat, lu.Ui_flat, bj,
-                trans))
         with obs.span("solve.fetch", cat="solve"):
             out = np.asarray(X)
     if pair:
@@ -3140,8 +3133,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
             return (xh, xl, berr, jnp.maximum(steps - 1, 0), tiny,
                     nzero)
 
-        core = obs.watch_jit("fused_step_dw", jax.jit(_core),
-                             cost_phase="FUSED")
+        core = obs.watch_jit("fused_step_dw", jax.jit(_core))
 
         def step(vals, b):
             vh, vl = split_f64(np.asarray(vals))
@@ -3175,8 +3167,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
             return step_body(_scale_impl(vals), resid_berr, b_r,
                              per_group_const)
 
-        step = _wrap_pair(obs.watch_jit("fused_step", step,
-                                        cost_phase="FUSED"))
+        step = _wrap_pair(obs.watch_jit("fused_step", step))
         step.resid_fn = _resid_fn
         step.spmv_layout = layout
         step.residual_mode = mode
@@ -3225,8 +3216,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
 
         jitted_c = obs.watch_jit(
             "fused_step_mesh",
-            jax.jit(lambda vals, b: mapped_c(vals, b, *idx_args)),
-            cost_phase="FUSED")
+            jax.jit(lambda vals, b: mapped_c(vals, b, *idx_args)))
 
         def step_c(vals, b):
             return jitted_c(vals, b)
@@ -3332,8 +3322,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     jitted = obs.watch_jit(
         "fused_step_mesh",
         jax.jit(lambda vsel, ssel, vchunk, rc, cc, b: mapped(
-            vsel, ssel, vchunk, rc, cc, b, *idx_args)),
-        cost_phase="FUSED")
+            vsel, ssel, vchunk, rc, cc, b, *idx_args)))
 
     def step(vals, b):
         # host-side one-time redistribution per call (dReDistribute_A

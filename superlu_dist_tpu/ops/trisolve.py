@@ -837,8 +837,7 @@ def _solve_packed_fn(sched, dtype, pair: bool):
                     slu_solve_packed,
                     aot.schedule_fingerprint(
                         sched, dt, extra=("packed", bool(pair))))
-            return obs.watch_jit("solve", wrapped,
-                                 cost_phase="SOLVE")
+            return obs.watch_jit("solve", wrapped)
 
         return mk(False), mk(True)
 
@@ -852,16 +851,13 @@ def solve_packed(lu, bb, trans: bool):
     ordering, dtype-resolved by the caller (and pair-encoded when the
     handle stores pair planes).  Returns the device solution (pair:
     still encoded — `_solve_device_common` decodes)."""
-    from .. import obs
     from .batched import _lu_is_pair
     pair = _lu_is_pair(lu)
     packs = get_packs(lu)
     fns = _solve_packed_fn(lu.schedule, lu.dtype, pair)
     fn = fns[1] if trans else fns[0]
     bj = jnp.asarray(bb)
-    X = fn(packs, bj)
-    obs.stamp_cost("solve", fn.cost_of(packs, bj))
-    return X
+    return fn(packs, bj)
 
 
 def solve_packed_cache_size(lu) -> int:

@@ -355,8 +355,7 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
 
     jitted = obs.watch_jit(
         "dist_step",
-        jax.jit(lambda vsel, b: mapped(vsel, b, *idx_args)),
-        cost_phase="FUSED")
+        jax.jit(lambda vsel, b: mapped(vsel, b, *idx_args)))
     vshard = jax.sharding.NamedSharding(mesh, P(axis))
 
     def step(vals, b):
@@ -450,8 +449,7 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
                 dsched, dtype,
                 extra=("dist_factor",)
                 + aot.mesh_fingerprint_legs(mesh, axis)))
-    jitted = obs.watch_jit("dist_factor", factor_fn,
-                           cost_phase="FACT")
+    jitted = obs.watch_jit("dist_factor", factor_fn)
     vshard = jax.sharding.NamedSharding(mesh, P(axis))
 
     def factor(vals) -> DistLU:
@@ -572,8 +570,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
 
     solve = _aot_wrap_dist("dist_solve_merged", slu_dist_solve_merged,
                            dsched, mesh, axis, dtype, trans)
-    return obs.watch_jit("dist_solve_merged", solve,
-                         cost_phase="SOLVE")
+    return obs.watch_jit("dist_solve_merged", solve)
 
 
 def mesh_oracle_solve(dlu: DistLU, b_factor_order,
@@ -671,7 +668,7 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
 
     solve = _aot_wrap_dist("dist_solve", slu_dist_solve, dsched, mesh,
                            axis, dtype, trans)
-    return obs.watch_jit("dist_solve", solve, cost_phase="SOLVE")
+    return obs.watch_jit("dist_solve", solve)
 
 
 def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
@@ -762,8 +759,7 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
     jitted = obs.watch_jit(
         "dist_solve_rhs_sharded",
         _aot_wrap_dist("dist_solve_rhs_sharded", jax.jit(mapped),
-                       dsched, mesh, axis, dtype, trans),
-        cost_phase="SOLVE")
+                       dsched, mesh, axis, dtype, trans))
 
     def solve(L_flat, U_flat, Li_flat, Ui_flat, b):
         r = b.shape[1]

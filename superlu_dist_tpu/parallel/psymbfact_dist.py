@@ -47,6 +47,7 @@ from MPI communicators.
 from __future__ import annotations
 
 import pickle
+import time
 from typing import List
 
 import numpy as np
@@ -57,7 +58,7 @@ from ..sparse import CSRMatrix
 from ..utils.stats import Stats
 from ..plan import colperm as colperm_mod
 from ..plan import equilibrate, rowperm
-from ..plan.plan import FactorPlan, plan_from_perms
+from ..plan.plan import FactorPlan, ledger_phases, plan_from_perms
 from ..plan.psymbfact import (complete_from_domains, domain_symbfact,
                               partition_domains)
 
@@ -333,6 +334,8 @@ def plan_factorization_dist(fst_row: int, indptr_loc, indices_loc,
             "plan path (this signature carries no user permutation); "
             "use plan_factorization on the assembled matrix")
     stats = stats if stats is not None else Stats()
+    t_plan0 = time.perf_counter()
+    u0 = dict(stats.utime)
     comm = comm if comm is not None else default_comm()
     indptr_loc = np.asarray(indptr_loc, dtype=np.int64)
     indices_loc = np.asarray(indices_loc, dtype=np.int64)
@@ -454,6 +457,8 @@ def plan_factorization_dist(fst_row: int, indptr_loc, indices_loc,
         return complete_from_domains(b_indptr, b_indices, part, dp,
                                      struct)
 
+    ledger_phases(t_plan0, u0, stats,
+                  ("GATHER", "EQUIL", "ROWPERM", "COLPERM"))
     return plan_from_perms(n, options, stats, equed, r_eff, c_eff,
                            perm_r, perm_c, coo_rows, coo_cols, anorm,
                            symbfact_fn=dist_symbfact)

@@ -21,7 +21,7 @@ import time
 import numpy as np
 import scipy.sparse as sp
 
-from .. import flags
+from .. import flags, obs
 from ..options import Options
 from ..sparse import CSRMatrix
 from ..utils.stats import Stats
@@ -182,6 +182,15 @@ def _check_structure(a: CSRMatrix, coo_rows, coo_cols) -> None:
         empty_rows=empty_rows, empty_cols=empty_cols)
 
 
+def ledger_phases(t0: float, before: dict, stats: Stats,
+                   names) -> None:
+    """What `stats.utime` gained under `names` since `before`, into
+    the start-up ledger (obs/compile_watch.py): once a plan."""
+    obs.COMPILE_WATCH.record_phases(
+        t0, {p: stats.utime.get(p, 0.0) - before.get(p, 0.0)
+             for p in names})
+
+
 def plan_factorization(a: CSRMatrix, options: Options | None = None,
                        stats: Stats | None = None,
                        user_perm_r: np.ndarray | None = None,
@@ -200,6 +209,7 @@ def plan_factorization(a: CSRMatrix, options: Options | None = None,
         raise ValueError("solver requires a square matrix")
     n = a.n
     t_plan0 = time.perf_counter()
+    u0 = dict(stats.utime)
 
     coo_rows, coo_cols, _ = a.to_coo()
 
@@ -239,6 +249,7 @@ def plan_factorization(a: CSRMatrix, options: Options | None = None,
             nd_threads=options.nd_threads)
 
     anorm = float(np.max(np.abs(scaled_vals))) if len(scaled_vals) else 1.0
+    ledger_phases(t_plan0, u0, stats, ("EQUIL", "ROWPERM", "COLPERM"))
     plan = plan_from_perms(n, options, stats, equed, r_eff, c_eff,
                            perm_r, perm_c, coo_rows, coo_cols, anorm,
                            autotune=autotune)
@@ -270,6 +281,8 @@ def plan_from_perms(n: int, options: Options, stats: Stats,
     None = the local (native, optionally threaded) pass."""
     if autotune is None:
         autotune = bool(getattr(options, "autotune", False))
+    t_back0 = time.perf_counter()
+    u0 = dict(stats.utime)
 
     # rows/cols after Pr then symmetric Pc
     r1 = perm_c[perm_r[coo_rows]]
@@ -342,4 +355,5 @@ def plan_from_perms(n: int, options: Options, stats: Stats,
                 sym, fr, fc, tuned.width_buckets, tuned.front_buckets)
         plan.options = tuned
     stats.lu_nnz = plan.lu_nnz()
+    ledger_phases(t_back0, u0, stats, ("ETREE", "SYMBFACT", "DIST"))
     return plan

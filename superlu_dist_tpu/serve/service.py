@@ -47,7 +47,7 @@ import numpy as np
 
 from .. import flags
 from ..models.gssvx import LUFactorization, solve
-from ..obs import flight, slo
+from ..obs import COMPILE_WATCH, flight, slo
 from ..obs import registry as obs_registry
 from ..options import Options, merge_solve_options, solve_options_key
 from ..resilience import breaker as breaker_defaults
@@ -442,12 +442,17 @@ class SolveService:
         with self._lock:
             if self._closed:
                 raise ServeError("service is closed")
+        t0 = time.perf_counter()
         options = self._stamp_mesh(options or Options())
         key = matrix_key(a, options)
         lu = self._resident_for(a, options, key)
         with self._lock:
             self._prefactor_opts[key] = options
         self._batcher_for(key, lu, options).warmup()
+        # the start-up ledger's one record of a key's warm-up (its
+        # plan, schedule and programs write their own inside it)
+        COMPILE_WATCH.record_phases(
+            t0, {"PREFACTOR": time.perf_counter() - t0})
         return key
 
     def grad_solve(self, a: CSRMatrix | CacheKey, b: np.ndarray,

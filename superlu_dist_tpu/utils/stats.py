@@ -81,13 +81,6 @@ class Stats:
         default_factory=lambda: {p: 0.0 for p in PHASES})
     ops: Dict[str, float] = dataclasses.field(
         default_factory=lambda: {p: 0.0 for p in PHASES})
-    # XLA cost-analysis flop counts per phase (obs/compile_watch.py,
-    # SLU_OBS_COST=1): the compiled program's own accounting, preferred
-    # over the hand-counted `ops` when present
-    ops_measured: Dict[str, float] = dataclasses.field(
-        default_factory=dict)
-    bytes_measured: Dict[str, float] = dataclasses.field(
-        default_factory=dict)
     tiny_pivots: int = 0
     refine_steps: int = 0
     berr: float = 0.0
@@ -199,28 +192,11 @@ class Stats:
         if where in self.packs:
             self.packs[where] += 1
 
-    def set_measured_cost(self, phase: str, cost: dict | None) -> None:
-        """Adopt an XLA cost-analysis record ({flops, bytes}) for ONE
-        execution of a phase program (obs/compile_watch.py under
-        SLU_OBS_COST=1).  Accumulates like add_ops/utime, so N
-        factorizations' measured flops divide by N factorizations'
-        wall in gflops()."""
-        if not cost:
-            return
-        if cost.get("flops"):
-            self.ops_measured[phase] = self.ops_measured.get(
-                phase, 0.0) + float(cost["flops"])
-        if cost.get("bytes"):
-            self.bytes_measured[phase] = self.bytes_measured.get(
-                phase, 0.0) + float(cost["bytes"])
-
     def gflops(self, phase: str) -> float:
         t = self.utime.get(phase, 0.0)
         if t <= 0:
             return 0.0
-        flops = self.ops_measured.get(phase) \
-            or self.ops.get(phase, 0.0)
-        return flops / t / 1e9
+        return self.ops.get(phase, 0.0) / t / 1e9
 
     def snapshot(self) -> dict:
         """JSON-ready view for the obs.Registry (the serve
@@ -228,8 +204,6 @@ class Stats:
         return {
             "utime": {p: t for p, t in self.utime.items() if t},
             "ops": {p: v for p, v in self.ops.items() if v},
-            "ops_measured": dict(self.ops_measured),
-            "bytes_measured": dict(self.bytes_measured),
             "tiny_pivots": self.tiny_pivots,
             "refine_steps": self.refine_steps,
             "berr": self.berr,
@@ -305,11 +279,13 @@ class Stats:
                        sorted(cw["by_phase"].items()))
         lines.append(f"  jit compiles:         {cw['misses']} miss"
                      + (f" ({by})" if by else ""))
-        if self.ops_measured:
-            meas = ", ".join(
-                f"{p}={v / 1e9:.2f}e9" for p, v in
-                sorted(self.ops_measured.items()))
-            lines.append(f"  measured flops (XLA): {meas}")
+        su = cw["startup"]
+        lines.append(
+            f"  start-up (process):   {su['programs']} new programs, "
+            f"trace {su['trace_s']:.2f} s, lower {su['lower_s']:.2f} s, "
+            f"compile {su['compile_s']:.2f} s, cache load "
+            f"{su['load_s']:.2f} s ({su['cache_hits']} hit, "
+            f"{su['cache_misses']} miss, {su['cache_off']} off)")
         lines.append(f"  health: {obs.HEALTH.summary()}")
         if self.escalations:
             lines.append(

@@ -277,26 +277,6 @@ def test_health_monitor_trajectories(traced):
     assert bt[-1] <= bt[0]
 
 
-def test_stats_measured_cost_adoption():
-    """SLU_OBS_COST plumbing: a cost record adopted by Stats flips
-    gflops() to the measured flop count."""
-    from superlu_dist_tpu.utils.stats import Stats
-    st = Stats()
-    st.utime["FACT"] = 2.0
-    st.add_ops("FACT", 4e9)
-    assert st.gflops("FACT") == pytest.approx(2.0)
-    st.set_measured_cost("FACT", {"flops": 8e9, "bytes": 1e6})
-    assert st.gflops("FACT") == pytest.approx(4.0)
-    assert st.bytes_measured["FACT"] == 1e6
-    assert st.snapshot()["ops_measured"]["FACT"] == 8e9
-    st.set_measured_cost("FACT", None)          # None is a no-op
-    assert st.ops_measured["FACT"] == 8e9
-    # one record per EXECUTION: repeated factorizations accumulate,
-    # mirroring add_ops/utime (gflops stays per-run consistent)
-    st.set_measured_cost("FACT", {"flops": 2e9})
-    assert st.ops_measured["FACT"] == 1e10
-
-
 # --------------------------------------------------------------------
 # the profiler sink: a live jax.profiler session is the switch
 # --------------------------------------------------------------------
