@@ -41,8 +41,6 @@ FLAGS: dict[str, str] = {
     # --- level-merged factor sweep (ops/batched.py) ---
     "SLU_FACTOR_MERGE_CELLS": "front-cell bound (n_loc*mb*ncols) at or below which a factor group joins a merged staged dispatch segment (default 65536); 0 = legacy per-group staged dispatch (the A/B arm).  Merging is dispatch granularity only — factors are bitwise-identical to the legacy sweep",
     "SLU_FACTOR_SEG_CELLS": "total front-cell budget of one merged factor segment (default 1048576) — bounds per-segment staged program size so segment compiles stay in the per-group compile class",
-    # --- AOT executable persistence (resilience/aot.py) ---
-    "SLU_AOT_CACHE": "AOT executable-persistence directory (0/off/unset = disabled, zero overhead): whole-phase jits (phase factor + packed solve) serialize via jax.export write-through/read-through, keyed by a schedule-layout + dtype + merge-flag fingerprint, and the XLA persistent compilation cache is pointed at <dir>/xla when not already configured — a fresh process skips trace+lower by deserializing and the backend compile through the cache.  Write-through costs one serialize per new program signature; mismatched-fingerprint entries are refused with a typed error and quarantined, never served",
     # --- residual SpMV layout (ops/spmv.py) ---
     "SLU_SPMV_LAYOUT": "auto|ell|coo residual SpMV layout (ell = scatter-free padded rows)",
     "SLU_SPMV_ELL_WASTE": "max ELL padding ratio over true nnz before falling back to COO (default 4)",

@@ -29,6 +29,13 @@ call.  Every new program of the process leaves one row:
     saved_s  jax's `compile_time_saved_sec` (can be negative)
     cache    "hit" | "miss" | "off" (no request reached the cache);
              None for a row that was traced and never compiled
+    aot      what the exported-program store (resilience/aot.py) did
+             for the program: "hit" (deserialized: `trace_s` and
+             `lower_s` are then the wrapper's, not the program's),
+             "miss" (exported and saved now), "refused" (an entry
+             failed verification: quarantined, re-exported),
+             "unexportable" (fell back to the plain jit) or "off"
+             (the store is off, or the program is not one it wraps)
     thread   ident of the compiling thread
     spans    the (kind, start, end) intervals the seconds are the
              union of, so that a reader can union ACROSS rows
@@ -124,7 +131,7 @@ class _Open:
     cache said inside the backend-compile span that has not ended."""
 
     __slots__ = ("watched", "outer", "spans", "name", "name_s", "cache",
-                 "saved_s", "request", "hit")
+                 "saved_s", "request", "hit", "aot")
 
     def __init__(self, watched, outer=None):
         self.watched = watched
@@ -135,6 +142,7 @@ class _Open:
         self.cache = None
         self.saved_s = 0.0
         self.request = self.hit = False
+        self.aot = "off"
 
 
 def _leaf_sig(a):
@@ -385,6 +393,13 @@ class CompileWatch:
         row = self._open[tid] = _Open(watched, outer)
         return row
 
+    def stamp_aot(self, status: str) -> None:
+        """`AotJit` resolved a new signature inside this thread's
+        watched first call: the row it is writing says how."""
+        row = self._open.get(threading.get_ident())
+        if row is not None and row.watched is not None:
+            row.aot = status
+
     def close_row(self, row: _Open, t0: float, wall_s: float) -> dict:
         t_in = time.perf_counter()
         tid = threading.get_ident()
@@ -417,7 +432,7 @@ class CompileWatch:
             split["first_call_other_s"] = _other_s(wall_s, spans)
         name = row.name or traced or row.watched
         tup = (name, row.watched, t0, threading.get_ident(), row.cache,
-               row.saved_s, wall_s, spans)
+               row.saved_s, wall_s, spans, row.aot)
         with self._lock:
             if self._header is None:
                 self._header = self._make_header()
@@ -518,9 +533,11 @@ class CompileWatch:
             folded = {name: dict(zip(_FOLD_KEYS, v))
                       for name, v in self._folded.items()}
         programs = []
-        for name, watched, t0, thread, cache, saved, wall, spans in rows:
+        for (name, watched, t0, thread, cache, saved, wall, spans,
+             aot) in rows:
             rec = {"name": name, "watched": watched, "t0": t0,
-                   "thread": thread, "cache": cache, "saved_s": saved,
+                   "thread": thread, "cache": cache, "aot": aot,
+                   "saved_s": saved,
                    "spans": [list(s) for s in spans]}
             rec.update(_seconds(spans))
             if wall is not None:
@@ -533,13 +550,15 @@ class CompileWatch:
                 "folded": folded}
 
     def snapshot(self) -> dict:
+        from ..resilience import aot
         with self._lock:
             return {
                 "calls": self.calls,
                 "misses": self._misses_total,
                 "by_phase": dict(self._by_phase),
                 "startup": dict(self._totals,
-                                ledger_self_s=self._self_s),
+                                ledger_self_s=self._self_s,
+                                aot=aot.stats()),
             }
 
 

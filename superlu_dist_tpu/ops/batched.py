@@ -184,7 +184,7 @@ class GroupSpec:
         (per-bucket 5-tuples) and block-copy buckets (per-bucket
         4-tuples, eb_hosts).  with_a_src=False leaves position 0
         as None — for callers that substitute a remapped a_src
-        (factor_dist._sharded_factor_operands), so the global array is
+        (factor_dist._factor_operands), so the global array is
         never uploaded or cached."""
         if self._dev is None:
             self._dev = {}
@@ -192,56 +192,66 @@ class GroupSpec:
         if key not in self._dev:
             ncols = self.cp if self.cp > 0 else self.mb
             f_loc = self.n_loc * self.mb * ncols
-            fdt = jnp.int32 if f_loc < 2**31 - 1 else jnp.int64
-            sdt = (jnp.int32 if int(self.a_src.max(initial=0)) < 2**31 - 1
-                   else jnp.int64)
+            fdt = np.int32 if f_loc < 2**31 - 1 else np.int64
+            sdt = (np.int32 if int(self.a_src.max(initial=0)) < 2**31 - 1
+                   else np.int64)
+
+            def put(a, dt):
+                # cast and squeeze in numpy, one transfer a leaf:
+                # `jnp.asarray(a, dtype=dt)[0]` is three one-operation
+                # programs a leaf, ~500 a schedule, each compiled on a
+                # cold start (ROADMAP S1 a).  Eager even when first
+                # called under a trace (the builders call this from
+                # inside their programs' traces, so that a program
+                # served from the exported store builds none of it):
+                # a traced constant cached here would leak its tracer
+                a = np.asarray(a, dtype=dt)
+                with jax.ensure_compile_time_eval():
+                    return jnp.asarray(a[0] if squeeze else a)
+
             eblocks = []
             for (rc_b, tc_b, _, C, *_), (so, st, db, pr, pc) in zip(
                     self.ea_meta, self.ea_hosts):
                 span = (int(so.max(initial=0))
                         + int(st.max(initial=0)) * rc_b + tc_b)
-                edt = jnp.int32 if span < 2**31 - 1 else jnp.int64
+                edt = np.int32 if span < 2**31 - 1 else np.int64
                 if C == 0:
                     # row lane: the positions ship as their inverse
                     # maps (front row -> child row, owned column ->
                     # child column; _ea_add_rows)
                     pr = _inverse_positions(pr, self.mb, rc_b)
                     pc = _inverse_positions(pc, ncols, tc_b)
-                prd = jnp.asarray(pr, dtype=jnp.int32)
-                eblocks.append((jnp.asarray(so, dtype=edt),
-                                jnp.asarray(st, dtype=edt),
-                                jnp.asarray(db, dtype=fdt),
-                                prd,
-                                prd if pc is pr
-                                else jnp.asarray(pc, dtype=jnp.int32)))
+                prd = put(pr, np.int32)
+                # squeezed leaves are each their own array, as when
+                # the squeeze ran a leaf on the device: one shared
+                # array would be one constant of the traced program
+                # where it has had two
+                eblocks.append((put(so, edt), put(st, edt),
+                                put(db, fdt), prd,
+                                prd if pc is pr and not squeeze
+                                else put(pc, np.int32)))
             bblocks = []
             for (li, lj, st, K), (so, dr, dc, w) in zip(
                     self.eb_meta, self.eb_hosts):
                 # dynamic_slice offsets need no gather-wrap dtype
                 # promotion, but must hold the largest start value
-                bdt = (jnp.int32
+                bdt = (np.int32
                        if int(so.max(initial=0)) + li * st < 2**31 - 1
-                       else jnp.int64)
-                bblocks.append((jnp.asarray(so, dtype=bdt),
-                                jnp.asarray(dr, dtype=jnp.int32),
-                                jnp.asarray(dc, dtype=jnp.int32),
-                                jnp.asarray(w, dtype=jnp.int32)))
+                       else np.int64)
+                bblocks.append((put(so, bdt), put(dr, np.int32),
+                                put(dc, np.int32), put(w, np.int32)))
             pos = (self.pos_of_slot if self.pos_of_slot is not None
                    else np.zeros((self.a_src.shape[0], 1, 1),
                                  dtype=np.int32))
-            arrs = (
-                jnp.asarray(self.a_src, dtype=sdt) if with_a_src
-                else None,
-                jnp.asarray(self.a_dst, dtype=fdt),
-                jnp.asarray(self.one_dst, dtype=fdt),
+            self._dev[key] = (
+                put(self.a_src, sdt) if with_a_src else None,
+                put(self.a_dst, fdt),
+                put(self.one_dst, fdt),
                 (tuple(eblocks), tuple(bblocks)),
-                jnp.asarray(pos, dtype=jnp.int32),
-                jnp.asarray(self.col_idx, dtype=jnp.int32),
-                jnp.asarray(self.struct_idx, dtype=jnp.int32),
+                put(pos, np.int32),
+                put(self.col_idx, np.int32),
+                put(self.struct_idx, np.int32),
             )
-            if squeeze:
-                arrs = jax.tree_util.tree_map(lambda a: a[0], arrs)
-            self._dev[key] = arrs
         return self._dev[key]
 
 
@@ -2344,9 +2354,14 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         if key in cache:
             return cache[key]
         from ..parallel.factor_dist import _factor_loop, _solve_loop
-        per_group = [g.dev(squeeze=True) for g in sched.groups]
-        pairs = [(t[5], t[6]) for t in per_group]
         dtype = np.dtype(dtype)
+
+        # the index constants of both traces, built once and only
+        # when one of them is traced: a process whose programs all
+        # come from the exported store (resilience/aot.py) never
+        # uploads them
+        per_group = functools.cache(
+            lambda: [g.dev(squeeze=True) for g in sched.groups])
 
         # the programs' names are what a profiler trace calls them
         # (`jit_slu_factor`), and part of their persistent-cache key,
@@ -2355,11 +2370,12 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         @jax.jit
         def slu_factor(vals):
             return _factor_loop(sched, vals, thresh_np, dtype,
-                                per_group, None, pair=pair)
+                                per_group(), None, pair=pair)
 
         @functools.partial(jax.jit, static_argnames=("trans",))
         def slu_solve(L, U, Li, Ui, b, trans=False):
-            return _solve_loop(sched, (L, U, Li, Ui), b, dtype, pairs,
+            return _solve_loop(sched, (L, U, Li, Ui), b, dtype,
+                               [(t[5], t[6]) for t in per_group()],
                                None, trans=trans, pair=pair)
 
         # compile telemetry (obs/compile_watch.py): each whole-phase
@@ -2367,22 +2383,23 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         # attribution — the recompile counter the
         # zero-recompiles-after-warmup contract is pinned on.  The proxies
         # delegate lower()/_cache_size() to the jits underneath.
-        # With SLU_AOT_CACHE active the factor program is AOT-wrapped
-        # (resilience/aot.py): a fresh process deserializes the
-        # persisted export instead of re-tracing the whole-phase
-        # factor.  The solve twin keeps its plain jit here (static
-        # `trans` leg; the serve hot path's solve program is the
-        # packed one, AOT-wrapped in trisolve._solve_packed_fn) and
-        # rides the compilation-cache leg.
-        # Complex lanes are never AOT-wrapped: the complex-on-TPU
-        # platform gate (utils/platform.py) executes complex programs
-        # on the host CPU while the default backend stays TPU, and an
-        # export records ONE platform — the gated dispatch would be
-        # refused at call time.  Real dtypes always run on the
-        # backend they export for.
+        # Where a compile cache is kept the factor program is
+        # AOT-wrapped (resilience/aot.py): a fresh process
+        # deserializes the persisted export instead of re-tracing the
+        # whole-phase factor.  The solve twin keeps its plain jit
+        # here (static `trans` leg; the serve hot path's solve program
+        # is the packed one, AOT-wrapped in
+        # trisolve._solve_packed_fn) and rides the compilation-cache
+        # leg.
+        # A pair-stored program is all-real and runs on the default
+        # backend, so it is wrapped like a real one.  Natively complex
+        # programs are not: the complex-on-TPU platform gate
+        # (utils/platform.py) executes them on the host CPU while the
+        # default backend stays TPU, and an export records ONE
+        # platform — the gated dispatch would be refused at call time.
         from ..resilience import aot
         factor_w = slu_factor
-        if not pair and dtype.kind != "c":
+        if pair or dtype.kind != "c":
             factor_w = aot.wrap_jit(
                 "phase_factor", slu_factor,
                 aot.schedule_fingerprint(
@@ -3184,18 +3201,18 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     # device's program.
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.factor_dist import (_group_operands, _regroup,
-                                        _shard_vals,
-                                        _sharded_factor_operands)
+    from ..parallel.factor_dist import (_factor_operands,
+                                        _group_operands, _regroup,
+                                        _shard_vals)
     from ..utils.compat import shard_map as _shard_map
 
+    idx_specs = (P(axis),) * (7 * len(sched.groups))
     if not _shard_vals(dtype):
         # complex: keep the round-3 replicated formulation — the
         # XLA:CPU multi-device complex lottery is acutely sensitive
         # to the assembly program's shape and the replicated variant
         # is the best-measured one (factor_dist._shard_vals note)
         idx_args = _group_operands(sched, range(7))
-        idx_specs = tuple(P(axis) for _ in idx_args)
 
         def mapped_body_c(vals, b, *idx_flat):
             b_r = b.astype(rdt)
@@ -3216,7 +3233,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
 
         jitted_c = obs.watch_jit(
             "fused_step_mesh",
-            jax.jit(lambda vals, b: mapped_c(vals, b, *idx_args)))
+            jax.jit(lambda vals, b: mapped_c(vals, b, *idx_args())))
 
         def step_c(vals, b):
             return jitted_c(vals, b)
@@ -3225,8 +3242,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
         return step_c
 
     nnz = len(plan.coo_rows)
-    sel, idx_args = _sharded_factor_operands(plan, sched, 7)
-    idx_specs = tuple(P(axis) for _ in idx_args)
+    sel, idx_args = _factor_operands(plan, sched, 7, True)
     # committed device placement: these enter the jit as ARGUMENTS
     # already sharded P(axis) — closed-over jnp arrays would be baked
     # into the lowered program as whole replicated constants, exactly
@@ -3322,7 +3338,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     jitted = obs.watch_jit(
         "fused_step_mesh",
         jax.jit(lambda vsel, ssel, vchunk, rc, cc, b: mapped(
-            vsel, ssel, vchunk, rc, cc, b, *idx_args)))
+            vsel, ssel, vchunk, rc, cc, b, *idx_args())))
 
     def step(vals, b):
         # host-side one-time redistribution per call (dReDistribute_A

@@ -811,10 +811,10 @@ def _solve_packed_fn(sched, dtype, pair: bool):
         # `trans` kwarg: a static_argnames keyword call drops jax to
         # the slow python dispatch path — measured ~ms per call
         # against this fn's ~200-operand pack pytree, real money at
-        # the nrhs=1 solve scale.  With SLU_AOT_CACHE active the jit
-        # is AOT-wrapped (resilience/aot.py): per call signature the
-        # program deserializes from the persistent export instead of
-        # re-tracing — the serve hot path's cold-boot lever — with
+        # the nrhs=1 solve scale.  Where a compile cache is kept the
+        # jit is AOT-wrapped (resilience/aot.py): per call signature
+        # the program deserializes from the persistent export instead
+        # of re-tracing — the serve hot path's cold-boot lever — with
         # the compile-watch proxy outermost as always.
         from ..resilience import aot
 
@@ -827,11 +827,11 @@ def _solve_packed_fn(sched, dtype, pair: bool):
                     return sweep(ts, packs, b, dt, trans,
                                  pair=pair)
             wrapped = slu_solve_packed
-            if not pair and dt.kind != "c":
-                # complex lanes skip AOT: the complex-on-TPU gate
-                # executes them on the host CPU under a TPU default
-                # backend, and an export records one platform (the
-                # batched._phase_fns note)
+            if pair or dt.kind != "c":
+                # natively complex lanes skip AOT: the complex-on-TPU
+                # gate executes them on the host CPU under a TPU
+                # default backend, and an export records one platform
+                # (the batched._phase_fns note)
                 wrapped = aot.wrap_jit(
                     f"solve_packed.{'T' if trans else 'N'}",
                     slu_solve_packed,

@@ -41,6 +41,7 @@ device (degenerate), an 8-device CPU mesh (tests), or a TPU pod slice
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -221,10 +222,14 @@ def _solve_loop(dsched, flats, b, dtype, per_group, axis,
 
 
 def _group_operands(dsched, fields):
-    """Flat operand tuple for the given GroupSpec.dev positions."""
-    group_idx = [g.dev(squeeze=False) for g in dsched.groups]
-    args = tuple(t[i] for t in group_idx for i in fields)
-    return args
+    """Once-only thunk of the flat operand tuple for the given
+    GroupSpec.dev positions.  The builders call it inside their
+    programs' traces: the operands are constants of the trace, and a
+    program served from the exported store (resilience/aot.py)
+    uploads none of them."""
+    return functools.cache(lambda: tuple(
+        t[i] for t in (g.dev(squeeze=False) for g in dsched.groups)
+        for i in fields))
 
 
 def _vals_partition(dsched, nnz):
@@ -265,21 +270,30 @@ def _vals_partition(dsched, nnz):
             v = a[d]
             m = v < nnz
             out[d][m] = np.searchsorted(sels[d], v[m])
-        a_src_loc.append(jnp.asarray(out))
+        a_src_loc.append(out)
     return sel, a_src_loc
 
 
-def _sharded_factor_operands(plan, dsched, per):
-    """(sel, idx_args) for a factor-group loop consuming per-device
-    value slices: group operand positions 0..per-1, with position 0
-    (a_src) replaced by its local-slice remap."""
+def _factor_operands(plan, dsched, per, sharded_in):
+    """(sel, idx_args) for a factor-group loop: group operand
+    positions 0..per-1 as a once-only thunk (`_group_operands`).
+    With per-device value slices (`sharded_in`) position 0 (a_src) is
+    replaced by its local-slice remap and `sel` is the slices' global
+    indices, which every call needs on the host; else `sel` is None."""
+    if not sharded_in:
+        return None, _group_operands(dsched, range(per))
     sel, a_src_loc = _vals_partition(dsched, len(plan.coo_rows))
-    group_idx = [g.dev(squeeze=False, with_a_src=False)
-                 for g in dsched.groups]
-    idx_args = tuple(
-        a_src_loc[gi] if i == 0 else t[i]
-        for gi, t in enumerate(group_idx) for i in range(per))
-    return sel, idx_args
+
+    def build():
+        with jax.ensure_compile_time_eval():    # called under a trace
+            a_src = [jnp.asarray(a) for a in a_src_loc]
+        group_idx = (g.dev(squeeze=False, with_a_src=False)
+                     for g in dsched.groups)
+        return tuple(
+            a_src[gi] if i == 0 else t[i]
+            for gi, t in enumerate(group_idx) for i in range(per))
+
+    return sel, functools.cache(build)
 
 
 # Complex systems keep the ROUND-3 replicated-vals program shape and
@@ -332,13 +346,9 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     thresh_np = _thresh_for(plan, dtype)
 
     sharded_in = _shard_vals(dtype)
-    if sharded_in:
-        sel, idx_args = _sharded_factor_operands(plan, dsched, 7)
-        vspec = P(axis)
-    else:
-        sel, idx_args = None, _group_operands(dsched, range(7))
-        vspec = P()
-    idx_specs = tuple(P(axis) for _ in idx_args)
+    sel, idx_args = _factor_operands(plan, dsched, 7, sharded_in)
+    vspec = P(axis) if sharded_in else P()
+    idx_specs = (P(axis),) * (7 * len(dsched.groups))
 
     def body(vals, b, *idx_flat):
         per_group = _regroup(dsched, idx_flat, 7)
@@ -355,7 +365,7 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
 
     jitted = obs.watch_jit(
         "dist_step",
-        jax.jit(lambda vsel, b: mapped(vsel, b, *idx_args)))
+        jax.jit(lambda vsel, b: mapped(vsel, b, *idx_args())))
     vshard = jax.sharding.NamedSharding(mesh, P(axis))
 
     def step(vals, b):
@@ -406,13 +416,9 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     thresh_np = _thresh_for(plan, dtype)
 
     sharded_in = _shard_vals(dtype)
-    if sharded_in:
-        sel, idx_args = _sharded_factor_operands(plan, dsched, 5)
-        vspec = P(axis)
-    else:
-        sel, idx_args = None, _group_operands(dsched, range(5))
-        vspec = P()
-    idx_specs = tuple(P(axis) for _ in idx_args)
+    sel, idx_args = _factor_operands(plan, dsched, 5, sharded_in)
+    vspec = P(axis) if sharded_in else P()
+    idx_specs = (P(axis),) * (5 * len(dsched.groups))
 
     def body(vals, *idx_flat):
         per_group = _regroup(dsched, idx_flat, 5)
@@ -439,7 +445,7 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     # batched._phase_fns note)
     @jax.jit
     def slu_dist_factor(vsel):
-        return mapped(vsel, *idx_args)
+        return mapped(vsel, *idx_args())
 
     factor_fn = slu_dist_factor
     if sharded_in:
@@ -500,9 +506,9 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
     dtype = np.dtype(dtype)
     n = dsched.n
 
-    idx_args = tuple(a for gs in ts.groups
-                     for a in gs.dev(squeeze=False))
-    idx_specs = tuple(P(axis) for _ in idx_args)
+    idx_args = functools.cache(lambda: tuple(
+        a for gs in ts.groups for a in gs.dev(squeeze=False)))
+    idx_specs = (P(axis),) * (3 * len(ts.groups))
 
     def body(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_flat):
         flats = tuple(_solve_view(f)
@@ -566,7 +572,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
 
     @jax.jit
     def slu_dist_solve_merged(L_flat, U_flat, Li_flat, Ui_flat, b):
-        return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args)
+        return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args())
 
     solve = _aot_wrap_dist("dist_solve_merged", slu_dist_solve_merged,
                            dsched, mesh, axis, dtype, trans)
@@ -650,7 +656,7 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     dtype = np.dtype(dtype)
 
     idx_args = _group_operands(dsched, (5, 6))
-    idx_specs = tuple(P(axis) for _ in idx_args)
+    idx_specs = (P(axis),) * (2 * len(dsched.groups))
 
     def body(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_flat):
         per_group = _regroup(dsched, idx_flat, 2)
@@ -664,7 +670,7 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
 
     @jax.jit
     def slu_dist_solve(L_flat, U_flat, Li_flat, Ui_flat, b):
-        return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args)
+        return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args())
 
     solve = _aot_wrap_dist("dist_solve", slu_dist_solve, dsched, mesh,
                            axis, dtype, trans)
@@ -695,11 +701,15 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
 
     # per-group index tensors over ALL devices' fronts, device-major —
     # matching the row order of the gathered slabs
-    g_idx = [(jnp.asarray(np.asarray(g.col_idx).reshape(
-                  ndev * g.n_loc, g.col_idx.shape[-1]), jnp.int32),
-              jnp.asarray(np.asarray(g.struct_idx).reshape(
-                  ndev * g.n_loc, g.struct_idx.shape[-1]), jnp.int32))
-             for g in dsched.groups]
+    # (constants of the trace, made inside it: `_group_operands`)
+    @functools.cache
+    def g_idx():
+        def rows(a):
+            a = np.asarray(a, dtype=np.int32)
+            return jnp.asarray(a.reshape(ndev * a.shape[1], a.shape[-1]))
+        with jax.ensure_compile_time_eval():
+            return [(rows(g.col_idx), rows(g.struct_idx))
+                    for g in dsched.groups]
 
     def body(L_flat, U_flat, Li_flat, Ui_flat, b):
         flats = [_solve_view(jax.lax.all_gather(f, axis, tiled=True))
@@ -737,14 +747,15 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
             bwd_off = lambda g: ((g.L_off, g.mb * g.wb),
                                  (g.Li_off, g.wb * g.wb))
 
-        for g, (ci, si) in zip(dsched.groups, g_idx):
+        idx = g_idx()
+        for g, (ci, si) in zip(dsched.groups, idx):
             (o1, s1), (o2, s2) = fwd_off(g)
             X = fwd_fn(X, gsl(fwd_src[0], o1, g.n_loc * s1),
                        gsl(fwd_src[1], o2, g.n_loc * s2), ci, si,
                        z, z, mb=g.mb, wb=g.wb,
                        n_pad=ndev * g.n_loc, cplx=cplx)
         for g, (ci, si) in zip(reversed(dsched.groups),
-                               reversed(g_idx)):
+                               reversed(idx)):
             (o1, s1), (o2, s2) = bwd_off(g)
             X = bwd_fn(X, gsl(bwd_src[0], o1, g.n_loc * s1),
                        gsl(bwd_src[1], o2, g.n_loc * s2), ci, si,

@@ -23,14 +23,31 @@ merge flags) — and this module persists them on two legs:
     exported module (`jax.jit(exported.call)`), so the two can never
     execute divergent programs.
   * **compilation-cache leg**: the deserialized module still needs a
-    backend compile — `ensure_xla_cache` points jax's persistent
-    compilation cache at `<dir>/xla` when none is configured, so that
-    compile is a disk hit across processes.  The staged per-segment
-    programs (factor segments + trisolve segments) ride this leg
-    alone: they are bounded per-segment compiles with donated
-    operands, already warmed/persisted by `utils/warmup.py` — the
-    "pinned reliance on the compilation cache" fallback the flags
-    table documents.
+    backend compile, which jax's persistent compilation cache makes a
+    disk hit across processes.  The staged per-segment programs
+    (factor segments + trisolve segments) ride this leg alone: they
+    are bounded per-segment compiles with donated operands, already
+    warmed/persisted by `utils/warmup.py`.
+
+**The rule** (ISSUE 39; no variable, no option): the store is on
+exactly when jax's persistent compilation cache is in force —
+`jax.config.jax_compilation_cache_dir` non-empty (jax reads
+`JAX_COMPILATION_CACHE_DIR` into it) and the cache enabled — and lives
+in the sub-directory `slu_aot/` of that directory, which jax's own
+eviction (`JAX_COMPILATION_CACHE_MAX_SIZE`; it globs `*-cache` in the
+directory itself) neither counts nor clears.  A process that keeps
+compiled programs on disk keeps exported ones beside them; one that
+keeps none pays one string check a program build.  To clear the
+store, remove `<cache dir>/slu_aot`.
+
+**The key sees the code**: the fingerprint leads with a sha256 over
+every `.py` file of this package (`source_fingerprint`), so any edit
+re-keys every entry — a parent and a change measured against one kept
+cache directory each run their own program.  `save` keeps, beside the
+entry it writes, the most recently used entry of the same program and
+signature under another fingerprint and removes the rest: a kept
+directory holds two generations (a parent and a change alternate
+without evicting each other), not one a PR.
 
 Storage discipline follows the factor store: atomic-rename writes
 (`utils/io.atomic_write_bytes`), a sha256 frame over the payload, and
@@ -39,12 +56,13 @@ frame, fingerprint, jax version, undeserializable payload — with the
 typed `AotMismatch` and quarantines the entry (*.quarantined, the
 store convention): a stale or corrupt executable is never dispatched.
 
-Off (`SLU_AOT_CACHE` unset/0) this module costs one string check per
+Off (no cache directory) this module costs one string check per
 program build — nothing on the dispatch path.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -57,6 +75,7 @@ from ..utils.io import atomic_write_bytes
 
 _MAGIC = b"SLUAOT1\n"
 SUFFIX = ".aot"
+SUBDIR = "slu_aot"
 
 
 class AotMismatch(RuntimeError):
@@ -72,38 +91,17 @@ class AotMismatch(RuntimeError):
 # --------------------------------------------------------------------
 
 def aot_dir() -> str | None:
-    """The AOT cache directory (SLU_AOT_CACHE), or None when the
-    feature is off (unset / '0' / 'off')."""
-    v = flags.env_str("SLU_AOT_CACHE", "").strip()
-    if not v or v.lower() in ("0", "off", "false"):
+    """`<jax's persistent compilation cache directory>/slu_aot`, or
+    None when no such cache is in force (the rule, module docstring)."""
+    import jax
+    d = jax.config.jax_compilation_cache_dir
+    if not d or not jax.config.jax_enable_compilation_cache:
         return None
-    return v
+    return os.path.join(d, SUBDIR)
 
 
 def enabled() -> bool:
     return aot_dir() is not None
-
-
-_xla_wired = False
-
-
-def ensure_xla_cache() -> None:
-    """The compilation-cache leg: when the AOT dir is active and no
-    persistent compile cache is configured (jax config or
-    JAX_COMPILATION_CACHE_DIR), point jax at `<dir>/xla` so the
-    deserialized programs' backend compiles — and the staged
-    per-segment programs, which ride this leg alone — hit disk
-    across processes."""
-    global _xla_wired
-    d = aot_dir()
-    if d is None or _xla_wired:
-        return
-    _xla_wired = True
-    import jax
-    if jax.config.jax_compilation_cache_dir:
-        return                      # an explicit cache wins
-    from ..utils.cache import place_compile_cache
-    place_compile_cache(os.path.join(d, "xla"))
 
 
 # --------------------------------------------------------------------
@@ -111,7 +109,8 @@ def ensure_xla_cache() -> None:
 # --------------------------------------------------------------------
 
 _stats_lock = threading.Lock()
-_STATS = {"hits": 0, "misses": 0, "saves": 0, "rejected": 0}
+_STATS = {"hits": 0, "misses": 0, "saves": 0, "rejected": 0,
+          "unexportable": 0}
 
 
 def _inc(k: str) -> None:
@@ -120,10 +119,12 @@ def _inc(k: str) -> None:
 
 
 def stats() -> dict:
-    """{'hits', 'misses', 'saves', 'rejected'} — hits = programs
-    served from a deserialized export, misses = absent entries
-    (trace+export paid), rejected = entries refused by verification
-    (quarantined, then re-exported)."""
+    """{'hits', 'misses', 'saves', 'rejected', 'unexportable'} — hits
+    = programs served from a deserialized export, misses = absent
+    entries (trace+export paid), rejected = entries refused by
+    verification (quarantined, then re-exported), unexportable =
+    signatures that fell back to the plain jit.  The start-up ledger
+    (obs/compile_watch.py) carries them in `snapshot()["startup"]`."""
     with _stats_lock:
         return dict(_STATS)
 
@@ -167,6 +168,31 @@ def _pattern_sig(sched) -> str:
     return sig
 
 
+def tree_fingerprint(root: str) -> str:
+    """sha256 over the relative path and the bytes of every `.py`
+    file under `root`, sorted by path."""
+    h = hashlib.sha256()
+    paths = []
+    for d, _dirs, files in os.walk(root):
+        paths.extend(os.path.join(d, f) for f in files
+                     if f.endswith(".py"))
+    for path in sorted(paths):
+        h.update(os.path.relpath(path, root).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read() + b"\0")
+    return h.hexdigest()
+
+
+@functools.cache
+def source_fingerprint() -> str:
+    """The package's own code as a key leg, computed once a process
+    (some 27,000 lines, milliseconds): nothing else of a fingerprint
+    sees what the kernels compute, and a kept store must not serve a
+    program traced from other sources."""
+    return tree_fingerprint(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+
 def mesh_fingerprint_legs(mesh, axis=None) -> tuple:
     """Fingerprint legs for a shard_map'd whole-phase program over a
     device mesh (ISSUE 17): mesh shape as (axis-name, extent) pairs in
@@ -204,7 +230,7 @@ def schedule_fingerprint(sched, dtype, extra=()) -> str:
     from ..ops import batched as B
     from ..ops import trisolve as T
     parts = (
-        "v3", jax.__version__, jax.default_backend(),
+        source_fingerprint(), jax.__version__, jax.default_backend(),
         _pattern_sig(sched),
         np.dtype(dtype).str,
         int(sched.n), int(sched.ndev), int(sched.upd_total),
@@ -236,6 +262,26 @@ def _entry_path(name: str, fp: str) -> str | None:
     return os.path.join(d, f"{safe}.{fp[:16]}{SUFFIX}")
 
 
+def _drop_superseded(path: str) -> None:
+    """Of the entries of `path`'s program and signature under other
+    fingerprints keep the most recently used one (a hit touches its
+    entry) and remove the rest."""
+    d, keep = os.path.split(path)
+    stem = keep[:-len(SUFFIX) - 16]     # `<program>.sig<arghash>.`
+    old = []
+    for f in os.listdir(d):
+        if f.startswith(stem) and len(f) == len(keep) and f != keep:
+            try:
+                old.append((os.path.getmtime(os.path.join(d, f)), f))
+            except OSError:
+                pass                # a racer removed it
+    for _mtime, f in sorted(old)[:-1]:
+        try:
+            os.remove(os.path.join(d, f))
+        except OSError:
+            pass
+
+
 def quarantine(path: str, reason: str = "") -> None:
     """Move a refused entry aside (the store convention): it is never
     dispatched again, and the evidence survives for inspection."""
@@ -246,8 +292,9 @@ def quarantine(path: str, reason: str = "") -> None:
 
 
 def save(name: str, fp: str, exported) -> str | None:
-    """Write-through one serialized export atomically; returns the
-    path, or None when the feature is off."""
+    """Write-through one serialized export atomically and drop what it
+    supersedes (`_drop_superseded`); returns the path, or None when
+    the feature is off."""
     path = _entry_path(name, fp)
     if path is None:
         return None
@@ -261,6 +308,7 @@ def save(name: str, fp: str, exported) -> str | None:
     blob = header + b"\n" + payload
     atomic_write_bytes(path, _MAGIC + hashlib.sha256(blob).digest()
                        + blob)
+    _drop_superseded(path)
     _inc("saves")
     return path
 
@@ -314,6 +362,10 @@ def load(name: str, fp: str):
         _inc("rejected")
         quarantine(path)
         raise
+    try:
+        os.utime(path)              # most recently used: save() keeps it
+    except OSError:
+        pass
     _inc("hits")
     return exported
 
@@ -322,13 +374,30 @@ def load(name: str, fp: str):
 # the per-signature jit proxy
 # --------------------------------------------------------------------
 
+def _named_jit(name: str, call):
+    """`jax.jit` of `call` under `name`: a served export is the same
+    program to every reader — the profiler's `jit_<name>`, the
+    start-up ledger's `name`, the persistent-cache entry — as the jit
+    it was exported from, not `jit_call`."""
+    import jax
+
+    def fn(*args):
+        return call(*args)
+
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 class AotJit:
     """Per-signature AOT-backed dispatch proxy over a jit: on each
     NEW call signature it read-throughs the cache (deserialized
-    export → `jax.jit(exported.call)`) and on a miss exports the
-    underlying jit ONCE at those avals, write-throughs, and
-    dispatches through the same exported module — producer and
-    consumer execute identical programs by construction.  `lower` and
+    export → a jit of `exported.call` under the wrapped jit's name)
+    and on a miss exports the underlying jit ONCE at those avals,
+    write-throughs, and dispatches through the same exported module —
+    producer and consumer execute identical programs by construction.
+    What each signature came to (`hit`, `miss`, `refused`,
+    `unexportable`) is stamped on the start-up ledger's open row
+    (obs/compile_watch.py).  `lower` and
     other attributes delegate to the wrapped jit (the compile-watch
     and HLO-pin contract); `_cache_size` sums the per-signature jits
     so the serve zero-recompile probes keep working."""
@@ -373,6 +442,7 @@ class AotJit:
             raise
 
     def _resolve(self, key, args):
+        from ..obs.compile_watch import COMPILE_WATCH
         with self._tlock:
             fn = self._table.get(key)
             if fn is not None:
@@ -384,8 +454,9 @@ class AotJit:
                      .hexdigest()[:12])
             try:
                 exp = load(ename, self._fp)
-            except AotMismatch:
-                exp = None          # refused + quarantined; re-export
+                status = "miss" if exp is None else "hit"
+            except AotMismatch:     # refused + quarantined; re-export
+                exp, status = None, "refused"
             if exp is None:
                 avals = jax.tree_util.tree_map(
                     lambda x: jax.ShapeDtypeStruct(tuple(x.shape),
@@ -399,9 +470,13 @@ class AotJit:
                     # program (exotic pytree/op) must never break the
                     # dispatch: fall back to the plain jit for this
                     # signature; the entry simply never persists
+                    _inc("unexportable")
+                    COMPILE_WATCH.stamp_aot("unexportable")
                     self._table[key] = self._fn
                     return self._fn
-            fn = jax.jit(exp.call)
+            COMPILE_WATCH.stamp_aot(status)
+            fn = _named_jit(getattr(self._fn, "__name__", self._name),
+                            exp.call)
             self._table[key] = fn
             return fn
 
@@ -420,10 +495,10 @@ class AotJit:
 
 
 def wrap_jit(name: str, fn, fingerprint: str):
-    """AOT-wrap `fn` when the cache is enabled (also wiring the
-    compilation-cache leg), else return it unchanged — the one-line
-    integration hook `_phase_fns` / `_solve_packed_fn` call."""
+    """AOT-wrap `fn` when the store is on (the rule, module
+    docstring), else return it unchanged — the one-line integration
+    hook `_phase_fns` / `_solve_packed_fn` and the mesh builders
+    call."""
     if not enabled():
         return fn
-    ensure_xla_cache()
     return AotJit(name, fn, fingerprint)

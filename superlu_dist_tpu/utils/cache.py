@@ -62,7 +62,7 @@ def place_compile_cache(path: str | None = None) -> str:
     """Place jax's persistent compilation cache (module docstring)
     and return the directory in force.  `path` overrides the
     in-checkout default for callers that own a directory (the test
-    suite's CPU cache, the AOT store's `<dir>/xla` leg); without it
+    suite's CPU cache); without it
     the default is resolved from the backend jax actually chose, so
     this call initializes the backend."""
     env = flags.env_opt("JAX_COMPILATION_CACHE_DIR")

@@ -285,7 +285,10 @@ class Stats:
             f"trace {su['trace_s']:.2f} s, lower {su['lower_s']:.2f} s, "
             f"compile {su['compile_s']:.2f} s, cache load "
             f"{su['load_s']:.2f} s ({su['cache_hits']} hit, "
-            f"{su['cache_misses']} miss, {su['cache_off']} off)")
+            f"{su['cache_misses']} miss, {su['cache_off']} off); "
+            f"exported store {su['aot']['hits']} hit, "
+            f"{su['aot']['misses']} miss, {su['aot']['rejected']} "
+            f"refused, {su['aot']['unexportable']} unexportable")
         lines.append(f"  health: {obs.HEALTH.summary()}")
         if self.escalations:
             lines.append(

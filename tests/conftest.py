@@ -38,17 +38,11 @@ jax.config.update("jax_platforms", "cpu")
 place_compile_cache(host_cache_dir(os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_cache")))
-# AOT executable persistence (resilience/aot.py, ISSUE 12), the
-# trace-side twin of the compile cache above: whole-phase factor /
-# packed-solve builds DESERIALIZE their exported programs instead of
-# re-tracing — the suite builds hundreds of them.  Exports are
-# StableHLO, ISA-independent (the ISA-sensitive executables live in
-# the fingerprinted compile cache), so one shared dir is safe; stale
-# entries are refused by fingerprint, never served.  setdefault so a
-# test (or operator) env override wins.
-os.environ.setdefault("SLU_AOT_CACHE", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    ".jax_cache", "aot"))
+# With a compile cache placed, the exported-program store
+# (resilience/aot.py) is on by its own rule, in `<that cache>/slu_aot`:
+# whole-phase factor / packed-solve builds DESERIALIZE their exported
+# programs instead of re-tracing — the suite builds hundreds of them.
+# Entries are keyed by the package's sources, so an edit re-keys them.
 
 
 # --- hang containment -----------------------------------------------
@@ -136,3 +130,17 @@ def pytest_collection_modifyitems(config, items):
         for item in items:
             if name in item.keywords:
                 item.add_marker(skip)
+
+
+@pytest.fixture
+def aot_store(tmp_path, monkeypatch):
+    """A compile cache kept at tmp_path (through the one helper that
+    places it): the exported-program store (resilience/aot.py) is then
+    its `slu_aot` sub-directory, by its rule alone.  Yields that
+    directory."""
+    from superlu_dist_tpu.resilience import aot
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
+    place_compile_cache(str(tmp_path))
+    yield aot.aot_dir()
+    place_compile_cache(old)

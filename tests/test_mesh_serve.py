@@ -313,7 +313,7 @@ def test_replica_pool_derives_mesh_capacity():
 # mesh AOT warm boot (in-process drill)
 # --------------------------------------------------------------------
 
-def test_mesh_aot_warm_boot_serves_from_exports(tmp_path, monkeypatch):
+def test_mesh_aot_warm_boot_serves_from_exports(aot_store):
     """The in-process cold→warm drill for the shard_map'd programs: a
     rebuilt world (fresh plan objects — the fresh-process stand-in)
     deserializes the mesh factor + merged solve exports (hits >= 2,
@@ -330,7 +330,6 @@ def test_mesh_aot_warm_boot_serves_from_exports(tmp_path, monkeypatch):
         return np.asarray(solve(dlu.L_flat, dlu.U_flat, dlu.Li_flat,
                                 dlu.Ui_flat, b))
 
-    monkeypatch.setenv("SLU_AOT_CACHE", str(tmp_path))
     aot.reset_stats()
     x_cold = run()                       # export write-through
     cold = aot.stats()
@@ -339,6 +338,7 @@ def test_mesh_aot_warm_boot_serves_from_exports(tmp_path, monkeypatch):
     x_warm = run()                       # rebuilt world: read-through
     warm = aot.stats()
     assert warm["hits"] >= 2, warm
-    assert warm["misses"] == 0 and warm["rejected"] == 0, warm
+    assert warm["misses"] == 0 and warm["rejected"] == 0 \
+        and warm["unexportable"] == 0, warm
     assert np.array_equal(x_cold, x_warm)
-    assert any(p.endswith(aot.SUFFIX) for p in os.listdir(tmp_path))
+    assert any(p.endswith(aot.SUFFIX) for p in os.listdir(aot_store))
