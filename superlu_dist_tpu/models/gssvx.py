@@ -186,6 +186,10 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                                    else plan.factor_flops)
     stats.ea_elements = sched.ea_elements if sched else {}
     stats.gesp = dict(getattr(plan, "gesp", None) or {})
+    # which route it took and what it dispatched (the one-device jax
+    # backend's handles say: ops/batched._route)
+    route = getattr(lu.device_lu, "route", None)
+    stats.dispatch.update(route or {})
     # where this factorization's solve mirror was dispatched: by
     # `factorize_device` under the merged sweep ("at_factor"), else
     # not yet ("none": a later solve that packs corrects the ring's
@@ -226,7 +230,7 @@ def factorize(a: CSRMatrix, options: Options | None = None,
                "executed": stats.factor_flops_executed},
         extend_add=stats.ea_elements,
         complex_lowering=stats.complex_lowering.get(_phase),
-        gesp=stats.gesp, pack=pack)
+        gesp=stats.gesp, pack=pack, route=route)
     stats.note_factor_event(tiny_pivots=int(getattr(src, "tiny_pivots",
                                                     0)),
                             dtype=options.factor_dtype,
@@ -345,9 +349,13 @@ def solve(lu: LUFactorization, b: np.ndarray,
     # sweep on the default backend (all-real programs), natively
     # stored ones cannot take the pair lowering and are gated on a TPU
     stored = "native"
+    sweep_segments = None   # programs a sweep dispatches
     if lu.backend == "jax":
-        from ..ops.batched import _lu_is_pair
+        from ..ops.batched import _lu_is_pair, sweep_programs
         stored = "pair" if _lu_is_pair(lu.device_lu) else "native"
+        sweep_segments = sweep_programs(lu.device_lu)
+        stats.dispatch.update(getattr(lu.device_lu, "route", None) or {},
+                              sweep_segments=sweep_segments)
 
     def sweep(lu_, v):
         # every triangular sweep — x0's and each refinement
@@ -383,7 +391,8 @@ def solve(lu: LUFactorization, b: np.ndarray,
                     lu, bb, x, sweep, to_factor_rhs, from_factor_sol,
                     trans=(options.trans == Trans.TRANS),
                     sweeps=sweeps,
-                    lowering=stats.complex_lowering.get("SOLVE"))
+                    lowering=stats.complex_lowering.get("SOLVE"),
+                    sweep_segments=sweep_segments)
             stats.berr = berr
             stats.refine_steps += steps
             stats.refine_stalled = stalled

@@ -88,12 +88,14 @@ def _operands(lu, sys_dtype):
 def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                      from_factor_sol, trans: bool = False,
                      sweeps: dict | None = None,
-                     lowering: str | None = None):
+                     lowering: str | None = None,
+                     sweep_segments: int | None = None):
     """`sweeps` is the caller's live count of this solve's sweeps by
     operand dtype (x0's included; `solve_factored` adds to it): it
-    rides the health ring's record next to `steps`, and so does
+    rides the health ring's record next to `steps`, and so do
     `lowering`, the complex lowering those sweeps ran under
-    (Stats.complex_lowering; None for a real system)."""
+    (Stats.complex_lowering; None for a real system), and
+    `sweep_segments`, the programs each of them dispatched."""
     opts = lu.effective_options
     # the system's realness is set by matrix AND rhs: a real matrix
     # with a complex b still needs a complex accumulator
@@ -162,7 +164,8 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                              ferr_trajectory=ferr_traj,
                              converged=converged,
                              stalled=stalled, sweeps=sweeps,
-                             complex_lowering=lowering)
+                             complex_lowering=lowering,
+                             sweep_segments=sweep_segments)
     # `stalled` rides back to the driver: the escalation ladder
     # (gssvx) labels its health event with the signal that fired
     # (precision/policy.classify_trigger), and "the loop quit because

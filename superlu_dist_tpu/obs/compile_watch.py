@@ -568,24 +568,27 @@ COMPILE_WATCH = CompileWatch()
 COMPILE_WATCH.listen()
 
 
-# thread-local hand-off between a backend call site and the Stats
-# consumer (models/gssvx.py) of the same driver call: where
-# `ops/trisolve.get_packs` took its miss.  The stamp must NOT ride the
-# shared LU handle: two threads solving through one cached
-# factorization (the serve layer's whole design) would read each
-# other's.  The stamp and the read happen on the same thread within
-# one driver call, so a thread-local slot is exact.
+# thread-local hand-off between a backend call site and the consumer
+# of the same driver call: where `ops/trisolve.get_packs` took its
+# miss, for the Stats of `models/gssvx.py`; what
+# `ops/batched._staged_factor_run` dispatched, for `factorize_device`.
+# A stamp must NOT ride the shared LU handle: two threads solving
+# through one cached factorization (the serve layer's whole design)
+# would read each other's.  The stamp and the read happen on the same
+# thread within one driver call, so a thread-local slot is exact.
 _TLS = threading.local()
 
 
-def stamp_cost(kind: str, cost: str | None) -> None:
-    """Record, for this thread's in-flight driver call, where the miss
-    path of `ops/trisolve.get_packs` was taken (kind "pack":
-    "at_factor" / "at_solve")."""
+def stamp_cost(kind: str, cost) -> None:
+    """Record for this thread's in-flight driver call: kind "pack",
+    where the miss path of `ops/trisolve.get_packs` was taken
+    ("at_factor" / "at_solve"); kind "dispatch", the staged run's
+    (programs dispatched, shapes of the members on the Pallas panel
+    LU)."""
     setattr(_TLS, kind, cost)
 
 
-def take_cost(kind: str) -> str | None:
+def take_cost(kind: str):
     """Pop this thread's stamp.  Popping (not peeking) means a
     backend path that stamps nothing — host, staged, dist solve —
     reads None instead of a stale earlier call's."""

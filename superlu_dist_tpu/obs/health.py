@@ -68,7 +68,8 @@ class HealthMonitor:
                       extend_add: dict | None = None,
                       complex_lowering: str | None = None,
                       gesp: dict | None = None,
-                      pack: str = "none") -> dict:
+                      pack: str = "none",
+                      route: dict | None = None) -> dict:
         """One factorization's numerical outcome.  `perturbation` is
         the tiny-pivot ledger dict (numerics/ledger.to_dict()) when
         GESP replaced any pivots; it rides the per-factorization ring
@@ -83,8 +84,12 @@ class HealthMonitor:
         Stats.complex_lowering; None for a real one), `gesp` the
         plan's static-pivoting facts (plan/plan.gesp_facts;
         Stats.gesp), `pack` where its solve mirror was dispatched
-        ("at_factor", or "none" so far: Stats.packs).  Returns the
-        ring's record, for `record_pack`."""
+        ("at_factor", or "none" so far: Stats.packs), `route` which
+        route it took and what it dispatched (`dispatch` "staged" or
+        "program", `segments`, `groups`, `pallas_buckets`,
+        `pallas_shapes`: ops/batched._route; its keys lie flat in
+        the record, absent where the backend has no such route).
+        Returns the ring's record, for `record_pack`."""
         with self._lock:
             self.factorizations += 1
             self.tiny_pivots_total += int(tiny_pivots)
@@ -106,6 +111,7 @@ class HealthMonitor:
                 "complex_lowering": complex_lowering,
                 "gesp": dict(gesp) if gesp else None,
                 "pack": pack,
+                **(route or {}),
             }
             self._factor_recent.append(rec)
         if tiny_pivots:
@@ -150,13 +156,16 @@ class HealthMonitor:
                       converged: bool = True,
                       stalled: bool = False,
                       sweeps: dict | None = None,
-                      complex_lowering: str | None = None) -> None:
+                      complex_lowering: str | None = None,
+                      sweep_segments: int | None = None) -> None:
         """One refinement loop's outcome.  `ferr_trajectory` is the
         per-step forward-error estimate ‖δ‖/‖x‖ (the correction-norm
         proxy for pdgsrfs' FERR output).  `sweeps` counts the solve's
         triangular sweeps (x0's and the corrections') by operand
         dtype, `complex_lowering` says how they were lowered and
-        where (as record_factor's).  `stalled` means the loop
+        where (as record_factor's), `sweep_segments` how many
+        programs each of them dispatched (ops/batched.sweep_programs;
+        None off the one-device jax backend).  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""
@@ -174,6 +183,7 @@ class HealthMonitor:
                 "stalled": bool(stalled),
                 "sweeps": dict(sweeps or {}),
                 "complex_lowering": complex_lowering,
+                "sweep_segments": sweep_segments,
             })
         if stalled:
             _tracer.instant("health.refine_stalled", cat="health",

@@ -2139,6 +2139,20 @@ def _vals_ext_pair(v, dtype_str: str):
                            axis=1)
 
 
+def _pallas_shapes(sched, dtype, picked) -> list:
+    """[n_loc, mb, wb] of the groups of one factorization whose panel
+    LU runs as the Pallas kernel: those whose segment meta says so
+    (`picked`, the last leg of `factor_seg_metas` in group order), or,
+    where SLU_TPU_PALLAS=1 routes every call (`dense_lu._use_pallas`
+    with no override; never a complex or pair-stored front), every
+    usable bucket."""
+    from . import pallas_lu
+    if pallas_lu.enabled(dtype):
+        picked = (pallas_lu.usable(g.mb, dtype) for g in sched.groups)
+    return [[g.n_loc, g.mb, g.wb]
+            for g, p in zip(sched.groups, picked) if p]
+
+
 def _staged_factor_run(sched, vals, thresh_np, dtype,
                        pair: bool = False):
     """Python-dispatched group loop: returns (panels, tiny, nzero)
@@ -2146,7 +2160,16 @@ def _staged_factor_run(sched, vals, thresh_np, dtype,
     the counters are device scalars (no per-group host sync — the
     dispatch loop must stay ahead of device execution).  In pair mode
     `vals` arrives host-encoded as (2, nnz) real planes and every
-    buffer carries the leading plane axis."""
+    buffer carries the leading plane axis.
+
+    Two host spans name the run's halves: `slu.fact.dispatch` around
+    the loop of donated-buffer dispatches, `slu.fact.wait` around the
+    one blocking read of the counters (the chip is still working
+    through the queued segments then; what is left of the device's
+    idle time inside a factorization lies under the first).  What was
+    dispatched (programs, and the shapes of the members that took the
+    Pallas panel LU) is stamped for this thread's `factorize_device`
+    (`obs.stamp_cost("dispatch", ...)`)."""
     dtype = np.dtype(dtype)
     rdt = _real_dtype(dtype)
     if pair:
@@ -2171,34 +2194,46 @@ def _staged_factor_run(sched, vals, thresh_np, dtype,
         # lanes keep the proven per-group dispatch and the bitwise
         # contract stays exact where it is pinned (real fp64, the PR 7
         # bar).  Whether the merged arm would hold for pair planes on
-        # a TPU has not been tried: no cell runs the staged path (M2)
-        for seg in get_factor_segments(sched):
-            ops = [sched.groups[i].dev(squeeze=True)[:4]
-                   for i in seg]
-            (upd_buf, pseg, t, z) = _staged_factor_segment(
-                upd_buf, vals_ext, thresh,
-                tuple(o[0] for o in ops), tuple(o[1] for o in ops),
-                tuple(o[2] for o in ops), tuple(o[3] for o in ops),
-                tuple(jnp.asarray(sched.groups[i].upd_off_global,
-                                  jnp.int64) for i in seg),
-                metas=factor_seg_metas(sched, seg, dtype), pair=pair)
-            panels.extend(pseg)
+        # a TPU has not been tried: the one cell on the staged path,
+        # `lap3d_k48.step`, is real (PERF.md section 4)
+        with obs.span("fact.dispatch", cat="fact"):
+            for seg in get_factor_segments(sched):
+                ops = [sched.groups[i].dev(squeeze=True)[:4]
+                       for i in seg]
+                (upd_buf, pseg, t, z) = _staged_factor_segment(
+                    upd_buf, vals_ext, thresh,
+                    tuple(o[0] for o in ops), tuple(o[1] for o in ops),
+                    tuple(o[2] for o in ops), tuple(o[3] for o in ops),
+                    tuple(jnp.asarray(sched.groups[i].upd_off_global,
+                                      jnp.int64) for i in seg),
+                    metas=factor_seg_metas(sched, seg, dtype),
+                    pair=pair)
+                panels.extend(pseg)
+                tiny = tiny + t
+                nzero = nzero + z
+        del upd_buf
+        segs = get_factor_segments(sched)
+        obs.stamp_cost("dispatch", (len(segs), _pallas_shapes(
+            sched, dtype, (m[-1] for seg in segs for m in
+                           factor_seg_metas(sched, seg, dtype)))))
+        with obs.span("fact.wait", cat="fact"):
+            return panels, int(tiny), int(nzero)
+    with obs.span("fact.dispatch", cat="fact"):
+        for g in sched.groups:
+            a_src, a_dst, one_dst, ea_blocks = g.dev(squeeze=True)[:4]
+            (upd_buf, L, U, Li, Ui, t, z) = _staged_factor_group(
+                upd_buf, vals_ext, thresh, a_src, a_dst, one_dst,
+                ea_blocks, jnp.asarray(g.upd_off_global, jnp.int64),
+                mb=g.mb, wb=g.wb, n_pad=g.n_loc, ea_meta=g.ea_meta,
+                eb_meta=g.eb_meta, pair=pair)
+            panels.append((L, U, Li, Ui))
             tiny = tiny + t
             nzero = nzero + z
-        del upd_buf
-        return panels, int(tiny), int(nzero)
-    for g in sched.groups:
-        a_src, a_dst, one_dst, ea_blocks = g.dev(squeeze=True)[:4]
-        (upd_buf, L, U, Li, Ui, t, z) = _staged_factor_group(
-            upd_buf, vals_ext, thresh, a_src, a_dst, one_dst,
-            ea_blocks, jnp.asarray(g.upd_off_global, jnp.int64),
-            mb=g.mb, wb=g.wb, n_pad=g.n_loc, ea_meta=g.ea_meta,
-            eb_meta=g.eb_meta, pair=pair)
-        panels.append((L, U, Li, Ui))
-        tiny = tiny + t
-        nzero = nzero + z
     del upd_buf
-    return panels, int(tiny), int(nzero)
+    obs.stamp_cost("dispatch", (len(sched.groups),
+                                _pallas_shapes(sched, dtype, ())))
+    with obs.span("fact.wait", cat="fact"):
+        return panels, int(tiny), int(nzero)
 
 
 def _staged_sweeps(sched, panels, bf, dtype, trans: bool,
@@ -2279,6 +2314,8 @@ class DeviceLU:
     Li_flat: jnp.ndarray
     Ui_flat: jnp.ndarray
     tiny_pivots: int
+    # what `factorize_device` dispatched for this handle (`_route`)
+    route: dict | None = None
 
 
 @dataclasses.dataclass
@@ -2292,6 +2329,9 @@ class StagedLU:
     dtype: np.dtype
     panels: list               # per group (L, U, Li, Ui) local flats
     tiny_pivots: int
+    # what `factorize_device` dispatched for this handle (`_route`);
+    # None for a handle built elsewhere (the batch engine, a restore)
+    route: dict | None = None
 
     def held_bytes(self) -> int:
         # pair-stored panels are real arrays of 2× the element count;
@@ -2412,6 +2452,32 @@ def _phase_fns(sched, dtype, thresh_np, pair=None):
         return cache[key]
 
 
+def _route(sched, dispatch: str, segments: int, pallas: list) -> dict:
+    """Which route one factorization took and what it dispatched, as
+    the health ring's `last_factor` and `Stats.dispatch` carry it:
+    `dispatch` "staged" (a program a segment, `staged_enabled`) or
+    "program" (the one `jit_slu_factor`), `segments` the factor
+    programs dispatched, `groups` the schedule's, `pallas_buckets` the
+    groups whose panel LU ran as the Pallas kernel and
+    `pallas_shapes` their [n_loc, mb, wb] (`_pallas_shapes`)."""
+    return {"dispatch": dispatch, "segments": int(segments),
+            "groups": len(sched.groups), "pallas_buckets": len(pallas),
+            "pallas_shapes": pallas}
+
+
+def sweep_programs(lu) -> int:
+    """Programs one triangular solve on this handle dispatches: the
+    forward and backward segment programs of `trisolve.staged_sweeps`
+    (a program a group each way under the legacy sweep) for a
+    `StagedLU`, else the one solve program."""
+    if not isinstance(lu, StagedLU):
+        return 1
+    from . import trisolve
+    if trisolve.sweeps_packed():
+        return 2 * len(trisolve.get_trisolve(lu.schedule).segments)
+    return 2 * len(lu.schedule.groups)
+
+
 def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
                      dtype=np.float64):
     from . import trisolve
@@ -2419,13 +2485,18 @@ def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
     dtype = np.dtype(dtype)
     pair = _pair_mode(dtype)
     if staged_enabled(sched):
-        vin = (_pair_encode_vals(scaled_vals, dtype) if pair
-               else np.asarray(scaled_vals))
+        # the staged run casts on the device (`_vals_ext`): what is
+        # left of the one-program branch's span is the pair encode
+        with obs.span("fact.scale", cat="fact"):
+            vin = (_pair_encode_vals(scaled_vals, dtype) if pair
+                   else np.asarray(scaled_vals))
         panels, tiny, nzero = _staged_factor_run(
             sched, jnp.asarray(vin),
             _thresh_for(plan, dtype), dtype, pair=pair)
         lu = StagedLU(plan=plan, schedule=sched, dtype=dtype,
-                      panels=panels, tiny_pivots=tiny)
+                      panels=panels, tiny_pivots=tiny,
+                      route=_route(sched, "staged",
+                                   *obs.take_cost("dispatch")))
     else:
         factor_fn, _ = _phase_fns(sched, dtype,
                                   _thresh_for(plan, dtype), pair=pair)
@@ -2439,12 +2510,16 @@ def factorize_device(plan: FactorPlan, scaled_vals: np.ndarray,
         lu = DeviceLU(plan=plan, schedule=sched, dtype=dtype,
                       L_flat=L_flat, U_flat=U_flat,
                       Li_flat=Li_flat, Ui_flat=Ui_flat,
-                      tiny_pivots=tiny)
+                      tiny_pivots=tiny,
+                      route=_route(sched, "program", 1,
+                                   _pallas_shapes(sched, dtype, ())))
     # a factorization under the merged sweep hands back a handle whose
     # packs are in flight: `jit_slu_pack` is dispatched on the factor
     # program's output futures BEFORE the blocking reads below, so the
-    # host hands out its buffers while the chip factors (the staged
-    # run has blocked on its counts already and hides nothing)
+    # host hands out its buffers while the chip factors.  The staged
+    # run has blocked on its counts already (`slu.fact.wait`), so its
+    # pack hides nothing: on `lap3d_k48.step` the chip idles some 21 ms
+    # a step under `slu.solve.pack` (PERF.md section 5)
     if trisolve.sweeps_packed():
         trisolve.get_packs(lu, at="factor")
     nzero = int(nzero)

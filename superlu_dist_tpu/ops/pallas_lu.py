@@ -49,8 +49,10 @@ def enabled(dtype) -> bool:
     kernel wins only the µs-scale (8, 16) bucket (1.3x), which never
     dominates a schedule — but IS the population the level-merged
     factor segments coalesce; `merged_eligible` promotes exactly that
-    regime.  Complex dtypes always use the XLA path (no complex in
-    Mosaic)."""
+    regime.  What that regime costs in a cell is read since PR 41
+    (`lap3d_k48.step`, PERF.md section 5): 5.5 ms of a factorization's
+    0.351 s on the device, 1.6 %.  Complex dtypes always use the XLA
+    path (no complex in Mosaic)."""
     return (mosaic_dtype(dtype)
             and flags.env_str("SLU_TPU_PALLAS", "0").strip() == "1")
 
@@ -59,11 +61,17 @@ def merged_eligible(wb: int, mb: int, dtype) -> bool:
     """Merged-factor-segment promotion (ISSUE 12): inside a merged
     staged factor segment (ops/batched.get_factor_segments) the
     panel-LU kernel engages BY DEFAULT for the µs-scale buckets a
-    pre-round chip record (not re-measured) priced it ahead on — wb ≤ 8, mb ≤ 16, the
+    pre-round chip record priced it ahead on — wb ≤ 8, mb ≤ 16, the
     (8, 16)-class population that level merging coalesces — on real
     TPU hardware only (kernels are resolved by measurement; interpret
     mode would merely slow the CPU rehearsal, and the bitwise fp64
     A/B never reaches here because f64 is structurally ineligible).
+    One cell runs this arm since PR 41, `lap3d_k48.step`: its one
+    eligible bucket, 4,096 leaf slots of (16, 8), takes 5.5 ms a
+    factorization under `slu.pallas_lu`, 0.18 % of the roofline for
+    the bytes it moves (my chip runs, PR 41; PERF.md section 5).  The
+    XLA arm on the same bucket has not been read beside it: the arm is
+    priced and kept or deleted by a `simplicity` issue (ROADMAP D-a).
     SLU_TPU_PALLAS=0 restores the XLA path; =1 forces the kernel for
     every usable bucket (the historical A/B arm)."""
     if not mosaic_dtype(dtype) or not usable(mb, dtype):
@@ -274,7 +282,10 @@ def partial_lu_batch_pallas(F, thresh, *, wb: int,
             "for deferred Mosaic lowering of the unrolled block chain",
             stacklevel=2)
         sys.setrecursionlimit(20000)
-    with jax.enable_x64(False):
+    # a scope of the kernel's own, inside the caller's
+    # `slu.partial_lu`: a device trace then says what the kernel costs
+    # (benchmark/metrics/pallas_lu_share.py, pallas_lu_roofline.py)
+    with jax.enable_x64(False), jax.named_scope("slu.pallas_lu"):
         out, tiny, nzero = _pallas_lu_call(kern, N, mb, F.dtype,
                                            interpret)(thresh_arr, F)
     return out, jnp.sum(tiny), jnp.sum(nzero)

@@ -157,6 +157,14 @@ class Stats:
     # stamped by the plan build and by every factorization on the
     # plan; the health ring's factor records carry them as `gesp`
     gesp: Dict[str, object] = dataclasses.field(default_factory=dict)
+    # which route the LAST factorization under this Stats took on the
+    # one-device jax backend and what it dispatched
+    # (ops/batched._route: `dispatch` "staged" or "program",
+    # `segments`, `groups`, `pallas_buckets`, `pallas_shapes`), and
+    # `sweep_segments`, the programs each sweep of the last solve
+    # dispatched; empty on the host oracle and the mesh.  The health
+    # ring's factor and solve records carry the same keys
+    dispatch: Dict[str, object] = dataclasses.field(default_factory=dict)
 
     @contextlib.contextmanager
     def timer(self, phase: str):
@@ -212,6 +220,7 @@ class Stats:
             "packs": dict(self.packs),
             "complex_lowering": dict(self.complex_lowering),
             "gesp": dict(self.gesp),
+            "dispatch": dict(self.dispatch),
             "escalations": self.escalations,
             "lu_nnz": self.lu_nnz,
             "lu_bytes": self.lu_bytes,
@@ -260,6 +269,15 @@ class Stats:
             lines.append(
                 f"  packs dispatched:     {self.packs['at_factor']} at "
                 f"factor, {self.packs['at_solve']} at solve")
+        if "dispatch" in self.dispatch:
+            d = self.dispatch
+            line = (f"  dispatch:             {d['dispatch']}, "
+                    f"{d['segments']} programs a factorization "
+                    f"({d['groups']} groups, {d['pallas_buckets']} on "
+                    "the Pallas panel LU)")
+            if "sweep_segments" in d:
+                line += f", {d['sweep_segments']} a sweep"
+            lines.append(line)
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
         if self.placement:
