@@ -164,8 +164,10 @@ class HealthMonitor:
         triangular sweeps (x0's and the corrections') by operand
         dtype, `complex_lowering` says how they were lowered and
         where (as record_factor's), `sweep_segments` how many
-        programs each of them dispatched (ops/batched.sweep_programs;
-        None off the one-device jax backend).  `stalled` means the loop
+        programs each of them dispatched (ops/batched.sweep_programs:
+        1 under the merged trisolve arm on either handle form, a
+        program a group each way for a staged handle under the legacy
+        sweep; None off the one-device jax backend).  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""

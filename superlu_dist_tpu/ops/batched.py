@@ -2249,7 +2249,10 @@ def _staged_sweeps(sched, panels, bf, dtype, trans: bool,
     SEGMENT over the lsum layout — the same arithmetic, a
     fraction of the Python/dispatch overhead at small nrhs.  `packs`
     lets a caller that solves repeatedly against one panel set (the
-    staged fused solver's refinement loop) pre-pack once."""
+    staged fused solver's refinement loop) pre-pack once.  Callers:
+    the staged fused solver under either arm, and a `StagedLU`'s
+    FACTORED solve under the legacy arm (under the merged one that
+    solve is one packed program, `_solve_device_common`)."""
     from . import trisolve
     if trisolve.trisolve_mode() == "merged":
         ts = trisolve.get_trisolve(sched)
@@ -2467,14 +2470,12 @@ def _route(sched, dispatch: str, segments: int, pallas: list) -> dict:
 
 def sweep_programs(lu) -> int:
     """Programs one triangular solve on this handle dispatches: the
-    forward and backward segment programs of `trisolve.staged_sweeps`
-    (a program a group each way under the legacy sweep) for a
-    `StagedLU`, else the one solve program."""
-    if not isinstance(lu, StagedLU):
-        return 1
+    one solve program (`jit_slu_solve_packed` under the merged arm,
+    whatever the handle's form), or, for a `StagedLU` under the legacy
+    sweep, a program a group each way."""
     from . import trisolve
-    if trisolve.sweeps_packed():
-        return 2 * len(trisolve.get_trisolve(lu.schedule).segments)
+    if trisolve.sweeps_packed() or not isinstance(lu, StagedLU):
+        return 1
     return 2 * len(lu.schedule.groups)
 
 
@@ -2561,21 +2562,27 @@ def _solve_device_common(lu, b: np.ndarray, trans: bool):
     # `factorize_device` packs; taken BEFORE the sweep span opens
     # all the same: a handle made under another arm or cell limit
     # packs here (`slu.solve.pack`), and that is not sweep time
-    packs = trisolve.get_packs(lu) if merged else None
+    if merged:
+        trisolve.get_packs(lu)
     with obs.span("solve.sweep", cat="solve",
                   args={"nrhs": bb.shape[1], "trans": int(trans)}):
-        if isinstance(lu, StagedLU):
-            X = _staged_sweeps(lu.schedule, lu.panels,
-                               jnp.asarray(bin_), lu.dtype, trans,
-                               pair=pair, packs=packs)
-        elif merged:
+        if merged:
             # the packed FACTORED fast path (ops/trisolve.py): panels
             # pre-sliced once per factorization, lsum layout instead
-            # of scatter-adds — the serve hot path's program.  Cost
-            # attribution happens inside solve_packed (same
-            # thread-local hand-off as below; its own get_packs is
-            # a hit by now).
+            # of scatter-adds — the serve hot path's program, ONE
+            # dispatch a sweep for a DeviceLU and a StagedLU alike
+            # (the pack's body and the sweep's member bodies are the
+            # same for both forms; the staged rule is the factor
+            # program's).  Cost attribution happens inside
+            # solve_packed (same thread-local hand-off as below; its
+            # own get_packs is a hit by now).
             X = trisolve.solve_packed(lu, bin_, trans)
+        elif isinstance(lu, StagedLU):
+            # the legacy sweep on per-group panels: a program a group
+            # each way
+            X = _staged_sweeps(lu.schedule, lu.panels,
+                               jnp.asarray(bin_), lu.dtype, trans,
+                               pair=pair)
         else:
             _, solve_fn = _phase_fns(lu.schedule, lu.dtype,
                                      _thresh_for(lu.plan, lu.dtype),

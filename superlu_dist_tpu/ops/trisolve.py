@@ -32,16 +32,19 @@ applied to the data movement rather than the arithmetic:
     separately compiled programs, so not bit for bit);
   * **level-merged segments** — consecutive small groups (the deep
     narrow chain tail that dominates nrhs=1 wall time) coalesce into
-    single dispatch segments: the staged path dispatches one program
-    per SEGMENT instead of per group, and the mesh trisolve
-    reconciles once per segment boundary instead of per group.
+    single dispatch segments: the staged fused solver's sweeps
+    (`staged_sweeps`) dispatch one program per SEGMENT instead of
+    per group, and the mesh trisolve reconciles once per segment
+    boundary instead of per group.
 
 Every execution mode threads through here: the whole-phase solve jit
 (`ops/batched._phase_fns` → `_solve_loop`), the packed FACTORED fast
-path (`solve_packed`, what `models/gssvx.solve` and the serve
-micro-batcher dispatch), the staged per-segment dispatch, the fused
-solvers' in-program sweeps, transpose solves, the complex pair-plane
-lane, and the row-partitioned mesh trisolve
+path (`solve_packed`: ONE program a sweep, what `models/gssvx.solve`
+and the serve micro-batcher dispatch on a DeviceLU and on a StagedLU
+alike — the staged rule is the factor program's, the sweep's program
+is chosen by the arm), the staged fused solver's per-segment
+dispatch, the fused solvers' in-program sweeps, transpose solves, the
+complex pair-plane lane, and the row-partitioned mesh trisolve
 (`parallel/factor_dist.make_dist_solve` with SLU_TRISOLVE=merged).
 
 Flags (see flags.py): SLU_TRISOLVE selects the arm (auto|merged|
@@ -130,10 +133,12 @@ def active_arm(device_lu=None) -> str:
     so p99 exemplars attribute latency to the right kernel.  The
     "+pallas" suffix is claimed only when the lsum kernel can
     actually execute for the handle: the env flag alone is not enough
-    (staged handles dispatch per-segment programs with no Pallas
-    routing, and f64/complex dtypes have no Mosaic lowering —
-    labeling those dispatches "merged+pallas" would be exactly the
-    misattribution the arm field exists to prevent)."""
+    (f64/complex dtypes have no Mosaic lowering — labeling those
+    dispatches "merged+pallas" would be exactly the misattribution
+    the arm field exists to prevent).  A staged handle's FACTORED
+    solve dispatches the packed program like any other
+    (`ops/batched._solve_device_common`), so it is labeled by its
+    dtype like any other."""
     mode = trisolve_mode()
     if mode != "merged":
         return mode
@@ -141,8 +146,6 @@ def active_arm(device_lu=None) -> str:
         return "merged"
     if device_lu is not None:
         from . import pallas_lsum
-        if getattr(device_lu, "panels", None) is not None:
-            return "merged"          # staged path: no pallas routing
         if not pallas_lsum.enabled(getattr(device_lu, "dtype",
                                            np.float32)):
             return "merged"
@@ -893,9 +896,8 @@ class _Meta:
 def seg_metas(ts: TrisolveSchedule, members, cplx: bool) -> tuple:
     """The static meta tuple of one staged segment's members, in the
     given order — THE single definition of the segment jit's static
-    key, shared by the dispatch site (staged_sweeps) and the AOT
-    warmup (utils/warmup.py): a drift between the two would turn
-    warmed programs into dead compiles."""
+    key, shared by the dispatch site (staged_sweeps) and the HLO
+    contract's builder below."""
     sched = ts.sched
     return tuple(
         (sched.groups[i].wb, sched.groups[i].mb,
@@ -946,10 +948,11 @@ def _final_gather(XF, final_idx, cplx: bool):
 
 def staged_sweeps(ts: TrisolveSchedule, packs, bf, dtype,
                   trans: bool, pair: bool = False):
-    """The staged-mode merged solve: ONE dispatch per merged segment
-    instead of one per group — the nrhs=1 dispatch-latency lever at
-    audikw-class group counts, where the legacy staged sweep paid
-    ~2·len(groups) Python dispatches per solve."""
+    """The merged solve as ONE dispatch per merged segment instead of
+    one per group, on caller-held packs: what the staged fused solver
+    (`ops/batched.make_fused_solver(staged=True)`) sweeps with.  A
+    handle's FACTORED solve does not come through here: it dispatches
+    the one packed program (`solve_packed`) whatever its form."""
     from .batched import _enc
     sched = ts.sched
     n = sched.n

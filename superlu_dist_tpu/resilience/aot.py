@@ -24,10 +24,11 @@ merge flags) — and this module persists them on two legs:
     execute divergent programs.
   * **compilation-cache leg**: the deserialized module still needs a
     backend compile, which jax's persistent compilation cache makes a
-    disk hit across processes.  The staged per-segment programs
-    (factor segments + trisolve segments) ride this leg alone: they
-    are bounded per-segment compiles with donated operands, already
-    warmed/persisted by `utils/warmup.py`.
+    disk hit across processes.  The staged per-segment factor
+    programs ride this leg alone: they are bounded per-segment
+    compiles with donated operands, already warmed/persisted by
+    `utils/warmup.py` (a staged handle's sweep is the packed solve
+    program, on both legs like any other handle's).
 
 **The rule** (ISSUE 39; no variable, no option): the store is on
 exactly when jax's persistent compilation cache is in force —
@@ -482,6 +483,16 @@ class AotJit:
 
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
+
+    def warm(self, *avals):
+        """Compile, without running it, the program a call at `avals`
+        (`jax.ShapeDtypeStruct` leaves) dispatches: the signature is
+        resolved as a call resolves it (read-through, or export and
+        write-through), so what is compiled is the exported module's
+        program, not the plain jit's (utils/warmup.py)."""
+        key = self._sig_key(avals)
+        fn = self._table.get(key) or self._resolve(key, avals)
+        fn.lower(*avals).compile()
 
     def _cache_size(self) -> int:
         # dedupe by identity: every export-failure fallback signature

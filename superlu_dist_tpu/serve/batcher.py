@@ -50,7 +50,7 @@ _DEADLINE_FLUSH_MARGIN_S = 0.001
 
 def _trisolve_arm(lu) -> str:
     """The solve arm serving this dispatch (ops/trisolve.active_arm,
-    resolved against the handle so a staged or non-Pallas-capable
+    resolved against the handle so a non-Pallas-capable
     factorization is never labeled '+pallas'); import deferred so the
     batcher never pays an ops import on the module path.  A
     mesh-resident handle (dist backend, ISSUE 17) is its own arm —

@@ -1270,7 +1270,8 @@ def solve_jit_cache_size(lu: LUFactorization) -> int:
     this handle — the recompile pin for the zero-recompiles-after-
     warmup contract (tests assert it is flat across a load run).
     Returns -1 when the handle has no single jitted solve program
-    (host backend, staged per-group execution)."""
+    (host backend; a staged handle under the legacy sweep, a program
+    a group each way)."""
     if lu.backend == "dist" and lu.device_lu is not None:
         # mesh replica (ISSUE 17): the handle dispatches through the
         # plan-level dist solve cache — sum every compiled signature
@@ -1282,12 +1283,13 @@ def solve_jit_cache_size(lu: LUFactorization) -> int:
         return -1
     from ..ops import batched, trisolve
     d = lu.device_lu
+    if trisolve.sweeps_packed():
+        # the merged arm dispatches the packed solve program
+        # (trisolve.solve_packed), not _phase_fns', for a DeviceLU
+        # and a StagedLU alike — probe that one
+        return trisolve.solve_packed_cache_size(d)
     if isinstance(d, batched.StagedLU):
         return -1
-    if trisolve.trisolve_mode() == "merged":
-        # the merged arm dispatches the packed solve program
-        # (trisolve.solve_packed), not _phase_fns' — probe that one
-        return trisolve.solve_packed_cache_size(d)
     _, solve_fn = batched._phase_fns(
         d.schedule, d.dtype, batched._thresh_for(lu.plan, d.dtype),
         pair=batched._lu_is_pair(d))

@@ -5,7 +5,11 @@ one cached program per distinct group signature — but a cold start
 still compiles them SEQUENTIALLY, in dispatch order, on one core
 (measured: ~13 min at the k=64 3D Laplacian on a 1-core host).  XLA
 releases the GIL during compilation, so a thread pool compiles
-signatures concurrently on multi-core hosts.  The warmed programs are
+signatures concurrently on multi-core hosts.  What is warmed is what a
+staged handle dispatches: the factor segments (or groups), and for
+the sweeps the ONE packed solve program of the merged arm
+(`jit_slu_solve_packed`, as on every other handle) or the legacy
+arm's program a group each way.  The warmed programs are
 reused at two levels, both verified by tests/test_warmup.py:
 
 - SAME process: `.lower().compile()` populates the in-memory pjit
@@ -57,13 +61,17 @@ _LOWER_LOCK = threading.Lock()
 
 def staged_signatures(sched, dtype="float32"):
     """The distinct (static-args + operand-aval) signatures of the
-    staged factor and sweep programs — what the jit executable cache
+    staged factor programs and of the sweep programs a staged
+    handle's solve dispatches — what the jit executable cache
     is actually keyed by.  Returns (factor_sigs, sweep_sigs) dicts
     mapping signature -> a representative GroupSpec (or a segment
-    index under the merged arms).  `dtype` is the FACTOR dtype the
-    dispatch will use: complex factorizations keep the per-group
-    dispatch (batched._staged_factor_run), so their factor keys stay
-    per-group even when the merged arm is on."""
+    index under the merged factor arm).  Under the merged trisolve
+    arm a sweep is ONE program whatever the handle's form
+    (`trisolve.solve_packed`), so `sweep_sigs` holds its one key, the
+    operand avals of the pack in group order.  `dtype` is the FACTOR
+    dtype the dispatch will use: complex factorizations keep the
+    per-group dispatch (batched._staged_factor_run), so their factor
+    keys stay per-group even when the merged arm is on."""
     import jax
 
     def aval(x):
@@ -106,31 +114,40 @@ def staged_signatures(sched, dtype="float32"):
                 (B.factor_seg_metas(sched, seg, np.float32), opnd),
                 seg_i)
     from ..ops import trisolve as T
-    if T.trisolve_mode() == "merged":
-        # the merged arm dispatches one program per SEGMENT
-        # (trisolve.staged_sweeps), keyed by the member meta tuple —
-        # warm THOSE, not the legacy per-group sweep programs
-        ts = T.get_trisolve(sched)
-        ssigs = {}
-        for seg_i, seg in enumerate(ts.segments):
-            # the shared static-key definition (trisolve.seg_metas):
-            # cplx is uniform across a warmup pass, so False is a
-            # valid dedup key here
-            ssigs.setdefault(T.seg_metas(ts, seg, False), seg_i)
+    if T.sweeps_packed():
+        # the merged arm dispatches ONE program a sweep
+        # (trisolve.solve_packed → jit_slu_solve_packed) — warm THAT,
+        # not the legacy per-group sweep programs
+        ssigs = {tuple(_pack_shapes(sched, T.get_trisolve(sched))):
+                 None}
     return fsigs, ssigs
+
+
+def _pack_shapes(sched, ts):
+    """The (Li, L21, Ui, U12) shapes of every group's pack, in group
+    order: what `trisolve.pack_panels_staged` cuts, dead lanes
+    dropped."""
+    for g, gs in zip(sched.groups, ts.groups):
+        rb = g.mb - g.wb
+        yield ((gs.trim, g.wb, g.wb), (gs.trim, rb, g.wb),
+               (gs.trim, g.wb, g.wb), (gs.trim, g.wb, rb))
 
 
 def warmup_staged(plan, dtype="float32", nrhs: int = 1,
                   rhs_dtype="float64", workers: Optional[int] = None,
                   trans: bool = False, force: bool = False) -> dict:
-    """AOT-compile every distinct staged program for `plan`
-    concurrently.  Covers the factor groups and the solve sweeps for
-    `rhs_dtype` right-hand sides (default float64, the gssvx flow:
-    the sweep X carries the FACTOR's precision whatever the rhs's,
+    """AOT-compile every distinct program a staged run of `plan`
+    dispatches, concurrently.  Covers the factor groups and the solve
+    sweep for `rhs_dtype` right-hand sides of width `nrhs` (default
+    float64, the gssvx flow: the sweep operand carries the FACTOR's
+    precision whatever the rhs's,
     precision/policy.sweep_operand_dtype; only the rhs's realness
     reaches the program).
 
-    Returns {"factor_programs", "sweep_programs", "workers", "secs"}.
+    Returns {"factor_programs", "sweep_programs", "workers", "secs"}:
+    `sweep_programs` is 1 under the merged trisolve arm (the packed
+    solve program, N or T by `trans`), two a distinct group signature
+    under the legacy one.
     """
     import os
     import warnings
@@ -235,61 +252,37 @@ def warmup_staged(plan, dtype="float32", nrhs: int = 1,
                     kind=kind)
             lowered.compile()
 
-    # merged-arm sweep warmup: one fwd + one bwd program per merged
-    # SEGMENT (trisolve.staged_sweeps), operands mirrored exactly —
-    # packs avals from the schedule extents, index avals from the
-    # GroupSolve layout, metas/member order identical to the dispatch
-    # site (bwd runs members reversed)
+    # merged-arm sweep warmup: the one packed solve program
+    # (trisolve.solve_packed), through the callable the dispatch
+    # calls: where the exported-program store is on
+    # (resilience/aot.py) that resolves the signature as a call does
+    # and compiles the exported module's program, so this process's
+    # dispatch and a later process's both find it.  The operand is
+    # what `_solve_device_common` hands in: (n, nrhs) in the sweep
+    # operand's dtype, the codec inside the program
     from ..ops import trisolve as T
-    merged = T.trisolve_mode() == "merged"
-    ts = T.get_trisolve(sched) if merged else None
+    merged = T.sweeps_packed()
 
-    def compile_seg(item):
-        _key, seg_i = item
-        seg = ts.segments[seg_i]
-
-        def operands(i):
-            g = sched.groups[i]
-            gs = ts.groups[i]
-            rb = g.mb - g.wb
-            pack = (
-                jax.ShapeDtypeStruct((gs.trim, g.wb, g.wb), dtype),
-                jax.ShapeDtypeStruct((gs.trim, rb, g.wb), dtype),
-                jax.ShapeDtypeStruct((gs.trim, g.wb, g.wb), dtype),
-                jax.ShapeDtypeStruct((gs.trim, g.wb, rb), dtype),
-            )
-            idx = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                        for a in gs.dev(squeeze=True))
-            return pack, idx
-
-        fwd = [operands(i) for i in seg]
-        bwd = [operands(i) for i in reversed(seg)]
-        Ba = jax.ShapeDtypeStruct((sched.n + 1, r_hat), xdt)
-        Ua = jax.ShapeDtypeStruct((ts.u_total + 1, r_hat), xdt)
-        Ya = jax.ShapeDtypeStruct((ts.y_total + 1, r_hat), xdt)
+    def compile_packed(item):
+        shapes, _ = item
+        fn = T._solve_packed_fn(sched, dtype, False)[int(trans)]
+        packs = T.PackSet(
+            tuple(jax.ShapeDtypeStruct(s, dtype) for s in grp)
+            for grp in shapes)
+        b = jax.ShapeDtypeStruct((sched.n, nrhs), pdt)
+        compile_at = getattr(fn, "warm", None) or (
+            lambda *avals: fn.lower(*avals).compile())
+        # one program, after the factor's: no compile to overlap
         with _LOWER_LOCK:
-            lf = T._staged_fwd_segment.lower(
-                Ba, Ua, Ya, tuple(p for p, _ in fwd),
-                tuple(ix for _, ix in fwd),
-                metas=T.seg_metas(ts, seg, x_cplx), trans=trans)
-        lf.compile()
-        with _LOWER_LOCK:
-            lb = T._staged_bwd_segment.lower(
-                Ya, Ya, tuple(p for p, _ in bwd),
-                tuple(ix for _, ix in bwd),
-                metas=T.seg_metas(ts, list(reversed(seg)), x_cplx),
-                trans=trans)
-        lb.compile()
+            compile_at(packs, b)
 
     t0 = time.perf_counter()
     with ThreadPoolExecutor(max_workers=workers) as ex:
         list(ex.map(compile_factor_seg if merged_factor
                     else compile_factor, fsigs.items()))
-        if merged:
-            list(ex.map(compile_seg, ssigs.items()))
-        else:
-            list(ex.map(compile_sweep, ssigs.items()))
+        list(ex.map(compile_packed if merged else compile_sweep,
+                    ssigs.items()))
     return {"factor_programs": len(fsigs),
-            "sweep_programs": len(ssigs) * 2,
+            "sweep_programs": len(ssigs) * (1 if merged else 2),
             "workers": workers,
             "secs": round(time.perf_counter() - t0, 2)}

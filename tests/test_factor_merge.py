@@ -216,7 +216,10 @@ def test_warmup_signatures_are_segment_keys(monkeypatch):
     a = _testmat(30)
     sched = B.get_schedule(_plan(a, "float32"), 1)
     monkeypatch.setenv("SLU_FACTOR_MERGE_CELLS", "65536")
-    fsigs, _ = staged_signatures(sched)
+    fsigs, ssigs = staged_signatures(sched)
+    # the sweep a staged handle dispatches is one program, whatever
+    # the factor arm
+    assert len(ssigs) == 1
     segs = B.get_factor_segments(sched)
     assert 0 < len(fsigs) <= len(segs)
     for (metas, _opnd), seg_i in fsigs.items():
@@ -224,8 +227,9 @@ def test_warmup_signatures_are_segment_keys(monkeypatch):
                                            np.float32)
     # legacy arm keeps the per-group keys
     monkeypatch.setenv("SLU_FACTOR_MERGE_CELLS", "0")
-    fsigs_leg, _ = staged_signatures(sched)
+    fsigs_leg, ssigs_leg = staged_signatures(sched)
     assert all(len(k) == 9 for k in fsigs_leg)
+    assert ssigs_leg == ssigs
 
 
 def test_factor_segment_hlo_contract():

@@ -8,15 +8,21 @@ ring's `last_factor` and solve records, `Stats.dispatch` and
 `Stats.report()` name the route and count what was dispatched
 (`dispatch`, `segments`, `groups`, `pallas_buckets`, `pallas_shapes`,
 `sweep_segments`) against the schedule's own segment lists, under
-either staged arm; the staged run opens `slu.fact.scale`,
+either staged arm; a sweep on a staged handle is ONE program under the
+merged trisolve arm, `jit_slu_solve_packed` as on every other handle
+(the staged rule is the factor program's), and a program a group each
+way under the legacy one; the staged run opens `slu.fact.scale`,
 `slu.fact.dispatch` and `slu.fact.wait` once a factorization inside
 `FACT`, in that order, and the one-program route opens neither of the
 last two; the Pallas panel LU traces under `slu.pallas_lu` inside the
 caller's scope, and the staged answers stay those of the one-program
 route and of scipy."""
 
+import dataclasses
+
 import numpy as np
 import pytest
+import scipy.sparse as sp
 import scipy.sparse.linalg as spla
 
 import jax
@@ -24,11 +30,14 @@ import jax.numpy as jnp
 
 from superlu_dist_tpu import (Options, Stats, csr_from_scipy, factorize,
                               obs, solve)
+from superlu_dist_tpu.options import Trans
 from superlu_dist_tpu.ops import batched, pallas_lu, trisolve
 from superlu_dist_tpu.plan.plan import plan_factorization
+from superlu_dist_tpu.serve import solve_jit_cache_size
 from superlu_dist_tpu.utils.testmat import laplacian_3d
 
 from test_pack_program import _inside
+from test_trisolve import _assert_ulp_close
 
 ROUTE_KEYS = {"dispatch", "segments", "groups", "pallas_buckets",
               "pallas_shapes"}
@@ -81,7 +90,7 @@ def test_the_staged_route_counts_what_it_dispatched(monkeypatch,
     sched = d.schedule
     programs = (len(sched.groups) if merge_cells == "0"
                 else len(batched.get_factor_segments(sched)))
-    sweeps = 2 * len(trisolve.get_trisolve(sched).segments)
+    sweeps = 1              # `jit_slu_solve_packed`, either factor arm
     want = {"dispatch": "staged", "segments": programs,
             "groups": len(sched.groups), "pallas_buckets": 0,
             "pallas_shapes": []}
@@ -111,6 +120,153 @@ def test_the_legacy_sweep_dispatches_a_program_a_group_each_way(
     assert st.dispatch["sweep_segments"] == 2 * groups
     assert obs.HEALTH.snapshot()["last_solve"]["sweep_segments"] \
         == 2 * groups
+
+
+def _staged_system(storage):
+    """A small system and its options: `laplacian_3d(6)` in real
+    float32 factors, or with a complex shift in complex64 factors
+    (pair-stored under `SLU_COMPLEX_PAIR=1`)."""
+    a = laplacian_3d(6)
+    if storage == "float32":
+        return a, Options(factor_dtype="float32"), np.float64
+    m = (a.to_scipy() + (0.4 + 0.3j) * sp.identity(a.n)).tocsr()
+    m.sort_indices()
+    return (csr_from_scipy(m), Options(factor_dtype="complex64"),
+            np.complex128)
+
+
+@pytest.mark.parametrize("storage", ["float32", "pair_complex64"])
+@pytest.mark.parametrize("nrhs", [1, 8])
+@pytest.mark.parametrize("trans", [False, True])
+def test_a_staged_handle_sweeps_in_one_program(monkeypatch, trans,
+                                               nrhs, storage):
+    """Merged arm, forced-staged plan: the handle's FACTORED sweep is
+    the packed program's, within 4·eps·max|x| of the per-segment
+    `trisolve.staged_sweeps` on the same packs (the same member bodies
+    in the same order, compiled as one program or as many); the
+    refined answer is the one-program route's and scipy's."""
+    monkeypatch.setenv("SLU_STAGED", "1")
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    pair = storage == "pair_complex64"
+    if pair:
+        monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
+    a, opts, rdt = _staged_system(storage)
+    plan = plan_factorization(a, opts)
+    lu = factorize(a, opts, plan=plan, backend="jax")
+    d = lu.device_lu
+    assert isinstance(d, batched.StagedLU)
+    assert batched._lu_is_pair(d) == pair
+    assert batched.sweep_programs(d) == 1
+    rng = np.random.default_rng(5)
+    bf = rng.standard_normal((a.n, nrhs))
+    if pair:
+        bf = bf + 1j * rng.standard_normal((a.n, nrhs))
+    bf = bf.astype(d.dtype)             # factor ordering and precision
+    fn = batched.solve_device_trans if trans else batched.solve_device
+    before = solve_jit_cache_size(lu)
+    x = fn(d, bf)
+    assert x.shape == bf.shape and x.dtype == bf.dtype
+    assert solve_jit_cache_size(lu) == max(before, 0) + 1
+    fn(d, bf)                           # the same signature: no compile
+    assert solve_jit_cache_size(lu) == max(before, 0) + 1
+    ts = trisolve.get_trisolve(d.schedule)
+    packs = trisolve.get_packs(d)
+    bin_ = batched._pair_encode_rhs(bf) if pair else bf
+    ref = np.asarray(trisolve.staged_sweeps(
+        ts, packs, jnp.asarray(bin_), d.dtype, trans, pair=pair))
+    if pair:
+        ref = batched._pair_decode_sol(ref, bf.dtype)
+    _assert_ulp_close(x, ref, f"{storage} trans={trans} nrhs={nrhs}")
+    # the refined answers: staged against one program against scipy
+    asp = a.to_scipy().tocsc()
+    xt = rng.standard_normal((a.n, nrhs)).astype(rdt)
+    b = (asp.T if trans else asp) @ xt
+    tr = Trans.TRANS if trans else Trans.NOTRANS
+    xs = {}
+    for flag in ("1", "0"):
+        monkeypatch.setenv("SLU_STAGED", flag)
+        h = lu if flag == "1" else factorize(a, opts, plan=plan,
+                                             backend="jax")
+        assert h.device_lu.route["dispatch"] == (
+            "staged" if flag == "1" else "program")
+        xs[flag] = solve(dataclasses.replace(
+            h, options=h.effective_options.replace(trans=tr)), b)
+    ref = spla.splu(asp).solve(b, trans="T" if trans else "N")
+    for x in xs.values():
+        assert np.abs(x - ref).max() / np.abs(ref).max() < 1e-9
+    assert np.abs(xs["1"] - xs["0"]).max() / np.abs(ref).max() < 1e-12
+
+
+@pytest.mark.parametrize("storage", ["float32", "pair_complex64"])
+def test_the_recompile_pin_holds_for_a_staged_tenant(monkeypatch,
+                                                     storage):
+    """`solve_jit_cache_size` probes the packed program for a
+    `StagedLU` too: at least 1 after a solve, and flat across three
+    refactorizations on a held plan and across repeated solves."""
+    monkeypatch.setenv("SLU_STAGED", "1")
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    if storage == "pair_complex64":
+        monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
+    a, opts, rdt = _staged_system(storage)
+    plan = plan_factorization(a, opts)
+    b = (a.to_scipy() @ np.ones(a.n)).astype(rdt)
+    sizes = []
+    for _ in range(3):
+        lu = factorize(a, opts, plan=plan, backend="jax")
+        assert isinstance(lu.device_lu, batched.StagedLU)
+        for _ in range(2):
+            x = solve(lu, b)
+            sizes.append(solve_jit_cache_size(lu))
+        assert np.abs(x - 1).max() < 1e-9
+    assert sizes[0] >= 1 and len(set(sizes)) == 1
+    # a program a group each way has no one cache to probe
+    monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    assert solve_jit_cache_size(lu) == -1
+
+
+def test_a_staged_solve_finds_its_packs(monkeypatch):
+    """The pack is the factorization's (`at_factor`): the first solve
+    on a staged handle takes a hit, opens no `slu.solve.pack` and
+    dispatches one sweep program."""
+    monkeypatch.setenv("SLU_STAGED", "1")
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    a, opts, _ = _staged_system("float32")
+    st = Stats()
+    lu = factorize(a, opts, backend="jax", stats=st)
+    d = lu.device_lu
+    assert isinstance(d, batched.StagedLU)
+    assert obs.HEALTH.snapshot()["last_factor"]["pack"] == "at_factor"
+    packs = d._trisolve_packs[1]
+    t = obs.configure(enabled=True)
+    t.clear()
+    try:
+        solve(lu, a.to_scipy() @ np.ones(a.n), stats=st)
+        names = [e["name"] for e in t.events()]
+    finally:
+        obs.configure(enabled=False)
+    assert "solve.sweep" in names and "solve.pack" not in names
+    assert trisolve.get_packs(d) is packs
+    assert st.packs == {"at_factor": 1, "at_solve": 0}
+    assert st.dispatch["sweep_segments"] == 1
+
+
+def test_a_staged_handle_is_labeled_by_what_it_dispatches(monkeypatch):
+    """`active_arm`: the packed program routes the Pallas lsum member
+    for a staged handle as for any other, so the label follows the
+    handle's dtype, not its form."""
+    monkeypatch.setenv("SLU_STAGED", "1")
+    monkeypatch.setenv("SLU_TRISOLVE", "merged")
+    a, opts, _ = _staged_system("float32")
+    d = factorize(a, opts, backend="jax").device_lu
+    assert isinstance(d, batched.StagedLU)
+    assert trisolve.active_arm(d) == "merged"
+    monkeypatch.setenv("SLU_TRISOLVE_PALLAS", "1")
+    assert trisolve.active_arm(d) == "merged+pallas"
+    d64 = factorize(a, Options(), backend="jax").device_lu
+    assert isinstance(d64, batched.StagedLU)
+    assert trisolve.active_arm(d64) == "merged"
+    monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    assert trisolve.active_arm(d) == "legacy"
 
 
 def test_the_host_oracle_has_no_route():

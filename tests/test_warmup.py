@@ -23,8 +23,15 @@ def _testmat(m=28):
     return csr_from_scipy(sp.kronsum(t, t, format="csr").tocsr())
 
 
-def test_warmup_compiles_all_signatures():
+@pytest.mark.parametrize("arm", ["merged", "legacy"])
+def test_warmup_compiles_all_signatures(monkeypatch, arm):
+    """The factor segments, and what a sweep on a staged handle
+    dispatches: the one packed solve program under the merged
+    trisolve arm, a program a distinct group signature each way under
+    the legacy one."""
+    from superlu_dist_tpu.ops import trisolve
     from superlu_dist_tpu.ops.batched import get_schedule
+    monkeypatch.setenv("SLU_TRISOLVE", arm)
     a = _testmat()
     plan = plan_factorization(a, Options(factor_dtype="float32"))
     sched = get_schedule(plan, 1)
@@ -36,7 +43,18 @@ def test_warmup_compiles_all_signatures():
     assert gate.get("staged_inactive") and gate["factor_programs"] == 0
     rep = warmup_staged(plan, dtype="float32", workers=2, force=True)
     assert rep["factor_programs"] == len(fsigs) > 0
-    assert rep["sweep_programs"] == 2 * len(ssigs) > 0
+    if arm == "legacy":
+        assert rep["sweep_programs"] == 2 * len(ssigs) > 0
+        assert all(len(k) == 5 for k in ssigs)
+        return
+    # one key: every group's (Li, L21, Ui, U12) pack shapes in order
+    assert rep["sweep_programs"] == len(ssigs) == 1
+    (shapes,) = ssigs
+    assert len(shapes) == len(sched.groups)
+    assert all(len(grp) == 4 for grp in shapes)
+    # warmed through the callable the dispatch calls, which the
+    # schedule now holds
+    assert trisolve._packed_key("float32", False) in sched._trisolve_fns
 
 
 def test_staged_run_after_warmup_is_correct(monkeypatch):
@@ -48,11 +66,14 @@ def test_staged_run_after_warmup_is_correct(monkeypatch):
     rng = np.random.default_rng(0)
     xtrue = rng.standard_normal(a.n)
     plan = plan_factorization(a, Options(factor_dtype="float32"))
-    warmup_staged(plan, dtype="float32", workers=2)
+    rep = warmup_staged(plan, dtype="float32", workers=2)
+    assert rep["sweep_programs"] == 1
     x, lu, stats = gssvx(Options(factor_dtype="float32"), a,
                          a.to_scipy() @ xtrue)
     relerr = np.linalg.norm(x - xtrue) / np.linalg.norm(xtrue)
     assert relerr < 1e-10
+    # the solve dispatched the program warm-up compiled, and no other
+    assert stats.dispatch["sweep_segments"] == 1
 
 
 # The warmup contract is CROSS-PROCESS: warmup in one process writes
@@ -140,7 +161,7 @@ def _run_sub(script, cache_dir):
 def test_staged_dispatch_hits_warmed_cache(tmp_path):   # the cache)
     """A staged dispatch in a FRESH process must land on the programs a
     previous process's warmup_staged wrote to the persistent cache: the
-    factor + fwd/bwd sweep compiles must all be persistent-cache HITS
+    factor + packed-sweep compiles must all be persistent-cache HITS
     (counted via jax's /jax/compilation_cache/cache_hits monitoring
     event).  Any drift between warmup's hand-mirrored operand
     signatures and the dispatch site turns warmed programs into dead
@@ -155,8 +176,8 @@ def test_staged_dispatch_hits_warmed_cache(tmp_path):   # the cache)
         "warmup wrote nothing to the cache"
     out = _run_sub(_DISPATCH_SCRIPT, cache_dir)
     assert out["relerr"] < 1e-10
-    # factor signatures + forward and backward sweep signatures all
-    # hit; other programs (refinement SpMV etc.) are misses and don't
-    # count here
-    want = out["fsigs"] + 2 * out["ssigs"]
+    # factor signatures + the one packed sweep program all hit;
+    # other programs (the pack, refinement SpMV etc.) are misses and
+    # don't count here
+    want = out["fsigs"] + out["ssigs"]
     assert out["hits"] >= want, (out, want)
