@@ -115,25 +115,19 @@ def factorize(a: CSRMatrix, options: Options | None = None,
             f"backend={backend!r} conflicts with grid=; pass "
             "backend='dist' (or 'auto') for mesh execution")
 
-    from ..utils.platform import complex_device_gate, complex_mesh_blocked
-    if backend == "dist" and grid is not None and complex_mesh_blocked(
-            np.dtype(options.factor_dtype), getattr(grid, "mesh", grid)):
-        raise ValueError(
-            "complex factorization on a TPU mesh is disabled: "
-            "native complex does not compile on this chip "
-            "(utils/platform.py) and the pair lowering that runs "
-            "complex there is single-device. Use a CPU mesh, or "
-            "SLU_COMPLEX_TPU=1 to override.")
+    from ..utils.platform import complex_device_gate
     # drop any stale stamp from a direct ops-layer call the driver
     # never read (the host path below stamps nothing)
     obs.take_cost("pack")
-    # complex on a TPU: the one-device jax backend takes the pair
-    # lowering and stays on the chip (utils/platform.complex_lowering);
-    # the host oracle and a CPU mesh have no pair storage and keep the
+    # complex on a TPU: the one-device jax backend and the process
+    # grid take the pair lowering and stay on the chips
+    # (utils/platform.complex_lowering; a mesh is judged by its own
+    # devices); the host oracle has no pair storage and keeps the
     # gated placement
+    mesh = getattr(grid, "mesh", grid) if backend == "dist" else None
     with complex_device_gate(np.dtype(options.factor_dtype),
-                             pair_capable=(backend == "jax"),
-                             stats=stats, phase=_phase), \
+                             pair_capable=(backend in ("jax", "dist")),
+                             stats=stats, phase=_phase, mesh=mesh), \
             stats.timer(_phase):
         # the host's share of a refactorization before any dispatch:
         # Dr·A·Dc in the plan's order (the backends' cast of it to the
@@ -159,15 +153,8 @@ def factorize(a: CSRMatrix, options: Options | None = None,
             from ..parallel import factor_dist
             if grid is None:
                 raise ValueError("backend='dist' requires grid=")
-            mesh = getattr(grid, "mesh", grid)
-            cache = getattr(plan, "_dist_factor_fns", None)
-            if cache is None:
-                cache = plan._dist_factor_fns = {}
-            key = (mesh, np.dtype(options.factor_dtype).str)
-            if key not in cache:
-                cache[key] = factor_dist.make_dist_factor(
-                    plan, mesh, dtype=np.dtype(options.factor_dtype))
-            dist_lu = cache[key](scaled)
+            dist_lu = factor_dist.dist_factor_fn(
+                plan, mesh, np.dtype(options.factor_dtype))(scaled)
             stats.tiny_pivots += dist_lu.tiny_pivots
             stats.comm_predicted = dist_lu.schedule.comm_summary(
                 np.dtype(options.factor_dtype))
@@ -187,7 +174,9 @@ def factorize(a: CSRMatrix, options: Options | None = None,
     stats.ea_elements = sched.ea_elements if sched else {}
     stats.gesp = dict(getattr(plan, "gesp", None) or {})
     # which route it took and what it dispatched (the one-device jax
-    # backend's handles say: ops/batched._route)
+    # backend's handles say: ops/batched._route), or on a process grid
+    # the devices, cooperative groups and predicted collective bytes
+    # (parallel/factor_dist._mesh_route)
     route = getattr(lu.device_lu, "route", None)
     stats.dispatch.update(route or {})
     # where this factorization's solve mirror was dispatched: by
@@ -350,9 +339,10 @@ def solve(lu: LUFactorization, b: np.ndarray,
     # stored ones cannot take the pair lowering and are gated on a TPU
     stored = "native"
     sweep_segments = None   # programs a sweep dispatches
-    if lu.backend == "jax":
+    if lu.backend in ("jax", "dist"):
         from ..ops.batched import _lu_is_pair, sweep_programs
         stored = "pair" if _lu_is_pair(lu.device_lu) else "native"
+    if lu.backend == "jax":
         sweep_segments = sweep_programs(lu.device_lu)
         stats.dispatch.update(getattr(lu.device_lu, "route", None) or {},
                               sweep_segments=sweep_segments)
@@ -373,7 +363,8 @@ def solve(lu: LUFactorization, b: np.ndarray,
 
     with complex_device_gate(factor_dt, bb.dtype,
                              pair_capable=(stored == "pair"),
-                             stats=stats, phase="SOLVE"):
+                             stats=stats, phase="SOLVE",
+                             mesh=getattr(lu.device_lu, "mesh", None)):
         obs.take_cost("pack")   # drop any stale unread stamp
         with stats.timer("SOLVE"):
             x = from_factor_sol(sweep(lu, to_factor_rhs(bb)))

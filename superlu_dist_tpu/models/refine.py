@@ -8,7 +8,9 @@ casts r after `to_factor_rhs`: precision/policy.sweep_operand_dtype,
 the one rule this loop and the fused device loop share), x += δ in
 refine_dtype, until the componentwise backward error `berr` stops
 improving (same stopping rule family as the reference: stop when
-berr < eps or improvement < 2×).
+berr <= eps or improvement < 2×; "eps" is the accumulator's
+`precision/policy.refine_eps`: eps for a real one, sqrt(2)·eps for a
+complex one, whose answers' berr stands astride eps itself).
 
 This is the HOST loop (scipy CSR residuals — already scatter-free).
 The fused device solver runs the same decisions on device with the
@@ -96,12 +98,13 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
     `lowering`, the complex lowering those sweeps ran under
     (Stats.complex_lowering; None for a real system), and
     `sweep_segments`, the programs each of them dispatched."""
+    from ..precision.policy import refine_eps
     opts = lu.effective_options
     # the system's realness is set by matrix AND rhs: a real matrix
     # with a complex b still needs a complex accumulator
     sys_dtype = np.promote_types(lu.a.dtype, b.dtype)
     rdt = _refine_dtype(opts, sys_dtype)
-    eps = np.finfo(rdt).eps
+    eps = refine_eps(rdt)
     asp, abs_a = _operands(lu, sys_dtype)
     if trans:
         asp = asp.T

@@ -1286,7 +1286,10 @@ def psum_exact(x, axis):
 # slu.assemble (values into fronts), slu.extend_add, slu.partial_lu,
 # slu.tri_inverse, slu.schur, slu.store (panels into the flats),
 # slu.fwd, slu.bwd, slu.lsum (the contributor chain), slu.resid
-# (device SpMV).  The innermost scope is the operation's kernel.
+# (device SpMV); on a mesh also slu.dist.gather (the level's update
+# slab gathered to every device) and slu.coop.psum / slu.coop.gather
+# (the cooperative tree-top LU's collectives, ops/coop_*.py).  The
+# innermost scope is the operation's kernel.
 
 @jax.named_scope("slu.extend_add")
 def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
@@ -1492,7 +1495,8 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
             vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat, tiny,
             nzero, thresh, a_src, a_dst, one_dst, ea_blocks, upd_off,
             L_off, U_off, Li_off, Ui_off, mb=mb, wb=wb, n_pad=n_pad,
-            ea_meta=ea_meta, eb_meta=eb_meta, axis=axis, coop=coop)
+            ea_meta=ea_meta, eb_meta=eb_meta, axis=axis, gather=gather,
+            coop=coop, pos_idx=pos_idx, cp=cp, tp=tp)
     dtype = L_flat.dtype
     one = jnp.ones((), dtype)
     sharded = coop and axis is not None and cp > 0
@@ -1587,7 +1591,8 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
                 # Z-axis panel exchange becomes one tiled all_gather along
                 # the mesh axis — device-major local slabs concatenate into
                 # exactly the global slab layout
-                upd = jax.lax.all_gather(upd, axis, tiled=True)
+                with jax.named_scope("slu.dist.gather"):
+                    upd = jax.lax.all_gather(upd, axis, tiled=True)
                 off = upd_off
             elif axis is not None:
                 # gather-free subforest interior (zone-affine placement):
@@ -1610,7 +1615,8 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
                             wb: int, n_pad: int, ea_meta: tuple = (),
                             eb_meta: tuple = (),
                             axis: Optional[str] = None,
-                            coop: bool = False):
+                            gather: bool = True, coop: bool = False,
+                            pos_idx=None, cp: int = 0, tp: int = 0):
     """_factor_group_impl on stacked real/imag planes (ops/pair_lu):
     the complex-factorization body for platforms whose native complex
     lowering is broken (utils/platform.py gate).  Every flat is
@@ -1619,17 +1625,21 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
     no re-encoding.  Assembly and extend-add are structural
     (plane-wise, vmapped over the plane axis, which preserves the
     scatter uniqueness/sortedness promises per plane); only the dense
-    kernels carry pair arithmetic.  Single-device only: complex on a
-    TPU mesh stays gated (parallel/factor_dist.py policy note)."""
-    if axis is not None or coop:
-        raise NotImplementedError(
-            "pair-mode complex factorization is single-device; "
-            "complex mesh execution stays on the CPU backend "
-            "(utils/platform.complex_mesh_blocked)")
+    kernels carry pair arithmetic.  On a mesh (`axis`) the level's
+    update slab is gathered plane-wise (device-major along the element
+    axis of each plane, the real path's layout twice), and tree-top
+    `coop` groups run the sharded cooperative chain in pair arithmetic
+    (ops/coop_sharded.coop_sharded_lu_pair_batch)."""
     from .pair_lu import (partial_lu_pair_batch, unit_lower_inverse_pair,
                           upper_inverse_pair)
     rdt = L_flat.dtype
-    ncols = mb
+    sharded = coop and axis is not None and cp > 0
+    if coop and axis is not None and not sharded:
+        raise NotImplementedError(
+            "the legacy replicated cooperative LU (SLU_COOP_SHARDED=0, "
+            "ops/coop_lu.py) has no pair arithmetic; complex on a TPU "
+            "mesh runs the sharded chain (ops/coop_sharded.py)")
+    ncols = cp if sharded else mb
     one_pl = jnp.stack([jnp.ones((), rdt), jnp.zeros((), rdt)])
 
     @jax.named_scope("slu.assemble")
@@ -1649,9 +1659,21 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
         f, u, blk_blocks, eb_meta, mb=mb, n_pad=n_pad,
         ncols=ncols))(F, upd_buf)
     F = F.reshape(2, n_pad, mb, ncols)
-    with jax.named_scope("slu.partial_lu"):
-        F, tiny_g, nzero_g = partial_lu_pair_batch(F, thresh, wb=wb)
-    Lsrc, Usrc = F[:, :, :, :wb], F[:, :, :wb, :]
+    if sharded:
+        # counters replicate off the psums: the owner device counts
+        from .coop_sharded import coop_sharded_lu_pair_batch
+        with jax.named_scope("slu.partial_lu"):
+            Lsrc, Usrc, upd_src, tiny_g, nzero_g = \
+                coop_sharded_lu_pair_batch(F, pos_idx, thresh, wb=wb,
+                                           cp=cp, tp=tp, axis=axis)
+        on_owner = (_flat_axis_index(axis) == 0).astype(jnp.int32)
+        tiny_g = tiny_g * on_owner
+        nzero_g = nzero_g * on_owner
+    else:
+        with jax.named_scope("slu.partial_lu"):
+            F, tiny_g, nzero_g = partial_lu_pair_batch(F, thresh, wb=wb)
+        Lsrc, Usrc = F[:, :, :, :wb], F[:, :, :wb, :]
+        upd_src = None      # F's trailing block, sliced where stored
 
     with jax.named_scope("slu.store"):
         rows = jnp.arange(mb)[:, None]
@@ -1674,11 +1696,22 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
             Li_flat, Li.reshape(2, -1), (z, Li_off))
         Ui_flat = jax.lax.dynamic_update_slice(
             Ui_flat, Ui.reshape(2, -1), (z, Ui_off))
-        if mb > wb:
+        if mb > wb and (not sharded or tp > 0):
+            if upd_src is None:
+                upd_src = F[:, :, wb:, wb:]
+            upd = upd_src.reshape(2, -1)
+            off = upd_off
+            # the three mesh cases of _factor_group_impl, per plane
+            if axis is not None and not coop:
+                if gather:
+                    with jax.named_scope("slu.dist.gather"):
+                        upd = jax.lax.all_gather(upd, axis, axis=1,
+                                                 tiled=True)
+                else:
+                    off = upd_off + _flat_axis_index(axis) * upd.shape[1]
             upd_buf = jax.lax.dynamic_update_slice(
-                upd_buf, F[:, :, wb:, wb:].reshape(2, -1),
-                (jnp.zeros((), getattr(upd_off, "dtype", jnp.int32)),
-                 upd_off))
+                upd_buf, upd,
+                (jnp.zeros((), getattr(off, "dtype", jnp.int32)), off))
     return (upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
             tiny + tiny_g, nzero + nzero_g)
 
@@ -2720,9 +2753,19 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     # the whole fused pipeline — scale, assemble, factor, sweeps,
     # SpMV residual, berr, while_loop — compiles complex-free; the
     # public step wrapper encodes/decodes on the host.  Single-device
-    # only (mesh complex stays on the replicated native formulation
-    # behind its own gate).
+    # only: a mesh keeps the replicated native formulation here, and
+    # where the rule gives that mesh the pair lowering (a TPU mesh;
+    # pair storage on a mesh is parallel/factor_dist's split
+    # factor/solve, what `factorize(grid=)` runs) this solver refuses
+    # before a native complex program reaches the TPU's compiler.
     pair = mesh is None and _pair_mode(dtype)
+    if mesh is not None and dtype.kind == "c":
+        from ..utils.platform import complex_lowering
+        if complex_lowering(dtype, mesh) == "pair":
+            raise NotImplementedError(
+                "the fused mesh solver has no pair storage; a complex "
+                "system on this mesh runs through factorize(grid=) / "
+                "solve (parallel/factor_dist)")
     # ---- residual-accumulation mode (precision/policy.py): "plain"
     # (working precision), "fp64" (native refine_dtype — exact on CPU,
     # EMULATED on TPU), or "doubleword" (two-float fp32 df64 pairs,
@@ -2730,7 +2773,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
     # the psgsrfs_d2 residual re-expressed in MXU-native arithmetic).
     # "auto" resolves through the plan's Options so this function and
     # models/refine.py cannot disagree on what a policy means. ----
-    from ..precision.policy import resolve_residual_mode
+    from ..precision.policy import refine_eps, resolve_residual_mode
     mode = (residual_mode if residual_mode != "auto"
             else resolve_residual_mode(plan.options))
     if mode not in ("plain", "doubleword", "fp64"):
@@ -3024,8 +3067,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
             _, berr = resid_berr(x)
             return x, berr, jnp.zeros((), jnp.int32), tiny, nzero
 
-        eps = float(np.finfo(rdt.char.lower()
-                             if rdt.kind == "c" else rdt).eps)
+        eps = refine_eps(rdt)
 
         # The sweeps are traced ONCE, inside the loop body: iteration 0
         # IS the base solve (x=0, r=b), iterations 1.. are refinement —
@@ -3071,8 +3113,7 @@ def make_fused_solver(plan: FactorPlan, dtype=np.float32,
         # logic), but the factor/sweep groups dispatch as per-group
         # programs and the refinement loop runs on the host — compile
         # stays bounded at audikw_1 scale (see staged_enabled)
-        eps = float(np.finfo(rdt.char.lower()
-                             if rdt.kind == "c" else rdt).eps)
+        eps = refine_eps(rdt)
 
         _scale = jax.jit(_scale_impl)
         _pre = jax.jit(_pre_impl)

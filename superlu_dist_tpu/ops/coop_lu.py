@@ -33,6 +33,13 @@ The result F is bitwise identical on every device, so the caller's
 panel extraction, inverse preparation and slab writes run unchanged
 (ops/batched._factor_group_impl); only the tiny-pivot counters must be
 taken from one device (they are replicated too).
+
+Scopes: the panel psums are `slu.coop.psum`, the recombination
+all_gather `slu.coop.gather` (one vocabulary with ops/coop_sharded.py,
+whose chain has psums only).  `_panel_eliminate_planes` is the panel
+chain in pair arithmetic (complex on real/imaginary planes,
+ops/pair_lu), which the sharded chain's pair port shares; this legacy
+scheme itself has no pair port.
 """
 
 from __future__ import annotations
@@ -44,6 +51,8 @@ import jax.numpy as jnp
 
 from .batched import psum_exact as _psum
 from .dense_lu import _newton_tri_inverse, _tiny_replace, _DIAG_UNROLL
+from .pair_lu import (_map, _sub, _tiny_replace_planes, _where, pdiv,
+                      pmul)
 
 
 def _pick_pb(wb: int, pb_max: int = 64) -> int:
@@ -96,6 +105,45 @@ def _panel_eliminate(P, k0, thresh, *, pb: int, mb: int):
     return jax.lax.fori_loop(0, pb // cu, chunk, (P, zero, zero))
 
 
+def _panel_eliminate_planes(P, k0, thresh, *, pb: int, mb: int):
+    """`_panel_eliminate` on planes P = (Pr, Pi), each (mb, pb): the
+    same masked rank-1 chain with the pivot's division and the outer
+    product in pair arithmetic (ops/pair_lu; elementwise, exact like
+    the real chain's broadcast multiply)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (mb, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, pb), 1)
+
+    def step(t, carry):
+        P, tiny, nzero = carry
+        g = k0 + t
+        is_t = cols == t
+        at_g = rows == g
+        ck = _map(lambda p: jnp.sum(jnp.where(is_t, p, 0), axis=1,
+                                    keepdims=True), P)
+        piv = _map(lambda c: jnp.sum(jnp.where(at_g, c, 0)), ck)
+        piv, was_tiny, was_zero = _tiny_replace_planes(piv, thresh)
+        below = rows > g
+        scaled = _where(below, pdiv(ck, piv), ck)
+        P = _where(is_t, _where(at_g, piv, scaled), P)
+        rk = _map(lambda p: jnp.sum(jnp.where(at_g, p, 0), axis=0,
+                                    keepdims=True), P)
+        upd = pmul(_map(lambda s: jnp.where(below, s, 0), scaled),
+                   _map(lambda r: jnp.where(cols > t, r, 0), rk))
+        return _sub(P, upd), tiny + was_tiny, nzero + was_zero
+
+    cu = max(1, min(_DIAG_UNROLL, pb))
+    while pb % cu:
+        cu -= 1
+
+    def chunk(c, carry):
+        for i in range(cu):
+            carry = step(c * cu + i, carry)
+        return carry
+
+    zero = jnp.zeros((), jnp.int32)
+    return jax.lax.fori_loop(0, pb // cu, chunk, (P, zero, zero))
+
+
 def _coop_lu_one(F, thresh, *, wb: int, mb: int, mbp: int, cb: int,
                  pb: int, axis):
     """Cooperative partial LU of ONE front.  F (mb, mbp) is the
@@ -118,7 +166,8 @@ def _coop_lu_one(F, thresh, *, wb: int, mb: int, mbp: int, cb: int,
         # one panel may straddle an ownership boundary)
         panel = jax.lax.dynamic_slice(F, (0, k0), (mb, pb))
         own = (k0 + cols_pb) // cb == dev
-        panel = _psum(jnp.where(own, panel, 0), axis)
+        with jax.named_scope("slu.coop.psum"):
+            panel = _psum(jnp.where(own, panel, 0), axis)
         panel, t_g, z_g = _panel_eliminate(panel, k0, thresh,
                                            pb=pb, mb=mb)
         tiny, nzero = tiny + t_g, nzero + z_g
@@ -155,7 +204,8 @@ def _coop_lu_one(F, thresh, *, wb: int, mb: int, mbp: int, cb: int,
     # floating-point adds at all.  Values are bitwise identical.
     if wb < mbp:
         mysl = jax.lax.dynamic_slice(F, (zero_i, my0), (mb, cb))
-        allsl = jax.lax.all_gather(mysl, axis)        # (ndev, mb, cb)
+        with jax.named_scope("slu.coop.gather"):
+            allsl = jax.lax.all_gather(mysl, axis)    # (ndev, mb, cb)
         full = jnp.moveaxis(allsl, 0, 1).reshape(mb, mbp)
         F = jnp.concatenate([F[:, :wb], full[:, wb:]], axis=1)
     return F, tiny, nzero

@@ -54,9 +54,12 @@ from ..utils.compat import shard_map as _shard_map
 from ..ops.batched import (_bwd_group_impl, _bwd_group_T_impl, _dec,
                            _enc, _factor_group_impl,
                            _flat_axis_index, _fwd_group_impl,
-                           _fwd_group_T_impl, _hi_prec, _real_dtype,
+                           _fwd_group_T_impl, _hi_prec, _lu_is_pair,
+                           _pair_decode_sol, _pair_encode_rhs,
+                           _pair_encode_vals, _real_dtype,
                            _solve_view, _thresh_for, get_schedule,
                            psum_exact)
+from ..utils.platform import complex_lowering
 
 
 def _resolve_axis(mesh: Mesh, axis):
@@ -296,40 +299,90 @@ def _factor_operands(plan, dsched, per, sharded_in):
     return sel, functools.cache(build)
 
 
-# Complex systems keep the ROUND-3 replicated-vals program shape and
-# real systems get the sharded input: the XLA:CPU forced-multi-device
-# client's per-process complex miscompile lottery (lottery_util
-# docstring) turned out to be acutely sensitive to the assembly
-# program's shape — measured per-draw clean rates on the coop-complex
-# body: replicated vals 4/5 (the documented ~1-in-5 loss), sharded
-# complex operands 2/5, sharded real/imag-plane operands 0/6.  Every
-# variation re-rolls unknown odds, so the policy is: pin the
-# best-measured shape for complex on this client, shard the real path
-# (which has never drawn a loss).  On a TPU mesh complex is refused
-# outright (utils/platform.complex_mesh_blocked).
+# Which numeric input a mesh program takes.  Real systems and
+# PAIR-lowered complex systems (real/imaginary planes, what complex
+# takes on a TPU mesh: utils/platform.complex_lowering) get the
+# sharded input: each device its own slice of the values, a pair
+# program both planes of it.  NATIVE complex (an XLA:CPU mesh without
+# the tests' hook) keeps the ROUND-3 replicated-vals program shape:
+# the XLA:CPU forced-multi-device client's per-process complex
+# miscompile lottery (lottery_util docstring) turned out to be acutely
+# sensitive to the assembly program's shape — measured per-draw clean
+# rates on the coop-complex body: replicated vals 4/5 (the documented
+# ~1-in-5 loss), sharded complex operands 2/5, sharded real/imag-plane
+# operands under complex arithmetic 0/6.  Every variation re-rolls
+# unknown odds, so the policy is: pin the best-measured shape for
+# native complex on this client.  A pair program holds no complex
+# operation at all, is outside that lottery like every all-real
+# program (which has never drawn a loss), and shards as the real path
+# does.
 
 
-def _shard_vals(dtype) -> bool:
-    return np.dtype(dtype).kind != "c"
+def _pair(dtype, mesh) -> bool:
+    """Complex in pair storage on this mesh?  THE rule's answer
+    (utils/platform.complex_lowering), judged on the mesh's devices."""
+    return complex_lowering(dtype, mesh) == "pair"
+
+
+def _shard_vals(dtype, pair: bool = False) -> bool:
+    return pair or np.dtype(dtype).kind != "c"
+
+
+def _pair_spec(axis, pair: bool):
+    """A flat's PartitionSpec: the element axis shards over the mesh,
+    under the leading plane axis of pair storage."""
+    return P(None, axis) if pair else P(axis)
+
+
+def _host_vals(vals, sel, dtype, pair: bool):
+    """The host's one-time redistribution of the numeric input
+    (dReDistribute_A analog, pddistribute.c:66): each device's slice
+    of the values, (ndev, Lsel) — in pair storage both planes of it,
+    (ndev, 2, Lsel), encoded here on the host (a complex→real
+    extraction inside the program would be a complex operation)."""
+    if not pair:
+        return np.asarray(vals)[sel]
+    with obs.span("pair.encode", cat="fact"):
+        return np.moveaxis(_pair_encode_vals(vals, dtype)[:, sel], 0, 1)
+
+
+def encode_rhs(bb: np.ndarray, blocks: int = 1) -> np.ndarray:
+    """The sweeps' real-view encoding of a complex right-hand side, on
+    the host (`ops/batched._pair_encode_rhs`: real and imaginary
+    halves side by side along the rhs axis, (n, R) -> (n, 2R)).  With
+    `blocks` > 1 (the rhs-sharded sweep, R a multiple of it) each of
+    the `blocks` column slices is encoded by itself, so that a
+    device's slice of the columns holds both halves of its own
+    right-hand sides."""
+    n = bb.shape[0]
+    return _pair_encode_rhs(bb.reshape(n, blocks, -1)).reshape(n, -1)
+
+
+def decode_sol(X: np.ndarray, xdt, blocks: int = 1) -> np.ndarray:
+    """Invert `encode_rhs` on the solved X (host side)."""
+    n = X.shape[0]
+    return _pair_decode_sol(X.reshape(n, blocks, -1), xdt).reshape(n, -1)
 
 
 def _aot_wrap_dist(name: str, jfn, dsched, mesh, axis, dtype,
-                   trans: bool):
+                   trans: bool, pair: bool = False):
     """AOT-wrap a shard_map'd dist solve program (resilience/aot.py,
     ISSUE 17) — fingerprint carries the mesh legs (shape + axis +
     device kinds) on top of the schedule layout, so a cold process
     deserializes the export only for the IDENTICAL mesh and refuses
-    typed (AotMismatch) otherwise.  Complex lanes are never wrapped
-    (the platform-gate note at batched._phase_fns); an unexportable
-    shard_map falls back to the plain jit inside AotJit."""
-    if np.dtype(dtype).kind == "c":
+    typed (AotMismatch) otherwise.  Natively complex lanes are never
+    wrapped (the platform-gate note at batched._phase_fns); a pair
+    program is all-real and is wrapped like a real one.  An
+    unexportable shard_map falls back to the plain jit inside
+    AotJit."""
+    if np.dtype(dtype).kind == "c" and not pair:
         return jfn
     from ..resilience import aot
     return aot.wrap_jit(
         name, jfn,
         aot.schedule_fingerprint(
             dsched, dtype,
-            extra=(name, bool(trans))
+            extra=(name, bool(trans), bool(pair))
             + aot.mesh_fingerprint_legs(mesh, axis)))
 
 
@@ -345,7 +398,8 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     dtype = np.dtype(dtype)
     thresh_np = _thresh_for(plan, dtype)
 
-    sharded_in = _shard_vals(dtype)
+    pair = _pair(dtype, mesh)
+    sharded_in = _shard_vals(dtype, pair)
     sel, idx_args = _factor_operands(plan, dsched, 7, sharded_in)
     vspec = P(axis) if sharded_in else P()
     idx_specs = (P(axis),) * (7 * len(dsched.groups))
@@ -354,10 +408,11 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
         per_group = _regroup(dsched, idx_flat, 7)
         flats = _factor_loop(dsched,
                              vals[0] if sharded_in else vals,
-                             thresh_np, dtype, per_group, axis)[:4]
+                             thresh_np, dtype, per_group, axis,
+                             pair=pair)[:4]
         solve_idx = [(t[5], t[6]) for t in per_group]
         return _solve_loop(dsched, flats, b, dtype, solve_idx, axis,
-                           trans=False)
+                           trans=False, pair=pair)
 
     mapped = _shard_map(
         body, mesh=mesh, in_specs=(vspec, P()) + idx_specs,
@@ -371,12 +426,19 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     def step(vals, b):
         # host-side one-time redistribution (dReDistribute_A analog):
         # each device's jit operand is its own value slice, committed
-        # to its shard — never the whole array.  Complex keeps the
-        # replicated round-3 shape (_shard_vals note).
-        if sharded_in:
-            return jitted(
-                jax.device_put(np.asarray(vals)[sel], vshard), b)
-        return jitted(jnp.asarray(vals), b)
+        # to its shard — never the whole array.  Native complex keeps
+        # the replicated round-3 shape (_shard_vals note); in pair
+        # storage the host encodes values and right-hand side and
+        # decodes the answer, which then comes back as a host array.
+        if not sharded_in:
+            return jitted(jnp.asarray(vals), b)
+        vv = jax.device_put(_host_vals(vals, sel, dtype, pair), vshard)
+        if not pair:
+            return jitted(vv, b)
+        bb = np.asarray(b)
+        xdt = np.promote_types(dtype, bb.dtype)
+        return decode_sol(np.asarray(jitted(vv, encode_rhs(
+            bb.astype(xdt)))), xdt)
 
     step.jitted = jitted
     step.sel = sel
@@ -393,7 +455,9 @@ def make_dist_step(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
 class DistLU:
     """Factor slabs sharded over the mesh (dLocalLU_t analog: each
     device holds its front partition's panels; flats are the
-    ndev-concatenated global arrays, device-major)."""
+    ndev-concatenated global arrays, device-major).  In pair storage
+    (complex on a TPU mesh) each flat is (2, ndev * total) real
+    planes, the element axis sharded the same way."""
     plan: FactorPlan
     mesh: Mesh
     axis: object
@@ -404,6 +468,20 @@ class DistLU:
     Li_flat: jnp.ndarray
     Ui_flat: jnp.ndarray
     tiny_pivots: int
+    # what the mesh factorization ran on, for `Stats.dispatch` and the
+    # health ring's `last_factor` (`_mesh_route`)
+    route: dict | None = None
+
+
+def _mesh_route(dsched, dtype) -> dict:
+    """A mesh factorization's record: the devices it ran on, its
+    cooperative tree-top groups, and the schedule's predicted
+    collective bytes a factorization and a one-column solve
+    (`comm_summary` at the factor dtype's width, which is the planes'
+    in pair storage)."""
+    return {"devices": int(dsched.ndev),
+            "coop_groups": sum(1 for g in dsched.groups if g.coop),
+            "comm_bytes": dsched.comm_summary(dtype)}
 
 
 def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
@@ -415,30 +493,33 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     dtype = np.dtype(dtype)
     thresh_np = _thresh_for(plan, dtype)
 
-    sharded_in = _shard_vals(dtype)
+    pair = _pair(dtype, mesh)
+    sharded_in = _shard_vals(dtype, pair)
     sel, idx_args = _factor_operands(plan, dsched, 5, sharded_in)
     vspec = P(axis) if sharded_in else P()
     idx_specs = (P(axis),) * (5 * len(dsched.groups))
+    fspec = _pair_spec(axis, pair)
 
     def body(vals, *idx_flat):
         per_group = _regroup(dsched, idx_flat, 5)
         L, U, Li, Ui, tiny, nzero = _factor_loop(
             dsched, vals[0] if sharded_in else vals, thresh_np,
-            dtype, per_group, axis)
+            dtype, per_group, axis, pair=pair)
         return (L, U, Li, Ui, jax.lax.psum(tiny, axis),
                 jax.lax.psum(nzero, axis))
 
     mapped = _shard_map(
         body, mesh=mesh, in_specs=(vspec,) + idx_specs,
-        out_specs=(P(axis), P(axis), P(axis), P(axis), P(), P()),
+        out_specs=(fspec, fspec, fspec, fspec, P(), P()),
         check_vma=False)
     # AOT persistence (resilience/aot.py, ISSUE 17): the shard_map'd
     # whole-phase factor exports like the single-device phase programs
     # — the fingerprint gains the mesh legs (shape + axis + device
     # kinds) so a mesh reshape refuses typed instead of dispatching a
-    # program compiled for a different collective topology.  Complex
-    # lanes skip AOT (the platform-gate note at batched._phase_fns),
-    # and an unexportable shard_map falls back to the plain jit inside
+    # program compiled for a different collective topology.  Natively
+    # complex lanes skip AOT (the platform-gate note at
+    # batched._phase_fns), a pair program is all-real and exports;
+    # an unexportable shard_map falls back to the plain jit inside
     # AotJit — never a dispatch break.
     from ..resilience import aot
     # named for the profiler and for the persistent-cache key (the
@@ -453,17 +534,19 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
             "dist_factor", factor_fn,
             aot.schedule_fingerprint(
                 dsched, dtype,
-                extra=("dist_factor",)
+                extra=("dist_factor", bool(pair))
                 + aot.mesh_fingerprint_legs(mesh, axis)))
     jitted = obs.watch_jit("dist_factor", factor_fn)
     vshard = jax.sharding.NamedSharding(mesh, P(axis))
+    route = _mesh_route(dsched, dtype)
 
     def factor(vals) -> DistLU:
         # host-side one-time redistribution (dReDistribute_A analog,
         # pddistribute.c:66): ship each device ONLY its slice,
-        # committed to its shard.  Complex keeps the replicated
-        # round-3 shape (_shard_vals note).
-        vv = (jax.device_put(np.asarray(vals)[sel], vshard)
+        # committed to its shard (`_host_vals`: both planes of it in
+        # pair storage).  Native complex keeps the replicated round-3
+        # shape (_shard_vals note).
+        vv = (jax.device_put(_host_vals(vals, sel, dtype, pair), vshard)
               if sharded_in else jnp.asarray(vals))
         L, U, Li, Ui, tiny, nzero = jitted(vv)
         if int(nzero) > 0:
@@ -471,16 +554,31 @@ def make_dist_factor(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
                 f"{int(nzero)} exactly-zero pivot(s); matrix singular")
         return DistLU(plan=plan, mesh=mesh, axis=axis, dtype=dtype,
                       schedule=dsched, L_flat=L, U_flat=U, Li_flat=Li,
-                      Ui_flat=Ui, tiny_pivots=int(tiny))
+                      Ui_flat=Ui, tiny_pivots=int(tiny), route=route)
 
     factor.jitted = jitted  # exposed for HLO inspection (measure_comm)
     factor.sel = sel        # per-device value-slice indices
+    factor.pair = pair
     return factor
+
+
+def dist_factor_fn(plan: FactorPlan, mesh: Mesh, dtype):
+    """`make_dist_factor`'s closure for this mesh, dtype and lowering,
+    cached on the PLAN: what `factorize(grid=)` runs and what
+    `measure_comm` lowers."""
+    cache = getattr(plan, "_dist_factor_fns", None)
+    if cache is None:
+        cache = plan._dist_factor_fns = {}
+    dtype = np.dtype(dtype)
+    key = (mesh, dtype.str, _pair(dtype, mesh))
+    if key not in cache:
+        cache[key] = make_dist_factor(plan, mesh, dtype=dtype)
+    return cache[key]
 
 
 def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                            dtype=np.float64, axis=None,
-                           trans: bool = False):
+                           trans: bool = False, pair: bool = False):
     """Row-partitioned merged mesh trisolve (SLU_TRISOLVE=merged on a
     mesh): one solve spans devices over the lsum layout
     (ops/trisolve.py).  Each device sweeps its own front partition —
@@ -518,9 +616,13 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
         per_group = [tuple(next(it)[0] for _ in range(3))
                      for _ in ts.groups]
         di = _flat_axis_index(axis)
-        xdt = jnp.promote_types(dtype, b.dtype)
-        cplx = bool(jnp.issubdtype(xdt, jnp.complexfloating))
-        B0 = _enc(b.astype(xdt), cplx)
+        if pair:
+            # (2, N) plane flats; b arrives encoded from the host
+            cplx, B0 = True, b
+        else:
+            xdt = jnp.promote_types(dtype, b.dtype)
+            cplx = bool(jnp.issubdtype(xdt, jnp.complexfloating))
+            B0 = _enc(b.astype(xdt), cplx)
         R = B0.shape[-1]
         rdt = B0.dtype
         B, UPD, Y = tsv.init_lsum_buffers(ts, B0)
@@ -562,12 +664,11 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                                      per_group[i], cplx, trans)
         XF, _ = sync(XF, XFs)     # replicate the final solution
         x = XF[jnp.asarray(ts.final_idx)]
-        return _dec(x, cplx)
+        return x if pair else _dec(x, cplx)   # pair: host decodes
 
     mapped = _shard_map(
         _hi_prec(body), mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P(axis), P())
-        + idx_specs,
+        in_specs=(_pair_spec(axis, pair),) * 4 + (P(),) + idx_specs,
         out_specs=P(), check_vma=False)
 
     @jax.jit
@@ -575,7 +676,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
         return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args())
 
     solve = _aot_wrap_dist("dist_solve_merged", slu_dist_solve_merged,
-                           dsched, mesh, axis, dtype, trans)
+                           dsched, mesh, axis, dtype, trans, pair)
     return obs.watch_jit("dist_solve_merged", solve)
 
 
@@ -648,9 +749,10 @@ def mesh_oracle_solve(dlu: DistLU, b_factor_order,
 
 
 def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
-                    axis=None, trans: bool = False):
+                    axis=None, trans: bool = False, pair: bool = False):
     """Build `solve(L, U, Li, Ui, b) -> x` against persistent sharded
-    factors.  b (n, nrhs) in factor ordering."""
+    factors.  b (n, nrhs) in factor ordering; against pair-stored
+    factors b is the host's encoding (`encode_rhs`) and so is x."""
     axis, ndev = _resolve_axis(mesh, axis)
     dsched = get_schedule(plan, ndev)
     dtype = np.dtype(dtype)
@@ -661,11 +763,12 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
     def body(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_flat):
         per_group = _regroup(dsched, idx_flat, 2)
         return _solve_loop(dsched, (L_flat, U_flat, Li_flat, Ui_flat),
-                           b, dtype, per_group, axis, trans=trans)
+                           b, dtype, per_group, axis, trans=trans,
+                           pair=pair)
 
     mapped = _shard_map(
         body, mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P(axis), P()) + idx_specs,
+        in_specs=(_pair_spec(axis, pair),) * 4 + (P(),) + idx_specs,
         out_specs=P(), check_vma=False)
 
     @jax.jit
@@ -673,13 +776,14 @@ def make_dist_solve(plan: FactorPlan, mesh: Mesh, dtype=np.float64,
         return mapped(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_args())
 
     solve = _aot_wrap_dist("dist_solve", slu_dist_solve, dsched, mesh,
-                           axis, dtype, trans)
+                           axis, dtype, trans, pair)
     return obs.watch_jit("dist_solve", solve)
 
 
 def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
                                 dtype=np.float64, axis=None,
-                                trans: bool = False):
+                                trans: bool = False,
+                                pair: bool = False):
     """Many-RHS distributed solve: shard X by RHS COLUMNS instead of
     replicating it.  Each device all_gathers the factor slabs ONCE
     (device-major concatenation IS the global layout) and then sweeps
@@ -693,7 +797,10 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
     words of psum — the gather amortizes over RHS columns, so this
     wins when nrhs is large (dist_solve auto-selects at
     nrhs ≥ 2·ndev).  `b` (n, nrhs) in factor ordering; nrhs is padded
-    to a multiple of ndev internally."""
+    to a multiple of ndev internally.  Against pair-stored factors `b`
+    is the host's encoding in `ndev` column blocks
+    (`encode_rhs(bb, blocks=ndev)`), so that each device's slice holds
+    both halves of its own right-hand sides."""
     axis, ndev = _resolve_axis(mesh, axis)
     dsched = get_schedule(plan, ndev)
     dtype = np.dtype(dtype)
@@ -712,8 +819,9 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
                     for g in dsched.groups]
 
     def body(L_flat, U_flat, Li_flat, Ui_flat, b):
-        flats = [_solve_view(jax.lax.all_gather(f, axis, tiled=True))
-                 for f in (L_flat, U_flat, Li_flat, Ui_flat)]
+        flats = [_solve_view(jax.lax.all_gather(
+            f, axis, axis=f.ndim - 1, tiled=True))
+            for f in (L_flat, U_flat, Li_flat, Ui_flat)]
         L, U, Li, Ui = flats
 
         def gsl(flat, off: int, size: int):
@@ -725,11 +833,15 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
             return (flat.reshape(ndev, -1)[:, off:off + size]
                     .reshape(ndev * size))
 
-        xdt = jnp.promote_types(dtype, b.dtype)
-        cplx = bool(jnp.issubdtype(xdt, jnp.complexfloating))
-        X = jnp.zeros((n + 1, b.shape[1]), xdt)
-        X = X.at[:n, :].set(b.astype(xdt))
-        X = _enc(X, cplx)
+        if pair:
+            cplx = True
+            X = jnp.zeros((n + 1, b.shape[1]), b.dtype).at[:n].set(b)
+        else:
+            xdt = jnp.promote_types(dtype, b.dtype)
+            cplx = bool(jnp.issubdtype(xdt, jnp.complexfloating))
+            X = jnp.zeros((n + 1, b.shape[1]), xdt)
+            X = X.at[:n, :].set(b.astype(xdt))
+            X = _enc(X, cplx)
         z = jnp.int32(0)
 
         if not trans:
@@ -761,16 +873,16 @@ def make_dist_solve_rhs_sharded(plan: FactorPlan, mesh: Mesh,
                        gsl(bwd_src[1], o2, g.n_loc * s2), ci, si,
                        z, z, mb=g.mb, wb=g.wb,
                        n_pad=ndev * g.n_loc, cplx=cplx)
-        return _dec(X, cplx)[:n]
+        return X[:n] if pair else _dec(X, cplx)[:n]
 
     mapped = _shard_map(
         _hi_prec(body), mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P(axis), P(None, axis)),
+        in_specs=(_pair_spec(axis, pair),) * 4 + (P(None, axis),),
         out_specs=P(None, axis), check_vma=False)
     jitted = obs.watch_jit(
         "dist_solve_rhs_sharded",
         _aot_wrap_dist("dist_solve_rhs_sharded", jax.jit(mapped),
-                       dsched, mesh, axis, dtype, trans))
+                       dsched, mesh, axis, dtype, trans, pair))
 
     def solve(L_flat, U_flat, Li_flat, Ui_flat, b):
         r = b.shape[1]
@@ -796,37 +908,24 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
     recompiled."""
     from ..utils.stats import hlo_collective_stats
     plan = dlu.plan
-    fcache = getattr(plan, "_dist_factor_fns", None)
-    if fcache is None:
-        fcache = plan._dist_factor_fns = {}
-    fkey = (dlu.mesh, dlu.dtype.str)
-    if fkey not in fcache:
-        fcache[fkey] = make_dist_factor(plan, dlu.mesh,
-                                        dtype=dlu.dtype, axis=dlu.axis)
-    factor = fcache[fkey]
-    scache = getattr(plan, "_dist_solve_fns", None)
-    if scache is None:
-        scache = plan._dist_solve_fns = {}
+    factor = dist_factor_fn(plan, dlu.mesh, dlu.dtype)
     _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
     # measure the solve program dist_solve actually runs at this nrhs
     from ..ops import trisolve as tsv
     sharded_rhs = _rhs_sharded_auto(nrhs, ndev)
     merged = tsv.mesh_merged_on() and not sharded_rhs
-    skey = (dlu.mesh, dlu.dtype.str, dlu.axis, False, sharded_rhs,
-            merged)
-    if skey not in scache:
-        mk = (make_dist_solve_rhs_sharded if sharded_rhs
-              else (make_dist_solve_merged if merged
-                    else make_dist_solve))
-        scache[skey] = mk(plan, dlu.mesh, dtype=dlu.dtype,
-                          axis=dlu.axis, trans=False)
-    solve = scache[skey]
+    solve = _solve_fn(dlu, False, sharded_rhs, merged)
     # lower with the dtype production traced with: factor consumes
     # plan.scaled_values(a) — f64 for real systems, c128 for complex —
     # NOT the factor dtype (the cast happens inside the program); a
     # mismatched aval here would force a pointless full recompile
-    if factor.sel is None:      # complex: replicated round-3 shape
+    pair = _lu_is_pair(dlu)
+    rdt = _real_dtype(dlu.dtype)
+    if factor.sel is None:      # native complex: replicated round-3
         vals = jnp.zeros(len(plan.coo_rows), np.complex128)
+    elif pair:                  # both planes of each device's slice
+        nd, lsel = factor.sel.shape
+        vals = jnp.zeros((nd, 2, lsel), rdt)
     else:
         vals = jnp.zeros(factor.sel.shape, np.float64)
     out = {}
@@ -835,11 +934,13 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
     if sharded_rhs:
         # the wrapper pads nrhs to a ndev multiple before its jit
         pad_r = nrhs + (-nrhs) % ndev
-        b = jnp.zeros((dlu.schedule.n, pad_r), dlu.dtype)
         lowerable = solve.jitted
     else:
-        b = jnp.zeros((dlu.schedule.n, nrhs), dlu.dtype)
+        pad_r = nrhs
         lowerable = solve
+    # a pair solve takes the host's encoding: two real columns a rhs
+    b = (jnp.zeros((dlu.schedule.n, 2 * pad_r), rdt) if pair
+         else jnp.zeros((dlu.schedule.n, pad_r), dlu.dtype))
     txt = lowerable.lower(dlu.L_flat, dlu.U_flat, dlu.Li_flat,
                           dlu.Ui_flat, b).compile().as_text()
     out["SOLVE"] = hlo_collective_stats(txt)
@@ -895,15 +996,35 @@ def _rhs_sharded_auto(nrhs: int, ndev: int) -> bool:
     return nrhs >= 2 * ndev
 
 
-def dist_solve(dlu: DistLU, b_factor_order, trans: bool = False):
-    """Solve against a DistLU.  Compiled solves are cached on the PLAN
-    keyed (mesh, dtype, trans, mode), so SamePattern re-factorizations
-    reuse them across handles.  Many-RHS solves auto-select the
-    rhs-sharded sweep (make_dist_solve_rhs_sharded)."""
+def _solve_fn(dlu: DistLU, trans: bool, sharded_rhs: bool,
+              merged: bool):
+    """The compiled solve for this handle's mesh, dtype and storage,
+    cached on the PLAN so SamePattern re-factorizations reuse it
+    across handles."""
     plan = dlu.plan
     cache = getattr(plan, "_dist_solve_fns", None)
     if cache is None:
         cache = plan._dist_solve_fns = {}
+    pair = _lu_is_pair(dlu)
+    key = (dlu.mesh, dlu.dtype.str, dlu.axis, trans, sharded_rhs,
+           merged, pair)
+    if key not in cache:
+        mk = (make_dist_solve_rhs_sharded if sharded_rhs
+              else (make_dist_solve_merged if merged
+                    else make_dist_solve))
+        cache[key] = mk(plan, dlu.mesh, dtype=dlu.dtype,
+                        axis=dlu.axis, trans=trans, pair=pair)
+    return cache[key]
+
+
+def dist_solve(dlu: DistLU, b_factor_order, trans: bool = False):
+    """Solve against a DistLU.  Compiled solves are cached on the PLAN
+    keyed (mesh, dtype, trans, mode, storage), so SamePattern
+    re-factorizations reuse them across handles.  Many-RHS solves
+    auto-select the rhs-sharded sweep (make_dist_solve_rhs_sharded).
+    Against pair-stored factors the host encodes the right-hand side
+    and decodes the answer (spans `slu.pair.encode` /
+    `slu.pair.decode`), and the answer is a host array."""
     nrhs = int(b_factor_order.shape[1]) \
         if getattr(b_factor_order, "ndim", 1) == 2 else 1
     _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
@@ -913,16 +1034,27 @@ def dist_solve(dlu: DistLU, b_factor_order, trans: bool = False):
     # trisolve replaces the replicated-X psum sweep (narrow-RHS lane
     # only — wide RHS keeps the gather-amortized rhs-sharded sweep)
     merged = tsv.mesh_merged_on() and not sharded_rhs
-    key = (dlu.mesh, dlu.dtype.str, dlu.axis, trans, sharded_rhs,
-           merged)
-    if key not in cache:
-        mk = (make_dist_solve_rhs_sharded if sharded_rhs
-              else (make_dist_solve_merged if merged
-                    else make_dist_solve))
-        cache[key] = mk(plan, dlu.mesh, dtype=dlu.dtype,
-                        axis=dlu.axis, trans=trans)
-    return cache[key](dlu.L_flat, dlu.U_flat, dlu.Li_flat,
-                      dlu.Ui_flat, b_factor_order)
+    solve = _solve_fn(dlu, trans, sharded_rhs, merged)
+    flats = (dlu.L_flat, dlu.U_flat, dlu.Li_flat, dlu.Ui_flat)
+    if not _lu_is_pair(dlu):
+        return solve(*flats, b_factor_order)
+    bb = np.asarray(b_factor_order)
+    squeeze = bb.ndim == 1
+    bb = bb[:, None] if squeeze else bb
+    xdt = np.promote_types(dlu.dtype, bb.dtype)
+    blocks = ndev if sharded_rhs else 1
+    with obs.span("pair.encode", cat="solve"):
+        pad = (-nrhs) % blocks
+        if pad:
+            bb = np.concatenate(
+                [bb, np.zeros((bb.shape[0], pad), bb.dtype)], axis=1)
+        b_in = encode_rhs(bb.astype(xdt), blocks)
+    X = solve(*flats, b_in)
+    with obs.span("solve.fetch", cat="solve"):
+        X = np.asarray(X)
+    with obs.span("pair.decode", cat="solve"):
+        x = decode_sol(X, xdt, blocks)[:, :nrhs]
+    return x[:, 0] if squeeze else x
 
 
 # --------------------------------------------------------------------

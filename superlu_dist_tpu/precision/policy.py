@@ -107,6 +107,30 @@ def sweep_operand_dtype(factor_dtype, operand_dtype) -> np.dtype:
     return fdt
 
 
+def refine_eps(refine_dtype) -> float:
+    """The berr at which a refinement loop stops: the ONE threshold of
+    the host loop (models/refine.iterative_refine) and of the fused
+    device loops (ops/batched.make_fused_solver), the rounding unit of
+    a product in the accumulator's arithmetic.
+
+    A real accumulator: eps(refine_dtype), the reference's class
+    (pdgsrfs.c: berr <= eps ends the loop).  A complex one: sqrt(2)
+    times the eps of the real dtype of the same width, because a
+    complex product rounds by up to sqrt(2)·gamma_2 = 2·sqrt(2)·u,
+    u = eps/2 (Higham, Accuracy and Stability of Numerical
+    Algorithms, Lemma 3.5), where a real one rounds by u.  What it
+    decides: the berr of a complex128 answer whose last correction
+    has arrived stands at 0.97-1.43 eps(float64) (61 value sets of
+    ex11 -n 256 on the chip, PERF.md section 6, PR 44), astride the
+    real threshold, so whether the loop paid one more sweep, which
+    gains nothing, fell by value set: three passes or four, 0.07 s
+    apart on the 2x2 grid."""
+    rdt = np.dtype(refine_dtype)
+    if rdt.kind == "c":
+        return float(np.finfo(rdt.char.lower()).eps) * 2.0 ** 0.5
+    return float(np.finfo(rdt).eps)
+
+
 def _eps(dtype_name: str) -> float:
     """eps of a dtype name; jnp.finfo understands the ml_dtypes
     families (bfloat16) that numpy's doesn't."""

@@ -18,25 +18,32 @@ factor dtype runs ON THE TPU through the pair lowering (real and
 imaginary planes, no complex op in the program: `ops/pair_lu`,
 `ops/batched._factor_group_impl_pair`, the sweeps' real-view codec),
 on every path that implements it — `factorize`, `factorize(plan=...)`
-and `solve` on one device, one-program and staged; on any other
-backend it stays native.  No environment variable is needed
-(PR 32: PETSc ex11's Helmholtz system, n=65,536, runs so in the
-benchmark's cell `helm2d_n512.zstep`).  Each factorization and solve
-says which lowering it took on `Stats.complex_lowering`.
+and `solve` on one device, one-program and staged, and on a process
+grid (`grid=`: `parallel/factor_dist`, whose flats are then (2, N)
+planes sharded over the mesh, with the cooperative tree-top LU of
+`ops/coop_sharded` in pair arithmetic); on any other backend it stays
+native.  A mesh is judged by its own devices, not by the default
+backend.  No environment variable is needed (PR 32: PETSc ex11's
+Helmholtz system, n=65,536, runs so in the benchmark's cell
+`helm2d_n512.zstep`; PR 44: the same system on the 2x2 grid,
+`helm2d_grid2x2.zstep`).  Each factorization and solve says which
+lowering it took on `Stats.complex_lowering`.
 
 What still leaves the chip, loudly: a path that cannot store pairs (a
 caller of `complex_device_gate(pair_capable=False)`; a handle whose
 factors are natively stored) is placed on the host CPU backend — an
 abort would take the caller's process with it — with a
 `ComplexPlacementWarning` naming the dtype and where it went, and
-`Stats.placement` / `Stats.complex_lowering` record "cpu"; a complex
-factorization on a TPU MESH is refused (`complex_mesh_blocked`: pair
-storage is single-device).
+`Stats.placement` / `Stats.complex_lowering` record "cpu".  What has no
+pair storage at all and would compile native complex onto TPU devices
+refuses instead: the fused mesh solver (`ops/batched.make_fused_solver`
+with `mesh=`) and the legacy replicated cooperative LU
+(SLU_COOP_SHARDED=0) raise NotImplementedError there.
 
 `SLU_COMPLEX_PAIR=1` is a TEST HOOK: it forces the pair lowering on
 a backend that would run native (XLA:CPU, where tier-1 runs), and
 decides nothing on a TPU.  `SLU_COMPLEX_TPU=1` runs NATIVE complex on
-the TPU (no pair, no gate, the mesh block lifted), for whoever
+the TPU (no pair, no gate, on one device and on a mesh), for whoever
 repairs the native lowering.  ROADMAP D4 owns deleting the paths this
 rule no longer reaches.
 
@@ -65,24 +72,32 @@ def _is_complex(dtype) -> bool:
     return np.issubdtype(np.dtype(dtype), np.complexfloating)
 
 
-def complex_lowering(dtype) -> str:
+def complex_lowering(dtype, mesh=None) -> str:
     """THE rule: how a program of this factor dtype is lowered on the
     paths that implement both lowerings (module docstring).  "pair":
     stacked real/imaginary planes, an all-real program — what a
-    complex dtype takes on a TPU default backend; "native": the
-    dtype's own arithmetic — every real dtype, and complex on any
-    other backend.  Two environment overrides, neither an option of
-    the program: SLU_COMPLEX_PAIR=1 forces pair wherever it is asked
-    (the tests' hook on XLA:CPU), SLU_COMPLEX_TPU=1 keeps a TPU
-    native."""
+    complex dtype takes on a TPU, one device and a mesh alike;
+    "native": the dtype's own arithmetic — every real dtype, and
+    complex on any other backend.  Where the program runs is the
+    default backend, or, for a program shard_map'd over `mesh`, the
+    mesh's own devices (a TPU mesh built while the default backend is
+    the CPU compiles for the TPU all the same).  Two environment
+    overrides, neither an option of the program: SLU_COMPLEX_PAIR=1
+    forces pair wherever it is asked (the tests' hook on XLA:CPU),
+    SLU_COMPLEX_TPU=1 keeps a TPU native."""
     if not _is_complex(dtype):
         return "native"
     if flags.env_str("SLU_COMPLEX_PAIR", "0") == "1":
         return "pair"
     if flags.env_str("SLU_COMPLEX_TPU", "0") == "1":
         return "native"
-    import jax
-    return "pair" if jax.default_backend() == "tpu" else "native"
+    if mesh is not None:
+        on_tpu = any(d.platform == "tpu"
+                     for d in np.asarray(mesh.devices).flat)
+    else:
+        import jax
+        on_tpu = jax.default_backend() == "tpu"
+    return "pair" if on_tpu else "native"
 
 
 def complex_needs_cpu(dtype, pair_capable: bool = True) -> bool:
@@ -132,23 +147,9 @@ def apply_accel_amalg_defaults() -> None:
         os.environ.setdefault(k, v)
 
 
-def complex_mesh_blocked(dtype, mesh) -> bool:
-    """True when a complex `dtype` is about to compile onto a mesh
-    containing TPU devices (and the override is not set).  Deliberately
-    independent of jax.default_backend(): a TPU mesh built while the
-    default backend is CPU would hit the same compile abort, so the
-    mesh's own devices are the predicate."""
-    if not _is_complex(dtype):
-        return False
-    if flags.env_str("SLU_COMPLEX_TPU", "0") == "1":
-        return False
-    return any(d.platform == "tpu"
-               for d in np.asarray(mesh.devices).flat)
-
-
 @contextlib.contextmanager
 def complex_device_gate(*dtypes, pair_capable: bool = True,
-                        stats=None, phase: str = ""):
+                        stats=None, phase: str = "", mesh=None):
     """Context manager: place jitted programs on the host CPU backend
     when any of `dtypes` trips complex_needs_cpu; no-op otherwise.
     Yields True when the gate engaged.  An engaged gate is never
@@ -168,7 +169,7 @@ def complex_device_gate(*dtypes, pair_capable: bool = True,
     if cplx and stats is not None:
         stats.complex_lowering[phase or "complex"] = (
             "cpu" if gated
-            else complex_lowering(cplx[0]) if pair_capable
+            else complex_lowering(cplx[0], mesh) if pair_capable
             else "native")
     if not gated:
         yield False

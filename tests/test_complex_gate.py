@@ -201,21 +201,3 @@ def test_accel_amalg_defaults(monkeypatch):
     apply_accel_amalg_defaults()
     assert os.environ["SUPERLU_AMALG_TAU_PCT"] == "150"
     assert os.environ["SUPERLU_AMALG_CAP"] == "1024"
-
-
-def test_complex_tpu_mesh_rejected(monkeypatch):
-    """backend='dist' with a TPU mesh and a complex dtype must fail
-    fast with the documented message, not hang in compilation."""
-    from superlu_dist_tpu.models.gssvx import factorize
-
-    class FakeDev:
-        platform = "tpu"
-
-    class FakeMesh:
-        devices = np.array([FakeDev()])
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    a = _cmat()
-    with pytest.raises(ValueError, match="complex factorization on a "
-                                         "TPU mesh is disabled"):
-        factorize(a, Options(), backend="dist", grid=FakeMesh())

@@ -318,3 +318,81 @@ def test_tier_cache_probe_order():
     assert hit is not None
     t_key, t_lu, d = hit
     assert d == "float32" and t_lu is lu32
+
+
+# -- the refinement loops' stopping threshold -------------------------
+
+@pytest.mark.parametrize("dtype,real,factor", [
+    ("float32", "float32", 1.0), ("float64", "float64", 1.0),
+    ("complex64", "float32", 2.0 ** 0.5),
+    ("complex128", "float64", 2.0 ** 0.5)])
+def test_refine_eps_by_accumulator(dtype, real, factor):
+    """A real accumulator stops at its eps (the reference's class); a
+    complex one at sqrt(2) times the eps of its planes (a complex
+    product rounds by sqrt(2)·gamma_2, Higham Lemma 3.5)."""
+    assert pp.refine_eps(dtype) == pytest.approx(
+        factor * float(np.finfo(real).eps), rel=1e-15)
+    assert pp.refine_eps(np.dtype(dtype)) == pp.refine_eps(dtype)
+
+
+def _helmholtz(n, sigma1=100.0, sigma2=10.0):
+    """PETSc ex11's matrix (complex Helmholtz, five-point, -norandom),
+    built from its equation."""
+    h2 = 1.0 / ((n + 1) * (n + 1))
+    t = sp.diags([-1.0, 0.0, -1.0], [-1, 0, 1], shape=(n, n))
+    a = sp.kronsum(t, t, format="csr").astype(np.complex128)
+    return (a + (4.0 - sigma1 * h2 + 1j * sigma2 * h2)
+            * sp.identity(n * n, format="csr")).tocsr()
+
+
+def test_complex_refinement_takes_no_pass_from_the_floor():
+    """The host loop on a complex128 accumulator with complex64
+    corrections (scipy's single-precision LU in the factorization's
+    place: the loop's rule is what is under test, not a kernel).  On
+    ex11 at -n 128 the berr of a refined answer stands at 0.9-1.3
+    eps(float64), astride eps: under the real threshold a value set
+    whose floor read 1.1 eps paid one more sweep, which gained
+    nothing, and one whose floor read 0.95 eps did not.  Under
+    refine_eps every value set stops on the pass that reaches the
+    floor: no pass starts from a berr at or under the threshold, the
+    answers stand inside it, and at least one of them stands above
+    eps(float64), where the real threshold would have gone on."""
+    import threading
+    import types
+
+    import scipy.sparse.linalg as spla
+
+    from superlu_dist_tpu.models.refine import iterative_refine
+    eps = float(np.finfo(np.float64).eps)
+    thresh = pp.refine_eps("complex128")
+    a0 = _helmholtz(128)
+    rng = np.random.default_rng(2147483648)
+    rows = np.diff(a0.indptr)
+    finals = []
+    for _ in range(4):
+        a = a0.copy()
+        a.data = a0.data * np.repeat(rng.uniform(0.5, 1.5, a0.shape[0]),
+                                     rows)
+        xtrue = (rng.standard_normal(a.shape[0])
+                 + 1j * rng.standard_normal(a.shape[0]))
+        b = a @ xtrue
+        lu32 = spla.splu(sp.csc_matrix(a.astype(np.complex64)))
+        handle = types.SimpleNamespace(
+            a=csr_from_scipy(a), refine_cache={},
+            cache_lock=threading.Lock(),
+            effective_options=Options(factor_dtype="complex64",
+                                      refine_dtype="complex128"))
+        x0 = lu32.solve(b.astype(np.complex64)).astype(np.complex128)
+        x, berr, steps, stalled = iterative_refine(
+            handle, b, x0, lambda lu, r: lu32.solve(r),
+            lambda r: r.astype(np.complex64),
+            lambda d: d.astype(np.complex128))
+        from superlu_dist_tpu import obs
+        traj = obs.HEALTH.snapshot()["recent_solves"][-1][
+            "berr_trajectory"]
+        assert len(traj) == steps + 1 and traj[-1] == berr
+        assert all(t > thresh for t in traj[:-1]), traj
+        assert berr <= thresh and not stalled, traj
+        assert np.linalg.norm(x - xtrue) < 1e-9 * np.linalg.norm(xtrue)
+        finals.append(berr)
+    assert max(finals) > eps, finals
