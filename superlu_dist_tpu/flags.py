@@ -34,7 +34,7 @@ FLAGS: dict[str, str] = {
     "SLU_EA_BLOCK": "1/0 block-copy extend-add lane for contiguous child runs (default on)",
     "SLU_EA_BLOCK_MIN_RUN": "minimum contiguous run length routed to the block lane (default 8)",
     # --- blocked trisolve (ops/trisolve.py, parallel/factor_dist.py) ---
-    "SLU_TRISOLVE": "auto|merged|legacy solve arm: merged = the communication-avoiding lsum trisolve (packed panels, dense lsum buffers, zero scatters; bitwise-identical to legacy, pinned); auto = merged on a single device and the legacy X-psum sweep on meshes; an EXPLICIT merged also routes mesh solves through the row-partitioned merged trisolve",
+    "SLU_TRISOLVE": "auto|merged|legacy solve arm: merged = the communication-avoiding lsum trisolve (packed panels, dense lsum buffers, zero scatters; the legacy sweep's arithmetic in its order, agreement to 4 eps pinned); auto = merged, on one device and on a mesh alike (there the row-partitioned merged program, parallel/factor_dist.make_dist_solve_merged, for a narrow rhs); legacy = the scatter-add sweep on one device and the replicated-X psum sweep on a mesh",
     "SLU_TRISOLVE_MERGE_CELLS": "panel-cell bound (trim*mb*wb) under which a group joins a merged dispatch segment (default 65536); larger groups stand alone",
     "SLU_TRISOLVE_SEG_CELLS": "total panel-cell budget of one merged segment (default 1048576) — bounds per-segment staged program size",
     "SLU_TRISOLVE_PALLAS": "1 = fuse each merged forward group's panel-solve + lsum update into the Pallas lsum kernel (ops/pallas_lsum.py; f32/bf16 real only, default off until a chip run prices it)",

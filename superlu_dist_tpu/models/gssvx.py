@@ -339,13 +339,23 @@ def solve(lu: LUFactorization, b: np.ndarray,
     # stored ones cannot take the pair lowering and are gated on a TPU
     stored = "native"
     sweep_segments = None   # programs a sweep dispatches
+    sweep_mesh = {}         # on a mesh: which program, its all-reduces
     if lu.backend in ("jax", "dist"):
         from ..ops.batched import _lu_is_pair, sweep_programs
         stored = "pair" if _lu_is_pair(lu.device_lu) else "native"
     if lu.backend == "jax":
         sweep_segments = sweep_programs(lu.device_lu)
+    elif lu.backend == "dist":
+        from ..parallel import factor_dist
+        arm = factor_dist.solve_arm(lu.device_lu, bb.shape[1])
+        sweep_segments = 1
+        sweep_mesh = {"sweep_arm": arm,
+                      "sweep_syncs": factor_dist.solve_syncs(
+                          lu.device_lu, arm)}
+    if sweep_segments is not None:
         stats.dispatch.update(getattr(lu.device_lu, "route", None) or {},
-                              sweep_segments=sweep_segments)
+                              sweep_segments=sweep_segments,
+                              **sweep_mesh)
 
     def sweep(lu_, v):
         # every triangular sweep — x0's and each refinement
@@ -383,7 +393,8 @@ def solve(lu: LUFactorization, b: np.ndarray,
                     trans=(options.trans == Trans.TRANS),
                     sweeps=sweeps,
                     lowering=stats.complex_lowering.get("SOLVE"),
-                    sweep_segments=sweep_segments)
+                    sweep_segments=sweep_segments,
+                    sweep_mesh=sweep_mesh)
             stats.berr = berr
             stats.refine_steps += steps
             stats.refine_stalled = stalled

@@ -91,13 +91,16 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                      from_factor_sol, trans: bool = False,
                      sweeps: dict | None = None,
                      lowering: str | None = None,
-                     sweep_segments: int | None = None):
+                     sweep_segments: int | None = None,
+                     sweep_mesh: dict | None = None):
     """`sweeps` is the caller's live count of this solve's sweeps by
     operand dtype (x0's included; `solve_factored` adds to it): it
     rides the health ring's record next to `steps`, and so do
     `lowering`, the complex lowering those sweeps ran under
-    (Stats.complex_lowering; None for a real system), and
-    `sweep_segments`, the programs each of them dispatched."""
+    (Stats.complex_lowering; None for a real system),
+    `sweep_segments`, the programs each of them dispatched, and on a
+    mesh `sweep_mesh`: `sweep_arm` and `sweep_syncs`
+    (parallel/factor_dist.solve_arm, solve_syncs)."""
     from ..precision.policy import refine_eps
     opts = lu.effective_options
     # the system's realness is set by matrix AND rhs: a real matrix
@@ -168,7 +171,8 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
                              converged=converged,
                              stalled=stalled, sweeps=sweeps,
                              complex_lowering=lowering,
-                             sweep_segments=sweep_segments)
+                             sweep_segments=sweep_segments,
+                             **(sweep_mesh or {}))
     # `stalled` rides back to the driver: the escalation ladder
     # (gssvx) labels its health event with the signal that fired
     # (precision/policy.classify_trigger), and "the loop quit because

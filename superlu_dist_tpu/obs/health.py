@@ -157,7 +157,9 @@ class HealthMonitor:
                       stalled: bool = False,
                       sweeps: dict | None = None,
                       complex_lowering: str | None = None,
-                      sweep_segments: int | None = None) -> None:
+                      sweep_segments: int | None = None,
+                      sweep_arm: str | None = None,
+                      sweep_syncs: int | None = None) -> None:
         """One refinement loop's outcome.  `ferr_trajectory` is the
         per-step forward-error estimate ‖δ‖/‖x‖ (the correction-norm
         proxy for pdgsrfs' FERR output).  `sweeps` counts the solve's
@@ -167,7 +169,10 @@ class HealthMonitor:
         programs each of them dispatched (ops/batched.sweep_programs:
         1 under the merged trisolve arm on either handle form, a
         program a group each way for a staged handle under the legacy
-        sweep; None off the one-device jax backend).  `stalled` means the loop
+        sweep; 1 on a mesh; None on the host oracle).  On a mesh
+        `sweep_arm` names that program (`merged`, `replicated`,
+        `rhs_sharded`: parallel/factor_dist.solve_arm) and
+        `sweep_syncs` counts its all-reduces.  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""
@@ -186,6 +191,8 @@ class HealthMonitor:
                 "sweeps": dict(sweeps or {}),
                 "complex_lowering": complex_lowering,
                 "sweep_segments": sweep_segments,
+                "sweep_arm": sweep_arm,
+                "sweep_syncs": sweep_syncs,
             })
         if stalled:
             _tracer.instant("health.refine_stalled", cat="health",

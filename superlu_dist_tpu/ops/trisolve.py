@@ -45,7 +45,10 @@ alike — the staged rule is the factor program's, the sweep's program
 is chosen by the arm), the staged fused solver's per-segment
 dispatch, the fused solvers' in-program sweeps, transpose solves, the
 complex pair-plane lane, and the row-partitioned mesh trisolve
-(`parallel/factor_dist.make_dist_solve` with SLU_TRISOLVE=merged).
+(`parallel/factor_dist.make_dist_solve_merged`: what a narrow-rhs
+sweep on a process grid is under this arm, by the same rule as on one
+device, `sweeps_packed()`; SLU_TRISOLVE=legacy selects the
+replicated-X psum sweep there as it selects the legacy sweep here).
 
 Flags (see flags.py): SLU_TRISOLVE selects the arm (auto|merged|
 legacy; auto = merged), SLU_TRISOLVE_MERGE_CELLS /
@@ -85,10 +88,12 @@ def trisolve_mode() -> str:
 
 
 def sweeps_packed() -> bool:
-    """Do solves sweep the packed panels (the merged arm)?  THE rule
+    """Do solves sweep the lsum layout (the merged arm)?  THE rule
     for who packs: `factorize_device` dispatches a factorization's
     pack where this holds and `_solve_device_common` takes the packs
-    where it holds, so the two sites cannot diverge."""
+    where it holds, so the two sites cannot diverge.  A mesh asks the
+    same question (`parallel/factor_dist.solve_arm`): its narrow-rhs
+    sweep is the row-partitioned merged program where this holds."""
     return trisolve_mode() == "merged"
 
 
@@ -114,17 +119,6 @@ def seg_cells_limit() -> int:
         return max(1, flags.env_int("SLU_TRISOLVE_SEG_CELLS", 1048576))
     except ValueError:
         return 1048576
-
-
-def mesh_merged_on() -> bool:
-    """Route MESH solves (parallel/factor_dist.dist_solve) through
-    the row-partitioned merged trisolve?  Requires an EXPLICIT
-    SLU_TRISOLVE=merged — `auto` keeps the proven X-psum sweep on
-    meshes while the merged arm's collective behavior is priced on
-    real hardware (single-device auto is merged: the same
-    arithmetic in strictly fewer ops)."""
-    return flags.env_str("SLU_TRISOLVE",
-                        "auto").strip().lower() == "merged"
 
 
 def active_arm(device_lu=None) -> str:
@@ -416,6 +410,41 @@ def get_trisolve(sched) -> TrisolveSchedule:
         return cache[key]
 
 
+def mesh_sync_ranges(ts: TrisolveSchedule):
+    """What each sync point of the row-partitioned mesh sweep
+    (`parallel/factor_dist.make_dist_solve_merged`) all-reduces.
+    Slot bases grow in group order and every slot is written once, so
+    what was written since the last sync point is ONE contiguous slot
+    range: of UPD before a forward segment that needs its operands
+    reconciled, of XF before a backward visit that does (the segments
+    walked in reverse), and the XF range still open at the end, which
+    the final replicate covers.  Returns (fwd, bwd, last): a (lo, hi)
+    range or None a segment (None: no sync there, or nothing written
+    since the last one), and the last range.  The ranges are disjoint,
+    so a sweep all-reduces at most u_total + y_total slots."""
+    first = [ts.groups[seg[0]] for seg in ts.segments]
+    fwd, lo = [], 0
+    for gs, need in zip(first, ts.seg_fwd_sync):
+        rng = (lo, gs.u_off) if need and gs.u_off > lo else None
+        if rng:
+            lo = gs.u_off
+        fwd.append(rng)
+    bwd, hi = [None] * len(first), ts.y_total
+    for s in reversed(range(len(first))):
+        # written since the last sync: the groups after segment s
+        nxt = first[s + 1].y_off if s + 1 < len(first) else ts.y_total
+        if ts.seg_bwd_sync[s] and hi > nxt:
+            bwd[s], hi = (nxt, hi), nxt
+    return fwd, bwd, (0, hi)
+
+
+def mesh_sync_count(ts: TrisolveSchedule) -> int:
+    """All-reduces one row-partitioned mesh sweep compiles to: one a
+    range of `mesh_sync_ranges`."""
+    fwd, bwd, _ = mesh_sync_ranges(ts)
+    return sum(r is not None for r in fwd + bwd) + 1
+
+
 def _sched_fn(sched, key, build):
     """The watched program cached on a schedule under `key`, built
     once (`build()`) under the lock; the hit path takes none."""
@@ -521,6 +550,22 @@ def _group_flats(sched, flats):
             for g in sched.groups]
 
 
+def pack_flats(ts: TrisolveSchedule, flats):
+    """pack_panels by way of the per-group local flats: the four
+    factor flats are first cut at the groups' offsets
+    (`_group_flats`), then packed as a StagedLU's panels are.  The
+    body of the pack program for a DeviceLU's flats, and of the mesh
+    sweep for a device's slice of a DistLU's.  The barrier after the
+    cut is load-bearing on the TPU: without it the compiler moves a
+    panel's reshape before its static slice wherever the offset is
+    not tile-aligned, i.e. reshapes the WHOLE flat to (N/wb, wb),
+    padded 16-fold at wb=8, once a group (n=27,000, v5e: 75 s of
+    compile, 220 MB of code and 1.1 GB of scratch, against 2 s,
+    11 MB and none with it)."""
+    return pack_panels_staged(ts, jax.lax.optimization_barrier(
+        _group_flats(ts.sched, flats)))
+
+
 def _pack_fn(sched):
     """Cached watched jit of the pack for one schedule: `fn(store)`
     -> PackSet, where `store` is a DeviceLU's four factor flats (1-D,
@@ -528,15 +573,7 @@ def _pack_fn(sched):
     the trace on the store's structure and avals.  Lives beside the
     packed solve programs on the schedule, so every refactorization
     on a held plan dispatches the program its first one compiled.
-
-    One body for both forms: flats are first cut into the per-group
-    local flats the staged form holds.  The barrier after the cut is
-    load-bearing on the TPU: without it the compiler moves a
-    panel's reshape before its static slice wherever the offset is
-    not tile-aligned, i.e. reshapes the WHOLE flat to (N/wb, wb),
-    padded 16-fold at wb=8, once a group (n=27,000, v5e: 75 s of
-    compile, 220 MB of code and 1.1 GB of scratch, against 2 s,
-    11 MB and none with it)."""
+    One body for both forms (`pack_flats`)."""
     from .. import obs
 
     def build():
@@ -546,8 +583,7 @@ def _pack_fn(sched):
         @jax.jit
         def slu_pack(store):
             if hasattr(store[0], "ndim"):       # four flats, not panels
-                store = jax.lax.optimization_barrier(
-                    _group_flats(sched, store))
+                return PackSet(pack_flats(ts, store))
             return PackSet(pack_panels_staged(ts, store))
 
         return obs.watch_jit("pack", slu_pack)

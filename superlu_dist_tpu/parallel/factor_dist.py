@@ -156,9 +156,10 @@ def _solve_loop(dsched, flats, b, dtype, per_group, axis,
     # the same arithmetic in the same order.  The packing slices here are
     # loop-invariant inside the fused solvers' refinement while_loop,
     # so XLA hoists them and the repeated sweeps pay only the lsum
-    # dataflow.  Mesh execution (axis mode) keeps the X psum sweep in
-    # THIS loop; the row-partitioned merged mesh trisolve lives in
-    # make_dist_solve (solve_merged_mesh).
+    # dataflow.  Axis mode below is the replicated-X psum sweep: what
+    # make_dist_step's fused program runs, and make_dist_solve under
+    # SLU_TRISOLVE=legacy.  A mesh's narrow-rhs sweep under the merged
+    # arm is make_dist_solve_merged, not this loop.
     if axis is None:
         from ..ops import trisolve
         if trisolve.trisolve_mode() == "merged":
@@ -579,20 +580,28 @@ def dist_factor_fn(plan: FactorPlan, mesh: Mesh, dtype):
 def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                            dtype=np.float64, axis=None,
                            trans: bool = False, pair: bool = False):
-    """Row-partitioned merged mesh trisolve (SLU_TRISOLVE=merged on a
-    mesh): one solve spans devices over the lsum layout
-    (ops/trisolve.py).  Each device sweeps its own front partition —
-    the rows its fronts own — writing y/update blocks DENSELY into
-    its device-major slices of the global Y/UPD/XF slot spaces, and
-    the cross-device dataflow is a psum-of-diffs reconciliation of
-    those dense buffers at the merged segments' static sync points:
-    the reference's C_Tree lsum reduction (SRC/pdgstrs.c:2133)
-    collapsed to one all-reduce per segment boundary instead of one
-    per supernode.  Interior segments (zone-affine subtrees) sweep
-    with ZERO collectives.
+    """Row-partitioned merged mesh trisolve: what a narrow-rhs sweep
+    on a mesh is under the merged trisolve arm (`solve_arm`).  One
+    solve spans devices over the lsum layout (ops/trisolve.py): each
+    device sweeps its own front partition — the rows its fronts own
+    — writing y/update blocks DENSELY into its device-major slices
+    of the global Y/UPD/XF slot spaces, and the cross-device dataflow
+    is an all-reduce at the merged segments' static sync points: the
+    reference's C_Tree lsum reduction (SRC/pdgstrs.c:2133) collapsed
+    to one all-reduce per segment boundary instead of one per
+    supernode.  Interior segments (zone-affine subtrees) sweep with
+    ZERO collectives.
 
-    Matching contract: every dense slot is written exactly once
-    by exactly one device and reconciled as v = 0 + (v - 0) + 0·…, so
+    A sync point reconciles only what was written since the last one
+    (`trisolve.mesh_sync_ranges`: slot bases grow in group order, so
+    that is one contiguous range of UPD going forward and of XF going
+    backward), so a sweep all-reduces each slot at most once,
+    u_total + y_total slots in all, whatever the number of
+    boundaries.
+
+    Matching contract: every dense slot is written exactly once by
+    exactly one device, every other device holds an exact zero
+    there, and the all-reduce of the raw range is v + 0 + 0·…, so
     the mesh execution does the arithmetic of the sequential
     execution of the same layout on one device (`mesh_oracle_solve`;
     tests/test_trisolve.py holds the two to 4·eps·max|x|: separately
@@ -611,7 +620,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
     def body(L_flat, U_flat, Li_flat, Ui_flat, b, *idx_flat):
         flats = tuple(_solve_view(f)
                       for f in (L_flat, U_flat, Li_flat, Ui_flat))
-        packs = tsv.pack_panels(ts, flats)
+        packs = tsv.pack_flats(ts, flats)
         it = iter(idx_flat)
         per_group = [tuple(next(it)[0] for _ in range(3))
                      for _ in ts.groups]
@@ -626,7 +635,7 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
         R = B0.shape[-1]
         rdt = B0.dtype
         B, UPD, Y = tsv.init_lsum_buffers(ts, B0)
-        UPDs = UPD
+        fwd_rng, bwd_rng, last_rng = tsv.mesh_sync_ranges(ts)
 
         def dev_meta(i):
             g = dsched.groups[i]
@@ -637,32 +646,32 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                 u_off=gs.u_off + di * gs.trim * gs.rtrim)
 
         @jax.named_scope("slu.lsum")
-        def sync(cur, snap):
-            new = snap + psum_exact(cur - snap, axis)
-            return new, new
+        def sync(buf, rng):
+            # the slots written since the last sync point: one device
+            # wrote each, the others hold zeros there
+            if rng is None:
+                return buf
+            lo, hi = rng
+            red = psum_exact(jax.lax.slice_in_dim(buf, lo, hi), axis)
+            return jax.lax.dynamic_update_slice_in_dim(buf, red, lo, 0)
 
         state = (B, UPD, Y)
-        for seg, need in zip(ts.segments, ts.seg_fwd_sync):
-            if need:
-                B_, UPD_, Y_ = state
-                UPD_, UPDs = sync(UPD_, UPDs)
-                state = (B_, UPD_, Y_)
+        for seg, rng in zip(ts.segments, fwd_rng):
+            B_, UPD_, Y_ = state
+            state = (B_, sync(UPD_, rng), Y_)
             for i in seg:
                 g, gsd = dev_meta(i)
                 state = tsv._fwd_member(state, g, gsd, packs[i],
                                         per_group[i], cplx, trans)
         _, _, Y = state
         XF = jnp.zeros((ts.y_total + 1, R), rdt)
-        XFs = XF
-        for seg, need in zip(reversed(ts.segments),
-                             list(reversed(ts.seg_bwd_sync))):
-            if need:
-                XF, XFs = sync(XF, XFs)
+        for seg, rng in zip(reversed(ts.segments), reversed(bwd_rng)):
+            XF = sync(XF, rng)
             for i in reversed(seg):
                 g, gsd = dev_meta(i)
                 XF = tsv._bwd_member(XF, Y, g, gsd, packs[i],
                                      per_group[i], cplx, trans)
-        XF, _ = sync(XF, XFs)     # replicate the final solution
+        XF = sync(XF, last_rng)   # replicate the rest of the solution
         x = XF[jnp.asarray(ts.final_idx)]
         return x if pair else _dec(x, cplx)   # pair: host decodes
 
@@ -683,15 +692,16 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
 def mesh_oracle_solve(dlu: DistLU, b_factor_order,
                       trans: bool = False):
     """Sequential one-device execution of a DistLU's merged mesh
-    layout: per group, each device's member step runs in device order
-    with EXACTLY the per-device operand shapes the shard_map'd solve
-    uses (XLA:CPU lowers a batch-2t GEMV differently from two
-    batch-t GEMVs, so shape identity is required for bit identity).
-    Every dense slot is written once by one device, and consumers
-    gather cross-device slots only after the mesh's sync points would
-    have replicated them (0 + (v - 0) + 0 + ... = v bit-exact), so
-    this sequential execution IS the mesh execution's arithmetic — the
-    oracle, no collectives, no shard_map."""
+    layout (either storage; `b` in the caller's dtype, complex against
+    pair-stored factors): per group, each device's member step runs
+    in device order with EXACTLY the per-device operand shapes the
+    shard_map'd solve uses (XLA:CPU lowers a batch-2t GEMV differently
+    from two batch-t GEMVs, so shape identity is required for bit
+    identity).  Every dense slot is written once by one device, and
+    consumers gather cross-device slots only after the mesh's sync
+    points would have replicated them (v + 0 + 0 + ... = v, exact),
+    so this sequential execution IS the mesh execution's arithmetic —
+    the oracle, no collectives, no shard_map."""
     from ..ops import trisolve as tsv
     from ..ops.batched import _dec, _enc
     dsched = dlu.schedule
@@ -701,17 +711,19 @@ def mesh_oracle_solve(dlu: DistLU, b_factor_order,
                                      dlu.Li_flat, dlu.Ui_flat)]
 
     def dev_pack(g, gs, d):
-        def cut(flat, off, shape):
+        def cut(flat, off, shape, keep=lambda p: p):
+            if flat.ndim == 2:      # pair storage: a plane at a time
+                return tuple(cut(p, off, shape, keep) for p in flat)
             per = shape[0] * shape[1]
             v = flat.reshape(ndev, -1)[d, off:off + gs.trim * per]
-            return v.reshape((gs.trim,) + shape)
+            return jnp.asarray(keep(v.reshape((gs.trim,) + shape)))
 
-        Lp = cut(flats[0], g.L_off, (g.mb, g.wb))
-        Up = cut(flats[1], g.U_off, (g.wb, g.mb))
-        Lip = cut(flats[2], g.Li_off, (g.wb, g.wb))
-        Uip = cut(flats[3], g.Ui_off, (g.wb, g.wb))
-        return (jnp.asarray(Lip), jnp.asarray(Lp[:, g.wb:, :]),
-                jnp.asarray(Uip), jnp.asarray(Up[:, :, g.wb:]))
+        return (cut(flats[2], g.Li_off, (g.wb, g.wb)),
+                cut(flats[0], g.L_off, (g.mb, g.wb),
+                    lambda p: p[:, g.wb:, :]),
+                cut(flats[3], g.Ui_off, (g.wb, g.wb)),
+                cut(flats[1], g.U_off, (g.wb, g.mb),
+                    lambda p: p[:, :, g.wb:]))
 
     def dev_meta(g, gs, d):
         return tsv._Meta(trim=gs.trim, rtrim=gs.rtrim, J=gs.J,
@@ -911,10 +923,9 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
     factor = dist_factor_fn(plan, dlu.mesh, dlu.dtype)
     _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
     # measure the solve program dist_solve actually runs at this nrhs
-    from ..ops import trisolve as tsv
-    sharded_rhs = _rhs_sharded_auto(nrhs, ndev)
-    merged = tsv.mesh_merged_on() and not sharded_rhs
-    solve = _solve_fn(dlu, False, sharded_rhs, merged)
+    arm = solve_arm(dlu, nrhs)
+    sharded_rhs = arm == "rhs_sharded"
+    solve = _solve_fn(dlu, False, arm)
     # lower with the dtype production traced with: factor consumes
     # plan.scaled_values(a) — f64 for real systems, c128 for complex —
     # NOT the factor dtype (the cast happens inside the program); a
@@ -947,8 +958,7 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
     # mesh stamps (ISSUE 17 satellite): scalar legs that let a caller
     # hold PER-DEVICE and PER-BOUNDARY ceilings, not just totals — a
     # mesh twice the size must not get twice the collective allowance.
-    syncs = int(dlu.schedule.comm_summary(dlu.dtype, nrhs)
-                .get("solve_syncs", 0))
+    syncs = solve_syncs(dlu, arm)
     psum_b = int(out["SOLVE"].get("all-reduce", {}).get("bytes", 0))
     out["MESH"] = {
         "n_devices": int(ndev),
@@ -958,8 +968,7 @@ def measure_comm(dlu: DistLU, nrhs: int = 1) -> dict:
         "solve_syncs": syncs,
         "solve_psum_bytes_per_boundary": (psum_b // syncs if syncs
                                           else 0),
-        "solve_arm": ("rhs_sharded" if sharded_rhs
-                      else ("merged" if merged else "replicated")),
+        "solve_arm": arm,
     }
     return out
 
@@ -996,45 +1005,73 @@ def _rhs_sharded_auto(nrhs: int, ndev: int) -> bool:
     return nrhs >= 2 * ndev
 
 
-def _solve_fn(dlu: DistLU, trans: bool, sharded_rhs: bool,
-              merged: bool):
-    """The compiled solve for this handle's mesh, dtype and storage,
-    cached on the PLAN so SamePattern re-factorizations reuse it
-    across handles."""
+def solve_arm(dlu: DistLU, nrhs: int) -> str:
+    """Which program a sweep of `nrhs` columns is on this handle's
+    mesh.  Many columns amortize one gather of the factors
+    (`rhs_sharded`, `_rhs_sharded_auto`); a narrow sweep is chosen by
+    the trisolve arm as on one device (`trisolve.sweeps_packed`):
+    `merged`, the row-partitioned lsum program, under `auto` and
+    `merged`; `replicated`, the X-psum sweep, under
+    SLU_TRISOLVE=legacy."""
+    from ..ops import trisolve as tsv
+    _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
+    if _rhs_sharded_auto(nrhs, ndev):
+        return "rhs_sharded"
+    return "merged" if tsv.sweeps_packed() else "replicated"
+
+
+def solve_syncs(dlu: DistLU, arm: str) -> int:
+    """All-reduces one sweep of that arm compiles to: a boundary of
+    the merged segments each (`trisolve.mesh_sync_count`), the
+    replicated sweep's one more (`comm_summary`: it reconciles X
+    after the forward sweep too), none where the columns are sharded
+    (one all-gather of the factors instead)."""
+    if arm == "rhs_sharded":
+        return 0
+    if arm == "merged":
+        from ..ops import trisolve as tsv
+        return tsv.mesh_sync_count(tsv.get_trisolve(dlu.schedule))
+    return int(dlu.schedule.comm_summary(dlu.dtype)["solve_syncs"])
+
+
+_SOLVE_MAKERS = {"rhs_sharded": make_dist_solve_rhs_sharded,
+                 "merged": make_dist_solve_merged,
+                 "replicated": make_dist_solve}
+
+
+def _solve_fn(dlu: DistLU, trans: bool, arm: str):
+    """The compiled solve of one arm (`solve_arm`) for this handle's
+    mesh, dtype and storage, cached on the PLAN so SamePattern
+    re-factorizations reuse it across handles."""
     plan = dlu.plan
     cache = getattr(plan, "_dist_solve_fns", None)
     if cache is None:
         cache = plan._dist_solve_fns = {}
     pair = _lu_is_pair(dlu)
-    key = (dlu.mesh, dlu.dtype.str, dlu.axis, trans, sharded_rhs,
-           merged, pair)
+    key = (dlu.mesh, dlu.dtype.str, dlu.axis, trans,
+           arm == "rhs_sharded", arm == "merged", pair)
     if key not in cache:
-        mk = (make_dist_solve_rhs_sharded if sharded_rhs
-              else (make_dist_solve_merged if merged
-                    else make_dist_solve))
-        cache[key] = mk(plan, dlu.mesh, dtype=dlu.dtype,
-                        axis=dlu.axis, trans=trans, pair=pair)
+        cache[key] = _SOLVE_MAKERS[arm](
+            plan, dlu.mesh, dtype=dlu.dtype, axis=dlu.axis,
+            trans=trans, pair=pair)
     return cache[key]
 
 
 def dist_solve(dlu: DistLU, b_factor_order, trans: bool = False):
     """Solve against a DistLU.  Compiled solves are cached on the PLAN
-    keyed (mesh, dtype, trans, mode, storage), so SamePattern
-    re-factorizations reuse them across handles.  Many-RHS solves
-    auto-select the rhs-sharded sweep (make_dist_solve_rhs_sharded).
+    keyed (mesh, dtype, trans, arm, storage), so SamePattern
+    re-factorizations reuse them across handles.  `solve_arm` picks
+    the program: many-RHS solves the rhs-sharded sweep
+    (make_dist_solve_rhs_sharded), narrow ones the trisolve arm's.
     Against pair-stored factors the host encodes the right-hand side
     and decodes the answer (spans `slu.pair.encode` /
     `slu.pair.decode`), and the answer is a host array."""
     nrhs = int(b_factor_order.shape[1]) \
         if getattr(b_factor_order, "ndim", 1) == 2 else 1
     _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
-    sharded_rhs = _rhs_sharded_auto(nrhs, ndev)
-    from ..ops import trisolve as tsv
-    # explicit SLU_TRISOLVE=merged: the row-partitioned merged mesh
-    # trisolve replaces the replicated-X psum sweep (narrow-RHS lane
-    # only — wide RHS keeps the gather-amortized rhs-sharded sweep)
-    merged = tsv.mesh_merged_on() and not sharded_rhs
-    solve = _solve_fn(dlu, trans, sharded_rhs, merged)
+    arm = solve_arm(dlu, nrhs)
+    sharded_rhs = arm == "rhs_sharded"
+    solve = _solve_fn(dlu, trans, arm)
     flats = (dlu.L_flat, dlu.U_flat, dlu.Li_flat, dlu.Ui_flat)
     if not _lu_is_pair(dlu):
         return solve(*flats, b_factor_order)
@@ -1118,9 +1155,7 @@ def _contract_psum_per_boundary():
     compiled = fn.lower(*args).compile()
     got = hlo_collective_stats(compiled.as_text()).get(
         "all-reduce", {}).get("count", 0)
-    ts = tsv.get_trisolve(dlu.schedule)
-    want = (sum(map(bool, ts.seg_fwd_sync))
-            + sum(map(bool, ts.seg_bwd_sync)) + 1)
+    want = tsv.mesh_sync_count(tsv.get_trisolve(dlu.schedule))
     return got == want, (f"{got} all-reduce(s) compiled for {want} "
                          "segment boundaries")
 

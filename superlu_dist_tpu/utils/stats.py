@@ -162,7 +162,11 @@ class Stats:
     # (ops/batched._route: `dispatch` "staged" or "program",
     # `segments`, `groups`, `pallas_buckets`, `pallas_shapes`), and
     # `sweep_segments`, the programs each sweep of the last solve
-    # dispatched; empty on the host oracle and the mesh.  The health
+    # dispatched; empty on the host oracle.  On a process grid the
+    # route is the mesh's (parallel/factor_dist._mesh_route:
+    # `devices`, `coop_groups`, `comm_bytes`) and the last solve adds
+    # `sweep_arm` (merged | replicated | rhs_sharded), `sweep_segments`
+    # 1 and `sweep_syncs`, the all-reduces a sweep.  The health
     # ring's factor and solve records carry the same keys
     dispatch: Dict[str, object] = dataclasses.field(default_factory=dict)
 
@@ -278,6 +282,12 @@ class Stats:
             if "sweep_segments" in d:
                 line += f", {d['sweep_segments']} a sweep"
             lines.append(line)
+        if "sweep_arm" in self.dispatch:
+            d = self.dispatch
+            lines.append(
+                f"  mesh sweep:           {d['sweep_arm']}, "
+                f"{d['sweep_segments']} program a sweep, "
+                f"{d['sweep_syncs']} all-reduces")
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
         if self.placement:

@@ -471,3 +471,49 @@ def test_comm_summary_coop_bytes(monkeypatch):
     cs = s.comm_summary(np.float32)
     assert cs["coop_psum_bytes"] == exp_psum
     assert cs["coop_gather_bytes"] == exp_gather
+
+
+@pytest.mark.parametrize("arm", ["merged", "replicated", "rhs_sharded"])
+def test_stats_dispatch_names_the_mesh_sweep(arm, monkeypatch):
+    """On the dist backend a solve fills `Stats.dispatch` as the
+    one-device path does: which program a sweep was (`sweep_arm`),
+    that it was one program (`sweep_segments`), and the all-reduces
+    it compiles to (`sweep_syncs`); the health ring's solve record
+    carries the same and `Stats.report()` prints them.  The trisolve
+    arm picks between the merged and the replicated sweep at one
+    column, eight columns on four devices shard the columns."""
+    from superlu_dist_tpu import Stats, factorize, obs, solve
+    from superlu_dist_tpu.ops import trisolve
+    from superlu_dist_tpu.parallel.factor_dist import measure_comm
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    if arm == "replicated":
+        monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    nrhs = 8 if arm == "rhs_sharded" else 1
+    a = laplacian_2d(12)
+    rng = np.random.default_rng(3)
+    b = rng.standard_normal((a.n, nrhs))
+    grid = make_solver_mesh(2, 2, 1, devices=jax.devices()[:4])
+    st = Stats()
+    lu = factorize(a, Options(), grid=grid, stats=st)
+    x = solve(lu, b, stats=st)
+    assert np.abs(a.to_scipy() @ x - b).max() < 1e-9
+    d = st.dispatch
+    assert d["sweep_arm"] == arm and d["sweep_segments"] == 1
+    ts = trisolve.get_trisolve(lu.device_lu.schedule)
+    want = {"merged": trisolve.mesh_sync_count(ts),
+            "replicated": st.comm_predicted["solve_syncs"],
+            "rhs_sharded": 0}[arm]
+    assert d["sweep_syncs"] == want
+    if arm != "rhs_sharded":
+        assert want > 1
+    # the count is the compiled program's own
+    meas = measure_comm(lu.device_lu, nrhs=nrhs)
+    assert meas["MESH"]["solve_arm"] == arm
+    assert meas["SOLVE"].get("all-reduce", {"count": 0})["count"] == want
+    # the mesh's route stays beside it, and the ring carries the solve's
+    assert d["devices"] == 4
+    last = obs.HEALTH.snapshot()["last_solve"]
+    assert (last["sweep_arm"], last["sweep_segments"],
+            last["sweep_syncs"]) == (arm, 1, want)
+    line = [ln for ln in st.report().splitlines() if "mesh sweep:" in ln]
+    assert len(line) == 1 and arm in line[0] and str(want) in line[0]

@@ -76,13 +76,19 @@ _OPTS = Options(factor_dtype="float64")
 # bitwise: serve path vs the sequential mesh oracle
 # --------------------------------------------------------------------
 
-def test_serve_path_bitwise_vs_mesh_oracle(monkeypatch):
+@pytest.mark.parametrize("arm", [None, "merged"])
+def test_serve_path_bitwise_vs_mesh_oracle(monkeypatch, arm):
     """End to end through SolveService on a mesh: the batched,
     shard_map'd solve of a keyed request bit-matches mesh_oracle_solve
-    (the sequential one-device execution of the SAME merged layout).
+    (the sequential one-device execution of the SAME merged layout:
+    the ranged reconciliation all-reduces v + 0, exactly v), with no
+    variable set as under an explicit SLU_TRISOLVE=merged: a mesh
+    replica's narrow sweep is the trisolve arm's.
     NOREFINE: default serving refines (gssvx), which the oracle
     deliberately does not model."""
-    monkeypatch.setenv("SLU_TRISOLVE", "merged")
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    if arm:
+        monkeypatch.setenv("SLU_TRISOLVE", arm)
     a = laplacian_3d(5)
     svc = _mesh_service()
     try:
@@ -101,6 +107,10 @@ def test_serve_path_bitwise_vs_mesh_oracle(monkeypatch):
         x_oracle = xo[plan.final_col] * plan.col_scale
         assert np.array_equal(x_serve, x_oracle), (
             f"maxdiff={np.abs(x_serve - x_oracle).max()}")
+        # (rhs_sharded, merged): the narrow bucket's program is the
+        # merged one, and no replicated-X sweep was built beside it
+        built = {k[4:6] for k in plan._dist_solve_fns}
+        assert (False, True) in built and (False, False) not in built
     finally:
         svc.close()
 

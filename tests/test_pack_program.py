@@ -316,19 +316,24 @@ def test_counters_say_at_factor(monkeypatch, form):
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described (not attached) v5e chip: the TPU's compiler runs
-    here without the device."""
+def v5e_host():
+    """One described (not attached) host of four v5e chips: the TPU's
+    compiler runs here without the devices."""
     import os
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — no compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_host):
+    """One chip of it."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_host.devices[0])
 
 
 def test_pack_program_compiles_small_for_v5e(v5e_chip, monkeypatch):
@@ -391,3 +396,44 @@ def test_row_lane_body_compiles_small_for_v5e(v5e_chip):
     front = 4 * mb * mb
     assert mem.temp_size_in_bytes < 3 * front
     assert mem.generated_code_size_in_bytes < 4 * 2 ** 20
+
+
+def test_mesh_sweep_compiles_small_for_v5e_host(v5e_host):
+    """The mesh's narrow-rhs sweep (`make_dist_solve_merged`) at the
+    grid cell's size (n=27,000, 62 groups on a 2x2 grid) cuts its
+    panels behind the pack program's fence (`trisolve.pack_flats`):
+    scratch of about one copy of a device's factors, where the cut
+    inside the trace (`pack_panels`) reshapes the whole flat once a
+    group (2.5 GB of scratch, 113 MB of code, 27 s); and its sync
+    points all-reduce each slot at most once (1.76 MB a sweep where
+    whole buffers at every boundary are 65 MB)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from superlu_dist_tpu.parallel import factor_dist
+    from superlu_dist_tpu.utils.stats import hlo_collective_stats
+    mesh = Mesh(np.array(v5e_host.devices).reshape(2, 2, 1),
+                ("r", "c", "z"))
+    plan = plan_factorization(laplacian_3d(30),
+                              Options(factor_dtype="float32"))
+    sched = batched.get_schedule(plan, 4)
+    ts = trisolve.get_trisolve(sched)
+    sharded = NamedSharding(mesh, P(("r", "c", "z")))
+    flats = tuple(
+        jax.ShapeDtypeStruct((4 * n,), jnp.float32, sharding=sharded)
+        for n in (sched.L_total, sched.U_total, sched.Li_total,
+                  sched.Ui_total))
+    b = jax.ShapeDtypeStruct((plan.n, 1), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    fn = factor_dist.make_dist_solve_merged(plan, mesh,
+                                            dtype=np.float32)
+    on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = fn.lower(*flats, b).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", on)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.25 * mem.argument_size_in_bytes
+    assert mem.generated_code_size_in_bytes < 80 * 2 ** 20
+    ar = hlo_collective_stats(compiled.as_text())["all-reduce"]
+    assert ar["count"] == trisolve.mesh_sync_count(ts)
+    assert ar["bytes"] <= (ts.u_total + ts.y_total) * 4

@@ -319,12 +319,14 @@ def test_a_second_value_set_on_the_held_plan(case, grid):
 @pytest.mark.parametrize("arm", ["rhs_sharded", "merged", "replicated"])
 def test_every_solve_arm_takes_planes(case, arm, monkeypatch):
     """Eight right-hand sides pick the rhs-sharded sweep (each
-    device's column block encoded by itself), SLU_TRISOLVE=merged the
-    row-partitioned merged sweep, two columns the replicated-X
-    sweep."""
+    device's column block encoded by itself); two columns the
+    trisolve arm's: the row-partitioned merged sweep with no variable
+    set, the replicated-X sweep under SLU_TRISOLVE=legacy."""
     nrhs = 8 if arm == "rhs_sharded" else 2
-    if arm == "merged":
-        monkeypatch.setenv("SLU_TRISOLVE", "merged")
+    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+    if arm == "replicated":
+        monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    case.plan._dist_solve_fns = {}
     rng = np.random.default_rng(6)
     xtrue, b = _system(case.A, rng, nrhs=nrhs)
     x = np.asarray(solve(case.lu, b))
@@ -333,9 +335,9 @@ def test_every_solve_arm_takes_planes(case, arm, monkeypatch):
         assert _berr(case.A, x[:, j], b[:, j]) <= BERR_MAX
     assert _relerr(x, xtrue) <= ERR_MAX
     built = {k[4:] for k in case.plan._dist_solve_fns}
-    assert {"rhs_sharded": (True, False, True),
-            "merged": (False, True, True),
-            "replicated": (False, False, True)}[arm] in built
+    assert built == {{"rhs_sharded": (True, False, True),
+                      "merged": (False, True, True),
+                      "replicated": (False, False, True)}[arm]}
 
 
 def test_gssvx_on_the_grid(grid, force_coop):
@@ -392,9 +394,9 @@ def _lowered(case, grid, debug=False):
     ftxt = factor.jitted.lower(
         jnp.zeros((nd, 2, lsel), jnp.float32)).as_text(debug_info=debug)
     flats = (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)
-    stxt = [factor_dist._solve_fn(d, trans, False, False).lower(
+    stxt = [factor_dist._solve_fn(d, trans, arm).lower(
         *flats, jnp.zeros((case.a.n, 2), jnp.float32)).as_text()
-        for trans in (False, True)]
+        for arm in ("merged", "replicated") for trans in (False, True)]
     return ftxt, stxt
 
 

@@ -80,9 +80,20 @@ def test_measured_comm_matches_prediction():
     # factor path: every update-slab all_gather is predicted
     ag = meas["FACT"].get("all-gather", {"count": 0, "bytes": 0})
     assert ag["bytes"] == pred["factor_allgather_bytes"], (ag, pred)
-    # solve path: one psum per predicted sync point, none elided twice
+    # solve path: two columns on eight devices sweep the trisolve
+    # arm's program, the row-partitioned merged one: one all-reduce a
+    # sync point of ITS count (the replicated sweep, which the
+    # schedule's prediction describes, reconciles once more: after
+    # the forward sweep), and each slot all-reduced at most once
     ar = meas["SOLVE"].get("all-reduce", {"count": 0, "bytes": 0})
-    assert ar["count"] == pred["solve_syncs"], (ar, pred)
+    stamp = meas["MESH"]
+    assert stamp["solve_arm"] == "merged"
+    assert ar["count"] == stamp["solve_syncs"] == pred["solve_syncs"] - 1
+    from superlu_dist_tpu.ops import trisolve
+    ts = trisolve.get_trisolve(lu.device_lu.schedule)
+    assert ar["bytes"] <= (ts.u_total + ts.y_total) * 2 * 8
+    assert stats.dispatch["sweep_arm"] == "merged"
+    assert stats.dispatch["sweep_syncs"] == ar["count"]
     # report renders both sections
     stats.comm_measured = meas
     rep = stats.report()

@@ -299,18 +299,146 @@ def test_mesh_merged_bitmatch_oracle(monkeypatch):
     np.testing.assert_allclose(x_mesh, x_leg, rtol=1e-12, atol=1e-12)
 
 
+def _mesh2_dlu(storage, monkeypatch):
+    """A factored 2-device DistLU in the two arithmetics the grid
+    cells run: an unsymmetric real system in float32, or the complex
+    Helmholtz one in complex64 pair storage (real and imaginary
+    planes, what a TPU mesh stores; forced here).  float64 at four
+    columns is ROADMAP D10's: one row of BOTH mesh programs' answers
+    is wrong there on XLA:CPU, and at no other width or dtype."""
+    from jax.sharding import Mesh
+    from superlu_dist_tpu.parallel import factor_dist
+    devs = np.array(jax.devices()[:2])
+    if len(devs) < 2:
+        pytest.skip("needs 2 virtual devices")
+    mesh = Mesh(devs.reshape(2), ("d",))
+    if storage == "pair":
+        monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
+        a, dtype = helmholtz_2d(10), np.dtype(np.complex64)
+    else:
+        a = random_unsymmetric(300, density=0.03, seed=5)
+        dtype = np.dtype(np.float32)
+    plan = plan_factorization(a, Options(factor_dtype=dtype.name))
+    dlu = factor_dist.make_dist_factor(plan, mesh, dtype=dtype)(
+        plan.scaled_values(a))
+    assert batched._lu_is_pair(dlu) == (storage == "pair")
+    return a, plan, mesh, dlu
+
+
+def _rhs(n, nrhs, cplx, seed=5):
+    """In the factor's precision, as `solve` hands a sweep its
+    operand."""
+    rng = np.random.default_rng(seed)
+    b = rng.standard_normal((n, nrhs)).astype(np.float32)
+    if cplx:
+        b = b + 1j * rng.standard_normal((n, nrhs)).astype(np.float32)
+    return b
+
+
 def test_mesh_merged_dist_solve_routing(monkeypatch):
-    """dist_solve routes through the merged mesh trisolve only under
-    an EXPLICIT SLU_TRISOLVE=merged (auto keeps the proven X-psum
-    sweep on meshes)."""
-    monkeypatch.delenv("SLU_TRISOLVE", raising=False)
-    assert not trisolve.mesh_merged_on()
-    assert trisolve.trisolve_mode() == "merged"
-    monkeypatch.setenv("SLU_TRISOLVE", "merged")
-    assert trisolve.mesh_merged_on()
-    monkeypatch.setenv("SLU_TRISOLVE", "legacy")
-    assert trisolve.trisolve_mode() == "legacy"
-    assert not trisolve.mesh_merged_on()
+    """A mesh sweep is chosen by the trisolve arm, as a one-device
+    sweep is: through `dist_solve` itself on a 2-device mesh, `auto`
+    and `merged` build the row-partitioned merged program,
+    SLU_TRISOLVE=legacy the replicated-X psum sweep, and
+    nrhs >= 2·ndev the rhs-sharded one whatever the arm."""
+    from superlu_dist_tpu.parallel import factor_dist
+    a, plan, mesh, dlu = _mesh2_dlu("real", monkeypatch)
+    b1, b4 = _rhs(a.n, 1, False), _rhs(a.n, 4, False)
+    ref = factor_dist.mesh_oracle_solve(dlu, b1)
+
+    def built():
+        # (…, trans, rhs_sharded, merged, pair)
+        return {k[4:6] for k in plan._dist_solve_fns}
+
+    for arm, want in ((None, (False, True)), ("merged", (False, True)),
+                      ("legacy", (False, False))):
+        plan._dist_solve_fns = {}
+        if arm is None:
+            monkeypatch.delenv("SLU_TRISOLVE", raising=False)
+        else:
+            monkeypatch.setenv("SLU_TRISOLVE", arm)
+        x = np.asarray(factor_dist.dist_solve(dlu, b1))
+        assert built() == {want}, (arm, built())
+        assert factor_dist.solve_arm(dlu, 1) == (
+            "merged" if want[1] else "replicated")
+        if want[1]:
+            _assert_ulp_close(x, ref, str(arm))
+        else:   # another order of the same sums, in float32
+            np.testing.assert_allclose(
+                x, ref, rtol=0, atol=2e-4 * np.abs(ref).max())
+        factor_dist.dist_solve(dlu, b4)     # 4 = 2·ndev columns
+        assert built() == {want, (True, False)}, (arm, built())
+        assert factor_dist.solve_arm(dlu, 4) == "rhs_sharded"
+    assert not hasattr(trisolve, "mesh_merged_on")
+
+
+@pytest.mark.parametrize("trans", [False, True])
+@pytest.mark.parametrize("storage", ["real", "pair"])
+@pytest.mark.parametrize("nrhs", [1, 4])
+def test_mesh_merged_ranged_sync_vs_oracle(monkeypatch, nrhs, storage,
+                                           trans):
+    """The ranged reconciliation (a sync point all-reduces only the
+    slots written since the last one) against the sequential
+    one-device execution of the same layout: within 4·eps·max|x|,
+    one and four columns, real and pair storage, both sweeps'
+    directions."""
+    from superlu_dist_tpu.parallel import factor_dist
+    a, plan, mesh, dlu = _mesh2_dlu(storage, monkeypatch)
+    pair = storage == "pair"
+    b = _rhs(a.n, nrhs, pair)
+    solve_m = factor_dist.make_dist_solve_merged(
+        plan, mesh, dtype=dlu.dtype, trans=trans, pair=pair)
+    flats = (dlu.L_flat, dlu.U_flat, dlu.Li_flat, dlu.Ui_flat)
+    if pair:
+        x = factor_dist.decode_sol(np.asarray(solve_m(
+            *flats, factor_dist.encode_rhs(b))), b.dtype)
+    else:
+        x = np.asarray(solve_m(*flats, jnp.asarray(b)))
+    ref = factor_dist.mesh_oracle_solve(dlu, b, trans=trans)
+    assert np.isfinite(x).all() and np.abs(ref).max() > 0
+    _assert_ulp_close(x, ref, f"{storage} nrhs={nrhs} trans={trans}")
+
+
+@pytest.mark.parametrize("storage", ["real", "pair"])
+def test_mesh_merged_allreduces_what_was_written(monkeypatch, storage):
+    """The compiled merged mesh sweep all-reduces each slot at most
+    once: one all-reduce a range of `mesh_sync_ranges`, and in all no
+    more than (u_total + y_total) slots of R words, however many
+    boundaries there are.  A whole-buffer reconciliation at every
+    boundary is a multiple of that."""
+    from superlu_dist_tpu.parallel import factor_dist
+    from superlu_dist_tpu.utils.stats import hlo_collective_stats
+    a, plan, mesh, dlu = _mesh2_dlu(storage, monkeypatch)
+    pair = storage == "pair"
+    ts = trisolve.get_trisolve(dlu.schedule)
+    fwd, bwd, last = trisolve.mesh_sync_ranges(ts)
+    ranges = [r for r in fwd + bwd + [last] if r is not None]
+    assert len(ranges) == trisolve.mesh_sync_count(ts) >= 3
+    # disjoint within each buffer, and XF is covered exactly once
+    for rs, total in (([r for r in fwd if r], ts.u_total),
+                      ([r for r in bwd if r] + [last], ts.y_total)):
+        rs = sorted(rs)
+        assert all(lo < hi for lo, hi in rs)
+        assert all(a_[1] <= b_[0] for a_, b_ in zip(rs, rs[1:]))
+        assert rs[0][0] >= 0 and rs[-1][1] <= total
+    assert sum(hi - lo for lo, hi in
+               [r for r in bwd if r] + [last]) == ts.y_total
+    solve_m = factor_dist.make_dist_solve_merged(
+        plan, mesh, dtype=dlu.dtype, pair=pair)
+    R = 2 if pair else 1            # one column; a pair encodes two
+    rdt = np.float32
+    b = jnp.zeros((a.n, R), rdt)
+    txt = solve_m.lower(dlu.L_flat, dlu.U_flat, dlu.Li_flat,
+                        dlu.Ui_flat, b).compile().as_text()
+    ar = hlo_collective_stats(txt)["all-reduce"]
+    assert ar["count"] == len(ranges)
+    words = sum(hi - lo for lo, hi in ranges) * R
+    assert ar["bytes"] == words * np.dtype(rdt).itemsize
+    assert words <= (ts.u_total + ts.y_total) * R
+    # what whole buffers at every boundary would move
+    whole = (sum(r is not None for r in fwd) * (ts.u_total + 1)
+             + (sum(r is not None for r in bwd) + 1) * (ts.y_total + 1))
+    assert words < whole
 
 
 def test_pallas_lsum_oracle():
