@@ -123,12 +123,18 @@ class GroupSpec:
     # per-bucket (src_off, stride, dst_base, pos_row, pos_col); for
     # ordinary groups pos_col IS pos_row (same array) — they diverge
     # only for sharded-coop parents, whose destination columns are
-    # owned-slot indices instead of front positions
+    # owned-slot indices instead of front positions.  A ROW-lane
+    # bucket's src_off is the child's slot in its wave's slab (ea_meta)
     ea_hosts: tuple
     # per-bucket statics (rc_b, tc_b, K, C): C children a chunk on the
     # element lane; C == 0 marks a ROW-lane bucket (_ea_row_lane,
-    # _ea_add_rows), whose meta carries a fifth entry, the sorted
-    # distinct slab strides of its children
+    # _ea_add_rows), whose records lie wave after wave (_ea_waves: no
+    # two records of one wave share a parent front) and whose meta
+    # carries a fifth entry, its waves as (W, Wc, src) triples: W
+    # records read from one child group's slab (src = (voff, nslots,
+    # rbc, stride): nslots blocks of (rbc, stride) from slab offset
+    # voff, the slots the loop's records span), of which Wc move a
+    # loop turn (K = Σ W)
     ea_meta: tuple
     col_idx: np.ndarray        # (ndev, n_loc, wb) global cols, pad -> n
     struct_idx: np.ndarray     # (ndev, n_loc, mb-wb) pad -> n
@@ -218,16 +224,19 @@ class GroupSpec:
                 if C == 0:
                     # row lane: the positions ship as their inverse
                     # maps (front row -> child row, owned column ->
-                    # child column; _ea_add_rows)
+                    # child column), the destination as the front's
+                    # index in the group (_ea_add_rows)
                     pr = _inverse_positions(pr, self.mb, rc_b)
                     pc = _inverse_positions(pc, ncols, tc_b)
+                    db = db // (self.mb * ncols)
                 prd = put(pr, np.int32)
                 # squeezed leaves are each their own array, as when
                 # the squeeze ran a leaf on the device: one shared
                 # array would be one constant of the traced program
                 # where it has had two
                 eblocks.append((put(so, edt), put(st, edt),
-                                put(db, fdt), prd,
+                                put(db, np.int32 if C == 0 else fdt),
+                                prd,
                                 prd if pc is pr and not squeeze
                                 else put(pc, np.int32)))
             bblocks = []
@@ -269,11 +278,11 @@ class BatchedSchedule:
     # tail padding of the update slab (in elements): the block-copy
     # extend-add lane reads each (li, lj) sub-block as one (li·st)
     # dynamic_slice whose final row over-reads up to st−lj elements
-    # past the child slab, and the row lane reads a child as one
-    # (rc_b·st) slice, rc_b − rc slab rows past it; the pad guarantees
-    # neither slice ever clamps (a clamped dynamic_slice silently
-    # SHIFTS its window).  1 when neither lane reaches past the slab
-    # (the legacy +1 sentinel slot).
+    # past the child slab; the pad guarantees the slice never clamps
+    # (a clamped dynamic_slice silently SHIFTS its window).  1 when
+    # the lane does not reach past the slab (the legacy +1 sentinel
+    # slot).  (The row lane reads whole slots of a child group's slab
+    # and never leaves it.)
     upd_pad: int = 1
 
     @functools.cached_property
@@ -293,19 +302,27 @@ class BatchedSchedule:
     def ea_elements(self) -> dict:
         """Extend-add elements a factorization moves, by lane
         (`element`, `row`, `block`) and over all devices: `padded` is
-        what the program touches at its bucket shapes (K-padding
-        records included), `real` the children's own entries (Σ rc·tc
-        of the plan; a column no device owns is nobody's)."""
+        what the program touches at its bucket shapes (padding records
+        included), `real` the children's own entries (Σ rc·tc of the
+        plan; a column no device owns is nobody's).  The row lane also
+        counts its `children` (real records) and the loop `turns` its
+        programs run to move them, a wave of children a turn
+        (`_ea_wave_runs`)."""
         out = {k: {"padded": 0, "real": 0}
                for k in ("element", "row", "block")}
+        out["row"].update(children=0, turns=0)
         for g in self.groups:
             ncols = g.cp if g.cp > 0 else g.mb
-            for (rc_b, tc_b, K, C, *_), (_, _, _, pr, pc) in zip(
+            for (rc_b, tc_b, K, C, *row), (_, _, _, pr, pc) in zip(
                     g.ea_meta, g.ea_hosts):
                 lane = out["row" if C == 0 else "element"]
                 lane["padded"] += pr.shape[0] * K * rc_b * tc_b
                 lane["real"] += int(((pr < g.mb).sum(-1)
                                      * (pc < ncols).sum(-1)).sum())
+                if C == 0:
+                    lane["children"] += int((pr < g.mb).any(-1).sum())
+                    lane["turns"] += pr.shape[0] * sum(
+                        t for _, t, _ in _ea_wave_runs(row[0]))
             for (li, lj, _, K), (_, _, _, w) in zip(g.eb_meta,
                                                     g.eb_hosts):
                 out["block"]["padded"] += w.shape[0] * K * li * lj
@@ -551,6 +568,94 @@ def _ea_row_lane(rc_b: int, tc_b: int, mb: int, ncols: int) -> bool:
     return rc_b * tc_b * _EA_ROW_GAIN >= mb * ncols + _EA_ROW_FIXED
 
 
+# front entries one row-lane loop turn may move (W·mb·ncols, the size
+# of each of the turn's few transients): a wave wider than this is cut
+# into chunks, as the element lane bounds its chunks with C
+_EA_WAVE_ENTRIES = 1 << 23
+
+
+def _ea_wave_chunk(W: int, mb: int, ncols: int) -> int:
+    """Records of a wave of W (on the size grid) that move in one loop
+    turn under fronts of (mb, ncols): all of them where the turn's
+    transients fit `_EA_WAVE_ENTRIES`, else the largest power of two
+    that fits and divides W."""
+    cap = max(1, _EA_WAVE_ENTRIES // (mb * ncols))
+    if W <= cap:
+        return W
+    c = 1 << (cap.bit_length() - 1)
+    while W % c:
+        c //= 2
+    return c
+
+
+def _ea_wave_runs(waves: tuple) -> list:
+    """A bucket's loops, [(Wc, turns, src), ...]: its waves' turns in
+    order, adjacent waves of one chunk width and source together."""
+    runs: list = []
+    for W, Wc, src in waves:
+        if runs and runs[-1][0] == Wc and runs[-1][2] == src:
+            runs[-1] = (Wc, runs[-1][1] + W // Wc, src)
+        else:
+            runs.append((Wc, W // Wc, src))
+    return runs
+
+
+def _ea_waves(per_d: list, mb: int, ncols: int):
+    """A row-lane bucket's records, a list a device in front order,
+    re-ordered into WAVES: the j-th record of every parent front that
+    has one, in the records' order, cut by the records' SOURCE, the
+    child group whose slab holds them, so that a wave's children are
+    slots of one array.  No two records of a wave share a parent, a
+    wave's fronts ascend, and a parent's records keep their order,
+    wave after wave.  Each wave is padded (None) to a width on the
+    size grid, the largest over the devices.  A source is
+    `(voff, nslots, rbc, stride)`: nslots blocks of (rbc, stride) from
+    slab offset voff, the part of the child group's slab that the
+    loop's records span and no more (`_ea_wave_runs`: a loop reads its
+    source whole, so what it reads is what its records need, not what
+    the child group wrote); rec[7] becomes that part.  Returns the
+    lists and the bucket's static ((W, Wc, src), ...)
+    (`_ea_wave_chunk`)."""
+    by_wave = []
+    for recs in per_d:
+        nth: dict = {}
+        wv: list = []
+        for rec in recs:
+            j = nth.get(rec[3], 0)          # rec[3]: the front's base
+            nth[rec[3]] = j + 1
+            if j == len(wv):
+                wv.append({})
+            wv[j].setdefault((rec[7], rec[2]), []).append(rec)
+        by_wave.append(wv)
+    out = [[] for _ in per_d]
+    waves = []
+    for j in range(max(len(wv) for wv in by_wave)):
+        at = [wv[j] if j < len(wv) else {} for wv in by_wave]
+        for src in sorted(set().union(*at)):
+            W = _next_bucket(max(len(w.get(src, ())) for w in at))
+            waves.append((W, _ea_wave_chunk(W, mb, ncols), src))
+            for o, w in zip(out, at):
+                recs = w.get(src, [])
+                o += recs + [None] * (W - len(recs))
+    # a loop's source: the slots its records span, over the devices
+    base = n = 0
+    for Wc, turns, ((_, _, rbc), stride) in _ea_wave_runs(waves):
+        span = range(base, base + Wc * turns)
+        offs = [o[i][1] for o in out for i in span if o[i] is not None]
+        blk = rbc * stride
+        part = (min(offs), max(offs) - min(offs) + blk, rbc)
+        for o in out:
+            for i in span:
+                if o[i] is not None:
+                    o[i] = o[i][:7] + (part,)
+        while base < span.stop:
+            waves[n] = (waves[n][0], Wc,
+                        (part[0], part[1] // blk, rbc, stride))
+            base += waves[n][0]
+            n += 1
+    return out, tuple(waves)
+
+
 def _coop_mb_min() -> int:
     """Minimum padded front size for cooperative (column-sharded)
     factorization; SLU_COOP_MB overrides, 0 disables."""
@@ -617,8 +722,6 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
     block_on = _ea_block_on()
     blk_min_run = _ea_block_min_run()
     max_blk_stride = 0           # sizes the upd-slab tail pad
-    row_read_end = 0             # farthest slab element a row-lane
-                                 # read reaches (so + rc_b·stride)
 
     sup_upd_off = np.full(fp.nsuper, -1, dtype=np.int64)
     # actual slab row/col stride each front was WRITTEN with — its
@@ -873,6 +976,10 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                         coff = sup_upd_off[c]
                         assert coff >= 0, "child scheduled after parent"
                         ps_row = _pad_pos(fp.ea_map[c], w, wb)
+                        # the child's group's slab, blocks of
+                        # (rbc, slab stride) a slot: where the row
+                        # lane reads it (_ea_waves)
+                        cslab = group_alloc[group_of_sup[int(c)]] + (rbc,)
                         if not sharded:
                             # slab columns ARE front positions: pos_col
                             # aliases pos_row (a sharded child under a
@@ -901,7 +1008,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                             else:
                                 child_recs[d].append(
                                     (rc, int(coff), rbc, base,
-                                     ps_row, ps_row, rc))
+                                     ps_row, ps_row, rc, cslab))
                         elif sharded_sup[int(c)]:
                             # device-local child slice (rbc, tp_c):
                             # owned columns align with this device's
@@ -914,7 +1021,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                             child_recs[d].append(
                                 (rc, int(coff),
                                  int(sup_slab_stride[int(c)]), base,
-                                 ps_row, pcl, len(jl)))
+                                 ps_row, pcl, len(jl), cslab))
                         else:
                             # replicated (gathered) child slab, full
                             # square: this device extend-adds only the
@@ -923,7 +1030,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                             pcl = np.where(pcl < 0, ncols, pcl)
                             child_recs[d].append(
                                 (rc, int(coff), rbc, base,
-                                 ps_row, pcl, rc))
+                                 ps_row, pcl, rc, cslab))
                     if coop and d != (int(s) % ndev if rotate else 0):
                         # coop fronts: factor work is shared, but
                         # ownership (slab slot, solve updates, diag-U
@@ -970,10 +1077,13 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
             # size when chunked.  The chunk cap bounds the per-chunk
             # transient gather/scatter tensors (~16 MB int32).
             # A bucket at or over the size test (_ea_row_lane) rides
-            # the ROW lane: one child a loop turn, moved by whole rows
-            # (C = 0 in its meta).  Its read is one dynamic_slice
-            # reshaped at the child's slab stride, so the bucket's
-            # distinct strides join its meta (a fifth, static entry).
+            # the ROW lane: a wave of children of distinct parents a
+            # loop turn, moved by whole rows (C = 0 in its meta; its
+            # records lie wave after wave, _ea_waves, and K is the
+            # waves' widths together).  A wave's children are read as
+            # slots of one child group's slab, so a wave holds one
+            # such source, and the bucket's waves (width, chunk and
+            # source each: static) join its meta.
             by_rc: dict = {}
             for d in range(ndev):
                 for rec in child_recs[d]:
@@ -985,14 +1095,11 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                 per_d = by_rc[(rc_b, tc_b)]
                 K = _next_bucket(max(len(v) for v in per_d))
                 C = max(1, (1 << 22) // (rc_b * tc_b))
-                row = ()
+                waves = ()
                 if _ea_row_lane(rc_b, tc_b, mb, ncols):
                     C = 0
-                    recs = [rec for v in per_d for rec in v]
-                    row = (tuple(sorted({int(r[2]) for r in recs})),)
-                    row_read_end = max(
-                        [row_read_end]
-                        + [r[1] + rc_b * r[2] for r in recs])
+                    per_d, waves = _ea_waves(per_d, mb, ncols)
+                    K = len(per_d[0])
                 elif K > C:
                     K = -(-K // C) * C
                 else:
@@ -1006,26 +1113,44 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                 pc = (pr if not sharded else
                       np.full((ndev, K, tc_b), ncols, dtype=np.int64))
                 for d in range(ndev):
-                    for i, (rc, coff, stride, base, ps_row, ps_col,
-                            tc) in enumerate(per_d[d]):
-                        so[d, i] = coff
+                    npad = 0
+                    for i, rec in enumerate(per_d[d]):
+                        if rec is None:
+                            # a wave's padding record (every position
+                            # absent): a front of its own past the
+                            # group's, so that a wave's fronts stay
+                            # distinct and ascending; its write is
+                            # dropped on device
+                            db[d, i] = (n_loc + npad) * mb * ncols
+                            npad += 1
+                            continue
+                        rc, coff, stride, base, ps_row, ps_col, tc = \
+                            rec[:7]
+                        if C:
+                            so[d, i] = coff
+                        else:
+                            # row lane: the child's slot in its wave's
+                            # slab (a padding record reads slot 0)
+                            voff, _, rbc = rec[7]
+                            so[d, i] = (coff - voff) // (rbc * stride)
                         st[d, i] = stride
                         db[d, i] = base
                         pr[d, i, :rc] = ps_row
                         if sharded:
                             pc[d, i, :tc] = ps_col
-                    # K-padding records repeat the LAST real dst_base:
-                    # their positions are all-sentinel (dropped) so db
-                    # is semantically dead on the element path, but the
-                    # Pallas scatter engine's output-block schedule
-                    # requires db monotone per device (a 0 would
-                    # revisit front 0 out of order and overwrite its
-                    # accumulated delta)
+                    # the element lane's K-padding records repeat the
+                    # LAST real dst_base: their positions are
+                    # all-sentinel (dropped) so db is semantically dead
+                    # there, but the Pallas scatter engine's
+                    # output-block schedule requires db monotone per
+                    # device (a 0 would revisit front 0 out of order
+                    # and overwrite its accumulated delta)
                     nreal = len(per_d[d])
-                    if 0 < nreal < K:
+                    if C and 0 < nreal < K:
                         db[d, nreal:] = db[d, nreal - 1]
                 ea_hosts.append((so, st, db, pr, pc))
-                ea_meta.append((rc_b, tc_b, K, C) + row)
+                ea_meta.append((rc_b, tc_b, K, C)
+                               + ((waves,) if C == 0 else ()))
 
             # bucket the block-copy records by exact (li, lj, stride):
             # every record in a bucket shares its slice shapes, so one
@@ -1163,8 +1288,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                             L_total=L_cur, U_total=U_cur,
                             Li_total=Li_cur, Ui_total=Ui_cur,
                             sup_dev=sup_dev,
-                            upd_pad=max(1 + max_blk_stride,
-                                        row_read_end - upd_peak))
+                            upd_pad=1 + max_blk_stride)
     # once a schedule, never a step (get_schedule caches it)
     obs.COMPILE_WATCH.record_phases(
         t_build0, {"SCHEDULE": time.perf_counter() - t_build0})
@@ -1183,7 +1307,7 @@ def get_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
            if ndev > 1 else 0,
            _level_merge_limit() if _level_merge_on() else None,
            (_ea_block_min_run() if _ea_block_on() else None),
-           _EA_ROW_GAIN)
+           _EA_ROW_GAIN, _EA_WAVE_ENTRIES)
     if key not in cache:
         cache[key] = build_schedule(plan, ndev)
     return cache[key]
@@ -1303,7 +1427,9 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     of materializing a whole leaf level at once.  A bucket at or over
     the size test (meta C == 0) takes the row lane, `_ea_add_rows`, in
     its place in the bucket order: its positions arrive as inverse
-    maps and no per-entry index is built.
+    maps, its destinations as front indices, its records wave after
+    wave (a wave of children of distinct parents a loop turn), and no
+    per-entry index is built.
 
     `ncols` is the front's column count (mb for the square layout;
     cp for sharded-coop owned-column slices, whose destination column
@@ -1340,8 +1466,8 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
             so = so.astype(jnp.int64)
             st = st.astype(jnp.int64)
         if C == 0:
-            F = _ea_add_rows(F, upd_buf, so, st, db, pr, pc, rc_b=rc_b,
-                             tc_b=tc_b, K=K, strides=row[0], mb=mb,
+            F = _ea_add_rows(F, upd_buf, so, db, pr, pc, rc_b=rc_b,
+                             tc_b=tc_b, waves=row[0], mb=mb,
                              n_pad=n_pad, ncols=ncols)
             continue
 
@@ -1391,53 +1517,89 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
     return F
 
 
-def _ea_add_rows(F, upd_buf, so, st, db, inv_r, inv_c, *, rc_b: int,
-                 tc_b: int, K: int, strides: tuple, mb: int, n_pad: int,
+def _ea_add_rows(F, upd_buf, slot, fr, inv_r, inv_c, *, rc_b: int,
+                 tc_b: int, waves: tuple, mb: int, n_pad: int,
                  ncols: int):
-    """Row lane of `_ea_add` (meta C == 0): one child a loop turn,
-    moved by whole rows — no index per matrix entry exists here.  The
-    child's update is READ as the contiguous block it is (one
-    dynamic_slice of rc_b·stride slab elements from `so`, reshaped to
-    rows at the slab stride, which is one of the bucket's static
-    `strides`; BatchedSchedule.upd_pad covers the over-read), PULLED
-    into its parent's shape by two whole-row gathers through the
-    inverse position maps the host ships (`inv_r`: front row -> child
-    row, `inv_c`: front column -> child column; each points where the
-    child has none at an appended zero row) with a transpose between,
-    and ADDED densely to the front's rows.  Children collide within a
-    parent, so they are added one at a time, in record order; a
-    K-padding record (every position absent) adds zeros."""
-    F2 = F.reshape(n_pad * mb, ncols)
+    """Row lane of `_ea_add` (meta C == 0): a WAVE of children a loop
+    turn, moved by whole rows — no index per matrix entry exists here.
+    The bucket's records lie wave after wave (`_ea_waves`): the
+    children of one wave have distinct parents and one source, so a
+    turn moves Wc of them at once (`waves`: static (W, Wc, src)
+    triples, W records of which Wc a turn; adjacent waves of one Wc
+    and source share a loop), and a parent's children stay in their
+    order, wave after wave, so every front entry takes the addends it
+    took one child a turn, in that order.  The children are READ as
+    what they are, slots of their group's slab (src = (voff, nslots,
+    rbc, stride): the part of the slab that the loop's records span,
+    from voff, viewed as nslots blocks of
+    (rbc, stride), of which the turn gathers those at `slot`), each
+    PULLED into its parent's shape by two whole-row gathers through
+    the inverse position maps the host ships (`inv_r`: front row ->
+    child row, `inv_c`: front column -> child column; each points
+    where the child has none at an appended zero row) with a transpose
+    between, and ADDED densely to their fronts `fr` of the
+    (n_pad, mb, ncols) view: the turn's fronts are gathered, added to
+    and set back.  A wave's padding record (every position absent, a
+    front past the group's) adds zeros to nothing.
 
-    def read_at(stride: int):
-        def read(off):
-            blk = jax.lax.dynamic_slice(upd_buf, (off,),
-                                        (rc_b * stride,))
-            blk = blk.reshape(rc_b, stride)[:, :tc_b]
-            # a slab row narrower than the bucket holds the child whole
-            return jnp.pad(blk, ((0, 0), (0, tc_b - blk.shape[1])))
-        return read
+    A turn of ONE child (a wave of one, or a front so large that the
+    chunk is one) updates its front in place, as the lane did before
+    it had waves: the child's block a `dynamic_slice` of the slab, its
+    front sliced, added to and written back with a
+    `dynamic_update_slice`, where the batched form gathers and sets
+    copies of its fronts (three fronts of scratch under a front of
+    6,144 rows by the TPU compiler's report,
+    tests/test_pack_program.py; priced alone on a v5e, PERF.md §6,
+    PR 46: the batched turn of one at 1.0 to 1.2 times this one)."""
+    F3 = F.reshape(n_pad, mb, ncols)
 
-    reads = [read_at(s) for s in strides]
-    below = jnp.asarray(strides[:-1], st.dtype)
-
-    def add_one(i, F2):
-        blk = (reads[0](so[i]) if len(reads) == 1 else jax.lax.switch(
-            jnp.sum(st[i] > below), reads, so[i]))
+    def pull(blk, inv_r, inv_c):
+        """One child's (rbc, stride) block -> its (mb, ncols) addend."""
+        blk = blk[:rc_b, :tc_b]
+        # a slab block smaller than the bucket holds the child whole
+        blk = jnp.pad(blk, ((0, rc_b - blk.shape[0]),
+                            (0, tc_b - blk.shape[1])))
         tall = jnp.concatenate([blk, jnp.zeros((1, tc_b), blk.dtype)]) \
-            .at[inv_r[i]].get(mode="promise_in_bounds")  # (mb, tc_b)
+            .at[inv_r].get(mode="promise_in_bounds")   # (mb, tc_b)
         wide = jnp.concatenate([tall.T, jnp.zeros((1, mb), blk.dtype)]) \
-            .at[inv_c[i]].get(mode="promise_in_bounds")  # (ncols, mb)
-        row0 = (db[i] // ncols).astype(jnp.int32)
-        z = jnp.zeros((), jnp.int32)
-        cur = jax.lax.dynamic_slice(F2, (row0, z), (mb, ncols))
-        return jax.lax.dynamic_update_slice(F2, cur + wide.T, (row0, z))
+            .at[inv_c].get(mode="promise_in_bounds")   # (ncols, mb)
+        return wide.T
 
-    if K == 1:
-        F2 = add_one(0, F2)
-    else:
-        F2 = jax.lax.fori_loop(0, K, add_one, F2)
-    return F2.reshape(-1)
+    def add_one(F3, src, slot, fr, inv_r, inv_c):
+        voff, _, rbc, stride = src
+        blk = jax.lax.dynamic_slice(
+            upd_buf, (voff + slot[0] * (rbc * stride),), (rbc * stride,))
+        add = pull(blk.reshape(rbc, stride), inv_r[0], inv_c[0])
+        cur = jax.lax.dynamic_index_in_dim(F3, fr[0], 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(F3, cur + add, fr[0], 0)
+
+    def add_wave(F3, slab, slot, fr, inv_r, inv_c):
+        add = jax.vmap(pull)(
+            slab.at[slot].get(mode="promise_in_bounds"), inv_r, inv_c)
+        cur = F3.at[fr].get(mode="clip", unique_indices=True,
+                            indices_are_sorted=True)
+        return F3.at[fr].set(cur + add, mode="drop",
+                             unique_indices=True, indices_are_sorted=True)
+
+    base = 0
+    for Wc, turns, src in _ea_wave_runs(waves):
+        if Wc == 1:
+            add, arg = add_one, src
+        else:
+            voff, nslots, rbc, stride = src
+            add, arg = add_wave, jax.lax.slice_in_dim(
+                upd_buf, voff, voff + nslots * rbc * stride
+            ).reshape(nslots, rbc, stride)
+
+        def turn(t, F3, base=base, Wc=Wc, add=add, arg=arg):
+            return add(F3, arg, *(
+                jax.lax.dynamic_slice_in_dim(a, base + t * Wc, Wc, 0)
+                for a in (slot, fr, inv_r, inv_c)))
+
+        F3 = turn(0, F3) if turns == 1 else jax.lax.fori_loop(
+            0, turns, turn, F3)
+        base += Wc * turns
+    return F3.reshape(-1)
 
 
 @jax.named_scope("slu.extend_add")

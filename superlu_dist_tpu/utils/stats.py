@@ -108,7 +108,8 @@ class Stats:
     factor_flops_executed: float = 0.0
     # extend-add elements of the LAST factorization by lane
     # (BatchedSchedule.ea_elements: element / row / block, each
-    # {padded, real}); empty on the host backend
+    # {padded, real}, the row lane also {children, turns}); empty on
+    # the host backend
     ea_elements: Dict[str, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
     # collective traffic: predicted from the schedule (comm_summary)
@@ -334,6 +335,11 @@ class Stats:
             lines.append("  extend-add elements (padded / real): " + ", ".join(
                 f"{k} {v['padded']:.4g} / {v['real']:.4g}"
                 for k, v in self.ea_elements.items()))
+            row = self.ea_elements.get("row", {})
+            if row.get("turns"):
+                lines.append(
+                    f"  row lane: {row['children']} children in "
+                    f"{row['turns']} loop turns")
         if self.comm_predicted:
             lines.append("** Collective traffic (predicted) **")
             for k, v in self.comm_predicted.items():

@@ -366,16 +366,19 @@ def test_pack_program_compiles_small_for_v5e(v5e_chip, monkeypatch):
 
 def test_row_lane_body_compiles_small_for_v5e(v5e_chip):
     """The extend-add row lane (`_ea_add_rows`) at the benchmark's
-    largest bucket: two children of 3,072 padded rows, read at a slab
-    stride that is no multiple of a tile (one child at another, so
-    the read is a switch), into a front of 6,144 x 6,144 (wb 512).
-    The TPU's compiler keeps it a loop of slices, row gathers and
-    transposes: scratch of a few fronts, code of a megabyte or two.
+    largest bucket: two children of 3,072 padded rows, each the one
+    slot of a slab whose stride is no multiple of a tile (two
+    sources, so two waves of one child), into a front of
+    6,144 x 6,144 (wb 512).  The TPU's compiler keeps it slices, row
+    gathers and transposes: scratch of a few fronts, code of a
+    megabyte or two.
     (The element lane's body for this bucket compiles in 20 s to
     7 MB of code; a reshape hoisted over the slice, as in the pack
     program's trap above, would show as scratch of the slab's size.)"""
     rc_b, mb, K, strides = 3072, 6144, 2, (2816, 3584)
-    meta = ((rc_b, rc_b, K, 0, strides),)
+    meta = ((rc_b, rc_b, K, 0,
+             tuple((1, 1, (i * 10_000_000, 1, s, s))
+                   for i, s in enumerate(strides))),)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
