@@ -13,10 +13,9 @@ groups; scan keeps every lane's ops at exact per-sample shapes).
 Both legs are pinned bitwise equal to per-sample execution at fp64
 (tests/test_batch.py).
 
-Pallas kernels are force-disabled under the batch traces
-(`force_xla=True` through _factor_group_impl and sweep): a
-pallas_call's batching rule is not a path we certify — the
-_factor_group_impl_pair precedent.  The XLA lowering is the pinned
+The Pallas panel LU is force-disabled under the batch traces
+(`force_xla=True` through _factor_group_impl): a pallas_call's
+batching rule is not a path we certify.  The XLA lowering is the pinned
 arm; a certified batched-Pallas arm is future work (GPU arm, ROADMAP
 item 2).
 """
@@ -263,8 +262,7 @@ def _batch_solve_fns(sched, dtype):
     panels the B-leading per-group pytree and b (B, n, nrhs).  The
     member body is _solve_packed_fn's sweep verbatim (pack inside the
     member lane, where tracers are unbatched-shaped, so
-    pack_panels_staged's pair discrimination stays valid); force_xla
-    pins the XLA lsum member under batching."""
+    pack_panels_staged's pair discrimination stays valid)."""
     key = ("batch_solve", np.dtype(dtype).str, _solve_arm(),
            trisolve.merge_cells_limit(), trisolve.seg_cells_limit())
     cache = getattr(sched, "_batch_solve_fns", None)
@@ -285,8 +283,7 @@ def _batch_solve_fns(sched, dtype):
         def mk(trans):
             def member(p, bb):
                 packs = trisolve.pack_panels_staged(ts, p)
-                return trisolve.sweep(ts, packs, bb, dt, trans,
-                                      force_xla=True)
+                return trisolve.sweep(ts, packs, bb, dt, trans)
 
             @jax.jit
             def fn(panels, b):

@@ -1,7 +1,7 @@
 """Registry of every `SLU_`-prefixed environment flag.
 
-The package and its tools grew ~50 `SLU_*` env knobs; this table is
-the single place they are all named and described.  The audit lives
+This table holds every `SLU_*` name the package reads: it is the
+single place they are all named and described.  The audit lives
 in tools/slulint (rules/envreads.flag_audit): it scans the package
 and tools/ for `SLU_[A-Z_0-9]+` tokens and fails when a
 read is undocumented here (or when an entry here no longer
@@ -27,17 +27,13 @@ FLAGS: dict[str, str] = {
     # --- execution-mode selection (ops/batched.py) ---
     "SLU_STAGED": "1/0 force per-group staged execution on/off (default: auto past SLU_STAGED_MIN_GROUPS groups)",
     "SLU_STAGED_MIN_GROUPS": "group count past which staged execution turns on automatically (default 96)",
-    "SLU_LEVEL_MERGE": "1 = coalesce each etree level's bucket groups into one padded group",
-    "SLU_LEVEL_MERGE_LIMIT": "max padded-flop growth factor a level merge may incur (default 1.5)",
-    "SLU_DIAG_UNROLL": "diagonal-panel elimination unroll factor, parsed once at import",
     # --- extend-add lanes (ops/batched.py) ---
     "SLU_EA_BLOCK": "1/0 block-copy extend-add lane for contiguous child runs (default on)",
     "SLU_EA_BLOCK_MIN_RUN": "minimum contiguous run length routed to the block lane (default 8)",
-    # --- blocked trisolve (ops/trisolve.py, parallel/factor_dist.py) ---
-    "SLU_TRISOLVE": "auto|merged|legacy solve arm: merged = the communication-avoiding lsum trisolve (packed panels, dense lsum buffers, zero scatters; the legacy sweep's arithmetic in its order, agreement to 4 eps pinned); auto = merged, on one device and on a mesh alike (there the row-partitioned merged program, parallel/factor_dist.make_dist_solve_merged, for a narrow rhs); legacy = the scatter-add sweep on one device and the replicated-X psum sweep on a mesh",
+    # --- blocked trisolve (ops/trisolve.py) ---
+    "SLU_TRISOLVE": "auto|merged|legacy: the one-device sweep only (a mesh reads nothing here).  merged = the communication-avoiding lsum trisolve (packed panels, dense lsum buffers, zero scatters; the legacy sweep's arithmetic in its order, agreement to 4 eps pinned); auto = merged; legacy = the scatter-add sweep",
     "SLU_TRISOLVE_MERGE_CELLS": "panel-cell bound (trim*mb*wb) under which a group joins a merged dispatch segment (default 65536); larger groups stand alone",
     "SLU_TRISOLVE_SEG_CELLS": "total panel-cell budget of one merged segment (default 1048576) — bounds per-segment staged program size",
-    "SLU_TRISOLVE_PALLAS": "1 = fuse each merged forward group's panel-solve + lsum update into the Pallas lsum kernel (ops/pallas_lsum.py; f32/bf16 real only, default off until a chip run prices it)",
     # --- level-merged factor sweep (ops/batched.py) ---
     "SLU_FACTOR_MERGE_CELLS": "front-cell bound (n_loc*mb*ncols) at or below which a factor group joins a merged staged dispatch segment (default 65536); 0 = legacy per-group staged dispatch (the A/B arm).  Merging is dispatch granularity only — factors are bitwise-identical to the legacy sweep",
     "SLU_FACTOR_SEG_CELLS": "total front-cell budget of one merged factor segment (default 1048576) — bounds per-segment staged program size so segment compiles stay in the per-group compile class",
@@ -54,10 +50,9 @@ FLAGS: dict[str, str] = {
     "SLU_COOP_MB": "front-size cap for cooperative factorization tiles (default 256)",
     "SLU_COOP_SOLVE_ROTATE": "1 = rotate solve ownership across devices instead of device 0",
     "SLU_RHS_SHARDED": "auto|1|0 shard wide RHS blocks over the mesh for the dist solve",
-    # --- Pallas kernels (ops/pallas_lu.py, pallas_scatter.py) ---
+    # --- Pallas kernel (ops/pallas_lu.py) ---
     "SLU_TPU_PALLAS": "1 = enable the Pallas diagonal-LU kernel (validated, retired to opt-in)",
     "SLU_TPU_PALLAS_COLUMN": "1 = force the per-column rank-1 Pallas LU variant",
-    "SLU_TPU_PALLAS_SCATTER": "1 = enable the Pallas one-hot MXU scatter engine for ragged extend-add",
     # --- planning / ordering (parallel/ordering_dist.py) ---
     "SLU_DORDER_CLUSTER": "distributed-ordering aggregation block size (default 16)",
     # --- observability (obs/tracer.py, obs/compile_watch.py) ---

@@ -170,8 +170,8 @@ class HealthMonitor:
         1 under the merged trisolve arm on either handle form, a
         program a group each way for a staged handle under the legacy
         sweep; 1 on a mesh; None on the host oracle).  On a mesh
-        `sweep_arm` names that program (`merged`, `replicated`,
-        `rhs_sharded`: parallel/factor_dist.solve_arm) and
+        `sweep_arm` names that program (`merged` or `rhs_sharded`:
+        parallel/factor_dist.solve_arm) and
         `sweep_syncs` counts its all-reduces.  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former

@@ -428,73 +428,6 @@ def _zone_assignment(fp, ndev: int) -> np.ndarray:
     return zone
 
 
-def _level_merge_on() -> bool:
-    """SLU_LEVEL_MERGE=1: coalesce each etree level's bucket groups
-    (cost-bounded; see the merge block in build_schedule).  Off by
-    default — on CPU the padded flops are real cost; the accelerator
-    A/B decides."""
-    return flags.env_str("SLU_LEVEL_MERGE", "0") == "1"
-
-
-def _level_merge_limit() -> float:
-    """Padded/original cell-ratio bound for level merging
-    (SLU_LEVEL_MERGE_LIMIT, default 1.5)."""
-    try:
-            v = flags.env_float("SLU_LEVEL_MERGE_LIMIT", 1.5)
-    except ValueError:
-        v = 1.5
-    return max(1.0, v)
-
-
-def _coalesce_buckets(by_bucket: dict, limit: float) -> dict:
-    """Cost-bounded coalescing of one level's {(wb, mb): [sup...]}
-    bucket groups into fewer padded groups.
-
-    A merged frame must hold every member's TRUE panel and struct
-    extents: wb = max panel bucket and rb = max struct capacity
-    (mb − wb) over the members.  Merging is COST-BOUNDED (`limit`×
-    padded cells; SLU_LEVEL_MERGE_LIMIT, default 1.5): an unbounded
-    per-level merge measured 2.9× the update-slab elements at
-    n=262k — past HBM — while near-size buckets merge almost free.
-    Greedy ascending scan: buckets join the open super-bucket while
-    the accumulated padded/original cell ratio holds.  Distinct
-    greedy groups can close with the SAME padded frame (a later
-    small-panel/large-struct group can pad to an earlier group's
-    exact extents) — they fold into one group (same shape, so the
-    union is well-formed); overwriting instead would silently drop
-    fronts from the schedule."""
-    def cells(nf, wb_, rb_):
-        mb_ = wb_ + rb_
-        return nf * (2 * wb_ * mb_ + rb_ * rb_)
-
-    items = sorted(
-        ((wb0, mb0 - wb0, len(sl), sl)
-         for (wb0, mb0), sl in by_bucket.items()),
-        key=lambda t: (t[0], t[1]))
-    merged: dict = {}
-
-    def close(cur):
-        merged.setdefault((cur[0], cur[0] + cur[1]),
-                          []).extend(cur[3])
-
-    cur = None      # [wb_m, rb_m, orig_cells, slist]
-    for wb0, rb0, nf, sl in items:
-        if cur is not None:
-            wb_m = max(cur[0], wb0)
-            rb_m = max(cur[1], rb0)
-            newc = cells(len(cur[3]) + nf, wb_m, rb_m)
-            if newc <= limit * (cur[2] + cells(nf, wb0, rb0)):
-                cur[0], cur[1] = wb_m, rb_m
-                cur[2] += cells(nf, wb0, rb0)
-                cur[3] = cur[3] + sl
-                continue
-            close(cur)
-        cur = [wb0, rb0, cells(nf, wb0, rb0), list(sl)]
-    if cur is not None:
-        close(cur)
-    return merged
-
-
 def _ea_block_on() -> bool:
     """Block-copy extend-add lane (SLU_EA_BLOCK, default ON): children
     whose extend-add position maps are a few long contiguous runs move
@@ -724,10 +657,6 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
     max_blk_stride = 0           # sizes the upd-slab tail pad
 
     sup_upd_off = np.full(fp.nsuper, -1, dtype=np.int64)
-    # actual slab row/col stride each front was WRITTEN with — its
-    # group's rb, which under SLU_LEVEL_MERGE can exceed the front's
-    # own bucket (fp.mb - fp.wb); parents must read with this stride
-    sup_slab_rb = np.zeros(fp.nsuper, dtype=np.int64)
     groups: List[GroupSpec] = []
     L_cur = U_cur = Li_cur = Ui_cur = 0
 
@@ -796,16 +725,6 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
         for s in sups:
             by_bucket.setdefault((int(fp.wb[s]), int(fp.mb[s])),
                                  []).append(int(s))
-        if _level_merge_on() and len(by_bucket) > 1:
-            # SLU_LEVEL_MERGE=1: coalesce the level's bucket groups
-            # into fewer padded groups (_coalesce_buckets) — the
-            # latency-regime trade: fewer sequential group bodies on
-            # the device at the price of padded flops/slab; the
-            # tau/cap amalgamation's sibling lever (a pre-round chip
-            # record, not re-measured, priced it at -2 % / -22 %;
-            # ROADMAP D2).
-            by_bucket = _coalesce_buckets(by_bucket,
-                                          _level_merge_limit())
         for (wb, mb), slist in sorted(by_bucket.items()):
             N = len(slist)
             rb = mb - wb
@@ -972,7 +891,7 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                         rc = int(fp.r[c])
                         if rc == 0:
                             continue
-                        rbc = int(sup_slab_rb[c])
+                        rbc = int(fp.mb[c] - fp.wb[c])
                         coff = sup_upd_off[c]
                         assert coff >= 0, "child scheduled after parent"
                         ps_row = _pad_pos(fp.ea_map[c], w, wb)
@@ -1049,7 +968,6 @@ def build_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
                     # (coop slabs: single owner-slot copy, bg = b)
                     sup_upd_off[s] = upd_off + (b if coop else bg) \
                         * rb * (tp if sharded else rb)
-                    sup_slab_rb[s] = rb
                     sup_dev[s] = d
                     sup_pos[pos_of[s]] = bg
             if sharded:
@@ -1299,13 +1217,11 @@ def get_schedule(plan: FactorPlan, ndev: int = 1) -> BatchedSchedule:
     cache = getattr(plan, "_batched_schedules", None)
     if cache is None:
         cache = plan._batched_schedules = {}
-    # the coop/merge knobs participate in the key so a mid-process
-    # SLU_COOP_*/SLU_LEVEL_MERGE change takes effect instead of
-    # hitting a stale entry
+    # the coop knobs participate in the key so a mid-process
+    # SLU_COOP_* change takes effect instead of hitting a stale entry
     key = (ndev, (_coop_mb_min(), _coop_sharded_on(), _coop_block(),
                   _coop_solve_rotate())
            if ndev > 1 else 0,
-           _level_merge_limit() if _level_merge_on() else None,
            (_ea_block_min_run() if _ea_block_on() else None),
            _EA_ROW_GAIN, _EA_WAVE_ENTRIES)
     if key not in cache:
@@ -1417,7 +1333,7 @@ def psum_exact(x, axis):
 
 @jax.named_scope("slu.extend_add")
 def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
-            ncols: int = 0, allow_pallas: bool = True):
+            ncols: int = 0):
     """Extend-add of child update blocks into the flat front batch F.
     Outer-product form: per child only its O(rc) position vectors ship
     from the host; the rc·tc flat indices are iota arithmetic on
@@ -1433,21 +1349,10 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
 
     `ncols` is the front's column count (mb for the square layout;
     cp for sharded-coop owned-column slices, whose destination column
-    index is an owned SLOT from the separate pos_col vector).
-
-    With SLU_TPU_PALLAS_SCATTER=1 (ops/pallas_scatter) the scatter
-    side of eligible buckets runs as the tiled Pallas scatter engine
-    (the dsuperlu_gpu.cu:115-143 analog): per-child one-hot expansion
-    on the MXU accumulating into per-front VMEM tiles — to be priced
-    on the chip before any default flips."""
+    index is an owned SLOT from the separate pos_col vector)."""
     if not ncols:
         ncols = mb
     f_loc = n_pad * mb * ncols
-    from . import pallas_scatter
-    # pair mode traces this under vmap, where a pallas_call's batching
-    # rule is not a path we certify — the plane loop keeps the element
-    # scatter there (allow_pallas=False from _factor_group_impl_pair)
-    use_ps = allow_pallas and pallas_scatter.enabled(F.dtype)
 
     for (rc_b, tc_b, K, C, *row), (so, st, db, pr, pc) in zip(
             ea_meta, ea_blocks):
@@ -1478,19 +1383,6 @@ def _ea_add(F, upd_buf, ea_blocks, ea_meta, *, mb: int, n_pad: int,
                    + ai[None, :, None] * st[:, None, None]
                    + aj[None, None, :]).reshape(-1)
             upd = upd_buf[src]
-            if use_ps and pallas_scatter.usable(mb, ncols, rc_b, tc_b,
-                                                upd.dtype):
-                # scatter engine: the gather above still feeds it, but
-                # the serialized element scatter becomes MXU one-hot
-                # accumulation into per-front VMEM tiles (records are
-                # front-sorted by the schedule builder; sentinel
-                # positions mb/ncols one-hot to zero rows — dropped)
-                fb = (db // (mb * ncols)).astype(jnp.int32)
-                delta = pallas_scatter.scatter_add_delta(
-                    upd.reshape(-1, rc_b, tc_b),
-                    pr.astype(jnp.int32), pc.astype(jnp.int32), fb,
-                    mb=mb, ncols=ncols, n_pad=n_pad)
-                return Ff + delta.reshape(-1)
             pi = pr[:, :, None].astype(db.dtype)
             pj = pc[:, None, :].astype(db.dtype)
             dst = db[:, None, None] + pi * ncols + pj
@@ -1676,12 +1568,8 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
                             unique_indices=True,
                             indices_are_sorted=True)
         F = F.at[one_dst].set(one, mode="drop", unique_indices=True)
-    # force_xla: the batch engine (superlu_dist_tpu/batch/engine.py)
-    # traces this body under jax.vmap, where a pallas_call's batching
-    # rule is not a path we certify — the _factor_group_impl_pair
-    # precedent, applied to the element scatter AND the panel-LU
     F = _ea_add(F, upd_buf, elem_blocks, ea_meta, mb=mb, n_pad=n_pad,
-                ncols=ncols, allow_pallas=not force_xla)
+                ncols=ncols)
     F = _ea_add_blocks(F, upd_buf, blk_blocks, eb_meta, mb=mb,
                        n_pad=n_pad, ncols=ncols)
     F = F.reshape(n_pad, mb, ncols)
@@ -1715,7 +1603,10 @@ def _factor_group_impl(vals, upd_buf, L_flat, U_flat, Li_flat, Ui_flat,
         # pallas_diag=True is the merged-factor-segment promotion of
         # the Pallas panel-LU kernel (ops/pallas_lu.merged_eligible):
         # the caller resolved eligibility per member bucket, so this
-        # call routes through the kernel unconditionally-if-available
+        # call routes through the kernel unconditionally-if-available.
+        # force_xla: the batch engine (batch/engine.py) traces this
+        # body under jax.vmap, where a pallas_call's batching rule is
+        # not a path we certify, so it pins the panel LU to XLA
         Lsrc, Usrc, upd_src, tiny_g, nzero_g = partial_lu_panels_batch(
             F, thresh, wb=wb,
             pallas=(False if force_xla
@@ -1816,7 +1707,7 @@ def _factor_group_impl_pair(vals, upd_buf, L_flat, U_flat, Li_flat,
                            vals, one_pl)
     F = jax.vmap(lambda f, u: _ea_add(
         f, u, elem_blocks, ea_meta, mb=mb, n_pad=n_pad,
-        ncols=ncols, allow_pallas=False))(F, upd_buf)
+        ncols=ncols))(F, upd_buf)
     F = jax.vmap(lambda f, u: _ea_add_blocks(
         f, u, blk_blocks, eb_meta, mb=mb, n_pad=n_pad,
         ncols=ncols))(F, upd_buf)

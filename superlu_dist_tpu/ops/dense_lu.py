@@ -27,21 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .. import flags
 
-
-def _env_unroll(default: int = 8) -> int:
-    """SLU_DIAG_UNROLL, parsed once at import (jit caches are keyed by
-    shapes only, so a mid-process change could never take effect
-    anyway); malformed values fall back to the default."""
-    try:
-        v = flags.env_int("SLU_DIAG_UNROLL", default)
-    except (TypeError, ValueError):
-        return default
-    return v if v >= 1 else default
-
-
-_DIAG_UNROLL = _env_unroll()
+# unroll chunk of the diagonal-block elimination chain
+_DIAG_UNROLL = 8
 
 
 def _newton_tri_inverse(T, *, lower: bool, unit: bool):
@@ -273,9 +261,8 @@ def _use_pallas(F, pallas: bool | None) -> bool:
     forces the XLA path, None keeps the historical SLU_TPU_PALLAS
     resolution."""
     from . import pallas_lu
-    from .pallas_common import mosaic_dtype
     use = (pallas_lu.enabled(F.dtype) if pallas is None
-           else bool(pallas) and mosaic_dtype(F.dtype))
+           else bool(pallas) and pallas_lu.mosaic_dtype(F.dtype))
     return use and pallas_lu.usable(F.shape[-1], F.dtype)
 
 

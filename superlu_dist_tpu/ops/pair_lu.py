@@ -39,9 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .dense_lu import _env_unroll
-
-_DIAG_UNROLL = _env_unroll()
+from .dense_lu import _DIAG_UNROLL
 
 
 # ---------------------------------------------------------------- algebra

@@ -32,8 +32,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import flags
-from .pallas_common import (VMEM_BUDGET_BYTES, interpret_default,
-                            mosaic_dtype)
+
+# the kernel keeps its operands plus an output copy VMEM-resident
+# (~16 MB/core on v5e); beyond this the XLA path keeps the bucket
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+
+def mosaic_dtype(dtype) -> bool:
+    """Real sub-64-bit dtypes only: Mosaic lowers neither complex nor
+    64-bit (the kernel traces under `jax.enable_x64(False)` for the
+    same reason — weak Python scalars must enter the jaxpr at 32
+    bit)."""
+    dtype = np.dtype(dtype)
+    return dtype.kind != "c" and dtype.itemsize < 8
+
+
+def interpret_default() -> bool:
+    """Interpret mode exists for the CPU test suite, which runs the
+    same kernel body through the Pallas interpreter.  On a TPU
+    backend this is False: the kernel there always goes through
+    Mosaic, and a compile failure surfaces as the compiler's error."""
+    return jax.default_backend() != "tpu"
 
 
 def enabled(dtype) -> bool:
@@ -71,7 +90,8 @@ def merged_eligible(wb: int, mb: int, dtype) -> bool:
     factorization under `slu.pallas_lu`, 0.18 % of the roofline for
     the bytes it moves (my chip runs, PR 41; PERF.md section 5).  The
     XLA arm on the same bucket has not been read beside it: the arm is
-    priced and kept or deleted by a `simplicity` issue (ROADMAP D-a).
+    pinned by the benchmark's `pallas_lu_roofline`; a `benchmark`
+    issue releases it (ROADMAP D-a).
     SLU_TPU_PALLAS=0 restores the XLA path; =1 forces the kernel for
     every usable bucket (the historical A/B arm)."""
     if not mosaic_dtype(dtype) or not usable(mb, dtype):

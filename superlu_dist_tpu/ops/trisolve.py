@@ -46,15 +46,11 @@ is chosen by the arm), the staged fused solver's per-segment
 dispatch, the fused solvers' in-program sweeps, transpose solves, the
 complex pair-plane lane, and the row-partitioned mesh trisolve
 (`parallel/factor_dist.make_dist_solve_merged`: what a narrow-rhs
-sweep on a process grid is under this arm, by the same rule as on one
-device, `sweeps_packed()`; SLU_TRISOLVE=legacy selects the
-replicated-X psum sweep there as it selects the legacy sweep here).
+sweep on a process grid is).
 
-Flags (see flags.py): SLU_TRISOLVE selects the arm (auto|merged|
-legacy; auto = merged), SLU_TRISOLVE_MERGE_CELLS /
-SLU_TRISOLVE_SEG_CELLS bound the segment cost model,
-SLU_TRISOLVE_PALLAS arms the fused Pallas lsum kernel
-(ops/pallas_lsum.py, TPU A/B arm, off by default).
+Flags (see flags.py): SLU_TRISOLVE selects the one-device arm
+(auto|merged|legacy; auto = merged), SLU_TRISOLVE_MERGE_CELLS /
+SLU_TRISOLVE_SEG_CELLS bound the segment cost model.
 """
 
 from __future__ import annotations
@@ -91,9 +87,9 @@ def sweeps_packed() -> bool:
     """Do solves sweep the lsum layout (the merged arm)?  THE rule
     for who packs: `factorize_device` dispatches a factorization's
     pack where this holds and `_solve_device_common` takes the packs
-    where it holds, so the two sites cannot diverge.  A mesh asks the
-    same question (`parallel/factor_dist.solve_arm`): its narrow-rhs
-    sweep is the row-partitioned merged program where this holds."""
+    where it holds, so the two sites cannot diverge.  A mesh does not
+    ask: its narrow-rhs sweep is the row-partitioned merged program
+    (`parallel/factor_dist.solve_arm`)."""
     return trisolve_mode() == "merged"
 
 
@@ -121,29 +117,13 @@ def seg_cells_limit() -> int:
         return 1048576
 
 
-def active_arm(device_lu=None) -> str:
+def active_arm() -> str:
     """One-token description of the solve arm serving dispatches —
     stamped onto serve flight-recorder queue events and bench records
-    so p99 exemplars attribute latency to the right kernel.  The
-    "+pallas" suffix is claimed only when the lsum kernel can
-    actually execute for the handle: the env flag alone is not enough
-    (f64/complex dtypes have no Mosaic lowering — labeling those
-    dispatches "merged+pallas" would be exactly the misattribution
-    the arm field exists to prevent).  A staged handle's FACTORED
-    solve dispatches the packed program like any other
-    (`ops/batched._solve_device_common`), so it is labeled by its
-    dtype like any other."""
-    mode = trisolve_mode()
-    if mode != "merged":
-        return mode
-    if flags.env_str("SLU_TRISOLVE_PALLAS", "0") != "1":
-        return "merged"
-    if device_lu is not None:
-        from . import pallas_lsum
-        if not pallas_lsum.enabled(getattr(device_lu, "dtype",
-                                           np.float32)):
-            return "merged"
-    return "merged+pallas"
+    so p99 exemplars attribute latency to the right kernel.  Every
+    one-device handle, staged or not, dispatches the program this
+    names (`ops/batched._solve_device_common`)."""
+    return trisolve_mode()
 
 
 # --------------------------------------------------------------------
@@ -757,17 +737,12 @@ def _bwd_member(XF, Y, g, gs, pack, idx, cplx, trans):
 
 
 def sweep(ts: TrisolveSchedule, packs, b, dtype, trans: bool,
-          pair: bool = False, per_group_idx=None,
-          force_xla: bool = False):
+          pair: bool = False, per_group_idx=None):
     """The full merged triangular solve inside one trace: b (n, nrhs)
     in factor ordering -> x (n, nrhs).  Complex systems ride the same
     real-view codec as the legacy sweep (`_enc`/`_dec`); pair mode
     takes pre-encoded b and returns encoded, exactly like
-    `_solve_loop`.  `force_xla` pins every member to the XLA lsum
-    body — the batch engine (superlu_dist_tpu/batch/engine.py) traces
-    this under jax.vmap, where a pallas_call's batching rule is not a
-    path we certify (the _factor_group_impl_pair precedent)."""
-    from . import pallas_lsum
+    `_solve_loop`."""
     from .batched import _dec, _enc
     sched = ts.sched
     n = sched.n
@@ -784,18 +759,10 @@ def sweep(ts: TrisolveSchedule, packs, b, dtype, trans: bool,
     if per_group_idx is None:
         per_group_idx = [gs.dev(squeeze=True) for gs in ts.groups]
 
-    use_pallas = (not force_xla and not pair and not cplx
-                  and not trans and pallas_lsum.enabled(rdt))
-
     state = (B, UPD, Y)
     for g, gs, pack, idx in zip(sched.groups, ts.groups, packs,
                                 per_group_idx):
-        if (use_pallas and gs.rtrim > 0
-                and pallas_lsum.usable(gs.trim, g.wb, gs.rtrim, R,
-                                       rdt)):
-            state = pallas_lsum.fwd_member(state, g, gs, pack, idx)
-        else:
-            state = _fwd_member(state, g, gs, pack, idx, cplx, trans)
+        state = _fwd_member(state, g, gs, pack, idx, cplx, trans)
     _, _, Y = state
     XF = jnp.zeros((ts.y_total + 1, R), rdt)
     for g, gs, pack, idx in zip(reversed(sched.groups),
@@ -830,8 +797,7 @@ def resident_sweep(ts: TrisolveSchedule, packs, b, dtype,
 
 def _packed_key(dtype, pair: bool):
     return ("packed", np.dtype(dtype).str, bool(pair),
-            merge_cells_limit(), seg_cells_limit(),
-            flags.env_str("SLU_TRISOLVE_PALLAS", "0"))
+            merge_cells_limit(), seg_cells_limit())
 
 
 def _solve_packed_fn(sched, dtype, pair: bool):

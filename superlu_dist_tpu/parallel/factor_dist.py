@@ -157,9 +157,9 @@ def _solve_loop(dsched, flats, b, dtype, per_group, axis,
     # loop-invariant inside the fused solvers' refinement while_loop,
     # so XLA hoists them and the repeated sweeps pay only the lsum
     # dataflow.  Axis mode below is the replicated-X psum sweep: what
-    # make_dist_step's fused program runs, and make_dist_solve under
-    # SLU_TRISOLVE=legacy.  A mesh's narrow-rhs sweep under the merged
-    # arm is make_dist_solve_merged, not this loop.
+    # make_dist_step's fused program and make_dist_solve run.  A
+    # mesh handle's narrow-rhs sweep is make_dist_solve_merged, not
+    # this loop.
     if axis is None:
         from ..ops import trisolve
         if trisolve.trisolve_mode() == "merged":
@@ -581,8 +581,8 @@ def make_dist_solve_merged(plan: FactorPlan, mesh: Mesh,
                            dtype=np.float64, axis=None,
                            trans: bool = False, pair: bool = False):
     """Row-partitioned merged mesh trisolve: what a narrow-rhs sweep
-    on a mesh is under the merged trisolve arm (`solve_arm`).  One
-    solve spans devices over the lsum layout (ops/trisolve.py): each
+    on a mesh is (`solve_arm`).  One solve spans devices over the lsum
+    layout (ops/trisolve.py): each
     device sweeps its own front partition — the rows its fronts own
     — writing y/update blocks DENSELY into its device-major slices
     of the global Y/UPD/XF slot spaces, and the cross-device dataflow
@@ -1008,35 +1008,25 @@ def _rhs_sharded_auto(nrhs: int, ndev: int) -> bool:
 def solve_arm(dlu: DistLU, nrhs: int) -> str:
     """Which program a sweep of `nrhs` columns is on this handle's
     mesh.  Many columns amortize one gather of the factors
-    (`rhs_sharded`, `_rhs_sharded_auto`); a narrow sweep is chosen by
-    the trisolve arm as on one device (`trisolve.sweeps_packed`):
-    `merged`, the row-partitioned lsum program, under `auto` and
-    `merged`; `replicated`, the X-psum sweep, under
-    SLU_TRISOLVE=legacy."""
-    from ..ops import trisolve as tsv
+    (`rhs_sharded`, `_rhs_sharded_auto`); a narrow sweep is `merged`,
+    the row-partitioned lsum program."""
     _, ndev = _resolve_axis(dlu.mesh, dlu.axis)
-    if _rhs_sharded_auto(nrhs, ndev):
-        return "rhs_sharded"
-    return "merged" if tsv.sweeps_packed() else "replicated"
+    return "rhs_sharded" if _rhs_sharded_auto(nrhs, ndev) else "merged"
 
 
 def solve_syncs(dlu: DistLU, arm: str) -> int:
     """All-reduces one sweep of that arm compiles to: a boundary of
-    the merged segments each (`trisolve.mesh_sync_count`), the
-    replicated sweep's one more (`comm_summary`: it reconciles X
-    after the forward sweep too), none where the columns are sharded
-    (one all-gather of the factors instead)."""
+    the merged segments each (`trisolve.mesh_sync_count`), none
+    where the columns are sharded (one all-gather of the factors
+    instead)."""
     if arm == "rhs_sharded":
         return 0
-    if arm == "merged":
-        from ..ops import trisolve as tsv
-        return tsv.mesh_sync_count(tsv.get_trisolve(dlu.schedule))
-    return int(dlu.schedule.comm_summary(dlu.dtype)["solve_syncs"])
+    from ..ops import trisolve as tsv
+    return tsv.mesh_sync_count(tsv.get_trisolve(dlu.schedule))
 
 
 _SOLVE_MAKERS = {"rhs_sharded": make_dist_solve_rhs_sharded,
-                 "merged": make_dist_solve_merged,
-                 "replicated": make_dist_solve}
+                 "merged": make_dist_solve_merged}
 
 
 def _solve_fn(dlu: DistLU, trans: bool, arm: str):
@@ -1062,7 +1052,8 @@ def dist_solve(dlu: DistLU, b_factor_order, trans: bool = False):
     keyed (mesh, dtype, trans, arm, storage), so SamePattern
     re-factorizations reuse them across handles.  `solve_arm` picks
     the program: many-RHS solves the rhs-sharded sweep
-    (make_dist_solve_rhs_sharded), narrow ones the trisolve arm's.
+    (make_dist_solve_rhs_sharded), narrow ones the merged one
+    (make_dist_solve_merged).
     Against pair-stored factors the host encodes the right-hand side
     and decodes the answer (spans `slu.pair.encode` /
     `slu.pair.decode`), and the answer is a host array."""

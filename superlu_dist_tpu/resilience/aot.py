@@ -242,7 +242,6 @@ def schedule_fingerprint(sched, dtype, extra=()) -> str:
               for g in sched.groups),
         B.factor_merge_cells(), B.factor_seg_cells(),
         T.trisolve_mode(), T.merge_cells_limit(), T.seg_cells_limit(),
-        flags.env_str("SLU_TRISOLVE_PALLAS", "0"),
         flags.env_str("SLU_TPU_PALLAS", "0"),
         tuple(extra),
     )

@@ -20,8 +20,8 @@ Durability discipline:
   * format version — an entry written by an incompatible layout is
     quarantined, not misinterpreted;
   * schedule-layout fingerprint — device flats are only valid against
-    the slab layout the CURRENT env knobs produce (SLU_LEVEL_MERGE
-    etc. move offsets); a mismatch quarantines rather than serving
+    the slab layout the CURRENT env knobs produce (SLU_COOP_MB etc.
+    move offsets); a mismatch quarantines rather than serving
     factors misaligned against a rebuilt schedule.
 
 Quarantine renames the file to `<entry>.quarantined` — the evidence

@@ -49,17 +49,16 @@ _DEADLINE_FLUSH_MARGIN_S = 0.001
 
 
 def _trisolve_arm(lu) -> str:
-    """The solve arm serving this dispatch (ops/trisolve.active_arm,
-    resolved against the handle so a non-Pallas-capable
-    factorization is never labeled '+pallas'); import deferred so the
-    batcher never pays an ops import on the module path.  A
+    """The solve arm serving this dispatch (ops/trisolve.active_arm);
+    import deferred so the batcher never pays an ops import on the
+    module path.  A
     mesh-resident handle (dist backend, ISSUE 17) is its own arm —
     its dispatch granularity is the shard_map'd whole-phase sweep,
     not any single-device trisolve variant."""
     if getattr(lu, "backend", None) == "dist":
         return "dist"
     from ..ops.trisolve import active_arm
-    return active_arm(getattr(lu, "device_lu", None))
+    return active_arm()
 
 
 def _mesh_leg(lu) -> str | None:

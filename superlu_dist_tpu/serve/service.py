@@ -1275,7 +1275,7 @@ def solve_jit_cache_size(lu: LUFactorization) -> int:
     if lu.backend == "dist" and lu.device_lu is not None:
         # mesh replica (ISSUE 17): the handle dispatches through the
         # plan-level dist solve cache — sum every compiled signature
-        # across its arms (replicated / merged / rhs-sharded), so a
+        # across its arms (merged / rhs-sharded), so a
         # ladder-induced recompile on ANY arm moves this probe
         from ..parallel.factor_dist import dist_solve_cache_size
         return dist_solve_cache_size(lu.device_lu)

@@ -166,7 +166,7 @@ class Stats:
     # dispatched; empty on the host oracle.  On a process grid the
     # route is the mesh's (parallel/factor_dist._mesh_route:
     # `devices`, `coop_groups`, `comm_bytes`) and the last solve adds
-    # `sweep_arm` (merged | replicated | rhs_sharded), `sweep_segments`
+    # `sweep_arm` (merged | rhs_sharded), `sweep_segments`
     # 1 and `sweep_syncs`, the all-reduces a sweep.  The health
     # ring's factor and solve records carry the same keys
     dispatch: Dict[str, object] = dataclasses.field(default_factory=dict)

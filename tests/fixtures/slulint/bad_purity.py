@@ -11,7 +11,7 @@ from superlu_dist_tpu import flags
 def stamped_step(x):
     t0 = time.time()            # trace-time constant, not a clock
     noise = np.random.rand()    # baked-in "random" draw
-    knob = flags.env_float("SLU_LEVEL_MERGE_LIMIT", 1.5)  # frozen knob
+    knob = flags.env_int("SLU_COOP_MB", 256)  # frozen knob
     return x * noise + t0 + knob
 
 

@@ -473,21 +473,23 @@ def test_comm_summary_coop_bytes(monkeypatch):
     assert cs["coop_gather_bytes"] == exp_gather
 
 
-@pytest.mark.parametrize("arm", ["merged", "replicated", "rhs_sharded"])
-def test_stats_dispatch_names_the_mesh_sweep(arm, monkeypatch):
+@pytest.mark.parametrize("arm, env", [
+    ("merged", None), ("merged", "legacy"), ("rhs_sharded", None)])
+def test_stats_dispatch_names_the_mesh_sweep(arm, env, monkeypatch):
     """On the dist backend a solve fills `Stats.dispatch` as the
     one-device path does: which program a sweep was (`sweep_arm`),
     that it was one program (`sweep_segments`), and the all-reduces
     it compiles to (`sweep_syncs`); the health ring's solve record
-    carries the same and `Stats.report()` prints them.  The trisolve
-    arm picks between the merged and the replicated sweep at one
-    column, eight columns on four devices shard the columns."""
+    carries the same and `Stats.report()` prints them.  One column
+    sweeps the merged program, with no variable set and under
+    SLU_TRISOLVE=legacy alike (the variable does not reach a mesh);
+    eight columns on four devices shard the columns."""
     from superlu_dist_tpu import Stats, factorize, obs, solve
     from superlu_dist_tpu.ops import trisolve
     from superlu_dist_tpu.parallel.factor_dist import measure_comm
     monkeypatch.delenv("SLU_TRISOLVE", raising=False)
-    if arm == "replicated":
-        monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    if env:
+        monkeypatch.setenv("SLU_TRISOLVE", env)
     nrhs = 8 if arm == "rhs_sharded" else 1
     a = laplacian_2d(12)
     rng = np.random.default_rng(3)
@@ -501,7 +503,6 @@ def test_stats_dispatch_names_the_mesh_sweep(arm, monkeypatch):
     assert d["sweep_arm"] == arm and d["sweep_segments"] == 1
     ts = trisolve.get_trisolve(lu.device_lu.schedule)
     want = {"merged": trisolve.mesh_sync_count(ts),
-            "replicated": st.comm_predicted["solve_syncs"],
             "rhs_sharded": 0}[arm]
     assert d["sweep_syncs"] == want
     if arm != "rhs_sharded":

@@ -1,11 +1,10 @@
-"""Block-copy extend-add lane + Pallas scatter engine (ISSUE 2b).
+"""Block-copy extend-add lane (ISSUE 2b).
 
 The slab↔GEMM-buffer traffic restructuring: contiguous-run detection
 on the host (crafted index-map unit tests), the device block-copy
 formulation (HLO pins dynamic-slice/dynamic-update-slice, zero
-scatter), numerical parity of the block lane against the element
-formulation, and the interpret-mode oracle for the Pallas scatter
-engine (`SLU_TPU_PALLAS_SCATTER`)."""
+scatter) and numerical parity of the block lane against the element
+formulation."""
 
 import os
 
@@ -181,46 +180,3 @@ def test_block_lane_dist_mesh():
     x, berr, *_ = step(jnp.asarray(a.data), jnp.asarray(b))
     relerr = np.linalg.norm(np.asarray(x) - xtrue) / np.linalg.norm(xtrue)
     assert relerr < 1e-10, relerr
-
-
-# ---- Pallas scatter engine (interpret-mode oracle) ----
-
-def test_pallas_scatter_delta_oracle():
-    from superlu_dist_tpu.ops import pallas_scatter as ps
-    rng = np.random.default_rng(0)
-    n_pad, mb, ncols = 3, 16, 16
-    K, rc_b, tc_b = 6, 4, 4
-    upd = rng.standard_normal((K, rc_b, tc_b)).astype(np.float32)
-    pr = rng.integers(0, mb, (K, rc_b)).astype(np.int32)
-    pc = rng.integers(0, ncols, (K, tc_b)).astype(np.int32)
-    pr[2, 3] = mb          # row sentinel drops
-    pc[4, 0] = ncols       # col sentinel drops
-    fb = np.array([0, 0, 0, 1, 2, 2], np.int32)   # front-sorted
-    delta = np.asarray(ps.scatter_add_delta(
-        jnp.asarray(upd), jnp.asarray(pr), jnp.asarray(pc),
-        jnp.asarray(fb), mb=mb, ncols=ncols, n_pad=n_pad,
-        interpret=True))
-    ref = np.zeros((n_pad, mb, ncols), np.float32)
-    for k in range(K):
-        for i in range(rc_b):
-            if pr[k, i] >= mb:
-                continue
-            for j in range(tc_b):
-                if pc[k, j] >= ncols:
-                    continue
-                ref[fb[k], pr[k, i], pc[k, j]] += upd[k, i, j]
-    np.testing.assert_allclose(delta, ref, rtol=1e-5, atol=1e-5)
-
-
-def test_pallas_scatter_end_to_end(monkeypatch):
-    """gssvx with the scatter engine forced on (interpret mode on
-    CPU), element lane only — full-pipeline correctness of the
-    one-hot MXU scatter formulation."""
-    monkeypatch.setenv("SLU_TPU_PALLAS_SCATTER", "1")
-    monkeypatch.setenv("SLU_EA_BLOCK", "0")
-    a = _testmat(30)
-    A = a.to_scipy()
-    xtrue = np.random.default_rng(4).standard_normal(a.n)
-    x, _, _ = slu.gssvx(slu.Options(factor_dtype="float32"), a,
-                        A @ xtrue)
-    assert np.linalg.norm(x - xtrue) / np.linalg.norm(xtrue) < 1e-10

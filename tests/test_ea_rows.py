@@ -38,7 +38,8 @@ from superlu_dist_tpu.ops import batched
 from superlu_dist_tpu.ops.batched import (_ea_add, _inverse_positions,
                                           get_schedule)
 from superlu_dist_tpu.plan.plan import plan_factorization
-from superlu_dist_tpu.utils.testmat import helmholtz_2d, laplacian_3d
+from superlu_dist_tpu.utils.testmat import (helmholtz_2d, laplacian_3d,
+                                            random_unsymmetric)
 
 ALL_ROWS, NO_ROWS = math.inf, 0
 
@@ -681,3 +682,32 @@ def test_no_wave_holds_two_records_of_one_parent(monkeypatch, mat,
             1 for c in range(fp.nsuper)
             if fp.r[c] > 0 and fp.sym.part.sparent[c] >= 0)
     assert row["children"] >= turns // ndev
+
+
+_PLANS = {"lap3d_k10": lambda: laplacian_3d(10),
+          "random_300": lambda: random_unsymmetric(300),
+          "helm2d_k6": lambda: helmholtz_2d(6)}
+
+
+@pytest.mark.parametrize("ndev", [1, 2])
+@pytest.mark.parametrize("mat", list(_PLANS))
+def test_every_front_has_one_slot_of_one_group(mat, ndev):
+    """Every supernode of the plan sits in exactly one slot of exactly
+    one group of the schedule, in a frame of its own bucket: a front
+    the builder dropped is never factored, and one it placed twice is
+    extend-added twice."""
+    a = _PLANS[mat]()
+    plan = plan_factorization(a, slu.Options(factor_dtype=a.dtype.name))
+    sched = batched.build_schedule(plan, ndev)
+    fp = plan.frontal
+    seen = np.zeros(fp.nsuper, dtype=np.int64)
+    for g in sched.groups:
+        ids = np.asarray(g.sup_ids, dtype=np.int64)
+        seen[ids] += 1
+        assert len(ids) == g.n_true
+        assert (fp.wb[ids] == g.wb).all() and (fp.mb[ids] == g.mb).all()
+        slots = np.asarray(g.sup_pos)
+        assert len(np.unique(slots)) == len(ids)
+        assert ((0 <= slots)
+                & (slots < (1 if g.coop else ndev) * g.n_loc)).all()
+    assert (seen == 1).all(), np.flatnonzero(seen != 1)

@@ -48,16 +48,13 @@ CASES = list(range(int(_os.environ.get("SLU_FUZZ_CASES", "24"))))
 
 @pytest.mark.parametrize("case", CASES)
 def test_fuzz_consistency(case, monkeypatch):
-    # rotate the schedule/storage execution modes through the sweep:
-    # level-merged schedules (SLU_LEVEL_MERGE, case % 7), the real-pair
+    # rotate the storage execution modes through the sweep: the real-pair
     # factor storage for complex cases (SLU_COMPLEX_PAIR, ops/pair_lu),
     # and the extend-add/residual-SpMV formulations (SLU_EA_BLOCK /
     # SLU_SPMV_LAYOUT: the defaults are the scatter-free block-copy +
     # ELL lanes, so rotating some cases onto the legacy element/COO
     # paths keeps BOTH formulations under the full option matrix) —
     # the same accuracy contract must hold under every execution mode
-    if case % 7 == 2:
-        monkeypatch.setenv("SLU_LEVEL_MERGE", "1")
     if case % 12 == 5:
         # half the complex cases (6k+5): 5, 17, 29… run pair storage,
         # 11, 23, 35… keep native complex — both modes stay covered

@@ -316,16 +316,18 @@ def test_a_second_value_set_on_the_held_plan(case, grid):
     assert _holds(scores), scores
 
 
-@pytest.mark.parametrize("arm", ["rhs_sharded", "merged", "replicated"])
-def test_every_solve_arm_takes_planes(case, arm, monkeypatch):
+@pytest.mark.parametrize("arm, env", [
+    ("rhs_sharded", None), ("merged", None), ("merged", "legacy")])
+def test_every_solve_arm_takes_planes(case, arm, env, monkeypatch):
     """Eight right-hand sides pick the rhs-sharded sweep (each
     device's column block encoded by itself); two columns the
-    trisolve arm's: the row-partitioned merged sweep with no variable
-    set, the replicated-X sweep under SLU_TRISOLVE=legacy."""
+    row-partitioned merged sweep, with no variable set and under
+    SLU_TRISOLVE=legacy alike: that variable selects the one-device
+    sweep and does not reach a mesh."""
     nrhs = 8 if arm == "rhs_sharded" else 2
     monkeypatch.delenv("SLU_TRISOLVE", raising=False)
-    if arm == "replicated":
-        monkeypatch.setenv("SLU_TRISOLVE", "legacy")
+    if env:
+        monkeypatch.setenv("SLU_TRISOLVE", env)
     case.plan._dist_solve_fns = {}
     rng = np.random.default_rng(6)
     xtrue, b = _system(case.A, rng, nrhs=nrhs)
@@ -336,8 +338,7 @@ def test_every_solve_arm_takes_planes(case, arm, monkeypatch):
     assert _relerr(x, xtrue) <= ERR_MAX
     built = {k[4:] for k in case.plan._dist_solve_fns}
     assert built == {{"rhs_sharded": (True, False, True),
-                      "merged": (False, True, True),
-                      "replicated": (False, False, True)}[arm]}
+                      "merged": (False, True, True)}[arm]}
 
 
 def test_gssvx_on_the_grid(grid, force_coop):
@@ -394,9 +395,15 @@ def _lowered(case, grid, debug=False):
     ftxt = factor.jitted.lower(
         jnp.zeros((nd, 2, lsel), jnp.float32)).as_text(debug_info=debug)
     flats = (d.L_flat, d.U_flat, d.Li_flat, d.Ui_flat)
-    stxt = [factor_dist._solve_fn(d, trans, arm).lower(
-        *flats, jnp.zeros((case.a.n, 2), jnp.float32)).as_text()
-        for arm in ("merged", "replicated") for trans in (False, True)]
+    # the handle's narrow sweep, and the replicated-X sweep the fused
+    # mesh step shares (`make_dist_solve`)
+    b = jnp.zeros((case.a.n, 2), jnp.float32)
+    stxt = [fn.lower(*flats, b).as_text()
+            for trans in (False, True)
+            for fn in (factor_dist._solve_fn(d, trans, "merged"),
+                       factor_dist.make_dist_solve(
+                           case.plan, grid.mesh, dtype=d.dtype,
+                           axis=d.axis, trans=trans, pair=True))]
     return ftxt, stxt
 
 

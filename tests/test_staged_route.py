@@ -251,22 +251,17 @@ def test_a_staged_solve_finds_its_packs(monkeypatch):
 
 
 def test_a_staged_handle_is_labeled_by_what_it_dispatches(monkeypatch):
-    """`active_arm`: the packed program routes the Pallas lsum member
-    for a staged handle as for any other, so the label follows the
-    handle's dtype, not its form."""
+    """`active_arm`: a staged handle's FACTORED solve dispatches the
+    packed program of the arm like any other handle, so the label is
+    the arm's, whatever the handle's form."""
     monkeypatch.setenv("SLU_STAGED", "1")
     monkeypatch.setenv("SLU_TRISOLVE", "merged")
     a, opts, _ = _staged_system("float32")
     d = factorize(a, opts, backend="jax").device_lu
     assert isinstance(d, batched.StagedLU)
-    assert trisolve.active_arm(d) == "merged"
-    monkeypatch.setenv("SLU_TRISOLVE_PALLAS", "1")
-    assert trisolve.active_arm(d) == "merged+pallas"
-    d64 = factorize(a, Options(), backend="jax").device_lu
-    assert isinstance(d64, batched.StagedLU)
-    assert trisolve.active_arm(d64) == "merged"
+    assert trisolve.active_arm() == "merged"
     monkeypatch.setenv("SLU_TRISOLVE", "legacy")
-    assert trisolve.active_arm(d) == "legacy"
+    assert trisolve.active_arm() == "legacy"
 
 
 def test_the_host_oracle_has_no_route():

@@ -158,43 +158,87 @@ def test_merged_fused_solver(monkeypatch):
     assert relerr < 1e-9
 
 
-def test_merged_complex_native_parity(monkeypatch):
-    """Native complex storage (real-view sweep codec) at c128: each
-    arm at the eps class, merged within 4·eps·max|x| of legacy."""
+def _complex_lu(storage, monkeypatch):
+    """helmholtz_2d(6) at c128, native or in pair planes."""
+    if storage == "pair":
+        monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
     a = helmholtz_2d(6)
-    lu = factorize(a, Options(factor_dtype="complex128"),
-                   backend="jax")
-    rng = np.random.default_rng(2)
-    b = (rng.standard_normal((a.n, 2))
-         + 1j * rng.standard_normal((a.n, 2)))
-    for trans in (False, True):
-        _assert_arms_agree(monkeypatch, lu, b, trans,
-                           f"trans={trans}")
+    lu = factorize(a, Options(factor_dtype="complex128"), backend="jax")
+    assert batched._lu_is_pair(lu.device_lu) == (storage == "pair")
+    return a, lu
 
 
-def test_merged_pair_storage_parity(monkeypatch):
-    """Pair-plane complex storage (SLU_COMPLEX_PAIR=1): the merged
-    sweep consumes (Ar, Ai) packed panels and stays within
-    4·eps·max|x| of the legacy pair sweep, each at the eps class — and
-    its packed program stays complex-free."""
-    monkeypatch.setenv("SLU_COMPLEX_PAIR", "1")
-    a = helmholtz_2d(6)
-    lu = factorize(a, Options(factor_dtype="complex128"),
-                   backend="jax")
+# What XLA:CPU of jaxlib 0.9.0 does to the LEGACY sweep's prologue at
+# one width (ROADMAP D10 (1)): appending the dummy row to an (n, 4)
+# float64 array, n a multiple of 4 from 8 up, leaves the row unwritten
+# and writes past the buffer (`.at[:n].set`, `concatenate` and `pad`
+# alike; widths 2, 3, 5, 8 and 12, float32 at any width, and n = 33,
+# 34, 35 are sound).  Two complex columns are four real ones in the
+# real-view codec, and helmholtz_2d(6) has n = 36.  No package import:
+_XLA_CPU_PAD_REPRO = r"""
+import numpy as np, jax, jax.numpy as jnp
+jax.config.update("jax_enable_x64", True)
+n = 40
+b = np.arange(n * 4, dtype=np.float64).reshape(n, 4) + 1.0
+pad = jax.jit(lambda b: jnp.zeros((n + 1, 4), b.dtype).at[:n].set(b))
+for _ in range(10):
+    x = np.asarray(pad(b))
+    assert (x[:n] == b).all() and (x[n] == 0).all(), x[n - 1:]
+"""
+_XLA_CPU_PAD = ("XLA:CPU (jax/jaxlib 0.9.0) pads an (n, 4) float64 array "
+                "by a row out of bounds when n % 4 == 0: the legacy "
+                "sweep's X at two complex columns, see "
+                "test_xla_cpu_pads_four_f64_columns_by_a_row")
+
+
+@pytest.mark.xfail(reason=_XLA_CPU_PAD, strict=False)
+def test_xla_cpu_pads_four_f64_columns_by_a_row():
+    """The reproducer, in a process of its own: the fault corrupts
+    the heap of the process that runs it (`malloc(): invalid size`,
+    `free(): corrupted unsorted chunks` at exit where the assertion
+    did not fire first).  Passes once a jaxlib repairs it: then the
+    nrhs = 2 cases below run again."""
+    import os
+    import subprocess
+    import sys
+    p = subprocess.run([sys.executable, "-c", _XLA_CPU_PAD_REPRO],
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-600:]
+
+
+@pytest.mark.parametrize("nrhs", [
+    1, 3,
+    # not run: a red case here is the fault above, and running it
+    # leaves the worker's heap corrupted for the tests after it
+    pytest.param(2, marks=pytest.mark.xfail(run=False,
+                                            reason=_XLA_CPU_PAD))])
+@pytest.mark.parametrize("trans", [False, True])
+@pytest.mark.parametrize("storage", ["native", "pair"])
+def test_merged_complex_parity(monkeypatch, storage, trans, nrhs):
+    """Complex at c128, native storage (real-view sweep codec) and
+    pair planes (SLU_COMPLEX_PAIR=1: the merged sweep consumes
+    (Ar, Ai) packed panels): each arm at the eps class, merged within
+    4·eps·max|x| of legacy, a width a case."""
+    a, lu = _complex_lu(storage, monkeypatch)
+    rng = np.random.default_rng(2 if storage == "native" else 3)
+    b = (rng.standard_normal((a.n, nrhs))
+         + 1j * rng.standard_normal((a.n, nrhs)))
+    _assert_arms_agree(monkeypatch, lu, b, trans,
+                       f"{storage} trans={trans} nrhs={nrhs}")
+
+
+def test_packed_pair_program_is_complex_free(monkeypatch):
+    """The packed merged program of a pair-stored handle holds no
+    complex type (the pair lane's certification property, test_pair
+    precedent)."""
+    a, lu = _complex_lu("pair", monkeypatch)
     d = lu.device_lu
-    assert batched._lu_is_pair(d)
-    rng = np.random.default_rng(3)
-    b = (rng.standard_normal((a.n, 2))
-         + 1j * rng.standard_normal((a.n, 2)))
-    for trans in (False, True):
-        _assert_arms_agree(monkeypatch, lu, b, trans,
-                           f"trans={trans}")
-    # complex-free pin on the packed merged program (the pair lane's
-    # certification property, test_pair precedent)
     monkeypatch.setenv("SLU_TRISOLVE", "merged")
     fn = trisolve._solve_packed_fn(d.schedule, d.dtype, True)[0]
     packs = trisolve.get_packs(d)
-    benc = batched._pair_encode_rhs(b.astype(np.complex128))
+    b = np.ones((a.n, 2), np.complex128)
+    benc = batched._pair_encode_rhs(b)
     txt = fn.lower(packs, jnp.asarray(benc)).as_text()
     assert "c128" not in txt and "c64" not in txt
 
@@ -336,11 +380,11 @@ def _rhs(n, nrhs, cplx, seed=5):
 
 
 def test_mesh_merged_dist_solve_routing(monkeypatch):
-    """A mesh sweep is chosen by the trisolve arm, as a one-device
-    sweep is: through `dist_solve` itself on a 2-device mesh, `auto`
-    and `merged` build the row-partitioned merged program,
-    SLU_TRISOLVE=legacy the replicated-X psum sweep, and
-    nrhs >= 2·ndev the rhs-sharded one whatever the arm."""
+    """A mesh sweep is chosen by its width alone: through `dist_solve`
+    itself on a 2-device mesh, a narrow rhs builds the
+    row-partitioned merged program whatever SLU_TRISOLVE says (it
+    selects the one-device sweep only), and nrhs >= 2·ndev the
+    rhs-sharded one."""
     from superlu_dist_tpu.parallel import factor_dist
     a, plan, mesh, dlu = _mesh2_dlu("real", monkeypatch)
     b1, b4 = _rhs(a.n, 1, False), _rhs(a.n, 4, False)
@@ -350,8 +394,8 @@ def test_mesh_merged_dist_solve_routing(monkeypatch):
         # (…, trans, rhs_sharded, merged, pair)
         return {k[4:6] for k in plan._dist_solve_fns}
 
-    for arm, want in ((None, (False, True)), ("merged", (False, True)),
-                      ("legacy", (False, False))):
+    want = (False, True)
+    for arm in (None, "merged", "legacy"):
         plan._dist_solve_fns = {}
         if arm is None:
             monkeypatch.delenv("SLU_TRISOLVE", raising=False)
@@ -359,13 +403,8 @@ def test_mesh_merged_dist_solve_routing(monkeypatch):
             monkeypatch.setenv("SLU_TRISOLVE", arm)
         x = np.asarray(factor_dist.dist_solve(dlu, b1))
         assert built() == {want}, (arm, built())
-        assert factor_dist.solve_arm(dlu, 1) == (
-            "merged" if want[1] else "replicated")
-        if want[1]:
-            _assert_ulp_close(x, ref, str(arm))
-        else:   # another order of the same sums, in float32
-            np.testing.assert_allclose(
-                x, ref, rtol=0, atol=2e-4 * np.abs(ref).max())
+        assert factor_dist.solve_arm(dlu, 1) == "merged"
+        _assert_ulp_close(x, ref, str(arm))
         factor_dist.dist_solve(dlu, b4)     # 4 = 2·ndev columns
         assert built() == {want, (True, False)}, (arm, built())
         assert factor_dist.solve_arm(dlu, 4) == "rhs_sharded"
@@ -439,41 +478,6 @@ def test_mesh_merged_allreduces_what_was_written(monkeypatch, storage):
     whole = (sum(r is not None for r in fwd) * (ts.u_total + 1)
              + (sum(r is not None for r in bwd) + 1) * (ts.y_total + 1))
     assert words < whole
-
-
-def test_pallas_lsum_oracle():
-    """The fused Pallas lsum kernel (interpret mode on CPU) matches
-    the einsum pair it replaces."""
-    from superlu_dist_tpu.ops import pallas_lsum
-    rng = np.random.default_rng(6)
-    t, wb, rb, R = 5, 16, 40, 3
-    Li = rng.standard_normal((t, wb, wb)).astype(np.float32)
-    L21 = rng.standard_normal((t, rb, wb)).astype(np.float32)
-    xb = rng.standard_normal((t, wb, R)).astype(np.float32)
-    y, upd = pallas_lsum.lsum_panel(
-        jnp.asarray(Li), jnp.asarray(L21), jnp.asarray(xb),
-        interpret=True)
-    yr, ur = pallas_lsum._oracle()(jnp.asarray(Li),
-                                   jnp.asarray(L21),
-                                   jnp.asarray(xb))
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(upd), np.asarray(ur),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_pallas_lsum_merged_solve(monkeypatch):
-    """SLU_TRISOLVE_PALLAS=1 routes merged forward members through
-    the kernel (interpret on CPU) and still solves to the oracle."""
-    monkeypatch.setenv("SLU_TRISOLVE", "merged")
-    monkeypatch.setenv("SLU_TRISOLVE_PALLAS", "1")
-    a = laplacian_3d(6)
-    xtrue, b = manufactured_rhs(a)
-    lu = factorize(a, Options(factor_dtype="float32"),
-                   backend="jax")
-    x = solve(lu, b)
-    np.testing.assert_allclose(x, xtrue, rtol=1e-4, atol=1e-4)
-    assert trisolve.active_arm() == "merged+pallas"
 
 
 def test_dead_lane_trim_single_device():
