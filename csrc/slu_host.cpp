@@ -1096,6 +1096,106 @@ void slu_symbfact_free(void* handle) {
   delete static_cast<SymbHandle*>(handle);
 }
 
+// ---------------------------------------------------- batch residual
+// r_m = b_m - A_m x_m and the componentwise backward error
+// max_i |r_i| / (|A||x| + |b|)_i of B systems on ONE CSR pattern, in
+// one pass over the values (batch/engine.batch_solve's refinement:
+// the residual of every member a pass, in the refine dtype, where a
+// TPU's float64 is two float32 words and cannot hold the guarantee).
+// `vals` is (B, nnz) with entry k of the pattern at src[k] (src null:
+// at k); x, b, r are (B, n, nrhs).  A row sums in the pattern's order
+// from zero, as scipy's csr_matvec does, so r is bitwise the
+// block-diagonal scipy product's (models/refine.py keeps that twin).
+// A member with a NaN anywhere reads berr NaN.  Members go to
+// std::thread workers in chunks: they share nothing.
+}  // extern "C"
+
+template <typename T>
+static void batch_residual_range(
+    int64_t m0, int64_t m1, int64_t n, int64_t nrhs, int64_t nnz,
+    const int64_t* indptr, const int64_t* indices, const int64_t* src,
+    const T* vals, const T* x, const T* b, T* r, T* berr) {
+  for (int64_t m = m0; m < m1; ++m) {
+    const T* v = vals + m * nnz;
+    const T* xm = x + m * n * nrhs;
+    const T* bm = b + m * n * nrhs;
+    T* rm = r + m * n * nrhs;
+    T worst = 0;
+    bool nan = false;
+    for (int64_t i = 0; i < n; ++i) {
+      for (int64_t c = 0; c < nrhs; ++c) {
+        T s = 0, a = 0;
+        for (int64_t k = indptr[i]; k < indptr[i + 1]; ++k) {
+          const T av = v[src ? src[k] : k];
+          const T xv = xm[indices[k] * nrhs + c];
+          s += av * xv;
+          a += std::fabs(av) * std::fabs(xv);
+        }
+        const T bi = bm[i * nrhs + c];
+        const T ri = bi - s;
+        rm[i * nrhs + c] = ri;
+        T den = a + std::fabs(bi);
+        if (den == 0) den = 1;
+        const T q = std::fabs(ri) / den;
+        if (q != q) nan = true;
+        else if (q > worst) worst = q;
+      }
+    }
+    berr[m] = nan ? std::numeric_limits<T>::quiet_NaN() : worst;
+  }
+}
+
+template <typename T>
+static void batch_residual(
+    int64_t B, int64_t n, int64_t nrhs, int64_t nnz,
+    const int64_t* indptr, const int64_t* indices, const int64_t* src,
+    const T* vals, const T* x, const T* b, T* r, T* berr,
+    int64_t threads) {
+  if (threads <= 0) {
+    unsigned hc = std::thread::hardware_concurrency();
+    threads = std::min<int64_t>(hc ? hc : 1, 8);
+  }
+  // members are handed out in chunks from one counter, not split up
+  // front: on a host whose cores are shared a thread that is held up
+  // leaves its share to the others
+  const int64_t chunk = 16;
+  threads = std::max<int64_t>(
+      1, std::min(threads, (B + chunk - 1) / chunk));
+  std::atomic<int64_t> next{0};
+  auto work = [&]() {
+    for (;;) {
+      const int64_t m0 = next.fetch_add(chunk);
+      if (m0 >= B) break;
+      batch_residual_range<T>(m0, std::min(B, m0 + chunk), n, nrhs, nnz,
+                              indptr, indices, src, vals, x, b, r, berr);
+    }
+  };
+  std::vector<std::thread> pool;
+  for (int64_t t = 1; t < threads; ++t) pool.emplace_back(work);
+  work();
+  for (auto& th : pool) th.join();
+}
+
+extern "C" {
+
+void slu_batch_residual_f64(
+    int64_t B, int64_t n, int64_t nrhs, int64_t nnz,
+    const int64_t* indptr, const int64_t* indices, const int64_t* src,
+    const double* vals, const double* x, const double* b, double* r,
+    double* berr, int64_t threads) {
+  batch_residual<double>(B, n, nrhs, nnz, indptr, indices, src, vals, x,
+                         b, r, berr, threads);
+}
+
+void slu_batch_residual_f32(
+    int64_t B, int64_t n, int64_t nrhs, int64_t nnz,
+    const int64_t* indptr, const int64_t* indices, const int64_t* src,
+    const float* vals, const float* x, const float* b, float* r,
+    float* berr, int64_t threads) {
+  batch_residual<float>(B, n, nrhs, nnz, indptr, indices, src, vals, x,
+                        b, r, berr, threads);
+}
+
 // ------------------------------------------------------------- cpuid
 // Implementation shared with the tiny standalone helper
 // (csrc/slu_cpuid.cc) — see csrc/slu_cpuid.h for the rationale.
@@ -1103,6 +1203,6 @@ int64_t slu_cpuid_words(int64_t* out, int64_t nwords) {
   return slu_cpuid_words_impl(out, nwords);
 }
 
-int64_t slu_version() { return 6; }
+int64_t slu_version() { return 7; }
 
 }  // extern "C"

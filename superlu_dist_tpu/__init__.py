@@ -69,6 +69,11 @@ from .models.gssvx import (  # noqa: E402
     solve,
     warm_solve,
 )
+from .batch.engine import (  # noqa: E402
+    BatchedLU,
+    batch_factorize,
+    batch_solve,
+)
 from .parallel.grid import make_solver_mesh  # noqa: E402
 from .parallel.multihost import (  # noqa: E402
     csr_from_row_slices,
@@ -108,6 +113,9 @@ __all__ = [
     "plan_factorization_multihost",
     "scaled_values_local",
     "LUFactorization",
+    "BatchedLU",
+    "batch_factorize",
+    "batch_solve",
     "PrecisionPolicy",
     "ResidualMode",
     "GradResult",

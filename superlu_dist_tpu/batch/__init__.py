@@ -10,7 +10,10 @@ with a natural leading batch axis.  This package vmaps them —
     x    = engine.batch_solve(blu, b)                # b (B, n[, nrhs])
 
 — one schedule, one warmup, B value sets, with every member pinned
-bitwise equal to its per-sample execution (tests/test_batch.py).
+bitwise equal to its per-sample execution (tests/test_batch.py), and
+with `options=` every member refined to the one-system guarantee
+(tests/test_batch_refine.py; both entry points are at the package
+root too).
 `serving.py` holds the B-ladder/warmup discipline the serve-layer
 factor coalescer (serve/coalescer.py) dispatches through.
 """

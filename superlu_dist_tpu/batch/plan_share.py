@@ -52,7 +52,10 @@ def batch_scaled_values(plan: FactorPlan,
     two-step multiply order (row scale, THEN column scale) replays
     the per-sample expression exactly, so each row is bitwise equal
     to plan.scaled_values of that member (elementwise broadcasting
-    over a leading axis changes nothing per lane)."""
+    over a leading axis changes nothing per lane).  The float64
+    ORACLE since PR 48: `batch_factorize` scales in its factor
+    program, in the factor dtype, and the tests hold that to this
+    (bitwise for float64 factors, 4 ulp for float32)."""
     values = np.asarray(values)
     if values.ndim != 2 or values.shape[1] != len(plan.coo_rows):
         raise ValueError(

@@ -116,7 +116,6 @@ FLAGS: dict[str, str] = {
     "SLU_SERVE_MESH": "1 = mesh-resident serving: ServeConfig.mesh defaults to a device mesh (SLU_MESH_SHAPE), the factor cache factors through the shard_map'd dist backend, and every request key carries an Options.mesh_shape leg.  Off (default) = single-device serving, one env read of overhead at ServeConfig construction",
     "SLU_MESH_SHAPE": "mesh grid for SLU_SERVE_MESH=1 ('2x2x2', '8'; default: all local devices on one flat axis) — resolved once per ServeConfig construction, zero per-request overhead",
     # --- batch engine (batch/, serve/coalescer.py) ---
-    "SLU_BATCH_SOLVE_MODE": "batched-trisolve program arm (batch/engine.py): 'scan' (default) loops members inside ONE jit via lax.scan, keeping every lane's ops at exact per-sample shapes — the bitwise pin; 'vmap' is the dense batched arm for accelerators (XLA:CPU's batch-collapsed dot kernels reassociate reductions on trim==1 groups, drifting 1-2 ulp, so 'vmap' trades the bitwise pin for batched-kernel throughput).  One env read per cached program build, zero per-dispatch overhead",
     "SLU_BATCH_LADDER": "batch-size bucket ladder for the batch engine and factor coalescer, comma ints ascending (default '1,4,8,16,32'); sizes quantize UP a rung (short batches pad by replicating a live member), so after warmup the compiled-program population is bounded by the rung count — the zero-recompile contract.  Read once per warmup/coalescer construction",
     "SLU_BATCH_COALESCE": "1 = serve-layer factor coalescing (serve/coalescer.py): same-pattern cold factor requests arriving within the coalesce window merge into one batch_factorize dispatch up the B-ladder, results fanned back into ordinary per-key cache residents; off (default) = every cold key factors solo (zero overhead: the serve path checks this once per SolveService construction)",
     "SLU_BATCH_WINDOW_MS": "factor-coalescer max linger (ms, default 2): how long the first cold request of a pattern waits for same-pattern siblings before the flusher dispatches the batch — the factor-side twin of SLU_SERVE_LINGER_MS; latency cost is bounded by the window, throughput gain by the rung reached",

@@ -178,3 +178,68 @@ def iterative_refine(lu, b, x, solve_factored, to_factor_rhs,
     # (precision/policy.classify_trigger), and "the loop quit because
     # berr stopped halving" is that signal's ground truth
     return xk, berr, steps, stalled
+
+
+class BatchResidual:
+    """r_m = b_m - A_m x_m and the componentwise backward error of B
+    systems on ONE pattern, on the host in the refine dtype: the
+    residual of `batch/engine.batch_solve`'s loop.  It is the host's
+    because a TPU's float64 is two float32 words (some 2^-47: the
+    compiler's own lowering), which stands at the guarantee's edge,
+    where this arithmetic is native.  One pass over the values in the
+    native library, members over threads
+    (`utils/native.batch_residual`); without the library the twin,
+    two block-diagonal scipy products (`twin`: the oracle the tests
+    hold the kernel to, bitwise in r).
+
+    Built once a (plan, trans): the pattern's CSR form over the
+    plan's COO order (`src` maps a sorted entry to its place in a
+    member's value array; None where the order is the array's)."""
+
+    def __init__(self, plan, trans: bool = False):
+        rows, cols = ((plan.coo_cols, plan.coo_rows) if trans
+                      else (plan.coo_rows, plan.coo_cols))
+        order = np.lexsort((cols, rows)).astype(np.int64)
+        self.n = int(plan.n)
+        self.indptr = np.searchsorted(
+            rows[order], np.arange(self.n + 1)).astype(np.int64)
+        self.indices = np.ascontiguousarray(cols[order], np.int64)
+        self.src = (None if np.array_equal(order,
+                                           np.arange(len(order)))
+                    else order)
+        self._block = {}        # members -> block-diagonal pattern
+
+    def __call__(self, vals, x, b, out=None):
+        """vals (B, nnz), x and b (B, n, nrhs), one dtype: (r, berr);
+        r in `out` where the caller keeps a buffer for it."""
+        from ..utils import native
+        with obs.span("refine.residual", cat="refine"):
+            if native.available():
+                return native.batch_residual(
+                    self.indptr, self.indices, self.src, vals, x, b,
+                    out=out)
+            r, berr = self.twin(vals, x, b)
+            if out is not None:
+                out[...] = r
+                r = out
+            return r, berr
+
+    def twin(self, vals, x, b):
+        import scipy.sparse as sp
+        B, n, nrhs = x.shape
+        nnz = len(self.indices)
+        if B not in self._block:
+            self._block = {B: (
+                np.concatenate([[0], (self.indptr[1:][None, :] + nnz
+                                      * np.arange(B)[:, None]).ravel()]),
+                (self.indices[None, :]
+                 + n * np.arange(B)[:, None]).ravel())}
+        indptr, indices = self._block[B]
+        data = (vals if self.src is None else vals[:, self.src]).ravel()
+        a = sp.csr_matrix((data, indices, indptr), shape=(B * n, B * n))
+        xf, bf = x.reshape(B * n, nrhs), b.reshape(B * n, nrhs)
+        r = bf - a @ xf
+        denom = abs(a) @ np.abs(xf) + np.abs(bf)
+        denom[denom == 0.0] = 1.0
+        q = (np.abs(r) / denom).reshape(B, n * nrhs)
+        return r.reshape(x.shape), np.max(q, axis=1)

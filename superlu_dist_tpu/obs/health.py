@@ -159,7 +159,9 @@ class HealthMonitor:
                       complex_lowering: str | None = None,
                       sweep_segments: int | None = None,
                       sweep_arm: str | None = None,
-                      sweep_syncs: int | None = None) -> None:
+                      sweep_syncs: int | None = None,
+                      members: int | None = None,
+                      members_stalled: int | None = None) -> None:
         """One refinement loop's outcome.  `ferr_trajectory` is the
         per-step forward-error estimate ‖δ‖/‖x‖ (the correction-norm
         proxy for pdgsrfs' FERR output).  `sweeps` counts the solve's
@@ -172,7 +174,12 @@ class HealthMonitor:
         sweep; 1 on a mesh; None on the host oracle).  On a mesh
         `sweep_arm` names that program (`merged` or `rhs_sharded`:
         parallel/factor_dist.solve_arm) and
-        `sweep_syncs` counts its all-reduces.  `stalled` means the loop
+        `sweep_syncs` counts its all-reduces.  A batched solve
+        (batch/engine.batch_solve) leaves ONE record: `berr` and
+        `steps` are the largest of its `members`, `members_stalled`
+        counts those that stalled (the two keys are absent from a
+        one-system record), and `sweep_arm` is the batched sweep's
+        (`vmap`, member-parallel, or `scan`).  `stalled` means the loop
         quit because berr stopped halving — NOT that it merely ran
         out of step budget while still improving; only the former
         raises the alarm event."""
@@ -182,7 +189,7 @@ class HealthMonitor:
             self.last_berr = float(berr)
             if stalled:
                 self.stalled_refines += 1
-            self._recent.append({
+            rec = {
                 "berr": float(berr), "steps": int(steps),
                 "berr_trajectory": [float(b) for b in berr_trajectory],
                 "ferr_trajectory": [float(f) for f in ferr_trajectory],
@@ -193,7 +200,11 @@ class HealthMonitor:
                 "sweep_segments": sweep_segments,
                 "sweep_arm": sweep_arm,
                 "sweep_syncs": sweep_syncs,
-            })
+            }
+            if members is not None:
+                rec.update(members=int(members),
+                           members_stalled=int(members_stalled or 0))
+            self._recent.append(rec)
         if stalled:
             _tracer.instant("health.refine_stalled", cat="health",
                             args={"berr": float(berr),

@@ -28,6 +28,7 @@ from .. import flags
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _F64 = ctypes.POINTER(ctypes.c_double)
+_F32 = ctypes.POINTER(ctypes.c_float)
 
 _lock = threading.Lock()
 _lib = None
@@ -165,8 +166,13 @@ def _load():
             lib.slu_supernodes.restype = ctypes.c_int64
             lib.slu_cpuid_words.argtypes = [_I64, ctypes.c_int64]
             lib.slu_cpuid_words.restype = ctypes.c_int64
+            for name, fp in (("slu_batch_residual_f64", _F64),
+                             ("slu_batch_residual_f32", _F32)):
+                getattr(lib, name).argtypes = (
+                    [ctypes.c_int64] * 4 + [_I64, _I64, _I64]
+                    + [fp] * 5 + [ctypes.c_int64])
             lib.slu_version.restype = ctypes.c_int64
-            assert lib.slu_version() == 6
+            assert lib.slu_version() == 7
             _lib = lib
         except (OSError, AssertionError, AttributeError) as e:
             _failed = True
@@ -317,6 +323,36 @@ def cpuid_words_fast() -> np.ndarray:
         return _read_cpuid(ctypes.CDLL(out))
     except (OSError, AttributeError):
         return np.zeros(0, dtype=np.int64)
+
+
+def batch_residual(indptr: np.ndarray, indices: np.ndarray, src,
+                   vals: np.ndarray, x: np.ndarray, b: np.ndarray,
+                   threads: int = 0, out: np.ndarray | None = None):
+    """r_m = b_m - A_m x_m and the componentwise backward error of B
+    systems on one CSR pattern, one pass over the values, members
+    over threads (0: hardware concurrency, at most 8).  `vals` is
+    (B, nnz) with the pattern's entry k at `src[k]` (None: at k); x
+    and b are (B, n, nrhs); all three share one dtype, float64 or
+    float32.  Returns (r, berr (B,)); r is bitwise the block-diagonal
+    scipy product's (models/refine.batch_residual_twin, the oracle),
+    written into `out` where one is given (x's shape and dtype,
+    contiguous: a caller's kept buffer costs no fresh pages)."""
+    lib = _load()
+    dt = np.dtype(vals.dtype)
+    fn, fp = {"float64": (lib.slu_batch_residual_f64, _F64),
+              "float32": (lib.slu_batch_residual_f32, _F32)}[dt.name]
+    vals, x, b = (np.ascontiguousarray(a, dtype=dt)
+                  for a in (vals, x, b))
+    B, n, nrhs = x.shape
+    a_pp, pp = _c64(indptr)
+    a_pi, pi = _c64(indices)
+    a_ps, ps = _c64(src) if src is not None else (None, None)
+    r = np.empty_like(x) if out is None else out
+    assert r.shape == x.shape and r.dtype == dt and r.flags.c_contiguous
+    berr = np.empty(B, dtype=dt)
+    fn(B, n, nrhs, vals.shape[1], pp, pi, ps,
+       *(a.ctypes.data_as(fp) for a in (vals, x, b, r, berr)), threads)
+    return r, berr
 
 
 def hwpm(n: int, colptr: np.ndarray, rowind: np.ndarray,

@@ -170,6 +170,16 @@ class Stats:
     # 1 and `sweep_syncs`, the all-reduces a sweep.  The health
     # ring's factor and solve records carry the same keys
     dispatch: Dict[str, object] = dataclasses.field(default_factory=dict)
+    # the LAST batched solve's outcome member by member
+    # (batch/engine.batch_solve): `berr`, `refine_steps` and `stalled`
+    # as (B,) arrays, and `missed`, the indices of the members with a
+    # zero pivot or a berr outside the contract's 64 eps; `passes`,
+    # a (members live, members swept) a refinement pass: all of them
+    # while more than the straggler rung are live, the rung from
+    # there; `berr`, `refine_steps` and `refine_stalled` above hold
+    # the worst member's.  `dispatch` gains `batch_members`, `batch_sweep_arm`
+    # (vmap | scan) and `batch_residual` (host, or None unrefined)
+    batch: Dict[str, object] = dataclasses.field(default_factory=dict)
 
     @contextlib.contextmanager
     def timer(self, phase: str):
@@ -289,6 +299,14 @@ class Stats:
                 f"  mesh sweep:           {d['sweep_arm']}, "
                 f"{d['sweep_segments']} program a sweep, "
                 f"{d['sweep_syncs']} all-reduces")
+        if "batch_members" in self.dispatch:
+            d = self.dispatch
+            line = (f"  batched solve:        {d['batch_members']} "
+                    f"members, {d['batch_sweep_arm']} sweep")
+            if self.batch:
+                line += (f", residual on the {d['batch_residual']}, "
+                         f"{len(self.batch['missed'])} missed")
+            lines.append(line)
         if self.rcond is not None:
             lines.append(f"  estimated rcond:      {self.rcond:.2e}")
         if self.placement:
